@@ -63,23 +63,6 @@ def cluster_spec_from_env() -> Optional[ClusterSpec]:
 _disarmed = False
 
 
-def _distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for jax
-    versions that predate it (<= 0.4.x): those expose the same fact via
-    the distributed global state's client handle."""
-    import jax
-
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:  # noqa: BLE001 — private module moved/renamed
-        return False
-
-
 def maybe_initialize() -> Optional[ClusterSpec]:
     """Initialize ``jax.distributed`` when a cluster env is present.
 
@@ -98,14 +81,14 @@ def maybe_initialize() -> Optional[ClusterSpec]:
     if spec is None:
         # The user may have initialized jax.distributed directly; honor it.
         # (is_initialized() does not touch the XLA backend.)
-        if _distributed_is_initialized() and jax.process_count() > 1:
+        if jax.distributed.is_initialized() and jax.process_count() > 1:
             return ClusterSpec(
                 coordinator=os.environ.get("JAX_COORDINATOR_ADDRESS", ""),
                 num_processes=jax.process_count(),
                 process_id=jax.process_index())
         return None
-    if spec.num_processes > 1 and not _distributed_is_initialized():
-        kwargs = dict(
+    if spec.num_processes > 1 and not jax.distributed.is_initialized():
+        jax.distributed.initialize(
             coordinator_address=spec.coordinator,
             num_processes=spec.num_processes,
             process_id=spec.process_id,
@@ -113,13 +96,6 @@ def maybe_initialize() -> Optional[ClusterSpec]:
                 os.environ.get("HVD_TPU_HEARTBEAT_TIMEOUT", "100")),
             shutdown_timeout_seconds=int(
                 os.environ.get("HVD_TPU_SHUTDOWN_TIMEOUT", "300")))
-        try:
-            jax.distributed.initialize(**kwargs)
-        except TypeError:
-            # Older jax without the timeout kwargs.
-            kwargs.pop("heartbeat_timeout_seconds")
-            kwargs.pop("shutdown_timeout_seconds")
-            jax.distributed.initialize(**kwargs)
     return spec
 
 
@@ -155,5 +131,5 @@ def disarm_distributed_shutdown() -> None:
             state.preemption_sync_manager.shutdown()
             state.preemption_sync_manager = None
         state.client = None  # leaked deliberately; the process is exiting
-    except Exception:  # noqa: BLE001 — best-effort across jax versions
+    except Exception:  # noqa: BLE001 — the process is exiting either way
         pass

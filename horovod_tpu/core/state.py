@@ -381,15 +381,15 @@ def init(devices=None) -> None:
             name="horovod_tpu-tick", daemon=True)
         _state.bg_thread.start()
 
-    # Persistent compile cache (hvd-pipeline; OUTSIDE the state lock —
-    # warm_start compiles and touches the filesystem): point jax's XLA
-    # compilation cache at HVD_TPU_COMPILE_CACHE_DIR and AOT-rebuild the
-    # megakernel executables the previous incarnation recorded there, so
-    # an elastic relaunch (or any repeat run) skips the cold-compile
-    # stall on its first training steps.
-    cache_dir = os.environ.get("HVD_TPU_COMPILE_CACHE_DIR")
-    if cache_dir:
-        _configure_compile_cache(cache_dir)
+    # Persistent compile cache (OUTSIDE the state lock — warm_start
+    # compiles and touches the filesystem): place jax's XLA compilation
+    # cache by the one rule of :func:`compile_cache_dir` and AOT-rebuild
+    # the megakernel executables the previous incarnation recorded
+    # there, so an elastic relaunch (or any repeat run) skips the
+    # cold-compile stall on its first training steps.
+    cache_dir = configure_compile_cache()
+    if cache_dir and not _state.multiprocess:
+        # The manifest holds single-process group variants only.
         from ..ops import megakernel as _megakernel
 
         _megakernel.warm_start(_state.mesh, cache_dir)
@@ -421,20 +421,40 @@ def init(devices=None) -> None:
         pass
 
 
-def _configure_compile_cache(directory: str) -> None:
-    """Point jax's persistent XLA compilation cache at ``directory``
-    (idempotent; thresholds dropped to zero so even small steady-state
-    executables — the megakernels — persist).  Unknown options on older
-    jax are skipped: the cache is an optimization, never a hard dep."""
-    os.makedirs(directory, exist_ok=True)
-    for option, value in (
-            ("jax_compilation_cache_dir", directory),
-            ("jax_persistent_cache_min_compile_time_secs", 0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(option, value)
-        except (AttributeError, ValueError):  # pragma: no cover - old jax
-            pass
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def compile_cache_dir() -> Optional[str]:
+    """Where the persistent XLA compilation cache — and the warm-start
+    manifest that rides it — lives.  One rule for ``hvd.init()``,
+    ``bench.py`` and ``chip_smoke.py``: a ``JAX_COMPILATION_CACHE_DIR``
+    placed from outside wins; otherwise ``<checkout>/.jax_cache``, a
+    fixed path (a directory that moves never hits).  A CPU backend gets
+    a cache only when one is placed from outside: XLA:CPU compiles in
+    seconds, and jaxlib 0.9.0's CPU loader logs a machine-feature
+    error of several kilobytes on every hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() == "cpu":
+        return None
+    return os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def configure_compile_cache() -> Optional[str]:
+    """Apply :func:`compile_cache_dir` (idempotent) and return it.  With
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment jax has already
+    read it and no directory is set here.  Thresholds drop to zero so
+    even small steady-state executables — the megakernels — persist."""
+    directory = compile_cache_dir()
+    if directory is None:
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return directory
 
 
 def shutdown() -> None:

@@ -37,8 +37,6 @@ from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-from . import compat as _compat
 import numpy as np
 
 # Canonical axis names.  ``REPLICA_AXIS`` ("hvd") from core.state is the
@@ -341,7 +339,7 @@ def replica_hierarchy(devices: Sequence) -> Optional[ReplicaHierarchy]:
 
 def axis_size(axis: str) -> int:
     """Extent of ``axis`` inside traced code (static under shard_map)."""
-    return _compat.axis_size(axis)
+    return jax.lax.axis_size(axis)
 
 
 def axis_index(axis: str):

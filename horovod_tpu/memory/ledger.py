@@ -230,12 +230,16 @@ def enabled() -> bool:
 
 def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
     """``device.memory_stats()`` where the backend provides it (TPU/GPU
-    do; CPU returns None).  Never raises — this feeds gauges and dumps."""
+    do; CPU returns None).  Without a ``device`` it is the fullest local
+    device's — headroom is decided by the chip closest to its limit,
+    which on a four-chip host need not be the first.  Never raises —
+    this feeds gauges and dumps."""
     try:
         import jax
 
-        dev = device if device is not None else jax.local_devices()[0]
-        stats = dev.memory_stats()
+        devices = [device] if device is not None else jax.local_devices()
+        stats = max(filter(None, (d.memory_stats() for d in devices)),
+                    key=lambda st: st.get("bytes_in_use", 0), default=None)
         if not stats:
             return None
         return {k: int(v) for k, v in stats.items()

@@ -227,13 +227,13 @@ _ANALYSIS_SUFFIX = "_in_bytes"
 def record_compiled(name: str, compiled) -> Optional[Dict[str, int]]:
     """Harvest ``compiled.memory_analysis()`` into the process-global
     table, keyed by executable name.  Returns the harvested dict, or
-    None when the backend does not implement the query (XLA:CPU) — the
+    None when the backend does not implement the query — the
     plan's ``compiled`` section then reports coverage honestly instead
     of zeros.  Never raises: harvesting is observability."""
     try:
         analysis = compiled.memory_analysis()
-    except Exception:  # noqa: BLE001 — Unimplemented on CPU, AttributeError
-        return None    # on old jax: the planner works without it
+    except Exception:  # noqa: BLE001 — a backend without the query:
+        return None    # the planner works without it
     if analysis is None:
         return None
     out: Dict[str, int] = {}

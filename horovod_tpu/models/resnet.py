@@ -107,7 +107,9 @@ def init_resnet(model: nn.Module, image_size: int = 224,
     """Initialize params + batch_stats."""
     rng = jax.random.PRNGKey(seed)
     dummy = jnp.zeros((batch_size, image_size, image_size, 3), jnp.float32)
-    variables = model.init(rng, dummy, train=False)
+    # One compiled program: un-jitted, flax's init dispatches (and
+    # compiles) every initializer and layer op by op.
+    variables = jax.jit(partial(model.init, train=False))(rng, dummy)
     return variables["params"], variables.get("batch_stats", {})
 
 

@@ -30,8 +30,6 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
-
-from ..core import compat as _compat
 import jax.numpy as jnp
 
 from ..core import topology as T
@@ -140,7 +138,7 @@ def _attention_block(x, lp, cfg: TransformerConfig, ax: ParallelAxes,
     h = _layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
 
     if ax.model is not None:
-        mp = _compat.axis_size(ax.model)
+        mp = jax.lax.axis_size(ax.model)
         if cfg.n_heads % mp != 0 or d % mp != 0:
             raise ValueError(
                 f"tensor-parallel degree {mp} must divide both "
@@ -256,7 +254,7 @@ def forward(params: dict, tokens, cfg: TransformerConfig,
     global_seq = s_loc
     if ax.seq is not None:
         seq_off = jax.lax.axis_index(ax.seq) * s_loc
-        global_seq = s_loc * _compat.axis_size(ax.seq)
+        global_seq = s_loc * jax.lax.axis_size(ax.seq)
     if global_seq > cfg.max_seq_len:
         raise ValueError(
             f"global sequence length {global_seq} exceeds "
@@ -268,7 +266,7 @@ def forward(params: dict, tokens, cfg: TransformerConfig,
     aux = jnp.zeros((), jnp.float32)
 
     if ax.pipe is not None:
-        n_stages = _compat.axis_size(ax.pipe)
+        n_stages = jax.lax.axis_size(ax.pipe)
         per_stage = cfg.n_layers // n_stages
         if per_stage * n_stages != cfg.n_layers:
             raise ValueError(
@@ -354,7 +352,7 @@ def make_loss_fn(cfg: TransformerConfig, ax: ParallelAxes = ParallelAxes(),
         def body(total, xt):
             return total + chunk_nll(*xt), None
 
-        total, _ = _compat.scan(body, jnp.zeros((), jnp.float32),
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
                                 (xs, ts))
         return total / (b * s_loc) + aux
 

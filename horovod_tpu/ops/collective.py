@@ -56,7 +56,6 @@ from ..analysis import lockorder as _lockorder
 from ..analysis import program as _program
 from ..analysis import threads as _athreads
 from .. import chaos as _chaos
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from . import compression as _compression
@@ -396,7 +395,7 @@ def _build_kernels(mesh):
         # check_vma=False where the output is replicated by construction
         # (all_gather / masked-psum broadcast) but the static checker cannot
         # infer it.
-        return jax.jit(_compat.shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
             check_vma=check_vma))
 
@@ -615,7 +614,7 @@ def _build_kernels(mesh):
                                          tiled=True),
             P(), P(), check_vma=False),
         # Per-replica [size, ...] + root -> replicated [...] = root's shard.
-        "bcast_pr": jax.jit(_compat.shard_map(
+        "bcast_pr": jax.jit(jax.shard_map(
             _bcast_block, mesh=mesh, in_specs=(P(REPLICA_AXIS), P()),
             out_specs=P(), check_vma=False)),
         # Reducescatter: per-replica [n, d0, ...] -> per-replica

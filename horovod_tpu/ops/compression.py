@@ -32,7 +32,7 @@ and EQuARX's in-XLA quantized allreduce, arXiv:2506.17615):
   difference between what a replica meant to send and what its peers
   decoded) is carried by the executor and added to the next step's
   contribution, so the error telescopes instead of accumulating
-  (SNIPPETS.md §EF-SGD lineage).  The residual store is real HBM — one
+  (the EF-SGD lineage).  The residual store is real HBM — one
   flat full-precision buffer per fusion group, held across steps by
   ``ops/megakernel.py`` for the fused AND eager-reference paths alike —
   and is accounted by the hvd-mem device-memory ledger as

@@ -56,8 +56,8 @@ Host-side, :class:`FusedProgram` wraps each fused group's executable
 with the repo's standard compiled-program services: AOT compile on
 first dispatch with ``compiled.memory_analysis()`` harvested into the
 memory planner, a manifest record (``variant: "fused"``) so a
-relaunched fleet warm-starts the same groups from
-``HVD_TPU_COMPILE_CACHE_DIR``, per-launch hvd-mem ledger charges via
+relaunched fleet warm-starts the same groups from the compile-cache
+directory, per-launch hvd-mem ledger charges via
 the planner's shared byte formula (:func:`..memory.planner.
 fused_group_bytes`), OOM-guarded dispatch, and the
 ``fused.groups_compiled`` / ``fused.launches`` /
@@ -268,8 +268,8 @@ def fused_manifest_entry(name: str, mesh, shapes: Sequence[Tuple[int, ...]],
     """The persistent-cache manifest record for one fused group
     (``variant: "fused"`` — same file, same dedup/bound/atomic-rename
     contract as the megakernel and serving entries, so one
-    ``HVD_TPU_COMPILE_CACHE_DIR`` warms a relaunched fleet's fused
-    groups too).  The chunk count is part of the record: it is part of
+    compile-cache directory warms a relaunched fleet's fused groups
+    too).  The chunk count is part of the record: it is part of
     the compiled program."""
     from . import megakernel as _mk
 

@@ -86,7 +86,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..analysis import donation as _donation
 from ..analysis import lockorder as _lockorder
 from ..analysis import program as _program
-from ..core import compat as _compat
+from ..core import state as _state
 from ..core import topology as _topology
 from ..core.state import REPLICA_AXIS
 from ..utils import xla_dispatch as _xla_dispatch
@@ -115,14 +115,13 @@ CACHE_CAPACITY = 128
 DCN_COMPRESS_ENV = "HVD_TPU_DCN_COMPRESS"
 ICI_COMPRESS_ENV = "HVD_TPU_ICI_COMPRESS"
 
-# Persistent compile cache (hvd-pipeline): when set, (a) jax's XLA
-# compilation cache persists to this directory (wired by core/state.init)
-# and (b) every cold megakernel build appends its group structure to
+# Persistent compile cache: jax's XLA compilation cache persists to the
+# directory core/state.compile_cache_dir() resolves, and every cold
+# megakernel build appends its group structure to
 # <dir>/megakernel_manifest.json, so an elastic relaunch — or any repeat
 # run — can AOT-rebuild the steady-state executables at init time
 # (:func:`warm_start`) and hit the disk cache instead of recompiling on
 # the first training step.
-COMPILE_CACHE_ENV = "HVD_TPU_COMPILE_CACHE_DIR"
 MANIFEST_NAME = "megakernel_manifest.json"
 MANIFEST_CAP = 256
 
@@ -743,8 +742,8 @@ def _build_quant(spec: GroupSpec, mesh) -> Callable:
         donate = tuple(i for i, d in enumerate(spec.donate) if d) \
             + ((k,) if use_ef else ())  # the residual is executor-owned
     return jax.jit(
-        _compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False),
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
         donate_argnums=donate)
 
 
@@ -792,8 +791,8 @@ def _build(spec: GroupSpec, mesh) -> Callable:
 
     donate = tuple(i for i, d in enumerate(spec.donate) if d)
     return jax.jit(
-        _compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False),
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
         donate_argnums=donate)
 
 
@@ -860,7 +859,7 @@ def executable(spec: GroupSpec, mesh,
     digest = digest_fn() if digest_fn is not None else None
     _cache_insert(spec, fn, digest,
                   seconds=time.perf_counter() - t0)
-    _record_manifest(spec, digest)  # cold builds only; no-op without env
+    _record_manifest(spec, digest)  # cold builds only
     return fn, True
 
 
@@ -869,7 +868,7 @@ def executable(spec: GroupSpec, mesh,
 # ---------------------------------------------------------------------------
 
 def compile_cache_dir() -> Optional[str]:
-    return os.environ.get(COMPILE_CACHE_ENV) or None
+    return _state.compile_cache_dir()
 
 
 def _mesh_fingerprint(mesh_key) -> dict:
@@ -917,7 +916,7 @@ def record_manifest_entry(entry: dict,
 
     Shared by the megakernel's cold-build recording and hvd-serve,
     whose prefill/decode executables ride the SAME manifest under
-    ``variant: "serving"`` so one ``HVD_TPU_COMPILE_CACHE_DIR`` warms a
+    ``variant: "serving"`` so one compile-cache directory warms a
     relaunched fleet's training AND serving programs
     (:func:`warm_start` here skips serving entries;
     ``serving.engine.InferenceEngine.warm_start`` consumes them)."""
@@ -1004,7 +1003,7 @@ def _warm_avals(spec: GroupSpec, mesh) -> List[jax.ShapeDtypeStruct]:
 def warm_start(mesh, directory: Optional[str] = None) -> int:
     """AOT-rebuild the manifest's group executables for ``mesh``.
 
-    Called by ``hvd.init()`` when ``HVD_TPU_COMPILE_CACHE_DIR`` is set:
+    Called by ``hvd.init()`` with the resolved compile-cache directory:
     every recorded group whose mesh fingerprint matches is re-traced and
     compiled ahead of the first training step — against a warm XLA disk
     cache the compile is a cache read, so an elastic relaunch resumes at

@@ -37,8 +37,6 @@ import os
 from typing import Any, NamedTuple, Optional
 
 import jax
-
-from ..core import compat as _compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -142,7 +140,7 @@ def _adasum_gradients(grads):
         raise ValueError(
             "op=Adasum does not support sparse (IndexedSlices) gradients; "
             "pass sparse_as_dense=True to densify them first.")
-    n = _compat.axis_size(REPLICA_AXIS)
+    n = jax.lax.axis_size(REPLICA_AXIS)
     if n & (n - 1) != 0:
         raise ValueError(
             f"op=Adasum requires a power-of-two replica count for its "

@@ -39,8 +39,6 @@ from __future__ import annotations
 from typing import NamedTuple, Optional
 
 import jax
-
-from ..core import compat as _compat
 import jax.numpy as jnp
 
 from ..ops import fused as _fused
@@ -73,7 +71,7 @@ def init_moe_params(key, num_experts: int, d_model: int, d_hidden: int,
 def local_experts(params: dict, *, axis_name: str) -> dict:
     """Slice this device's expert shard (inside shard_map) from replicated
     full params; the router stays replicated."""
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
 
     def shard(leaf):
@@ -148,7 +146,7 @@ def moe_layer(x, params: dict, *, axis_name: str, num_experts: int,
       fuse_chunks: override ``HVD_TPU_FUSE_CHUNKS`` — capacity-axis
         chunks of the fused dispatch→FFN→combine round trip.
     """
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     tokens, d_model = x.shape
     e_local = num_experts // n
     if e_local * n != num_experts:

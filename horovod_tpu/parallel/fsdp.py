@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from .data import DistributedOptimizer
@@ -168,7 +167,7 @@ def make_fsdp_train_step(
             p_chunk = _local_chunk(flat_padded, chunk)
             return p_chunk, optimizer.init(p_chunk)
 
-        jitted = jax.jit(_compat.shard_map(
+        jitted = jax.jit(jax.shard_map(
             shard_and_init, mesh=mesh, in_specs=(P(),),
             out_specs=(P(REPLICA_AXIS), _sharded_state_specs(abstract)),
             check_vma=False), donate_argnums=(0,))
@@ -180,7 +179,7 @@ def make_fsdp_train_step(
         checkpoint restore or broadcast-then-reshard."""
         flat, chunk = _capture_layout(params)
         if "shard_fn" not in layout:
-            layout["shard_fn"] = jax.jit(_compat.shard_map(
+            layout["shard_fn"] = jax.jit(jax.shard_map(
                 lambda f: _local_chunk(f, chunk), mesh=mesh,
                 in_specs=(P(),), out_specs=P(REPLICA_AXIS),
                 check_vma=False), donate_argnums=(0,))
@@ -259,7 +258,7 @@ def make_fsdp_train_step(
                 out_specs = (P(REPLICA_AXIS), specs, P())
                 donate_argnums = (0, 1) if donate else ()
             jitted = jax.jit(
-                _compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                               out_specs=out_specs, check_vma=False),
                 donate_argnums=donate_argnums)
             step_cache[key] = _throttle_on_cpu(jitted, mesh)

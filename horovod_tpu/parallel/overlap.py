@@ -42,18 +42,28 @@ machinery instead of a new runtime:
   int8/int4 the per-bucket quantization blocks and EF residual keys,
   stay deterministic.
 
-**Bitwise contract** (tested in tests/test_overlap.py and gated by
-``bench.py --mode overlap``): with full-precision wire formats the
-overlapped step's parameters are bitwise identical to the monolithic
-``HVD_TPU_OVERLAP=off`` step after any number of steps — the segmented
-VJP chain is the same jaxpr AD produces, and the megakernel's flat
-psum is the same reduction the in-program bucketed psum runs.  Under
-quantized wire formats (``HVD_TPU_COMPRESSION=int8``/``int4``) the
-monolithic static path does not quantize at all, so the comparator is
-the ``serial`` schedule: the SAME per-bucket sub-programs dispatched
-strictly after the full backward (same bucket partition ⇒ same
-pow2-scale blocks, same stochastic-rounding ticks, same per-bucket EF
-residual keys ⇒ bitwise-identical parameters).
+**Identity contract** (tested in tests/test_overlap.py and gated by
+``bench.py --mode overlap``): the streamed schedule's parameters are
+bitwise identical to the ``serial`` schedule's after any number of
+steps — the SAME per-bucket sub-programs dispatched strictly after the
+full backward, so only the interleaving differs.  That also covers the
+quantized wire formats (``HVD_TPU_COMPRESSION=int8``/``int4``), which
+the monolithic static path does not run at all: same bucket partition
+⇒ same pow2-scale blocks, same stochastic-rounding ticks, same
+per-bucket EF residual keys.  Against the monolithic
+``HVD_TPU_OVERLAP=off`` step the gradients are the same bits (the
+segmented VJP chain is the same jaxpr AD produces, and the megakernel's
+flat psum is the same reduction the in-program bucketed psum runs), so
+the first optimizer step is bitwise; from the second step a float32
+optimizer with non-zero moments may differ in the last bits, because
+the apply is its own XLA program: XLA:CPU (jax 0.9.0) contracts Adam's
+``b*m + (1-b)*g`` into FMAs differently when ``g`` is computed in the
+program than when it is a program input.  Measured on the 8-device CPU
+mesh: at most 3.6e-8 on weights of magnitude 0.47 after 3 Adam steps
+and 1.0e-7 after 30 (1-3 ulp of the leaf's largest magnitude);
+bfloat16 leaves and SGD stay bitwise.  Pinning the bits would take an
+``optimization_barrier`` on the gradients in BOTH programs — i.e. in
+the monolithic hot path — which a CPU-only identity does not justify.
 
 Env contract (docs/performance.md, validated at ``hvd.init`` and
 carried in the control-plane HELLO env fingerprint like the
@@ -84,10 +94,10 @@ executed by the mp megakernel (one donated reduce+unpack over the
 process mesh per bucket).  ``take_async`` waits for the broadcast
 response (control plane) but NOT for device completion, so the
 optimizer apply consumes in-flight reductions exactly like
-single-process.  The mp overlapped step is bitwise-identical to the
-monolithic mp step for the same reason the sp one is: same backward
-jaxprs, and the per-bucket psum over the process mesh reduces the
-same contributions the in-program psum reduces.
+single-process.  The mp overlapped step holds the same identity
+contract as the sp one (≡ serial bitwise; monolithic to the measured
+bound): same backward jaxprs, and the per-bucket psum over the process
+mesh reduces the same contributions the in-program psum reduces.
 
 Named fallbacks (each warns once, increments ``overlap.fallbacks``
 and flight-records an ``overlap_fallback`` event carrying the
@@ -118,7 +128,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .. import telemetry as _telemetry
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from ..ops import collective as C
@@ -205,30 +214,16 @@ def resolve_mode(override: Optional[str], mesh) -> str:
     return mode  # "off" | "serial"
 
 
-@jax.custom_vjp
 def stage_boundary(carry):
     """Bucket-boundary marker: an identity whose forward AND cotangent
-    materialize at an ``optimization_barrier`` — the custom_vjp boundary
-    the overlap schedule cuts the backward at.  In the monolithic
-    evaluation it reproduces exactly the materialization points the
-    segmented schedule gets for free from its program boundaries
-    (without it, XLA fuses stage K+1's cotangent into stage K's
-    gradient contractions and drifts a ULP from the per-program
-    backward — the bitwise on≡off contract would break).  jax 0.4.37's
-    ``optimization_barrier`` has no AD rule, so the custom_vjp supplies
-    the (linear, self-transpose) differentiation."""
+    materialize at an ``optimization_barrier`` (its AD rule barriers the
+    cotangent too) — the boundary the overlap schedule cuts the
+    backward at.  In the monolithic evaluation it reproduces exactly
+    the materialization points the segmented schedule gets for free
+    from its program boundaries (without it, XLA fuses stage K+1's
+    cotangent into stage K's gradient contractions and drifts a ULP
+    from the per-program backward)."""
     return jax.lax.optimization_barrier(carry)
-
-
-def _stage_boundary_fwd(carry):
-    return stage_boundary(carry), None
-
-
-def _stage_boundary_bwd(_res, ct):
-    return (jax.lax.optimization_barrier(ct),)
-
-
-stage_boundary.defvjp(_stage_boundary_fwd, _stage_boundary_bwd)
 
 
 class ChainedLoss:
@@ -246,15 +241,15 @@ class ChainedLoss:
     Calling the object evaluates the chain monolithically — exactly
     what the ``HVD_TPU_OVERLAP=off`` step differentiates — with each
     stage wrapped in ``jax.checkpoint``.  The checkpointing is
-    load-bearing for the bitwise contract, not just a memory policy:
+    load-bearing for the gradients' identity, not just a memory policy:
     the segmented backward programs rematerialize their stage's forward
     from the boundary carry (that is what makes per-stage backward
     programs possible), and XLA:CPU contracts a *saved* activation
     against a cotangent with different fusion decisions than a
     *recomputed* one — observed as 1-ULP drift in ``wo``/``w_out``-style
     gradients.  Checkpointing the monolithic evaluation gives both
-    schedules the identical per-stage backward jaxpr, so
-    ``HVD_TPU_OVERLAP=on`` ≡ ``off`` holds bitwise.
+    schedules the identical per-stage backward jaxpr, so the two
+    schedules' gradients are the same bits.
     """
 
     def __init__(self, stages: Sequence[Callable]):
@@ -673,7 +668,7 @@ class _OverlapStep:
             grads = jax.tree_util.tree_map(lambda g: g[None], grads)
             return loss, grads, extra
 
-        self._grads_program = jax.jit(_compat.shard_map(
+        self._grads_program = jax.jit(jax.shard_map(
             per_replica, mesh=self._mesh,
             in_specs=(P(), P(), P(REPLICA_AXIS)),
             out_specs=(P(), P(REPLICA_AXIS), P()), check_vma=False))
@@ -729,7 +724,7 @@ class _OverlapStep:
             loss = stages[-1](params[-1], carry, batch)
             return jax.lax.pmean(loss, REPLICA_AXIS), tuple(carries)
 
-        self._fwd_program = jax.jit(_compat.shard_map(
+        self._fwd_program = jax.jit(jax.shard_map(
             fwd, mesh=self._mesh, in_specs=(P(), P(REPLICA_AXIS)),
             out_specs=(P(), P(REPLICA_AXIS)), check_vma=False))
 
@@ -763,7 +758,7 @@ class _OverlapStep:
                 return pr(self._compress_tree(g))
             return bwd
 
-        sm = _compat.shard_map
+        sm = jax.shard_map
         R = P(REPLICA_AXIS)
         self._bwd_programs: List[Callable] = [None] * S
         # Stage-boundary carries and cotangents are step-internal
@@ -819,7 +814,7 @@ class _OverlapStep:
         # the mp-local-replicas guard pinned size == process_count),
         # which is exactly the mp AVERAGE denominator.
         grads_spec = P() if self._mp else P(REPLICA_AXIS)
-        return jax.jit(_compat.shard_map(
+        return jax.jit(jax.shard_map(
             apply_body, mesh=self._mesh,
             in_specs=(grads_spec, P(), P()), out_specs=(P(), P()),
             check_vma=False), donate_argnums=donate)

@@ -107,7 +107,6 @@ from jax.sharding import PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from ..analysis import donation as _donation
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from ..core.topology import MODEL_AXIS, PIPE_AXIS
@@ -677,7 +676,7 @@ class _PipelineStep:
     def _build_programs(self) -> None:
         stages = self._chain.stages
         S, m = self._S, self._m
-        sm = _compat.shard_map
+        sm = jax.shard_map
         R = P(REPLICA_AXIS)
 
         def mesh_of(k: int):
@@ -828,7 +827,7 @@ class _PipelineStep:
                 return scale(g, opt_state, prm)
 
             donate = (0, 1, 2) if self._donate else (0,)
-            return jax.jit(_compat.shard_map(
+            return jax.jit(jax.shard_map(
                 apply_body, mesh=self._mesh,
                 in_specs=(P(REPLICA_AXIS), P(), P()),
                 out_specs=(P(), P()), check_vma=False),
@@ -848,7 +847,7 @@ class _PipelineStep:
 
         self._apply_s = []
         for k, mk in enumerate(self._stage_meshes):
-            jitted = jax.jit(_compat.shard_map(
+            jitted = jax.jit(jax.shard_map(
                 apply_body, mesh=mk,
                 in_specs=(P(REPLICA_AXIS), P(), P()),
                 out_specs=(P(), P()), check_vma=False))
@@ -1145,7 +1144,7 @@ def gpipe(stage_fn: Callable, stage_params, x, *, num_microbatches: int,
       stage's results are summed across the axis, other stages contribute
       zeros — one psum at the end).
     """
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = num_microbatches
     if x.shape[0] % m != 0:
@@ -1179,7 +1178,7 @@ def gpipe(stage_fn: Callable, stage_params, x, *, num_microbatches: int,
     ticks = jnp.arange(m + n - 1)
     recv0 = jnp.zeros((mb,) + x.shape[1:], x.dtype)
     outs0 = jnp.zeros_like(xs)
-    (_, outs), _ = _compat.scan(tick, (recv0, outs0), ticks)
+    (_, outs), _ = jax.lax.scan(tick, (recv0, outs0), ticks)
     # Only the last stage holds real outputs; share them with one psum.
     outs = jnp.where(idx == n - 1, outs, jnp.zeros_like(outs))
     outs = jax.lax.psum(outs, axis_name)

@@ -31,8 +31,6 @@ import functools
 from typing import Optional
 
 import jax
-
-from ..core import compat as _compat
 import jax.numpy as jnp
 
 from ..core.topology import SEQ_AXIS
@@ -96,7 +94,7 @@ def _attend_chunk(q, k_c, v_c, src, my, causal, sm_scale, block_q, block_k,
 
 def _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, block_q, block_k,
                    interpret):
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = _rot_perm(n)
 
@@ -156,7 +154,7 @@ def _chunk_grads(q, k_c, v_c, o, lse, g, src, my, causal, sm_scale,
 def _ring_bwd(axis_name, causal, sm_scale, block_q, block_k, interpret,
               res, g):
     q, k, v, o, lse = res
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = _rot_perm(n)
 
@@ -216,7 +214,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = SEQ_AXIS,
     through the native transpose of ``all_to_all``.  Requires the head
     count to divide evenly.
     """
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n != 0:
         raise ValueError(f"ulysses_attention needs heads ({h}) divisible "

@@ -39,8 +39,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-from ..core import compat as _compat
 import jax.numpy as jnp
 
 from ..core.topology import MODEL_AXIS
@@ -80,7 +78,7 @@ def row_parallel(x, w, b=None, *, axis_name: str = MODEL_AXIS,
     elementwise in the chunked rows.
     """
     if not input_is_parallel:
-        n = _compat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         idx = jax.lax.axis_index(axis_name)
         shard = x.shape[-1] // n
         x = jax.lax.dynamic_slice_in_dim(x, idx * shard, shard, axis=-1)
@@ -167,7 +165,7 @@ def tp_mlp(x, w_in, b_in, w_out, b_out, *, axis_name: str = MODEL_AXIS,
 def local_shard(full, dim: int, *, axis_name: str = MODEL_AXIS):
     """``full``'s shard for the calling device along ``dim`` (inside
     shard_map)."""
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     size = full.shape[dim] // n
     return jax.lax.dynamic_slice_in_dim(full, idx * size, size, axis=dim)

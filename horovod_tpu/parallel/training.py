@@ -29,7 +29,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from .. import trace as _trace
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from ..memory import ledger as _mem
@@ -318,7 +317,7 @@ def _build_static_step(loss_fn, optimizer, mesh, average, fusion_threshold,
             lambda x: jax.lax.pmean(x, REPLICA_AXIS), aux)
         return loss, grads, aux
 
-    sharded = _compat.shard_map(
+    sharded = jax.shard_map(
         per_replica, mesh=mesh,
         in_specs=(P(), P(), P(REPLICA_AXIS)),
         out_specs=(P(), P(), P()),
@@ -387,7 +386,8 @@ def make_train_step(
     Returns:
       ``step(params, opt_state, batch) -> (params, opt_state, loss[, aux])``
       — one compiled SPMD program (overlap off), or the bucketed-backward
-      sub-program pipeline with bitwise-identical results (overlap on);
+      sub-program pipeline (overlap on; same gradients, identity contract
+      in parallel/overlap.py);
       batch's leading axis must be divisible by the replica count.
     """
     return _make_step(loss_fn, optimizer, mesh, average, fusion_threshold,
@@ -434,7 +434,7 @@ def make_parallel_train_step(loss_fn: Callable[..., Any], optimizer,
     ``batch_spec`` is the PartitionSpec (or pytree of specs) describing
     how the host batch is laid out over the mesh.
     """
-    sharded_loss = _compat.shard_map(
+    sharded_loss = jax.shard_map(
         loss_fn, mesh=mesh, in_specs=(P(), batch_spec), out_specs=P(),
         check_vma=False)
 
@@ -517,7 +517,7 @@ def make_eval_step(metric_fn: Callable[..., Any], mesh=None):
         return jax.tree_util.tree_map(
             lambda x: jax.lax.pmean(x, REPLICA_AXIS), m)
 
-    sharded = _compat.shard_map(
+    sharded = jax.shard_map(
         per_replica, mesh=mesh, in_specs=(P(), P(REPLICA_AXIS)),
         out_specs=P(), check_vma=False)
     return _throttle_on_cpu(jax.jit(sharded), mesh)

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
 
-from ..core import compat as _compat
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from .data import DistributedOptimizer
@@ -298,7 +297,7 @@ def make_zero_train_step(
         key = (chunk, str(dtype))
         if key not in init_cache:
             abstract = _abstract_state_or_raise(optimizer, chunk, dtype)
-            init_cache[key] = jax.jit(_compat.shard_map(
+            init_cache[key] = jax.jit(jax.shard_map(
                 per_replica_init, mesh=mesh,
                 in_specs=(P(),), out_specs=_state_specs(abstract),
                 check_vma=False))
@@ -325,7 +324,7 @@ def make_zero_train_step(
                 out_specs = (P(), specs, P())
                 donate_argnums = (0, 1) if donate else ()
             jitted = jax.jit(
-                _compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                               out_specs=out_specs, check_vma=False),
                 donate_argnums=donate_argnums)
             step_cache[key] = _throttle_on_cpu(jitted, mesh)

@@ -10,10 +10,10 @@ place.  Executables are built ahead of time (``jit(...).lower(...)
 .compile()``) and recorded in the PR-5 persistent-cache manifest under
 ``variant: "serving"`` (ops/megakernel.py ``record_manifest_entry``):
 :meth:`InferenceEngine.warm_start` rebuilds every recorded executable
-at startup — against a warm ``HVD_TPU_COMPILE_CACHE_DIR`` the XLA
-compile is a disk-cache read — so a relaunched serving fleet reaches
-full token rate before its first request, and ``/healthz`` reports
-NOT_READY until it has.
+at startup — against a warm compile-cache directory
+(core/state.compile_cache_dir) the XLA compile is a disk-cache read —
+so a relaunched serving fleet reaches full token rate before its first
+request, and ``/healthz`` reports NOT_READY until it has.
 
 Bitwise contract (CI-gated by tests/test_serving.py and ``bench.py
 --mode serving``): a prefill of the prompt followed by N single-token
@@ -31,9 +31,7 @@ Multi-host serving: rank 0 owns the scheduler and the HTTP front door;
 workers mirror its per-iteration plan (admissions, then sampled
 tokens/evictions) over the control plane's object collectives and run
 the identical executables — the same rank-0-decides/broadcast
-convention the checkpoint and elastic paths use.  Like every
-multi-process data-plane leg, this needs a jax build whose CPU backend
-executes np>1 collectives (CI), not the container's 0.4.37.
+convention the checkpoint and elastic paths use.
 """
 
 from __future__ import annotations
@@ -347,9 +345,8 @@ class InferenceEngine:
     def warm_start(self, directory: Optional[str] = None) -> int:
         """Build the decode executable plus every serving executable the
         persistent-cache manifest recorded for this model/mesh, then
-        mark the engine ready.  On a relaunch with a warm
-        ``HVD_TPU_COMPILE_CACHE_DIR`` the compiles are disk-cache
-        reads — the fleet serves at full token rate from the first
+        mark the engine ready.  On a relaunch with a warm compile-cache
+        directory the compiles are disk-cache reads — the fleet serves at full token rate from the first
         request.  A non-None ``directory`` is also where this engine
         RECORDS its executables from now on (read and write sides must
         agree, or a custom warm-start dir never accumulates entries); a

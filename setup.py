@@ -122,7 +122,7 @@ setup(
     package_data={"horovod_tpu.native": ["*.cc", "*.h", "Makefile",
                                          "libhvdtpu.so"]},
     python_requires=">=3.10",
-    install_requires=["jax", "numpy"],
+    install_requires=["jax>=0.9", "numpy"],
     extras_require={
         "models": ["flax", "optax"],
         "torch": ["torch>=2.1"],

@@ -16,28 +16,41 @@ import numpy as np
 
 
 def scenario_basic(hvd):
+    """Every eager collective across REAL processes, for any world size
+    on one host (-np 2 on the CPU; -np 4 with one chip per worker on
+    the four-chip TPU host)."""
+    import math
+
+    import jax
     import jax.numpy as jnp
 
-    rank = hvd.rank()
-    assert hvd.size() == 2, hvd.size()
-    assert rank == int(os.environ["HVD_TPU_PROCESS_ID"])
-    assert hvd.local_size() == 2  # both processes on this host
+    rank, size = hvd.rank(), hvd.size()
+    assert size == int(os.environ["HVD_TPU_NUM_PROCESSES"]), size
+    # The runtime numbers the processes: on the CPU that is the
+    # launcher's HVD_TPU_PROCESS_ID, on a TPU host the position of the
+    # worker's chip in the slice (the allgather_object check below sees
+    # that the ranks are a permutation either way).
+    assert rank == jax.process_index()
+    if jax.devices()[0].platform == "cpu":
+        assert rank == int(os.environ["HVD_TPU_PROCESS_ID"])
+    assert hvd.local_size() == size  # every process on this host
     assert hvd.local_rank() == rank
     assert hvd.cross_size() == 1
     assert hvd.cross_rank() == 0
+    tri = size * (size + 1) // 2  # sum of (rank + 1) over the ranks
 
     # Allreduce: sum and average of genuinely different contributions.
     out = hvd.allreduce(jnp.array([float(rank + 1)] * 4), average=False)
-    np.testing.assert_allclose(np.asarray(out), 3.0)
+    np.testing.assert_allclose(np.asarray(out), float(tri))
     out = hvd.allreduce(jnp.array([float(rank + 1)] * 4), average=True)
-    np.testing.assert_allclose(np.asarray(out), 1.5)
+    np.testing.assert_allclose(np.asarray(out), tri / size)
 
     # Ragged allgather: dim 0 differs per rank (MPI_Allgatherv case).
     mine = jnp.full((rank + 1, 2), float(rank), jnp.float32)
     out = np.asarray(hvd.allgather(mine))
-    assert out.shape == (3, 2), out.shape
-    np.testing.assert_allclose(out[:1], 0.0)
-    np.testing.assert_allclose(out[1:], 1.0)
+    assert out.shape == (tri, 2), out.shape
+    np.testing.assert_allclose(
+        out[:, 0], np.repeat(np.arange(size), np.arange(size) + 1))
 
     # Broadcast from a non-zero root.
     out = hvd.broadcast(jnp.array([float(rank)] * 3), root_rank=1)
@@ -48,7 +61,7 @@ def scenario_basic(hvd):
                               name=f"fused.{i}") for i in range(4)]
     for i, h in enumerate(hs):
         np.testing.assert_allclose(np.asarray(hvd.synchronize(h)),
-                                   2.0 * i + 1.0)
+                                   float(size * i + tri - size))
 
     # Sparse allreduce (IndexedSlices -> allgather of values+indices,
     # the reference's tensorflow/__init__.py:67-78 path) across REAL
@@ -57,26 +70,28 @@ def scenario_basic(hvd):
     from horovod_tpu.ops.sparse import as_dense
 
     sl = IndexedSlices(jnp.full((1, 2), float(rank + 1), jnp.float32),
-                       jnp.array([rank], jnp.int32), (2, 2))
+                       jnp.array([rank], jnp.int32), (size, 2))
     out = hvd.allreduce(sl, average=False, name="sparse.op")
-    np.testing.assert_allclose(np.asarray(as_dense(out)),
-                               [[1.0, 1.0], [2.0, 2.0]])
+    np.testing.assert_allclose(
+        np.asarray(as_dense(out)),
+        np.repeat(np.arange(1.0, size + 1)[:, None], 2, axis=1))
 
     # Reduce operators across REAL processes (post-v0.13 op= API):
     # rank r contributes r+1, so min/max/product are all distinct; the
-    # adasum of [1,0] and [0,2] (orthogonal) is their sum; mismatched
-    # ops for one name must fail validation on both ranks.
-    import jax.numpy as _jnp
-
-    x = _jnp.array([float(rank + 1)])
+    # adasum of mutually orthogonal vectors is their sum; mismatched
+    # ops for one name must fail validation on every rank.
+    x = jnp.array([float(rank + 1)])
     assert float(hvd.allreduce(x, op=hvd.Min, name="red.min")[0]) == 1.0
-    assert float(hvd.allreduce(x, op=hvd.Max, name="red.max")[0]) == 2.0
-    assert float(hvd.allreduce(x, op=hvd.Product,
-                               name="red.prod")[0]) == 2.0
-    ada = hvd.allreduce(_jnp.array([1.0, 0.0]) if rank == 0
-                        else _jnp.array([0.0, 2.0]),
-                        op=hvd.Adasum, name="red.adasum")
-    np.testing.assert_allclose(np.asarray(ada), [1.0, 2.0], rtol=1e-6)
+    assert float(hvd.allreduce(x, op=hvd.Max,
+                               name="red.max")[0]) == float(size)
+    assert float(hvd.allreduce(x, op=hvd.Product, name="red.prod")[0]) \
+        == float(math.factorial(size))
+    if size & (size - 1) == 0:  # the ladder needs a power of two
+        ada = hvd.allreduce(
+            jnp.zeros((size,)).at[rank].set(float(rank + 1)),
+            op=hvd.Adasum, name="red.adasum")
+        np.testing.assert_allclose(np.asarray(ada),
+                                   np.arange(1.0, size + 1), rtol=1e-6)
     from horovod_tpu import HorovodError as _HErr
 
     try:
@@ -87,36 +102,44 @@ def scenario_basic(hvd):
         assert "Mismatched reduce operations" in str(e), str(e)
 
     # Reducescatter across REAL processes (post-v0.13): each rank gets
-    # its own chunk of the reduction — here, half of sum_r(arange+r).
-    out = hvd.reducescatter(_jnp.arange(4.0) + rank, average=False,
+    # its own chunk of the reduction of (arange + rank).
+    out = hvd.reducescatter(jnp.arange(2.0 * size) + rank, average=False,
                             name="red.rscatter")
-    want = (2.0 * np.arange(4.0) + 1.0)[2 * rank:2 * rank + 2]
+    want = (size * np.arange(2.0 * size)
+            + (tri - size))[2 * rank:2 * rank + 2]
     np.testing.assert_allclose(np.asarray(out), want)
-    out = hvd.reducescatter(_jnp.arange(4.0) + rank, average=True,
+    out = hvd.reducescatter(jnp.arange(2.0 * size) + rank, average=True,
                             name="red.rscatter.avg")
-    np.testing.assert_allclose(np.asarray(out), want / 2.0)
+    np.testing.assert_allclose(np.asarray(out), want / size)
 
-    # Alltoall across REAL processes (post-v0.13), ragged splits: rank 0
-    # sends [1 row to 0, 2 rows to 1]; rank 1 sends [2, 1].  Receiver r
-    # concatenates in sender order.
-    mine = _jnp.asarray(np.arange(3.0).reshape(3, 1) + 100 * rank)
-    out = np.asarray(hvd.alltoall(mine,
-                                  splits=[1, 2] if rank == 0 else [2, 1],
-                                  name="red.a2a"))
-    if rank == 0:
-        np.testing.assert_allclose(out[:, 0], [0, 100, 101])
-    else:
-        np.testing.assert_allclose(out[:, 0], [1, 2, 102])
+    # Alltoall across REAL processes (post-v0.13), ragged splits:
+    # sender s ships 1 + (s + d) % 2 rows to destination d, tagged
+    # 100*s + its running row index.  Receiver d concatenates in
+    # sender order.
+    def splits(s):
+        return [1 + (s + d) % 2 for d in range(size)]
+
+    def rows(s):
+        return np.arange(float(sum(splits(s)))) + 100 * s
+
+    out = np.asarray(hvd.alltoall(jnp.asarray(rows(rank).reshape(-1, 1)),
+                                  splits=splits(rank), name="red.a2a"))
+    want = []
+    for s in range(size):
+        lo = sum(splits(s)[:rank])
+        want.extend(rows(s)[lo:lo + splits(s)[rank]])
+    np.testing.assert_allclose(out[:, 0], want)
     hvd.barrier()
 
     # Object collectives across REAL processes: per-rank pickles of
     # genuinely different sizes ride the ragged allgather; broadcast
-    # ships the root's object to the non-root.
+    # ships the root's object to the non-roots.
     from horovod_tpu import allgather_object, broadcast_object
 
     objs = allgather_object({"rank": rank, "pad": "x" * (10 * rank)})
-    assert [o["rank"] for o in objs] == [0, 1], objs
-    assert len(objs[1]["pad"]) == 10
+    assert [o["rank"] for o in objs] == list(range(size)), objs
+    assert [len(o["pad"]) for o in objs] == \
+        [10 * r for r in range(size)]
     got = broadcast_object({"resume": 7} if rank == 0 else None,
                            root_rank=0)
     assert got == {"resume": 7}, got
@@ -355,7 +378,9 @@ def scenario_overlap(hvd):
     overlapped np=2 train step — per-bucket partial cycles negotiated
     over the REAL TCP control plane, mp megakernel reductions,
     take_async feeding in-flight results into the apply — is
-    BITWISE-identical to the monolithic mp step, for both the plain
+    BITWISE-identical to the serial mp schedule (same sub-programs,
+    fenced) and within float tolerance of the monolithic mp step (the
+    identity contract of parallel/overlap.py), for both the plain
     (single-backward) and the ChainedLoss (segmented) schedule; on the
     steady state every bucket replays from the response cache with
     ZERO new negotiation misses."""
@@ -416,31 +441,41 @@ def scenario_overlap(hvd):
     fallbacks0 = _tel.metrics().get(
         "overlap.fallbacks", {}).get("value", 0)
 
+    def leaves_close(a, b):
+        return all(
+            np.allclose(np.asarray(u), np.asarray(v), rtol=1e-4,
+                        atol=1e-5)
+            for u, v in zip(jax.tree_util.tree_leaves(a),
+                            jax.tree_util.tree_leaves(b)))
+
+    def build(loss, mode):
+        return make_train_step(loss, opt, donate=False,
+                               fusion_threshold=threshold, overlap=mode)
+
     # Leg 1 — segmented schedule (ChainedLoss): streamed mp partial
-    # cycles ≡ the monolithic mp step, bitwise after 4 adam steps.
-    step_on = make_train_step(chain, opt, donate=False,
-                              fusion_threshold=threshold, overlap="on")
+    # cycles ≡ the serial mp schedule, bitwise after 4 adam steps, and
+    # ≈ the monolithic mp step.
+    step_on = build(chain, "on")
     p_on, l_on = run(step_on)
     assert step_on.overlap_active, "mp build fell back"
     assert step_on.segment_count == 2
     assert step_on.bucket_count == 4
-    step_off = make_train_step(chain, opt, donate=False,
-                               fusion_threshold=threshold, overlap="off")
-    p_off, l_off = run(step_off)
-    assert l_on == l_off, (l_on, l_off)
-    assert leaves_equal(p_on, p_off), "overlapped mp != monolithic mp"
+    step_serial = build(chain, "serial")
+    p_ser, l_ser = run(step_serial)
+    assert l_on == l_ser, (l_on, l_ser)
+    assert leaves_equal(p_on, p_ser), "streamed mp != serial mp"
+    p_off, _ = run(build(chain, "off"))
+    assert leaves_close(p_on, p_off), "overlapped mp !~ monolithic mp"
     print(f"OVERLAP_SEG_OK rank={rank} loss={l_on:.6f}")
 
     # Leg 2 — plain loss (single-backward streaming): same contract.
-    step_u_on = make_train_step(plain_loss, opt, donate=False,
-                                fusion_threshold=threshold, overlap="on")
+    step_u_on = build(plain_loss, "on")
     p_u_on, _ = run(step_u_on, 2)
     assert step_u_on.overlap_active
-    step_u_off = make_train_step(plain_loss, opt, donate=False,
-                                 fusion_threshold=threshold,
-                                 overlap="off")
-    p_u_off, _ = run(step_u_off, 2)
-    assert leaves_equal(p_u_on, p_u_off)
+    p_u_ser, _ = run(build(plain_loss, "serial"), 2)
+    assert leaves_equal(p_u_on, p_u_ser)
+    p_u_off, _ = run(build(plain_loss, "off"), 2)
+    assert leaves_close(p_u_on, p_u_off)
     print(f"OVERLAP_PLAIN_OK rank={rank}")
 
     # Leg 3 — steady state: every bucket's partial cycle replays from
@@ -471,7 +506,7 @@ def scenario_overlap(hvd):
     # next bucket's coalesced request frame hits the dead socket
     # mid-flush; the session-resume protocol replays the lost frames
     # (cache replicas stay index-aligned) and the trained parameters
-    # stay BITWISE-identical to the uninterrupted monolithic run — the
+    # stay BITWISE-identical to the uninterrupted serial run — the
     # no-new-hang-class contract for partial cycles.
     p, s = params0, opt.init(params0)
     for stepi in range(6):
@@ -483,10 +518,10 @@ def scenario_overlap(hvd):
     jax.block_until_ready(jax.tree_util.tree_leaves(p))
     q, t = params0, opt.init(params0)
     for _ in range(6):
-        q, t, _loss = step_off(q, t, batch)
+        q, t, _loss = step_serial(q, t, batch)
     jax.block_until_ready(jax.tree_util.tree_leaves(q))
     assert leaves_equal(p, q), \
-        "post-reconnect overlapped params != uninterrupted monolithic"
+        "post-reconnect overlapped params != uninterrupted serial"
     if rank == 1:
         got = _tel.metrics().get("transport.reconnects",
                                  {}).get("value", 0)

@@ -1,7 +1,7 @@
 """hvd-pipeline checkpoint half: the background rank-0 writer
 (utils/checkpoint.py) — overlap, atomicity under a mid-write kill,
 ordering, the elastic commit() integration — plus the persistent
-compile cache (HVD_TPU_COMPILE_CACHE_DIR: megakernel manifest +
+compile cache (core/state.compile_cache_dir: megakernel manifest +
 warm start across a simulated elastic relaunch)."""
 
 import os
@@ -183,7 +183,7 @@ def test_elastic_relaunch_resumes_from_async_commit(hvd, tmp_path,
 
 
 # ---------------------------------------------------------------------------
-# Persistent compile cache (HVD_TPU_COMPILE_CACHE_DIR)
+# Persistent compile cache (core/state.compile_cache_dir: one rule)
 # ---------------------------------------------------------------------------
 
 def _fused_cycle(hvd, tag):
@@ -194,18 +194,72 @@ def _fused_cycle(hvd, tag):
     return [np.asarray(hvd.synchronize(h)) for h in hs]
 
 
+def test_compile_cache_rule(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` placed from outside wins and the
+    package never writes ``jax_compilation_cache_dir``; unset, an
+    accelerator gets ``<checkout>/.jax_cache`` and a CPU run nothing."""
+    from horovod_tpu.core import state as st
+
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name),
+                             real_update(name, value))[1])
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert st.compile_cache_dir() == str(tmp_path)
+    assert st.configure_compile_cache() == str(tmp_path)
+    assert updates and "jax_compilation_cache_dir" not in updates
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert st.compile_cache_dir() is None  # CPU backend, nothing placed
+    assert st.configure_compile_cache() is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert st.compile_cache_dir() == os.path.join(checkout, ".jax_cache")
+
+
+def test_compile_cache_from_outside_fills_and_is_never_set(tmp_path):
+    """A fresh process with ``JAX_COMPILATION_CACHE_DIR`` set: jax reads
+    it, ``hvd.init()`` writes no directory, and the directory fills."""
+    import subprocess
+    import sys
+
+    cache = tmp_path / "outside"
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "writes = []\n"
+        "real = jax.config.update\n"
+        "jax.config.update = lambda n, v: (writes.append(n), "
+        "real(n, v))[1]\n"
+        "import horovod_tpu as hvd\n"
+        "hvd.init()\n"
+        "jax.block_until_ready(jax.jit(lambda x: x * 2 + 1)"
+        "(jnp.arange(8.0)))\n"
+        "hvd.shutdown()\n"
+        "assert 'jax_compilation_cache_dir' not in writes, writes\n"
+        "print('CACHE_DIR', jax.config.jax_compilation_cache_dir)\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert f"CACHE_DIR {cache}" in out.stdout
+    assert any(cache.iterdir())
+
+
 def test_compile_cache_reuse_across_simulated_relaunch(tmp_path,
                                                        monkeypatch):
     """First incarnation: a fused allreduce builds a megakernel and
-    records it in the manifest.  Simulated relaunch (executables
-    flushed, re-init): warm_start AOT-rebuilds the executable at init —
-    before any collective runs — and it serves the replayed cycle with
-    identical results.  jax's persistent compilation cache is pointed
-    at the same directory."""
+    records it in the manifest of the resolved directory.  Simulated
+    relaunch (executables flushed, re-init): warm_start AOT-rebuilds the
+    executable at init — before any collective runs — and it serves the
+    replayed cycle with identical results."""
     from horovod_tpu.ops import megakernel as mk
 
     cache_dir = str(tmp_path / "compile-cache")
-    monkeypatch.setenv("HVD_TPU_COMPILE_CACHE_DIR", cache_dir)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
     import horovod_tpu as hvd
 
     hvd.init(devices=jax.devices())
@@ -214,7 +268,6 @@ def test_compile_cache_reuse_across_simulated_relaunch(tmp_path,
         manifest = mk.load_manifest(cache_dir)
         assert len(manifest) >= 1
         assert manifest[0]["variant"] in ("sp_pr", "sp_rep")
-        assert jax.config.jax_compilation_cache_dir == cache_dir
     finally:
         hvd.shutdown()
 
@@ -256,7 +309,7 @@ def test_compile_cache_manifest_ignores_foreign_mesh(tmp_path,
                                 "count": 4096}}]}, f)
     import horovod_tpu as hvd
 
-    monkeypatch.setenv("HVD_TPU_COMPILE_CACHE_DIR", cache_dir)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
     hvd.init(devices=jax.devices())
     try:
         assert mk.warm_start(horovod_tpu.mesh(), cache_dir) == 0

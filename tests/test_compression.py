@@ -9,7 +9,6 @@ uncompressed one — on both the static (fused psum) and eager
 """
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -101,7 +100,7 @@ def test_compressed_average_divides_after_decompress():
                     {"w": x}, average=avg,
                     compression=Compression.bf16)["w"]
                 return out[None]
-            return jax.jit(_compat.shard_map(
+            return jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=P("hvd"), out_specs=P("hvd"),
                 check_vma=False))
 

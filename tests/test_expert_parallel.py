@@ -5,7 +5,6 @@ must reproduce the single-group computation when capacity is ample, and
 degrade only by dropping when it is not."""
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -34,7 +33,7 @@ def _run(n_devices, x, params, **kw):
         return moe_layer(x, mine, axis_name=EXPERT_AXIS, num_experts=E,
                          **kw)
 
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(EXPERT_AXIS), P()),
         out_specs=MoEOutput(P(EXPERT_AXIS), P(), P()),
         check_vma=False))(x, params)
@@ -155,7 +154,7 @@ def test_moe_gradients_flow_to_all_param_groups():
     x, params = _inputs(tokens=32)
     mesh = make_mesh(expert=4, devices=jax.devices()[:4])
 
-    sm = jax.jit(_compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         lambda x, params: moe_layer(
             x, local_experts(params, axis_name=EXPERT_AXIS),
             axis_name=EXPERT_AXIS, num_experts=E, top_k=2,

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.core import compat as _compat
 from horovod_tpu.core.topology import MODEL_AXIS, make_mesh
 from horovod_tpu.memory import ledger as ledger_mod
 from horovod_tpu.memory import planner
@@ -173,7 +172,7 @@ def test_chunked_map_respects_axis():
 # ---------------------------------------------------------------------------
 
 def _bitwise(mesh, fn_fused, fn_ref, *args):
-    run = lambda fn: np.asarray(jax.jit(_compat.shard_map(
+    run = lambda fn: np.asarray(jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(P() for _ in args), out_specs=P(),
         check_vma=False))(*args)).tobytes()
     return run(fn_fused) == run(fn_ref)
@@ -236,7 +235,7 @@ def test_fused_program_compiles_once_and_matches_jit():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((16, 8)).astype(np.float32))
     w = jnp.asarray(rng.standard_normal((8, 8)).astype(np.float32))
-    fn = jax.jit(_compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x, w: F.matmul_psum(x, w, axis_name=MODEL_AXIS,
                                    chunks=4, fuse=True),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(),
@@ -257,7 +256,7 @@ def test_fused_program_ledger_charge_is_scoped_to_the_launch():
     mesh = _mesh()
     x = jnp.ones((16, 8), jnp.float32)
     w = jnp.ones((8, 8), jnp.float32)
-    fn = jax.jit(_compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x, w: F.matmul_psum(x, w, axis_name=MODEL_AXIS,
                                    chunks=4, fuse=True),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(),
@@ -277,7 +276,7 @@ def test_fused_program_ledger_charge_is_scoped_to_the_launch():
 def test_fused_manifest_entry_round_trip(tmp_path, monkeypatch):
     from horovod_tpu.ops import megakernel as mk
 
-    monkeypatch.setenv("HVD_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     mesh = _mesh()
     entry = F.fused_manifest_entry("fused/test.g1", mesh,
                                    [(16, 8), (8, 8)], jnp.float32, 4)

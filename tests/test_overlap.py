@@ -4,11 +4,13 @@ The bucketed-backward train step streams each gradient bucket's
 pack→reduce→unpack megakernel out of the backward pass instead of
 waiting for the full gradient pytree.  Its load-bearing contracts:
 
-* bitwise identity: the overlapped step's parameters equal the
-  monolithic ``HVD_TPU_OVERLAP=off`` step's, bitwise, for the
-  single-backward streaming schedule, across leaf dtypes; the
-  segmented schedule equals the serialized dispatch of the same
-  sub-programs bitwise (same programs, different interleaving);
+* bitwise identity: the streamed schedule's parameters equal the
+  serialized dispatch of the same sub-programs, bitwise (same programs,
+  different interleaving), for the single-backward and the segmented
+  schedule, across leaf dtypes; against the monolithic
+  ``HVD_TPU_OVERLAP=off`` step the first step is bitwise and float32
+  Adam steps stay within a measured few-ulp bound (the apply compiles
+  as its own program — see parallel/overlap.py);
 * steady state: exactly one megakernel launch per bucket per cycle,
   with the response cache replaying every bucket's sub-program (no
   renegotiation after warmup) — counted at jax's real dispatch choke
@@ -120,21 +122,44 @@ def _run(step, params, opt, batch, steps):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_stream_bitwise_identical_to_monolithic(hvd, dtype):
-    """The streaming schedule's params ≡ the monolithic step's, bitwise,
-    after several steps — per leaf dtype (buckets partition by wire
-    dtype, so each dtype rides its own megakernels)."""
+def test_stream_identical_to_serial_and_monolithic(hvd, dtype):
+    """The streaming schedule's params ≡ the serial schedule's, bitwise,
+    after several steps, per leaf dtype (buckets partition by wire
+    dtype, so each dtype rides its own megakernels) — the contract that
+    holds on every backend: same sub-programs, different interleaving.
+
+    Against the monolithic step the first Adam step is bitwise and
+    later float32 steps may drift: XLA:CPU contracts the moment update
+    ``b*m + (1-b)*g`` into FMAs one way when ``g`` is produced inside
+    the program (monolithic) and another when it is a program input
+    (the bucketed apply).  Measured under jax 0.9.0: at most 3.6e-8 on
+    weights of magnitude 0.47 after 3 steps, 1.0e-7 after 30 (1-3 ulp
+    of the leaf's largest magnitude); bfloat16 leaves stay bitwise.
+    The bound asserted is 4 ulp of each leaf's largest magnitude."""
     params = _plain_params(jax.random.PRNGKey(0), dtype)
     batch = _batch(hvd, jax.random.PRNGKey(1))
     opt = optax.adam(1e-3)
-    p_on, l_on = _run(make_train_step(
-        _plain_loss, opt, donate=False, fusion_threshold=_THRESHOLD,
-        overlap="on"), params, opt, batch, 3)
-    p_off, l_off = _run(make_train_step(
-        _plain_loss, opt, donate=False, fusion_threshold=_THRESHOLD,
-        overlap="off"), params, opt, batch, 3)
-    assert l_on == l_off
-    assert _leaves_equal(p_on, p_off)
+
+    def run(mode, steps=3):
+        return _run(make_train_step(
+            _plain_loss, opt, donate=False, fusion_threshold=_THRESHOLD,
+            overlap=mode), params, opt, batch, steps)
+
+    p_on, l_on = run("on")
+    p_ser, l_ser = run("serial")
+    p_off, l_off = run("off")
+    assert l_on == l_ser
+    assert _leaves_equal(p_on, p_ser)
+    assert _leaves_equal(run("on", 1)[0], run("off", 1)[0])
+    if dtype == jnp.bfloat16:
+        assert l_on == l_off
+        assert _leaves_equal(p_on, p_off)
+        return
+    for a, b in zip(jax.tree_util.tree_leaves(p_on),
+                    jax.tree_util.tree_leaves(p_off)):
+        a, b = np.asarray(a), np.asarray(b)
+        bound = 4 * np.finfo(np.float32).eps * np.abs(b).max()
+        assert np.abs(a - b).max() <= bound, (np.abs(a - b).max(), bound)
 
 
 def test_stream_bitwise_identical_mixed_dtypes(hvd):

@@ -17,7 +17,6 @@ import os
 import jax
 import numpy as np
 import optax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -63,7 +62,7 @@ def test_gpipe_matches_sequential(n_stages, n_micro):
         mine = select_stage_params(params)
         return gpipe(_stage_fn, mine, x, num_microbatches=n_micro)
 
-    got = jax.jit(_compat.shard_map(run, mesh=mesh, in_specs=(P(), P()),
+    got = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P(), P()),
                                 out_specs=P(), check_vma=False))(params, x)
     want = _sequential(params, x)
     assert jnp.max(jnp.abs(got - want)) < TOL
@@ -76,7 +75,7 @@ def test_gpipe_gradients_match_sequential():
     params = _stacked_params(n_stages, d, seed=2)
     x = jax.random.normal(jax.random.PRNGKey(3), (8, d))
 
-    sm = _compat.shard_map(
+    sm = jax.shard_map(
         lambda params, x: gpipe(_stage_fn, select_stage_params(params), x,
                                 num_microbatches=n_micro),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
@@ -90,7 +89,7 @@ def test_gpipe_rejects_indivisible_microbatches():
     mesh = make_mesh(pipe=2, devices=jax.devices()[:2])
     params = _stacked_params(2, 4)
     x = jnp.zeros((6, 4))
-    sm = _compat.shard_map(
+    sm = jax.shard_map(
         lambda params, x: gpipe(_stage_fn, select_stage_params(params), x,
                                 num_microbatches=4),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
@@ -100,7 +99,7 @@ def test_gpipe_rejects_indivisible_microbatches():
 
 def test_stage_index():
     mesh = make_mesh(pipe=4, devices=jax.devices()[:4])
-    out = jax.jit(_compat.shard_map(lambda: stage_index()[None], mesh=mesh,
+    out = jax.jit(jax.shard_map(lambda: stage_index()[None], mesh=mesh,
                                 in_specs=(), out_specs=P(PIPE_AXIS),
                                 check_vma=False))()
     assert list(out) == [0, 1, 2, 3]
@@ -116,7 +115,7 @@ def test_gpipe_composes_with_data_parallel():
         mine = select_stage_params(params)
         return gpipe(_stage_fn, mine, x, num_microbatches=2)
 
-    got = jax.jit(_compat.shard_map(run, mesh=mesh, in_specs=(P(), P("data")),
+    got = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P(), P("data")),
                                 out_specs=P("data"),
                                 check_vma=False))(params, x)
     want = _sequential(params, x)
@@ -129,7 +128,7 @@ def test_gpipe_error_names_axis_and_nearest_counts():
     mesh = make_mesh(pipe=2, devices=jax.devices()[:2])
     params = _stacked_params(2, 4)
     x = jnp.zeros((6, 4))
-    sm = _compat.shard_map(
+    sm = jax.shard_map(
         lambda params, x: gpipe(_stage_fn, select_stage_params(params), x,
                                 num_microbatches=4),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
@@ -146,13 +145,13 @@ def test_select_stage_params_pytree():
     mesh = make_mesh(pipe=4, devices=jax.devices()[:4])
     stacked = {"w": jnp.arange(4 * 3).reshape(4, 3).astype(jnp.float32),
                "b": jnp.arange(4.0)}
-    out = jax.jit(_compat.shard_map(
+    out = jax.jit(jax.shard_map(
         lambda p: select_stage_params(p)["w"][None],
         mesh=mesh, in_specs=(P(),), out_specs=P(PIPE_AXIS),
         check_vma=False))(stacked)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(stacked["w"]))
-    outb = jax.jit(_compat.shard_map(
+    outb = jax.jit(jax.shard_map(
         lambda p: select_stage_params(p)["b"][None],
         mesh=mesh, in_specs=(P(),), out_specs=P(PIPE_AXIS),
         check_vma=False))(stacked)

@@ -6,7 +6,6 @@ sequence, forward and backward.
 """
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -25,7 +24,7 @@ def _qkv(b=2, h=4, s=256, d=32, seed=1):
 
 
 def _sharded(fn, mesh):
-    return jax.jit(_compat.shard_map(fn, mesh=mesh, in_specs=SPEC,
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=SPEC,
                                  out_specs=SPEC, check_vma=False))
 
 
@@ -105,7 +104,7 @@ def test_ring_attention_composes_with_data_parallel():
     q, k, v = _qkv(b=4, s=128)
 
     spec = P("data", None, SEQ_AXIS)
-    sm = jax.jit(_compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, causal=True, block_q=32,
                                        block_k=32),
         mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False))

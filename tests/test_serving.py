@@ -631,7 +631,7 @@ def test_engine_warm_start_from_manifest(tmp_path, monkeypatch):
     """Relaunch: the manifest records the serving executables; a fresh
     engine's warm_start rebuilds them BEFORE any request arrives and
     flips readiness, and the rebuilt executables replay bitwise."""
-    monkeypatch.setenv("HVD_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     e1 = make_engine()
     e1.warm_start()
     out1 = e1.generate([1, 2, 3, 4, 5], max_new_tokens=6)

@@ -2,7 +2,6 @@
 tensorflow/__init__.py:67-78, and the word2vec example that exercises it)."""
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -181,7 +180,7 @@ def test_static_path_sparse_gradients(hvd):
     mesh = hvd.mesh()
     vals = jnp.stack([jnp.full((1, 3), float(r + 1)) for r in range(size)])
     idxs = jnp.stack([jnp.asarray([2 * r], jnp.int32) for r in range(size)])
-    fn = jax.jit(_compat.shard_map(step, mesh=mesh,
+    fn = jax.jit(jax.shard_map(step, mesh=mesh,
                                in_specs=(P("hvd"), P("hvd")),
                                out_specs=P("hvd"), check_vma=False))
     out = np.asarray(fn(hvd.shard(vals), hvd.shard(idxs)))

@@ -305,7 +305,7 @@ def test_spec_warm_start_records_and_rebuilds_executables(tmp_path,
     different depth skips the foreign entries."""
     import json as _json
 
-    monkeypatch.setenv("HVD_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     e1 = make_spec_engine()
     e1.warm_start()
     out1 = e1.generate([1, 2, 3, 4, 5], max_new_tokens=6)

@@ -2,7 +2,6 @@
 single-device computation (self-verifying, SURVEY.md §4 style)."""
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -36,7 +35,7 @@ def test_column_then_row_matches_dense():
         h = jax.nn.gelu(h)
         return row_parallel(h, local_shard(w2, 0), b2)
 
-    got = jax.jit(_compat.shard_map(
+    got = jax.jit(jax.shard_map(
         tp, mesh=mesh, in_specs=(P(), P(), P(), P(), P()),
         out_specs=P(), check_vma=False))(x, w1, b1, w2, b2)
     want = jax.nn.gelu(x @ w1 + b1) @ w2 + b2
@@ -55,7 +54,7 @@ def test_tp_mlp_helper_matches_dense():
         return tp_mlp(x, local_shard(w1, 1), None, local_shard(w2, 0),
                       None)
 
-    got = jax.jit(_compat.shard_map(tp, mesh=mesh, in_specs=(P(),) * 3,
+    got = jax.jit(jax.shard_map(tp, mesh=mesh, in_specs=(P(),) * 3,
                                 out_specs=P(), check_vma=False))(x, w1, w2)
     want = jax.nn.gelu(x @ w1) @ w2
     assert jnp.max(jnp.abs(got - want)) < TOL
@@ -69,7 +68,7 @@ def test_column_parallel_gather_output():
     def tp(x, w):
         return column_parallel(x, local_shard(w, 1), gather_output=True)
 
-    got = jax.jit(_compat.shard_map(tp, mesh=mesh, in_specs=(P(), P()),
+    got = jax.jit(jax.shard_map(tp, mesh=mesh, in_specs=(P(), P()),
                                 out_specs=P(), check_vma=False))(x, w)
     assert jnp.max(jnp.abs(got - w)) < TOL
 
@@ -84,7 +83,7 @@ def test_row_parallel_unsharded_input():
         return row_parallel(x, local_shard(w, 0),
                             input_is_parallel=False)
 
-    got = jax.jit(_compat.shard_map(tp, mesh=mesh, in_specs=(P(), P()),
+    got = jax.jit(jax.shard_map(tp, mesh=mesh, in_specs=(P(), P()),
                                 out_specs=P(), check_vma=False))(x, w)
     assert jnp.max(jnp.abs(got - x @ w)) < TOL
 
@@ -106,8 +105,8 @@ def _tp_mlp_bytes(fuse, fuse_chunks=None):
         return tp_mlp(x, local_shard(w1, 1), None, local_shard(w2, 0),
                       None, fuse=fuse, fuse_chunks=fuse_chunks)
 
-    got = jax.jit(_compat.shard_map(tp, mesh=mesh, in_specs=(P(),) * 3,
-                                    out_specs=P(), check_vma=False))(
+    got = jax.jit(jax.shard_map(tp, mesh=mesh, in_specs=(P(),) * 3,
+                                out_specs=P(), check_vma=False))(
         x, w1, w2)
     import numpy as np
     return np.asarray(got).tobytes()
@@ -143,7 +142,7 @@ def test_scatter_gather_pair_matches_dense():
         s = row_parallel_scatter(x, local_shard(w1, 0))
         return gather_column_parallel(s, local_shard(w2, 1))
 
-    got = jax.jit(_compat.shard_map(
+    got = jax.jit(jax.shard_map(
         tp, mesh=mesh,
         in_specs=(P(None, MODEL_AXIS), P(), P()),
         out_specs=P(None, MODEL_AXIS), check_vma=False))(x, w1, w2)
@@ -167,7 +166,7 @@ def test_fused_scatter_gather_pair_bitwise_vs_unfused(chunks):
             return gather_column_parallel(s, local_shard(w2, 1),
                                           fuse=fuse, fuse_chunks=n)
 
-        got = jax.jit(_compat.shard_map(
+        got = jax.jit(jax.shard_map(
             tp, mesh=mesh, in_specs=(P(None, MODEL_AXIS), P(), P()),
             out_specs=P(None, MODEL_AXIS), check_vma=False))(x, w1, w2)
         import numpy as np
@@ -184,7 +183,7 @@ def test_tp_gradients_match_dense():
     w1 = jax.random.normal(k2, (8, 16)) * 0.1
     w2 = jax.random.normal(k3, (16, 8)) * 0.1
 
-    sm = _compat.shard_map(
+    sm = jax.shard_map(
         lambda x, w1, w2: tp_mlp(x, local_shard(w1, 1), None,
                                  local_shard(w2, 0), None),
         mesh=mesh, in_specs=(P(),) * 3, out_specs=P(), check_vma=False)

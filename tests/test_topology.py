@@ -1,7 +1,6 @@
 """Multi-axis mesh topology tests (horovod_tpu.core.topology)."""
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -49,7 +48,7 @@ def test_axis_helpers_inside_shard_map():
                 + T.axis_size(T.MODEL_AXIS)
                 + T.axis_index(T.DATA_AXIS))[None]
 
-    out = _compat.shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=P(),
         out_specs=P((T.DATA_AXIS, T.PIPE_AXIS, T.SEQ_AXIS, T.MODEL_AXIS)),
         check_vma=False)(jnp.zeros(()))

@@ -2,7 +2,6 @@
 single-device forward, and the combined train step must learn."""
 
 import jax
-from horovod_tpu.core import compat as _compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -56,7 +55,7 @@ def test_parallel_forward_matches_single_device(axes_kw, mesh_kw,
         return logits
 
     out_spec = P(ax.data, ax.seq, None)
-    got = jax.jit(_compat.shard_map(local, mesh=mesh,
+    got = jax.jit(jax.shard_map(local, mesh=mesh,
                                 in_specs=(P(), batch_spec),
                                 out_specs=out_spec,
                                 check_vma=False))(params, tokens)
@@ -73,7 +72,7 @@ def test_pipeline_forward_matches_single_device():
         logits, aux = forward(params, tokens, CFG, ax)
         return logits
 
-    got = jax.jit(_compat.shard_map(local, mesh=mesh,
+    got = jax.jit(jax.shard_map(local, mesh=mesh,
                                 in_specs=(P(), P("data", None)),
                                 out_specs=P("data", None, None),
                                 check_vma=False))(params, tokens)
@@ -91,7 +90,7 @@ def test_moe_transformer_runs_and_is_finite():
     params, tokens, targets = _data(cfg)
 
     loss_fn = make_loss_fn(cfg, ax, mesh_axes=mesh.axis_names)
-    sm = _compat.shard_map(loss_fn, mesh=mesh,
+    sm = jax.shard_map(loss_fn, mesh=mesh,
                        in_specs=(P(), P("data", None)), out_specs=P(),
                        check_vma=False)
     loss = jax.jit(sm)(params, (tokens, targets))
@@ -130,7 +129,7 @@ def test_parallel_gradients_match_single_device():
     params, tokens, targets = _data(batch=4)
 
     loss_fn = make_loss_fn(CFG, ax, mesh_axes=mesh.axis_names)
-    sm = _compat.shard_map(loss_fn, mesh=mesh,
+    sm = jax.shard_map(loss_fn, mesh=mesh,
                        in_specs=(P(), P("data", "seq")), out_specs=P(),
                        check_vma=False)
     got = jax.jit(jax.grad(sm))(params, (tokens, targets))
@@ -196,7 +195,7 @@ def test_chunked_loss_composes_with_seq_parallel():
     cfg = dataclasses.replace(CFG, loss_chunk=4, remat=True)
     params, tokens, targets = _data(batch=4)
     loss_fn = make_loss_fn(cfg, ax, mesh_axes=mesh.axis_names)
-    sm = _compat.shard_map(loss_fn, mesh=mesh,
+    sm = jax.shard_map(loss_fn, mesh=mesh,
                        in_specs=(P(), P("data", "seq")), out_specs=P(),
                        check_vma=False)
     loss, grads = jax.jit(jax.value_and_grad(sm))(params,
@@ -219,7 +218,7 @@ def test_remat_composes_with_parallel_axes():
     cfg = dataclasses.replace(CFG, remat=True)
     params, tokens, targets = _data(batch=4)
     loss_fn = make_loss_fn(cfg, ax, mesh_axes=mesh.axis_names)
-    sm = _compat.shard_map(loss_fn, mesh=mesh,
+    sm = jax.shard_map(loss_fn, mesh=mesh,
                        in_specs=(P(), P("data", "seq")), out_specs=P(),
                        check_vma=False)
     loss, grads = jax.jit(jax.value_and_grad(sm))(params,
@@ -237,7 +236,7 @@ def test_pipeline_rejects_indivisible_layers():
     mesh = make_mesh(pipe=3, devices=jax.devices()[:3])
     ax = ParallelAxes(data=None, pipe="pipe")
     params, tokens, _ = _data()
-    sm = _compat.shard_map(
+    sm = jax.shard_map(
         lambda p, t: forward(p, t, CFG, ax)[0], mesh=mesh,
         in_specs=(P(), P()), out_specs=P(), check_vma=False)
     with pytest.raises(ValueError, match="not divisible"):
