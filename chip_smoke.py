@@ -25,14 +25,16 @@ through the entry points a user calls, at full width per chip:
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
 whose ``device_kind`` is in bench.PEAK_BF16_FLOPS: a CPU fallback is an
-error, not a slower run.  On success the last line of stdout is one
-JSON object, ``{"ok": true, "device": {"platform": "tpu", ...}, ...}``.
-Step and request times in it are orientation, not a benchmark.
+error, not a slower run.  On success stdout ends with two JSON lines:
+the report (versions, compile-cache counters, every leg's numbers,
+``"claim": null``) and then, last, the verdict with exactly these keys:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": n}}``.
+Step and request times in the report are orientation, not a benchmark.
 
 ``--dry-run`` exists for the test suite: the same legs at toy widths on
-whatever platform jax has, Pallas in interpret mode.  Its result line
-says ``"ok": false, "dry_run": "passed"`` and names the platform, so it
-cannot be read as a pass on the chip.
+whatever platform jax has, Pallas in interpret mode.  Its verdict says
+``"ok": false`` and names the platform, and its report says
+``"dry_run": "passed"``, so it cannot be read as a pass on the chip.
 """
 
 from __future__ import annotations
@@ -612,7 +614,6 @@ def main() -> int:
     args = ap.parse_args()
     dry = args.dry_run
 
-
     import bench
 
     # A TPU whose device_kind is in the peak table, or no run at all.
@@ -682,9 +683,8 @@ def main() -> int:
               file=sys.stderr, flush=True)
     hvd.shutdown()
 
-    out = {
-        "ok": not dry and len(legs) == len(plan),
-        "device": device,
+    # The report: orientation for whoever reads the run, second to last.
+    report = {
         "platform": device["platform"],
         "device_kind": device["kind"],
         "n": device["count"],
@@ -698,9 +698,12 @@ def main() -> int:
         "legs": legs,
     }
     if dry:
-        out["dry_run"] = "passed"
-    out["claim"] = None
-    print(json.dumps(out))
+        report["dry_run"] = "passed"
+    report["claim"] = None
+    print(json.dumps(report))
+    # The verdict: the last line, these two keys and no others.
+    print(json.dumps({"ok": not dry and len(legs) == len(plan),
+                      "device": device}), flush=True)
     return 0
 
 

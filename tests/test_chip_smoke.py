@@ -32,10 +32,15 @@ def test_chip_smoke_refuses_the_cpu_and_names_it():
 def test_chip_smoke_dry_run_reaches_every_leg():
     proc = _run("--dry-run", timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    # Never mistakable for a pass on the chip.
-    assert out["ok"] is False and out["dry_run"] == "passed"
-    assert out["device"]["platform"] == "cpu" and out["platform"] == "cpu"
+    report, verdict = map(json.loads, proc.stdout.strip().splitlines()[-2:])
+    # The last line has the driver's two keys and no others, and is
+    # never mistakable for a pass on the chip.
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is False
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert verdict["device"]["platform"] == "cpu"
+    assert verdict["device"]["count"] == 8
+    out = report
+    assert out["dry_run"] == "passed" and out["platform"] == "cpu"
     assert out["n"] == 8 and out["claim"] is None
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
