@@ -41,6 +41,7 @@ from typing import Any, Optional
 
 import jax
 
+from .. import trace as _trace
 from ..analysis import lockorder as _lockorder
 from ..analysis import races as _races
 
@@ -157,6 +158,14 @@ def _build_mesh(devices) -> jax.sharding.Mesh:
     return jax.sharding.Mesh(np.asarray(devices), (REPLICA_AXIS,))
 
 
+# hvd-trace regions of set-up.  init() resets the span buffer half way
+# (trace.reset_run), so their readers take the registry's
+# trace.span_seconds.init.* histograms, which outlive it.
+_R_INIT_DEVICES = _trace.region("init.devices", "init")
+_R_INIT_CONTROL_PLANE = _trace.region("init.control_plane", "init")
+_R_INIT_WARM_START = _trace.region("init.megakernel_warm_start", "init")
+
+
 def init(devices=None) -> None:
     """Initialize horovod_tpu.
 
@@ -215,9 +224,15 @@ def init(devices=None) -> None:
     # (≙ MPI_Init_thread before MPI_Comm_rank, operations.cc:1173-1181).
     from . import cluster as _cluster
 
-    spec = _cluster.maybe_initialize()
+    with _R_INIT_DEVICES() as r:
+        # Backend start-up and device discovery: the first jax call of
+        # the process pays for the client.
+        spec = _cluster.maybe_initialize()
+        process_index = jax.process_index()
+        devs = tuple(devices if devices is not None else jax.devices())
+        r.note(devices=len(devs))
     with _state.lock:
-        _state.process_index = jax.process_index()
+        _state.process_index = process_index
         _state.process_count = jax.process_count()
         _state.multiprocess = _state.process_count > 1
         if _state.multiprocess and devices is not None:
@@ -226,7 +241,6 @@ def init(devices=None) -> None:
                 "multi-process mode every process must use the full global "
                 "topology (the reference likewise fixes the communicator "
                 "at MPI_COMM_WORLD).")
-        devs = tuple(devices if devices is not None else jax.devices())
         _state.devices = devs
         _state.mesh = _build_mesh(devs)
         _state.size = len(devs)
@@ -256,74 +270,79 @@ def init(devices=None) -> None:
         else:
             _state.timeline = None
 
-        from ..ops.handles import HandleManager
+        # The control plane: handles, response cache, coordinator and
+        # (multi-process) the TCP transport with its HELLO handshake.
+        with _R_INIT_CONTROL_PLANE():
+            from ..ops.handles import HandleManager
 
-        _state.handle_manager = HandleManager()
+            _state.handle_manager = HandleManager()
 
-        from ..ops import cache as _cache
-        from ..ops.coordinator import Coordinator
+            from ..ops import cache as _cache
+            from ..ops.coordinator import Coordinator
 
-        _state.response_cache = (
-            _cache.ResponseCache(rank=_state.process_index)
-            if _cache.cache_enabled() else None)
+            _state.response_cache = (
+                _cache.ResponseCache(rank=_state.process_index)
+                if _cache.cache_enabled() else None)
 
-        if _state.multiprocess:
-            # Reference topology: negotiation runs at process (MPI-rank)
-            # granularity, with rank 0 as the coordinator and a TCP control
-            # plane carrying the wire messages (≙ operations.cc:1226-1374).
-            from ..ops import transport as _transport
+            if _state.multiprocess:
+                # Reference topology: negotiation runs at process
+                # (MPI-rank) granularity, with rank 0 as the coordinator
+                # and a TCP control plane carrying the wire messages
+                # (≙ operations.cc:1226-1374).
+                from ..ops import transport as _transport
 
-            if spec is None:
-                raise RuntimeError(
-                    "jax.distributed is active but no HVD_TPU_COORDINATOR/"
-                    "JAX_COORDINATOR_ADDRESS is visible; the eager control "
-                    "plane needs it to locate the rank-0 controller.")
-            # Tree overlay (ops/tree.py, ROADMAP "thousand-rank control
-            # plane"): above HVD_TPU_TREE_THRESHOLD ranks the star
-            # becomes a fanout-ary tree — interiors aggregate their
-            # subtree's control traffic and relay broadcasts, so rank
-            # 0's per-tick frame count drops from O(world) to O(fanout).
-            from ..ops import tree as _tree
+                if spec is None:
+                    raise RuntimeError(
+                        "jax.distributed is active but no "
+                        "HVD_TPU_COORDINATOR/JAX_COORDINATOR_ADDRESS is "
+                        "visible; the eager control plane needs it to "
+                        "locate the rank-0 controller.")
+                # Tree overlay (ops/tree.py, ROADMAP "thousand-rank control
+                # plane"): above HVD_TPU_TREE_THRESHOLD ranks the star
+                # becomes a fanout-ary tree — interiors aggregate their
+                # subtree's control traffic and relay broadcasts, so rank
+                # 0's per-tick frame count drops from O(world) to O(fanout).
+                from ..ops import tree as _tree
 
-            layout = (_tree.build_layout(_state.process_count)
-                      if _tree.tree_active(_state.process_count)
-                      else None)
-            if _state.process_index == 0:
+                layout = (_tree.build_layout(_state.process_count)
+                          if _tree.tree_active(_state.process_count)
+                          else None)
+                if _state.process_index == 0:
+                    _state.coordinator = Coordinator(
+                        size=_state.process_count,
+                        fusion_threshold=_state.fusion_threshold_bytes,
+                        timeline=_state.timeline,
+                        cache=_state.response_cache,
+                    )
+                    _state.transport = _transport.ControllerTransport(
+                        _state.coordinator, _state.process_count,
+                        spec.controller_port, tree=layout)
+                    _state.topology = _state.transport.topology[0]
+                else:
+                    _state.coordinator = None
+                    if layout is not None:
+                        _state.transport = _tree.TreeWorkerTransport(
+                            spec.controller_host, spec.controller_port,
+                            _state.process_index, layout)
+                    else:
+                        _state.transport = _transport.WorkerTransport(
+                            spec.controller_host, spec.controller_port,
+                            _state.process_index)
+                    _state.topology = _state.transport.topology
+                    if not _state.transport.controller_cache:
+                        # Rank 0 advertised no response cache (its env
+                        # disables it, or its program tracker is armed): a
+                        # local replica would emit bits rank 0 can never
+                        # resolve — run cache-less instead.
+                        _state.response_cache = None
+                _state.transport.cache = _state.response_cache
+            else:
                 _state.coordinator = Coordinator(
-                    size=_state.process_count,
+                    size=_state.size,
                     fusion_threshold=_state.fusion_threshold_bytes,
                     timeline=_state.timeline,
                     cache=_state.response_cache,
                 )
-                _state.transport = _transport.ControllerTransport(
-                    _state.coordinator, _state.process_count,
-                    spec.controller_port, tree=layout)
-                _state.topology = _state.transport.topology[0]
-            else:
-                _state.coordinator = None
-                if layout is not None:
-                    _state.transport = _tree.TreeWorkerTransport(
-                        spec.controller_host, spec.controller_port,
-                        _state.process_index, layout)
-                else:
-                    _state.transport = _transport.WorkerTransport(
-                        spec.controller_host, spec.controller_port,
-                        _state.process_index)
-                _state.topology = _state.transport.topology
-                if not _state.transport.controller_cache:
-                    # Rank 0 advertised no response cache (its env
-                    # disables it, or its program tracker is armed): a
-                    # local replica would emit bits rank 0 can never
-                    # resolve — run cache-less instead.
-                    _state.response_cache = None
-            _state.transport.cache = _state.response_cache
-        else:
-            _state.coordinator = Coordinator(
-                size=_state.size,
-                fusion_threshold=_state.fusion_threshold_bytes,
-                timeline=_state.timeline,
-                cache=_state.response_cache,
-            )
 
         # hvd-tune (HVD_TPU_TUNE=1; HOROVOD_AUTOTUNE=1 is the deprecated
         # round-4 sweep alias): collector on every rank, controller on
@@ -387,12 +406,17 @@ def init(devices=None) -> None:
     # the megakernel executables the previous incarnation recorded
     # there, so an elastic relaunch (or any repeat run) skips the
     # cold-compile stall on its first training steps.
-    cache_dir = configure_compile_cache()
-    if cache_dir and not _state.multiprocess:
-        # The manifest holds single-process group variants only.
-        from ..ops import megakernel as _megakernel
+    # The region runs on every init, also with nothing to warm
+    # (``entries`` 0): a reader finds its histogram in every process.
+    with _R_INIT_WARM_START() as r:
+        cache_dir = configure_compile_cache()
+        entries = 0
+        if cache_dir and not _state.multiprocess:
+            # The manifest holds single-process group variants only.
+            from ..ops import megakernel as _megakernel
 
-        _megakernel.warm_start(_state.mesh, cache_dir)
+            entries = _megakernel.warm_start(_state.mesh, cache_dir)
+        r.note(entries=entries)
     # hvd-mem pre-flight (docs/memory.md): when the per-rank HBM
     # capacity is known (backend memory_stats or HVD_TPU_MEM_CAPACITY),
     # size the largest recorded executable — the warm-start manifest's

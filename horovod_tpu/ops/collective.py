@@ -90,9 +90,11 @@ class HorovodError(RuntimeError):
 
 
 # hvd-telemetry instrumentation (docs/metrics.md).  Event-granularity
-# budget: _enqueue and the response executor each spend exactly one
-# perf_counter pair per event; the per-submit steady-state hot path
-# (cache hits) is instrumented pull-side from CacheStats instead.
+# budget: _enqueue spends one ``time.monotonic`` read per op and the
+# response executor one pair per response (its ``execute/<op>`` region's:
+# the histogram and the span share them); the per-submit steady-state
+# hot path (cache hits) is instrumented pull-side from CacheStats
+# instead.
 _M_SUBMITTED = _telemetry.counter(
     "collective.submitted", "eager collectives entering negotiation")
 _M_COMPLETED = _telemetry.counter(
@@ -1233,13 +1235,11 @@ class _QueuedOp:
     # True when negotiation was served from the response cache — rides
     # the timeline EXECUTE span so cache wins are visible per tensor.
     cache_hit: bool = False
-    # perf_counter at enqueue: the telemetry negotiate-latency stamp
-    # (the one clock read this op spends before execution).
+    # time.monotonic at enqueue, the one clock read this op spends
+    # before execution: the telemetry negotiate-latency stamp AND the
+    # start of the hvd-trace negotiate.wait span (the clock the offset
+    # estimator aligns); 0.0 = telemetry and tracing both off.
     t_submit: float = 0.0
-    # monotonic at enqueue (hvd-trace): start of the negotiate.wait
-    # span.  Separate stamp because spans must live on the clock the
-    # offset estimator aligns; 0.0 = tracing disabled at enqueue.
-    t_submit_mono: float = 0.0
 
 
 @_races.race_checked
@@ -1383,68 +1383,89 @@ _DATA_RESPONSES = (ResponseType.ALLREDUCE, ResponseType.ALLGATHER,
                    ResponseType.BROADCAST, ResponseType.REDUCESCATTER,
                    ResponseType.ALLTOALL)
 
+# hvd-trace regions (trace/__init__.py): built once, like the _M_*
+# handles.  ``execute/<op>`` is timed: collective.execute_seconds reads
+# the region's own pair of clock reads.
+_R_EXECUTE = _trace.RegionFamily("execute/", "dispatch", timed=True)
+_R_TICK = _trace.region("negotiate.tick", "negotiate")
+
+
+def _close_tick(tick, resps) -> None:
+    """End of one coordinator drain tick inside its ``negotiate.tick``
+    region: a tick that produced responses advances the fleet-wide
+    cycle id BEFORE the broadcast (the frame's trace trailer and every
+    rank's execution spans then share it) and keeps its span; an empty
+    tick — 200 a second on the background thread — keeps nothing."""
+    if resps and _trace.enabled():
+        _trace.next_cycle()
+        tick.note(responses=len(resps))
+    else:
+        tick.cancel()
+
 
 def _execute_response(resp: Response, ops: List[_QueuedOp]) -> None:
-    """Telemetry shell around :func:`_execute_response_inner`: one
-    perf_counter pair per response feeds the negotiate- and
-    execute-latency histograms, payload bytes and fusion-group width;
+    """Telemetry shell around :func:`_execute_response_inner`: the
+    ``execute/<op>`` region's one pair of clock reads per response
+    feeds the span, the negotiate- and execute-latency histograms,
+    payload bytes and fusion-group width;
     ERROR and dead-peer SHUTDOWN responses additionally dump the flight
     ring — the forensic record of the 2000 control-plane events that
     led here."""
     tracing = _trace.enabled()
     if not _telemetry.enabled() and not tracing:
         return _execute_response_inner(resp, ops)
-    t0 = time.perf_counter()
-    mt0 = time.monotonic() if tracing else 0.0
     is_data = resp.response_type in _DATA_RESPONSES
-    if _telemetry.enabled():
-        for o in ops:
-            if o.t_submit:
-                _M_NEGOTIATE_S.observe(t0 - o.t_submit)
-            _M_PAYLOAD_B.observe(o.nbytes)
-        if is_data:
-            _M_GROUP_WIDTH.observe(len(resp.tensor_names))
-        elif resp.response_type == ResponseType.ERROR:
-            _M_ERRORS.inc(max(len(ops), 1))
-            _telemetry.error_event(resp.error_message or "")
-        elif resp.response_type == ResponseType.SHUTDOWN and \
-                wire.DEAD_PEER_MARKER in (resp.error_message or ""):
-            # Worker-side dead-peer poison (the controller side dumps in
-            # _handle_lost_ranks before broadcasting this diagnosis).
-            _telemetry.dead_peer_event(resp.error_message or "")
-    out = _execute_response_inner(resp, ops)
+    # hvd-trace: the dispatch span — the response execution (pack +
+    # launch + unpack); the launch span it contains
+    # (ops/megakernel.launch) lets the analyzer carve it into pack /
+    # collective / dcn / unpack legs.  ERROR responses trace too (the
+    # error path is real work and the control-plane-only tests ride
+    # it); the completed counter below stays data-only.
+    with _R_EXECUTE[resp.response_type.name.lower()](
+            tensors=len(resp.tensor_names),
+            first=resp.tensor_names[0] if resp.tensor_names else "") as r:
+        if not (ops and (is_data
+                         or resp.response_type == ResponseType.ERROR)):
+            r.cancel()
+        t0 = r.t0
+        if _telemetry.enabled():
+            for o in ops:
+                if o.t_submit:
+                    _M_NEGOTIATE_S.observe(t0 - o.t_submit)
+                _M_PAYLOAD_B.observe(o.nbytes)
+            if is_data:
+                _M_GROUP_WIDTH.observe(len(resp.tensor_names))
+            elif resp.response_type == ResponseType.ERROR:
+                _M_ERRORS.inc(max(len(ops), 1))
+                _telemetry.error_event(resp.error_message or "")
+            elif resp.response_type == ResponseType.SHUTDOWN and \
+                    wire.DEAD_PEER_MARKER in (resp.error_message or ""):
+                # Worker-side dead-peer poison (the controller side dumps
+                # in _handle_lost_ranks before broadcasting this
+                # diagnosis).
+                _telemetry.dead_peer_event(resp.error_message or "")
+        if tracing and ops and (is_data or resp.response_type
+                                == ResponseType.ERROR):
+            # The negotiate.wait span — this rank's local submit up to
+            # execution; it starts in the past, so it stays a plain
+            # span.  Every participating rank's wait span for one
+            # collective CONTAINS the shared window [last submit,
+            # broadcast], so same-(step, cycle) spans are guaranteed to
+            # overlap across ranks once clocks are aligned — the fleet
+            # -trace acceptance property.
+            t_neg = min((o.t_submit for o in ops if o.t_submit > 0.0),
+                        default=0.0)
+            if t_neg:
+                _trace.span("negotiate.wait", "negotiate", t_neg, t0,
+                            args={"tensors": len(resp.tensor_names)})
+        out = _execute_response_inner(resp, ops)
     # Counted AFTER a successful data launch only: an ERROR/SHUTDOWN
     # response (or an exception from the executor) must not inflate the
     # success counter — "failed = submitted - completed" has to read
     # true during a failure storm.
     if ops and is_data and _telemetry.enabled():
         _M_COMPLETED.inc(len(ops))
-        _M_EXECUTE_S.observe(time.perf_counter() - t0)
-    if ops and tracing and (is_data
-                            or resp.response_type == ResponseType.ERROR):
-        # hvd-trace: (1) the negotiate.wait span — this rank's local
-        # submit up to execution.  Every participating rank's wait span
-        # for one collective CONTAINS the shared window [last submit,
-        # broadcast], so same-(step, cycle) spans are guaranteed to
-        # overlap across ranks once clocks are aligned — the fleet
-        # -trace acceptance property.  (2) the dispatch span — the
-        # response execution (pack + launch + unpack); the launch span
-        # it contains (ops/megakernel.launch) lets the analyzer carve
-        # it into pack / collective / dcn / unpack legs.  ERROR
-        # responses trace too (the error path is real work and the
-        # control-plane-only tests ride it); the completed counter
-        # above stays data-only.
-        t_neg = min((o.t_submit_mono for o in ops
-                     if o.t_submit_mono > 0.0), default=0.0)
-        if t_neg:
-            _trace.span("negotiate.wait", "negotiate", t_neg, mt0,
-                        args={"tensors": len(resp.tensor_names)})
-        _trace.span(
-            f"execute/{resp.response_type.name.lower()}",
-            "dispatch", mt0, time.monotonic(),
-            args={"tensors": len(resp.tensor_names),
-                  "first": resp.tensor_names[0]
-                  if resp.tensor_names else ""})
+        _M_EXECUTE_S.observe(r.seconds)
     return out
 
 
@@ -2204,18 +2225,11 @@ def _drain() -> None:
                 # (≙ MPI_Bcast of the response list, operations.cc:1290).
                 tp.flush_unrouted()  # set requests that beat registration
                 tp.maybe_ping()  # hvd-trace clock probes (trace/clock.py)
-                tick_t0 = time.monotonic() if _trace.enabled() else 0.0
-                resps, groups, epoch, compact, n_other, replay_ids = \
-                    _coordinator_tick(st)
+                with _R_TICK() as tick:
+                    resps, groups, epoch, compact, n_other, replay_ids \
+                        = _coordinator_tick(st)
+                    _close_tick(tick, resps)
                 if resps:
-                    # Advance the fleet-wide cycle id BEFORE the
-                    # broadcast: the frame's trace trailer and every
-                    # rank's execution spans then share it.
-                    if _trace.enabled():
-                        _trace.next_cycle()
-                        _trace.span("negotiate.tick", "negotiate",
-                                    tick_t0, time.monotonic(),
-                                    args={"responses": len(resps)})
                     # The controller reaches its own cache stream
                     # position BEFORE publishing the stream: a fast
                     # worker can observe the frame, hit its fresh
@@ -2263,15 +2277,12 @@ def _drain() -> None:
                                     if o.request is not None}})
                         _execute_response(resp, ops)
             return
-        tick_t0 = time.monotonic() if _trace.enabled() else 0.0
-        resps, _groups, _epoch, _compact, _n, replay_ids = \
-            _coordinator_tick(st)
-        if resps and _trace.enabled():
+        with _R_TICK() as tick:
+            resps, _groups, _epoch, _compact, _n, replay_ids = \
+                _coordinator_tick(st)
             # Single-process cycles advance the same counter so the
             # local trace analyzes identically to a fleet's.
-            _trace.next_cycle()
-            _trace.span("negotiate.tick", "negotiate", tick_t0,
-                        time.monotonic(), args={"responses": len(resps)})
+            _close_tick(tick, resps)
         for resp in resps:
             ops = _queue.take(resp.tensor_names)
             if cache is not None:
@@ -2409,10 +2420,9 @@ def _enqueue(x, op: RequestType, name: Optional[str],
     qop = _QueuedOp(name=name, op=op, contrib=c, red_op=red_op,
                     root_rank=root_rank, handle=handle, nbytes=nbytes,
                     ps=process_set,
-                    t_submit=(time.perf_counter()
-                              if _telemetry.enabled() else 0.0),
-                    t_submit_mono=(time.monotonic()
-                                   if _trace.enabled() else 0.0))
+                    t_submit=(time.monotonic()
+                              if _telemetry.enabled() or _trace.enabled()
+                              else 0.0))
     _M_SUBMITTED.inc()
     _queue.put(qop)
     # The execute paths read split info from the NEGOTIATED response

@@ -105,6 +105,7 @@ from .wire import ReduceOp
 _M_WIRE_BYTES = _telemetry.histogram(
     "collective.wire_bytes", "bytes",
     "wire-format bytes per fused collective launch")
+_R_LAUNCH = _trace.RegionFamily("megakernel/", "collective")
 
 # Compiled-executable cache bound: a stable program needs one entry per
 # (fusion group structure x mesh); jittery tick partitioning can mint a
@@ -1157,8 +1158,6 @@ def launch(spec: GroupSpec, mesh, values: Sequence,
             lambda: _launch_name(spec),
             _mem_planner.fusion_group_device_bytes(spec.shapes,
                                                    spec.dtype))
-    trace_t0 = time.monotonic() if trace_on else 0.0
-
     # hvd-race donation sanitizer: every launch routes through the
     # registry — re-dispatching a buffer a previous launch donated
     # raises a DonationError naming THAT launch, and this launch's
@@ -1180,55 +1179,52 @@ def launch(spec: GroupSpec, mesh, values: Sequence,
         return out
 
     counting = _xla_dispatch.counting_enabled()
-    if mem_on:
-        _mem.ledger.alloc("megakernel.fusion", fusion_b)
-    try:
-        if counting:
-            probes = [weakref.ref(v)
-                      for v, d in zip(values, mask) if d]
-            with _xla_dispatch.record() as scope:
-                outs = dispatch()
-            with _lock:
-                stats.launches += 1
-                stats.launch_dispatches += scope.count
-                stats.donated_inputs += sum(mask)
-                stats.logical_bytes += logical_b
-                stats.wire_bytes += wire_b
-                if spec.hier is not None:
-                    stats.hier_launches += 1
-                if _needs_quant_build(spec):
-                    stats.quant_launches += 1
-                last_donated[:] = probes
-        else:
-            outs = dispatch()
-            with _lock:
-                stats.launches += 1
-                stats.donated_inputs += sum(mask)
-                stats.logical_bytes += logical_b
-                stats.wire_bytes += wire_b
-                if spec.hier is not None:
-                    stats.hier_launches += 1
-                if _needs_quant_build(spec):
-                    stats.quant_launches += 1
-    except Exception as e:  # noqa: BLE001 — re-raised: forensics only
-        if _oom.is_resource_exhausted(e):
-            _oom.oom_event(_launch_name(spec), e, fusion_b or None)
-        raise
-    finally:
+    # hvd-trace launch region: the compiled collective itself.  The
+    # wire-byte legs let the analyzer split a hierarchical launch's
+    # time into its ICI ("collective") and DCN shares; mem_bytes
+    # mirrors the ledger charge so the fleet trace shows each
+    # launch's HBM footprint next to its wall time (hvd-mem).
+    with _R_LAUNCH[spec.op](groups=len(spec.shapes),
+                            hier=spec.hier is not None,
+                            wire_bytes=wire_b, dcn_bytes=dcn_b,
+                            mem_bytes=fusion_b):
         if mem_on:
-            _mem.ledger.free("megakernel.fusion", fusion_b)
+            _mem.ledger.alloc("megakernel.fusion", fusion_b)
+        try:
+            if counting:
+                probes = [weakref.ref(v)
+                          for v, d in zip(values, mask) if d]
+                with _xla_dispatch.record() as scope:
+                    outs = dispatch()
+                with _lock:
+                    stats.launches += 1
+                    stats.launch_dispatches += scope.count
+                    stats.donated_inputs += sum(mask)
+                    stats.logical_bytes += logical_b
+                    stats.wire_bytes += wire_b
+                    if spec.hier is not None:
+                        stats.hier_launches += 1
+                    if _needs_quant_build(spec):
+                        stats.quant_launches += 1
+                    last_donated[:] = probes
+            else:
+                outs = dispatch()
+                with _lock:
+                    stats.launches += 1
+                    stats.donated_inputs += sum(mask)
+                    stats.logical_bytes += logical_b
+                    stats.wire_bytes += wire_b
+                    if spec.hier is not None:
+                        stats.hier_launches += 1
+                    if _needs_quant_build(spec):
+                        stats.quant_launches += 1
+        except Exception as e:  # noqa: BLE001 — re-raised: forensics only
+            if _oom.is_resource_exhausted(e):
+                _oom.oom_event(_launch_name(spec), e, fusion_b or None)
+            raise
+        finally:
+            if mem_on:
+                _mem.ledger.free("megakernel.fusion", fusion_b)
     if _telemetry.enabled():
         _M_WIRE_BYTES.observe(wire_b)
-    if _trace.enabled():
-        # hvd-trace launch span: the compiled collective itself.  The
-        # wire-byte legs let the analyzer split a hierarchical launch's
-        # time into its ICI ("collective") and DCN shares; mem_bytes
-        # mirrors the ledger charge so the fleet trace shows each
-        # launch's HBM footprint next to its wall time (hvd-mem).
-        _trace.span(f"megakernel/{spec.op}", "collective", trace_t0,
-                    time.monotonic(),
-                    args={"groups": len(spec.shapes),
-                          "hier": spec.hier is not None,
-                          "wire_bytes": wire_b, "dcn_bytes": dcn_b,
-                          "mem_bytes": fusion_b})
     return outs

@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Iterable, Iterator, Optional
 
 import jax
@@ -64,6 +63,7 @@ from ..telemetry import flight as _flight
 _M_STALL = _telemetry.histogram(
     "host.stall_seconds", "seconds",
     "time the training loop blocked waiting on the input queue")
+_R_WAIT = _trace.region("prefetch.wait", "host", timed=True)
 _M_STAGED = _telemetry.counter(
     "input.batches_staged", "batches staged host->device by prefetchers")
 _M_DEPTH = _telemetry.gauge(
@@ -199,26 +199,27 @@ class PrefetchIterator:
             item = self._q.get_nowait()
         except queue.Empty:
             # The stall the prefetch could not hide: the loader (or the
-            # transfer) is slower than the step.  One perf_counter pair,
-            # blocked path only.  Timed gets so a close() from another
-            # thread (which enqueues nothing) wakes this consumer too.
-            t0 = time.perf_counter()
-            mt0 = time.monotonic() if _trace.enabled() else 0.0
-            while True:
-                try:
-                    item = self._q.get(timeout=0.05)
-                    break
-                except queue.Empty:
-                    if self._stop.is_set():
-                        _M_STALL.observe(time.perf_counter() - t0)
-                        raise StopIteration from None
-            _M_STALL.observe(time.perf_counter() - t0)
-            if _trace.enabled():
-                # hvd-trace host span: the analyzer's "this rank was
-                # input-bound" signal — the blame category a seeded
-                # slow loader must surface under (docs/tracing.md).
-                _trace.span("prefetch.wait", "host", mt0,
-                            time.monotonic())
+            # transfer) is slower than the step.  One pair of clock
+            # reads, blocked path only: the prefetch.wait region's,
+            # which host.stall_seconds reads too — the analyzer's
+            # "this rank was input-bound" signal, the blame category a
+            # seeded slow loader must surface under (docs/tracing.md).
+            # Timed gets so a close() from another thread (which
+            # enqueues nothing) wakes this consumer too.
+            stopped = False
+            with _R_WAIT() as r:
+                while True:
+                    try:
+                        item = self._q.get(timeout=0.05)
+                        break
+                    except queue.Empty:
+                        if self._stop.is_set():
+                            stopped = True
+                            r.cancel()
+                            break
+            _M_STALL.observe(r.seconds)
+            if stopped:
+                raise StopIteration from None
         _M_DEPTH.set(self._q.qsize())
         if item is _END:
             self._stop.set()

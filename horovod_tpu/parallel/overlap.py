@@ -128,6 +128,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .. import telemetry as _telemetry
+from .. import trace as _trace
 from ..core import state as _state
 from ..core.state import REPLICA_AXIS
 from ..ops import collective as C
@@ -162,6 +163,15 @@ _M_EXPOSED = _telemetry.histogram(
 _M_HOST_STALL = _telemetry.histogram(
     "host.stall_seconds", "seconds",
     "time the training loop blocked waiting on the input queue")
+
+# hvd-trace regions of one stream step (docs/tracing.md): where the
+# host is between two programs of the schedule.  Per program dispatch
+# and per bucket, never per tensor.
+_R_BACKWARD = _trace.region("stream.backward", "stream")
+_R_SUBMIT = _trace.region("stream.submit", "stream")
+_R_DRAIN = _trace.region("stream.drain", "stream")
+_R_TAKE = _trace.region("stream.take", "stream", timed=True)
+_R_APPLY = _trace.region("stream.apply", "stream")
 
 
 def overlap_mode() -> str:
@@ -413,11 +423,14 @@ def dispatch_bucket_segment(prefix: str, seg: _Segment, seg_leaves: List,
         base = f"{prefix}.g{b.gi}"
         if mp:
             tensors = [t.addressable_data(0) for t in tensors]
-        with C._drain_lock:
-            hs = C.grouped_allreduce_async(
-                tensors, op=ReduceOp.SUM, name=base,
-                donate_inputs=not mp)
-        C._drain()
+        with _R_SUBMIT(bucket=b.gi, tensors=len(tensors),
+                       bytes=b.nbytes):
+            with C._drain_lock:
+                hs = C.grouped_allreduce_async(
+                    tensors, op=ReduceOp.SUM, name=base,
+                    donate_inputs=not mp)
+        with _R_DRAIN(bucket=b.gi):
+            C._drain()
         for idx, h in zip(b.global_idx, hs):
             handles[idx] = h
         _M_BUCKETS.inc()
@@ -866,21 +879,24 @@ class _OverlapStep:
 
         if self._segmented:
             chain_params = list(params)
-            loss, carries = self._fwd_program(chain_params, batch)
+            with _R_BACKWARD(program="fwd"):
+                loss, carries = self._fwd_program(chain_params, batch)
             segs = self._plan.segments
             S = len(segs)
             staged = []  # serial schedule: submit only after the fence
             ct = None
             for k in range(S - 1, -1, -1):
-                if k == S - 1:
-                    g, ct = self._bwd_programs[k](
-                        chain_params[k], carries[k - 1], batch)
-                elif k == 0:
-                    g = self._bwd_programs[k](chain_params[k], batch, ct)
-                    ct = None
-                else:
-                    g, ct = self._bwd_programs[k](
-                        chain_params[k], carries[k - 1], batch, ct)
+                with _R_BACKWARD(program="bwd", segment=k):
+                    if k == S - 1:
+                        g, ct = self._bwd_programs[k](
+                            chain_params[k], carries[k - 1], batch)
+                    elif k == 0:
+                        g = self._bwd_programs[k](chain_params[k], batch,
+                                                  ct)
+                        ct = None
+                    else:
+                        g, ct = self._bwd_programs[k](
+                            chain_params[k], carries[k - 1], batch, ct)
                 if window is not None:
                     window.admit((g, ct))
                 seg_leaves = jax.tree_util.tree_leaves(g)
@@ -897,8 +913,9 @@ class _OverlapStep:
                 for seg, seg_leaves in staged:
                     self._submit_segment(seg, seg_leaves, handles, tl)
         else:
-            loss, grads_pr, extra = self._grads_program(
-                params, model_state, batch)
+            with _R_BACKWARD(program="grads"):
+                loss, grads_pr, extra = self._grads_program(
+                    params, model_state, batch)
             if window is not None:
                 window.admit(grads_pr)
             seg_leaves = jax.tree_util.tree_leaves(grads_pr)
@@ -907,14 +924,16 @@ class _OverlapStep:
             self._submit_segment(self._plan.segments[0], seg_leaves,
                                  handles, tl)
 
-        t0 = time.perf_counter()
-        reduced = [C.take_async(h) for h in handles]
-        if not stream:
-            jax.block_until_ready(reduced)
-        if _telemetry.enabled():
-            _M_EXPOSED.observe(time.perf_counter() - t0)
-        red_tree = jax.tree_util.tree_unflatten(self._treedef, reduced)
-        new_params, opt_state = self._apply(red_tree, opt_state, params)
+        with _R_TAKE(handles=len(handles)) as took:
+            reduced = [C.take_async(h) for h in handles]
+            if not stream:
+                jax.block_until_ready(reduced)
+        _M_EXPOSED.observe(took.seconds)
+        with _R_APPLY():
+            red_tree = jax.tree_util.tree_unflatten(self._treedef,
+                                                    reduced)
+            new_params, opt_state = self._apply(red_tree, opt_state,
+                                                params)
         if self._has_state:
             return new_params, extra, opt_state, loss
         if self._has_aux:

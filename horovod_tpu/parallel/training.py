@@ -46,6 +46,9 @@ except Exception:  # pragma: no cover - optax is baked into the image
 _M_HOST_STALL = _telemetry.histogram(
     "host.stall_seconds", "seconds",
     "time the training loop blocked waiting on the input queue")
+# hvd-trace: one region per step call, named by what was built
+# (step/stream, step/serial, step/monolithic, step/parallel).
+_R_STEP = _trace.RegionFamily("step/", "step")
 
 
 def batch_sharding(mesh=None) -> NamedSharding:
@@ -193,11 +196,15 @@ class _TracedStep:
     gauge), and — first call only — pre-flight-warn when the working
     set this step implies (params + gradients + optimizer slots +
     batch) exceeds the advertised HBM capacity (memory/oom.py).
-    Arithmetic is untouched; the jit surface passes through like
-    :class:`_ThrottledStep`'s."""
+    The call of the step itself is one ``step/<kind>`` region: the host
+    time of a step, on the profiler's clock too.  ``kind`` None asks the
+    overlap step what it built (its schedule, or ``monolithic`` after a
+    fallback).  Arithmetic is untouched; the jit surface passes through
+    like :class:`_ThrottledStep`'s."""
 
-    def __init__(self, step_fn):
+    def __init__(self, step_fn, kind: Optional[str]):
         self._step_fn = step_fn
+        self._kind = kind
         self._preflighted = False
 
     def _preflight(self, args) -> None:
@@ -222,7 +229,12 @@ class _TracedStep:
             _trace.on_step()
         if not self._preflighted:
             self._preflight(args)
-        out = self._step_fn(*args, **kw)
+        kind = self._kind
+        if kind is None:
+            fn = self._step_fn
+            kind = fn.schedule if fn.overlap_active else "monolithic"
+        with _R_STEP[kind]():
+            out = self._step_fn(*args, **kw)
         if _mem.enabled():
             _mem.ledger.note_step()
         return out
@@ -231,8 +243,8 @@ class _TracedStep:
         return getattr(self._step_fn, name)
 
 
-def _traced(step_fn):
-    return _TracedStep(step_fn)
+def _traced(step_fn, kind: Optional[str]):
+    return _TracedStep(step_fn, kind)
 
 
 def _make_step(loss_fn, optimizer, mesh, average, fusion_threshold,
@@ -281,10 +293,14 @@ def _make_step(loss_fn, optimizer, mesh, average, fusion_threshold,
             loss_fn, optimizer, mesh, red_op, fusion_threshold, has_aux,
             donate, has_state, compression, stream=schedule == "stream",
             fallback_builder=fallback_builder)
-        return _traced(_throttle_on_cpu(step, mesh))
+        return _traced(
+            _throttle_on_cpu(step, mesh),
+            None if isinstance(step, _overlap._OverlapStep)
+            else "monolithic")
     return _traced(_build_static_step(loss_fn, optimizer, mesh, average,
                                       fusion_threshold, has_aux, donate,
-                                      has_state, op, compression))
+                                      has_state, op, compression),
+                   "monolithic")
 
 
 def _build_static_step(loss_fn, optimizer, mesh, average, fusion_threshold,
@@ -446,7 +462,7 @@ def make_parallel_train_step(loss_fn: Callable[..., Any], optimizer,
 
     donate_argnums = (0, 1) if donate else ()
     return _traced(_throttle_on_cpu(
-        jax.jit(step, donate_argnums=donate_argnums), mesh))
+        jax.jit(step, donate_argnums=donate_argnums), mesh), "parallel")
 
 
 def shard_parallel_batch(batch, mesh, batch_spec):

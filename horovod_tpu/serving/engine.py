@@ -68,6 +68,9 @@ _M_TTFT = _telemetry.histogram(
 _M_TOKEN_LAT = _telemetry.histogram(
     "serving.token_seconds", "seconds",
     "per-token decode latency (one continuous-batching iteration)")
+_M_QUEUE_WAIT = _telemetry.histogram(
+    "serving.queue_wait_seconds", "seconds",
+    "time from submission to the scheduler granting a decode slot")
 _M_TOKENS = _telemetry.counter(
     "serving.tokens_generated", "tokens sampled across all sequences")
 _M_PREFILLS = _telemetry.counter(
@@ -85,6 +88,24 @@ _M_SPEC_ACCEPTED = _telemetry.counter(
 _M_SPEC_RATE = _telemetry.gauge(
     "serving.spec_acceptance_rate", "cumulative spec_accepted / "
     "spec_proposed for this engine")
+
+
+# hvd-trace regions of one serving iteration (docs/tracing.md), all on
+# the serve-loop thread and all carrying ``iter``, the engine's own
+# iteration counter.  Per iteration and per prefill, never per slot or
+# per token.  ``serve.tables`` .. ``serve.sample`` tile one decode
+# iteration: serving.token_seconds reads the first one's start and the
+# last one's end, so they are timed (a speculative iteration starts at
+# its launch).
+_R_ITERATION = _trace.region("serve.iteration", "serve")
+_R_ADMIT = _trace.region("serve.admit", "serve")
+_R_PREFILL = _trace.region("serve.prefill", "serve")
+_R_ENSURE = _trace.region("serve.ensure", "serve")
+_R_TABLES = _trace.region("serve.tables", "serve", timed=True)
+_R_LAUNCH = _trace.region("serve.launch", "serve", timed=True)
+_R_LOGITS_WAIT = _trace.region("serve.logits_wait", "serve")
+_R_SAMPLE = _trace.region("serve.sample", "serve", timed=True)
+_R_WARM_START = _trace.region("serve.warm_start", "init")
 
 
 def _model_dict(cfg) -> dict:
@@ -281,6 +302,8 @@ class InferenceEngine:
         # models/transformer.speculative_propose).
         self._prev_token = np.zeros((max_slots,), np.int32)
         self._spec_proposed = 0
+        self._iter = 0              # serve.* regions' ``iter``
+        self._prefill_bucket = 0    # the last admission prefill's
         self._spec_accepted = 0
         self._ready = False
         self._drained = False
@@ -353,6 +376,12 @@ class InferenceEngine:
         ``None`` directory keeps a previously chosen one rather than
         reverting to the env default.  Returns the number of manifest
         entries rebuilt."""
+        with _R_WARM_START() as r:
+            warmed = self._warm_start(directory)
+            r.note(entries=warmed)
+        return warmed
+
+    def _warm_start(self, directory: Optional[str]) -> int:
         if directory is None:
             directory = self._manifest_dir
         self._manifest_dir = directory
@@ -694,7 +723,7 @@ class InferenceEngine:
                       arrival=arrival)
         if prefix is not None:
             req.prefix = list(prefix)
-        req.t_submit = time.perf_counter()
+        req.t_submit = time.monotonic()
         return self.scheduler.submit(req)
 
     def generate(self, prompt: List[int], max_new_tokens: int = 32,
@@ -731,8 +760,15 @@ class InferenceEngine:
         the admission plan, then post-prefill state, then the sampled
         tokens, so :meth:`follow` on worker ranks mirrors the cache and
         runs the identical executables in the same order."""
+        self._iter += 1
+        with _R_ITERATION(iter=self._iter):
+            return self._step(now, admit)
+
+    def _step(self, now: Optional[int], admit: bool) -> bool:
         mp = self._multiprocess()
-        admitted = self._admit(now) if admit else []
+        it = self._iter
+        with _R_ADMIT(iter=it):
+            admitted = self._admit(now) if admit else []
         if mp:
             self._bcast({"stop": False,
                          "admit": [(slot, list(req.prompt))
@@ -768,7 +804,8 @@ class InferenceEngine:
         spec = (self._draft_params is not None
                 and any(req.temperature <= 0.0 for _, req in active))
         depth = self.spec_tokens if spec else 0
-        self._ensure_block(active, depth)
+        with _R_ENSURE(iter=it, slots=len(active)):
+            self._ensure_block(active, depth)
         if mp:
             # Post-prefill sync: first sampled tokens + which slots
             # survived into the decode batch (a max_new_tokens=1
@@ -798,10 +835,18 @@ class InferenceEngine:
         is a structural safety net — a free slot always implies
         headroom — but it keeps overcommitted or future configs
         honest (the pricing is pure: no refcounts move here)."""
-        return self.scheduler.admit(
+        admitted = self.scheduler.admit(
             now, page_budget=self.cache.free_pages(),
             pages_needed=lambda req:
                 self.cache.admission_cost(req.prompt))
+        if admitted:
+            # The slot is granted: one stamp for the whole admission.
+            t_admit = time.monotonic()
+            for _, req in admitted:
+                req.t_admit = t_admit
+                if req.t_submit:
+                    _M_QUEUE_WAIT.observe(t_admit - req.t_submit)
+        return admitted
 
     def _ensure_block(self, active, depth: int) -> None:
         for slot, _ in active:
@@ -842,30 +887,40 @@ class InferenceEngine:
         """Record one sampled/accepted token; returns the finish
         reason when this token ended the sequence (the speculative
         path stops feeding its block there), else None."""
+        # One stamp per fed token, on the clock of the request's other
+        # stamps and of its span (time.monotonic): the first is
+        # t_first_token, the last t_done.
+        stamp = time.monotonic()
         if not req.generated:
-            req.t_first_token = time.perf_counter()
-            _M_TTFT.observe(req.t_first_token - req.t_submit)
+            req.t_first_token = stamp
+            _M_TTFT.observe(stamp - req.t_submit)
         _M_TOKENS.inc()
         # expect=req: a concurrent drain may have evicted the slot
         # mid-iteration — the token is then discarded (the exported
         # continuation reproduces it) instead of poisoning the step.
-        reason = self.scheduler.feed(slot, token, expect=req)
+        reason = self.scheduler.feed(slot, token, expect=req, stamp=stamp)
         if reason is not None:
-            req.t_done = time.perf_counter()
+            req.t_done = stamp
             self._free_slot(slot)  # idempotent vs the drain
             if _trace.enabled():
                 # hvd-trace serving span: the whole request lifetime
-                # (submit -> completion), reconstructed from the wall
-                # stamps the engine already keeps — serving load on the
-                # shared mesh is visible next to training cycles in
-                # the fleet trace.
-                now = time.monotonic()
+                # (submit -> completion) from the stamps the engine
+                # keeps — serving load on the shared mesh is visible
+                # next to training cycles in the fleet trace.  It
+                # starts in the past on another thread, so it is a
+                # plain span; ``itl_ms`` are the request's inter-token
+                # gaps, ``queue_ms`` its wait for a slot.
+                times = req.token_times
                 _trace.span(
-                    "serving.request", "serving",
-                    now - (req.t_done - req.t_submit), now,
+                    "serving.request", "serving", req.t_submit, stamp,
                     args={"rid": req.rid,
                           "tokens": len(req.generated),
-                          "reason": reason})
+                          "reason": reason,
+                          "queue_ms": round(
+                              (req.t_admit - req.t_submit) * 1e3, 3)
+                          if req.t_admit else None,
+                          "itl_ms": [round((b - a) * 1e3, 3) for a, b
+                                     in zip(times, times[1:])]})
         else:
             self._last_token[slot] = token
         return reason
@@ -891,7 +946,7 @@ class InferenceEngine:
         n_shared = len(shared) * self.cache.page_size
         self.cache.begin_slot(slot, n, prefix_pages=shared)
         suffix = prompt[n_shared:]
-        bucket = self._bucket_for(len(suffix))
+        bucket = self._prefill_bucket = self._bucket_for(len(suffix))
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(suffix)] = suffix
         compiled = self._prefill_exec(bucket)
@@ -930,36 +985,46 @@ class InferenceEngine:
     def _decode_iteration(self, active) -> np.ndarray:
         """One batched decode over ``active``; the caller (step) has
         already run ``cache.ensure`` for every slot."""
-        t0 = time.perf_counter()
-        table, lengths = self.cache.device_tables()
-        tokens = np.zeros((self.max_slots,), np.int32)
-        for slot, _ in active:
-            tokens[slot] = self._last_token[slot]
-        compiled = self._decode_exec()
-        with _oom.guard("serving/decode"):
-            logits, kp, vp = compiled(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                table, lengths, self._rep(tokens))
-        self.cache.replace_pages(kp, vp)
-        logits_np = np.asarray(logits)
-        fed = {}
-        evicted = []
-        for slot, req in active:
-            self.cache.advance(slot)  # the input token's KV landed
-            token = self._sample(req, logits_np[slot])
-            fed[slot] = token
-            self._feed(slot, req, token)
-            if self.cache.length(slot) < 0:
-                evicted.append(slot)
-        if self._multiprocess():
-            self._bcast({"tokens": fed, "evict": evicted})
+        it = self._iter
+        with _R_TABLES(iter=it) as first:
+            table, lengths = self.cache.device_tables()
+            tokens = np.zeros((self.max_slots,), np.int32)
+            for slot, _ in active:
+                tokens[slot] = self._last_token[slot]
+            tokens = self._rep(tokens)
+        with _R_LAUNCH(iter=it):
+            compiled = self._decode_exec()
+            with _oom.guard("serving/decode"):
+                logits, kp, vp = compiled(
+                    self.params, self.cache.k_pages, self.cache.v_pages,
+                    table, lengths, tokens)
+            self.cache.replace_pages(kp, vp)
+        with _R_LOGITS_WAIT(iter=it):
+            # The host blocked on the device: everything the iteration's
+            # program takes shows here.
+            logits_np = np.asarray(logits)
+        with _R_SAMPLE(iter=it, slots=len(active)) as last:
+            fed = {}
+            evicted = []
+            for slot, req in active:
+                self.cache.advance(slot)  # the input token's KV landed
+                token = self._sample(req, logits_np[slot])
+                fed[slot] = token
+                self._feed(slot, req, token)
+                if self.cache.length(slot) < 0:
+                    evicted.append(slot)
+            if self._multiprocess():
+                self._bcast({"tokens": fed, "evict": evicted})
         _M_DECODES.inc()
-        _M_TOKEN_LAT.observe(time.perf_counter() - t0)
+        _M_TOKEN_LAT.observe(last.t1 - first.t0)
         return logits_np
 
     def _prefill_and_sample(self, slot: int, req: Request) -> None:
-        last = self._prefill(slot, req)
-        self._feed(slot, req, self._sample(req, last))
+        with _R_PREFILL(iter=self._iter, rid=req.rid,
+                        prompt_tokens=len(req.prompt)) as r:
+            last = self._prefill(slot, req)
+            r.note(bucket=self._prefill_bucket)
+            self._feed(slot, req, self._sample(req, last))
 
     # -- speculative decoding ---------------------------------------------
     def _spec_dispatch(self, slots: Sequence[int]):
@@ -1011,63 +1076,69 @@ class InferenceEngine:
         bitwise what the decode path would sample.  Rejected tail:
         the write cursor (cache lengths) just does not advance over it;
         the pages stay masked and the next block overwrites them."""
-        t0 = time.perf_counter()
+        it = self._iter
         m = self.spec_tokens
-        props, logits_np = self._spec_dispatch([s for s, _ in active])
-        fed: Dict[int, int] = {}
-        prev: Dict[int, int] = {}
-        advance: Dict[int, int] = {}
-        evicted: List[int] = []
-        for slot, req in active:
-            if req.temperature <= 0.0:
-                greedy = np.argmax(logits_np[slot], axis=-1)
-                accept = 0
-                while (accept < m
-                       and int(props[slot, accept])
-                       == int(greedy[accept])):
-                    accept += 1
-                emitted = [int(props[slot, j]) for j in range(accept)]
-                emitted.append(int(greedy[accept]))
-                # Greedy slots only: a temperature slot never consults
-                # the proposals (accept == 0 by construction), so
-                # counting it would dilute spec_acceptance_rate — the
-                # gauge operators size spec_tokens by.
-                self._spec_proposed += m
-                self._spec_accepted += accept
-                _M_SPEC_PROPOSED.inc(m)
-                if accept:
-                    _M_SPEC_ACCEPTED.inc(accept)
-            else:
-                emitted = [self._sample(req, logits_np[slot, 0])]
-                accept = 0
-            last_before = int(self._last_token[slot])
-            finished = False
-            for t in emitted:
-                if self._feed(slot, req, t) is not None:
-                    finished = True
-                    break
-            if finished or self.cache.length(slot) < 0:
-                evicted.append(slot)
-                continue
-            # The accepted inputs' KV is now valid: pending plus the
-            # accepted drafts (the bonus token is the new pending — its
-            # KV lands next iteration).
-            n_adv = 1 + accept
-            for _ in range(n_adv):
-                self.cache.advance(slot)
-                self.draft_cache.advance(slot)
-            self._prev_token[slot] = (emitted[-2] if len(emitted) >= 2
-                                      else last_before)
-            fed[slot] = int(self._last_token[slot])
-            prev[slot] = int(self._prev_token[slot])
-            advance[slot] = n_adv
-        if self._spec_proposed:
-            _M_SPEC_RATE.set(self._spec_accepted / self._spec_proposed)
-        if self._multiprocess():
-            self._bcast({"tokens": fed, "prev": prev,
-                         "advance": advance, "evict": evicted})
+        # The boundaries this iteration shares with plain decode carry
+        # the same names: its two dispatches (with their host copies)
+        # are the launch, the acceptance loop is the sampling.
+        with _R_LAUNCH(iter=it, spec=m) as first:
+            props, logits_np = self._spec_dispatch(
+                [s for s, _ in active])
+        with _R_SAMPLE(iter=it, slots=len(active)) as last:
+            fed: Dict[int, int] = {}
+            prev: Dict[int, int] = {}
+            advance: Dict[int, int] = {}
+            evicted: List[int] = []
+            for slot, req in active:
+                if req.temperature <= 0.0:
+                    greedy = np.argmax(logits_np[slot], axis=-1)
+                    accept = 0
+                    while (accept < m
+                           and int(props[slot, accept])
+                           == int(greedy[accept])):
+                        accept += 1
+                    emitted = [int(props[slot, j]) for j in range(accept)]
+                    emitted.append(int(greedy[accept]))
+                    # Greedy slots only: a temperature slot never consults
+                    # the proposals (accept == 0 by construction), so
+                    # counting it would dilute spec_acceptance_rate — the
+                    # gauge operators size spec_tokens by.
+                    self._spec_proposed += m
+                    self._spec_accepted += accept
+                    _M_SPEC_PROPOSED.inc(m)
+                    if accept:
+                        _M_SPEC_ACCEPTED.inc(accept)
+                else:
+                    emitted = [self._sample(req, logits_np[slot, 0])]
+                    accept = 0
+                last_before = int(self._last_token[slot])
+                finished = False
+                for t in emitted:
+                    if self._feed(slot, req, t) is not None:
+                        finished = True
+                        break
+                if finished or self.cache.length(slot) < 0:
+                    evicted.append(slot)
+                    continue
+                # The accepted inputs' KV is now valid: pending plus the
+                # accepted drafts (the bonus token is the new pending — its
+                # KV lands next iteration).
+                n_adv = 1 + accept
+                for _ in range(n_adv):
+                    self.cache.advance(slot)
+                    self.draft_cache.advance(slot)
+                self._prev_token[slot] = (emitted[-2] if len(emitted) >= 2
+                                          else last_before)
+                fed[slot] = int(self._last_token[slot])
+                prev[slot] = int(self._prev_token[slot])
+                advance[slot] = n_adv
+            if self._spec_proposed:
+                _M_SPEC_RATE.set(self._spec_accepted / self._spec_proposed)
+            if self._multiprocess():
+                self._bcast({"tokens": fed, "prev": prev,
+                             "advance": advance, "evict": evicted})
         _M_DECODES.inc()
-        _M_TOKEN_LAT.observe(time.perf_counter() - t0)
+        _M_TOKEN_LAT.observe(last.t1 - first.t0)
 
     @property
     def spec_acceptance_rate(self) -> Optional[float]:

@@ -76,8 +76,12 @@ class Request:
     caller's own).  ``done`` is set at eviction; ``generated`` then
     holds the sampled tokens and ``finish_reason`` why it stopped.
     ``arrival`` is a logical admission gate for trace replay (see
-    module docstring); ``t_submit``/``t_first_token``/``t_done`` are
-    wall stamps the engine fills for the TTFT / per-token histograms.
+    module docstring); ``t_submit``/``t_admit``/``t_first_token``/
+    ``t_done`` and ``token_times`` (one stamp per token of
+    ``generated``; the first is ``t_first_token``) are
+    ``time.monotonic`` stamps the engine fills: the TTFT, queue-wait
+    and per-token histograms, ``/generate``'s ``token_ms`` and the
+    ``serving.request`` span all read this one clock.
     """
 
     prompt: List[int]
@@ -100,8 +104,10 @@ class Request:
     cancel_reason: Optional[str] = None
     done: threading.Event = field(default_factory=threading.Event)
     t_submit: float = 0.0
+    t_admit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+    token_times: List[float] = field(default_factory=list)
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         """Block until eviction; returns the generated tokens (with any
@@ -200,8 +206,10 @@ class ContinuousBatchingScheduler:
         return admitted
 
     def feed(self, slot: int, token: int,
-             expect: Optional[Request] = None) -> Optional[str]:
-        """Record one sampled token for ``slot``.  Returns None while
+             expect: Optional[Request] = None,
+             stamp: float = 0.0) -> Optional[str]:
+        """Record one sampled token for ``slot`` (and ``stamp``, the
+        engine's clock read for it, beside it).  Returns None while
         the sequence continues, or the :class:`FinishReason` when this
         token finished it (the slot is then already free — iteration-
         level eviction).
@@ -219,6 +227,7 @@ class ContinuousBatchingScheduler:
                     return expect.finish_reason or FinishReason.DRAINED
                 raise ValueError(f"slot {slot} is not active")
             req.generated.append(token)
+            req.token_times.append(stamp)
             reason = None
             if req.eos_id is not None and token == req.eos_id:
                 reason = FinishReason.EOS

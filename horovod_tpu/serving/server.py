@@ -32,6 +32,7 @@ import time
 from typing import Optional, Tuple
 
 from .. import telemetry as _telemetry
+from .. import trace as _trace
 from ..analysis import threads as _athreads
 from ..telemetry import exporter as _exporter
 from .engine import InferenceEngine
@@ -75,6 +76,9 @@ _M_CLIENT_DISCONNECTS = _telemetry.counter(
 _M_CP_LOSSES = _telemetry.counter(
     "serving.control_plane_losses", "serve loops degraded to 503+drain "
     "after a persistent control-plane loss")
+# hvd-trace: the serve loop parked with nothing to run (the iteration's
+# own regions are the engine's, serving/engine.py).
+_R_PARK = _trace.region("serve.park", "serve")
 
 
 def encode_text(text: str, vocab_size: int) -> list:
@@ -239,7 +243,9 @@ class LMServer:
             if self.engine.scheduler.idle():
                 # Park until a submission wakes us; short timeout so a
                 # racing submit-after-idle-check is picked up anyway.
-                self._wake.wait(timeout=0.05)
+                with _R_PARK() as r:
+                    if not self._wake.wait(timeout=0.05):
+                        r.cancel()   # an idle tick: nobody woke us
                 self._wake.clear()
                 continue
             try:
@@ -309,7 +315,9 @@ class LMServer:
                     "application/json")
         self._wake.set()
         timeout = float(payload.get("timeout", 120.0))
-        t0 = time.perf_counter()
+        # The request's own clock and origin (time.monotonic from
+        # t_submit): total_ms, ttft_ms and token_ms then share both.
+        t0 = req.t_submit
         # Block for the completion in short slices, watching the client
         # connection between slices: a client that disconnected
         # mid-generation releases its slot through the abort path
@@ -317,7 +325,7 @@ class LMServer:
         # read (hvd-chaos hardening; counted below).
         deadline = t0 + timeout
         while True:
-            remaining = deadline - time.perf_counter()
+            remaining = deadline - time.monotonic()
             if remaining <= 0:
                 return (504, json.dumps(
                     {"error": "generation timed out", "rid": req.rid}
@@ -348,13 +356,18 @@ class LMServer:
                 "error": msg,
                 "rid": req.rid, "finish_reason": req.finish_reason,
                 "tokens": out}) + "\n").encode(), "application/json")
-        total = time.perf_counter() - t0
+        total = time.monotonic() - t0
         resp = {
             "rid": req.rid,
             "tokens": out,
             "finish_reason": req.finish_reason,
             "ttft_ms": round((req.t_first_token - req.t_submit) * 1e3, 3)
             if req.t_first_token else None,
+            # One offset from submission per token of this incarnation
+            # (a relaunch continuation's prefix carries none): the
+            # first is ttft_ms, the differences are the inter-token gaps.
+            "token_ms": [round((t - req.t_submit) * 1e3, 3)
+                         for t in req.token_times],
             "total_ms": round(total * 1e3, 3),
             "tokens_per_sec": round(len(out) / total, 1) if total else None,
         }

@@ -36,11 +36,25 @@ three pieces (docs/tracing.md):
    live when one rank's skew exceeds a threshold for N consecutive
    steps.
 
+4. **Regions** (:func:`region`) — the context-manager form of a span,
+   and the one call that lands on the PROFILER's clock as well: the
+   same pair of ``time.monotonic`` reads feeds the span buffer, a
+   ``trace.span_seconds.<name>`` registry histogram and, while a
+   profiler session records, a
+   ``jax.profiler.TraceAnnotation("hvd:<name>")``, so the spans of the
+   stream step, the serving iteration and set-up sit beside the device
+   ops in the ``.xplane.pb`` a profiler run writes (docs/tracing.md,
+   "On the profiler's clock").  Spans that start in the past on
+   another thread (``negotiate.wait``, ``serving.request``) stay
+   :func:`span`-only; every annotation carries ``mono_us`` so a reader
+   can place them on the profiler's timeline.
+
 Hot-path budget mirrors the flight recorder's: recording a span is one
-flag check, two ``time.monotonic`` reads (taken by the caller) and one
-``deque.append`` (atomic in CPython — no lock).  ``HVD_TPU_TRACE=0``
-opts out; ``set_enabled(False)`` is the runtime switch the bench's
-overhead A/B flips (gated ≤ 5 % like telemetry was).
+flag check, two ``time.monotonic`` reads (taken by the caller, or by
+the region) and one ``deque.append`` (atomic in CPython — no lock).
+``HVD_TPU_TRACE=0`` opts out; ``set_enabled(False)`` is the runtime
+switch the bench's overhead A/B flips (gated ≤ 5 % like telemetry
+was).
 
 Env contract:
   HVD_TPU_TRACE=0           disable span recording (default on)
@@ -54,8 +68,11 @@ from __future__ import annotations
 import collections
 import os
 import struct
+import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import telemetry as _telemetry
 
@@ -107,9 +124,11 @@ class TraceState:
             maxlen=_capacity())
 
     # -- hot path ----------------------------------------------------------
-    def record(self, ev: dict) -> None:
+    def record(self, ev) -> None:
         """The one append path every event kind funnels through (the
-        event-shape and accounting stay in one place)."""
+        event-shape and accounting stay in one place).  ``ev`` is a
+        Chrome event, or a closed region that builds its event when the
+        buffer is exported."""
         self._events.append(ev)
         _M_SPANS.inc()
 
@@ -136,7 +155,8 @@ class TraceState:
 
     # -- cold paths --------------------------------------------------------
     def export(self) -> List[dict]:
-        return list(self._events)
+        return [ev if type(ev) is dict else ev.event()
+                for ev in list(self._events)]
 
     def clear(self) -> None:
         self._events.clear()
@@ -167,6 +187,176 @@ def span(name: str, cat: str, t0: float, t1: float,
 
 def instant(name: str, cat: str, args: Optional[dict] = None) -> None:
     _state.instant(name, cat, args)
+
+
+# -- regions: one span call, three sinks -------------------------------------
+
+_tls = threading.local()     # .stack: names of this thread's open regions
+
+
+class _Off:
+    """What a region call returns with tracing off: one shared object,
+    nothing read and nothing built."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+class _Stopwatch(_Off):
+    """A ``timed`` region with tracing off: the pair of clock reads the
+    histogram beside the region (``collective.execute_seconds``, ...)
+    still needs, and nothing else."""
+
+    __slots__ = ("t0", "t1")
+
+    def __enter__(self):
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic()
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+class _Open(_Stopwatch):
+    """One entered region, and afterwards its record in the span buffer.
+    ``t0``/``t1`` (``time.monotonic`` seconds) are the ONLY clock reads
+    of the boundary: the call site feeds its own histogram from
+    ``seconds`` instead of reading a second clock.
+
+    The hot path builds as little as it can (measured in a serving run
+    on the chip's host, where cold caches make a region five times its
+    tight-loop cost): the annotation only while a profiler session is
+    recording, and the Chrome event only when the buffer is exported."""
+
+    __slots__ = ("_region", "_args", "_ann", "_keep")
+
+    def __init__(self, region: "Region", args: dict) -> None:
+        self._region = region
+        self._args = args       # the call's own kwargs dict, reused
+        self._ann = None
+        self._keep = True
+
+    def __enter__(self):
+        args = self._args
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if stack:
+            args["parent"] = stack[-1]
+        stack.append(self._region.name)
+        t0 = self.t0 = time.monotonic()
+        if _TraceAnnotation.is_enabled():
+            args["step"] = _state.step
+            args["cycle"] = _state.cycle
+            args["mono_us"] = int(t0 * 1e6)
+            self._ann = _TraceAnnotation(self._region.annotation, **args)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        self.t1 = time.monotonic()
+        _tls.stack.pop()
+        if self._keep:
+            # Context as of the END, like span(): a tick's span carries
+            # the cycle it opened.
+            args = self._args
+            args["step"] = _state.step
+            args["cycle"] = _state.cycle
+            _state.record(self)
+            self._region.seconds.observe(self.t1 - self.t0)
+        return False
+
+    def event(self) -> dict:
+        """The Chrome complete event :func:`span` would have appended."""
+        region = self._region
+        return {"name": region.name, "cat": region.cat, "ph": "X",
+                "ts": self.t0 * 1e6, "dur": (self.t1 - self.t0) * 1e6,
+                "args": self._args}
+
+    def note(self, **args) -> None:
+        """Arguments only known at the end (a tick's response count)."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def cancel(self) -> None:
+        """Keep this one out of the buffer and the histogram (an empty
+        drain tick); the profiler's timeline has it already."""
+        self._keep = False
+
+
+class Region:
+    """A span call site's handle, built once at import like the ``_M_*``
+    metric handles.  ``with R(bucket=3) as r: ...`` reads
+    ``time.monotonic`` once on each side and feeds three sinks from
+    that pair: the span buffer (the same Chrome event :func:`span`
+    appends, plus ``parent``, the enclosing region on this thread), the
+    ``trace.span_seconds.<name>`` histogram, and — while a profiler
+    session records — a ``jax.profiler.TraceAnnotation("hvd:<name>")``
+    whose arguments are the span's (``step``, ``cycle``, the caller's)
+    plus ``mono_us``, the region's start on ``time.monotonic``.  With
+    ``HVD_TPU_TRACE=0``
+    the call is one flag check and returns a shared no-op — unless the
+    site is ``timed``: then a histogram of its own reads ``r.seconds``
+    and the call still takes the two clock reads.
+
+    Regions sit at per-step, per-bucket and per-iteration boundaries,
+    never per tensor, slot or token: each costs a few microseconds."""
+
+    __slots__ = ("name", "cat", "annotation", "seconds", "timed")
+
+    def __init__(self, name: str, cat: str, timed: bool = False) -> None:
+        self.name = name
+        self.cat = cat
+        self.annotation = "hvd:" + name
+        self.timed = timed
+        self.seconds = _telemetry.histogram(
+            "trace.span_seconds." + name, "seconds",
+            f"duration of the hvd-trace region {name}")
+
+    def __call__(self, **args):
+        if not _state.enabled:
+            return _Stopwatch() if self.timed else _OFF
+        return _Open(self, args)
+
+
+region = Region      # the call sites' spelling: ``_trace.region(name, cat)``
+
+
+class RegionFamily(dict):
+    """Regions named ``<prefix><key>`` for a small closed set of keys
+    (``execute/<op>``): each built at its first use, then a dict hit."""
+
+    def __init__(self, prefix: str, cat: str, timed: bool = False) -> None:
+        super().__init__()
+        self._prefix, self._cat, self._timed = prefix, cat, timed
+
+    def __missing__(self, key: str) -> Region:
+        made = self[key] = Region(self._prefix + key, self._cat,
+                                  self._timed)
+        return made
 
 
 def export_events() -> List[dict]:

@@ -40,6 +40,15 @@ LEGS = ("host", "pack", "collective", "dcn", "unpack", "dispatch",
 # Span category -> leg for the directly-mapped categories.
 _DIRECT = {"host": "host", "negotiate": "negotiate",
            "checkpoint": "checkpoint", "serving": "serving"}
+# The categories the leg model is made of.  The regions of a step, a
+# serving iteration and set-up (``step``, ``stream``, ``serve``,
+# ``init``) ENCLOSE these spans: counted here they would stretch every
+# cycle's wall time into a dispatch-gap the cycle never had.
+_LEG_CATS = frozenset(_DIRECT) | {"dispatch", "collective"}
+
+
+def _is_leg_span(ev: dict) -> bool:
+    return ev.get("ph") == "X" and ev.get("cat") in _LEG_CATS
 
 
 def load_trace(path: str) -> List[dict]:
@@ -126,7 +135,7 @@ def window_legs(events: List[dict]) -> Dict[str, float]:
     ``dispatch-gap``.  Same leg model as :func:`analyze`, but windowed
     and file-free — the online tuner calls this every decision window
     instead of round-tripping ``dump_fleet_trace``."""
-    spans = [e for e in events if e.get("ph") == "X"]
+    spans = [e for e in events if _is_leg_span(e)]
     legs = _decompose(spans)
     groups: Dict[Tuple[int, int], List[dict]] = {}
     for s in spans:
@@ -158,7 +167,7 @@ def analyze(events: List[dict]) -> dict:
             arrivals.setdefault(key, {}).setdefault(
                 rank, float(ev.get("ts", 0.0)))
             continue
-        if ev.get("ph") != "X":
+        if not _is_leg_span(ev):
             continue
         nspans += 1
         rank = int(ev.get("pid", 0))

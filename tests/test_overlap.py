@@ -305,6 +305,105 @@ def test_telemetry_counters_and_timeline_instants(hvd, tmp_path):
         == set(range(step.bucket_count))
 
 
+def _step_spans(step_no):
+    import horovod_tpu.trace as trace
+
+    return [e for e in trace.export_events()
+            if e.get("ph") == "X" and e["args"].get("step") == step_no]
+
+
+def _covers(outer, inner):
+    return (outer["ts"] <= inner["ts"] and outer["ts"] + outer["dur"]
+            >= inner["ts"] + inner["dur"])
+
+
+def test_stream_step_emits_nested_regions_with_one_step(hvd):
+    """One stream step on the 8-device mesh: step/stream > stream.backward,
+    stream.submit, stream.drain > execute/allreduce > megakernel/psum,
+    stream.take, stream.apply, all with one ``step`` (ISSUE 24)."""
+    import horovod_tpu as H
+    import horovod_tpu.trace as trace
+
+    chain = _chain()
+    params = _chain_params(jax.random.PRNGKey(0))
+    batch = _batch(hvd, jax.random.PRNGKey(1))
+    opt = optax.sgd(0.1)
+    step = make_train_step(chain, opt, donate=False,
+                           fusion_threshold=_THRESHOLD, overlap="on")
+    p, s = params, opt.init(params)
+    for _ in range(2):                       # build, negotiate, replay
+        p, s, _loss = step(p, s, batch)
+    jax.block_until_ready(jax.tree_util.tree_leaves(p))
+    before = H.metrics()
+    p, s, _loss = step(p, s, batch)
+    jax.block_until_ready(jax.tree_util.tree_leaves(p))
+    after = H.metrics()
+    spans = _step_spans(trace.current_step())
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    n_buckets, n_segs = step.bucket_count, step.segment_count
+    (whole,) = by_name["step/stream"]
+    assert "parent" not in whole["args"]
+    assert len(by_name["stream.backward"]) == n_segs + 1   # fwd + bwd_k
+    assert len(by_name["stream.submit"]) == n_buckets
+    assert len(by_name["stream.drain"]) == n_buckets
+    assert len(by_name["execute/allreduce"]) == n_buckets
+    assert len(by_name["megakernel/psum"]) == n_buckets
+    assert len(by_name["stream.take"]) == 1
+    assert len(by_name["stream.apply"]) == 1
+    for name in ("stream.backward", "stream.submit", "stream.drain",
+                 "stream.take", "stream.apply"):
+        for e in by_name[name]:
+            assert e["args"]["parent"] == "step/stream", name
+            assert _covers(whole, e), name
+    assert {e["args"]["bucket"] for e in by_name["stream.submit"]} \
+        == set(range(n_buckets))
+    assert all(e["args"]["tensors"] >= 1 and e["args"]["bytes"] > 0
+               for e in by_name["stream.submit"])
+    drains = sorted(by_name["stream.drain"], key=lambda e: e["ts"])
+    execs = sorted(by_name["execute/allreduce"], key=lambda e: e["ts"])
+    launches = sorted(by_name["megakernel/psum"], key=lambda e: e["ts"])
+    for d, x, m in zip(drains, execs, launches):
+        assert x["args"]["parent"] == "stream.drain" and _covers(d, x)
+        assert m["args"]["parent"] == "execute/allreduce"
+        assert _covers(x, m)
+    # The registry keeps what the ring may wrap: every region fed its
+    # trace.span_seconds histogram once per occurrence...
+    def moved(name, field="count"):
+        key = "trace.span_seconds." + name
+        return after[key][field] - before.get(key, {}).get(field, 0)
+
+    assert moved("step/stream") == 1
+    assert moved("stream.submit") == n_buckets
+    assert moved("stream.drain") == n_buckets
+    assert moved("step/stream", "sum") == pytest.approx(
+        whole["dur"] / 1e6)
+    # ... and the histogram beside stream.take reads the same pair.
+    took = after["overlap.exposed_comm_seconds"]["sum"] \
+        - before["overlap.exposed_comm_seconds"]["sum"]
+    assert took == pytest.approx(by_name["stream.take"][0]["dur"] / 1e6)
+
+
+def test_step_region_is_named_by_what_was_built(hvd):
+    import horovod_tpu.trace as trace
+
+    params = _plain_params(jax.random.PRNGKey(0))
+    batch = _batch(hvd, jax.random.PRNGKey(1))
+    opt = optax.sgd(0.1)
+    for overlap, name in (("off", "step/monolithic"),
+                          ("serial", "step/serial"),
+                          ("on", "step/stream")):
+        step = make_train_step(_plain_loss, opt, donate=False,
+                               fusion_threshold=_THRESHOLD,
+                               overlap=overlap)
+        _run(step, params, opt, batch, 1)
+        names = {e["name"] for e in _step_spans(trace.current_step())}
+        assert name in names, (overlap, sorted(names))
+        assert not {"step/monolithic", "step/serial",
+                    "step/stream"} - {name} & names
+
+
 # ---------------------------------------------------------------------------
 # Quantized wire: per-bucket error-feedback residuals
 # ---------------------------------------------------------------------------

@@ -674,6 +674,128 @@ def test_engine_serving_metrics_flow():
     assert snap["serving.token_seconds"]["count"] >= 1
 
 
+def _iteration_spans(it):
+    import horovod_tpu.trace as trace
+
+    return [e for e in trace.export_events()
+            if e.get("ph") == "X" and e["args"].get("iter") == it]
+
+
+def test_engine_iteration_emits_serve_regions_with_one_iter():
+    """One engine iteration: serve.iteration > serve.admit,
+    serve.prefill (one per admission), serve.ensure, serve.tables,
+    serve.launch, serve.logits_wait, serve.sample — all with the
+    engine's own ``iter`` (ISSUE 24)."""
+    import horovod_tpu.telemetry as telemetry
+    import horovod_tpu.trace as trace
+
+    trace.set_enabled(True)
+    eng = make_engine()
+    eng.warm_start()
+    eng.generate([1, 2, 3], max_new_tokens=2)       # compiles
+    reqs = [eng.submit([i + 1, 2, 3], max_new_tokens=4)
+            for i in range(2)]
+    trace.clear()       # ``iter`` is per engine: earlier engines' spans
+    before = telemetry.metrics()
+    eng.step()
+    after = telemetry.metrics()
+    spans = _iteration_spans(eng._iter)
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    assert sorted(by_name) == sorted(
+        ["serve.iteration", "serve.admit", "serve.prefill",
+         "serve.ensure", "serve.tables", "serve.launch",
+         "serve.logits_wait", "serve.sample"])
+    (whole,) = by_name["serve.iteration"]
+    assert "parent" not in whole["args"] and whole["cat"] == "serve"
+    for name, evs in by_name.items():
+        if name == "serve.iteration":
+            continue
+        assert len(evs) == (2 if name == "serve.prefill" else 1), name
+        for e in evs:
+            assert e["args"]["parent"] == "serve.iteration", name
+            assert whole["ts"] <= e["ts"] and (
+                whole["ts"] + whole["dur"] >= e["ts"] + e["dur"]), name
+    assert {e["args"]["rid"] for e in by_name["serve.prefill"]} \
+        == {r.rid for r in reqs}
+    assert all(e["args"]["prompt_tokens"] == 3 and e["args"]["bucket"] >= 3
+               for e in by_name["serve.prefill"])
+    # The decode iteration's four regions tile it: serving.token_seconds
+    # is fed from the first one's start and the last one's end.
+    tables, sample = by_name["serve.tables"][0], by_name["serve.sample"][0]
+    took = after["serving.token_seconds"]["sum"] \
+        - before["serving.token_seconds"]["sum"]
+    assert took == pytest.approx(
+        (sample["ts"] + sample["dur"] - tables["ts"]) / 1e6)
+    assert after["trace.span_seconds.serve.iteration"]["count"] \
+        - before["trace.span_seconds.serve.iteration"]["count"] == 1
+    # The next iteration carries the next iter.
+    eng.step()
+    assert {e["name"] for e in _iteration_spans(eng._iter)} >= {
+        "serve.iteration", "serve.sample"}
+    assert not [e for e in _iteration_spans(eng._iter)
+                if e["name"] == "serve.prefill"]
+    eng.run_until_idle()
+
+
+def test_request_token_times_one_stamp_per_token_on_one_clock():
+    import horovod_tpu.telemetry as telemetry
+    import horovod_tpu.trace as trace
+
+    trace.set_enabled(True)
+    eng = make_engine(max_slots=2)
+    eng.warm_start()
+    trace.clear()       # ``rid`` is per scheduler
+    q0 = telemetry.metrics().get("serving.queue_wait_seconds",
+                                 {}).get("count", 0)
+    reqs = [eng.submit([i + 1, 2, 3], max_new_tokens=5) for i in range(3)]
+    eng.run_until_idle()
+    for r in reqs:
+        assert len(r.token_times) == len(r.generated) == 5
+        assert r.token_times == sorted(r.token_times)
+        assert r.token_times[0] == r.t_first_token
+        assert r.token_times[-1] == r.t_done
+        assert r.t_submit <= r.t_admit <= r.t_first_token
+    # The third request waited for a slot: its wait is in its stamps,
+    # and every admitted request was observed exactly once.
+    assert reqs[2].t_admit > reqs[0].t_admit
+    assert telemetry.metrics()["serving.queue_wait_seconds"]["count"] \
+        == q0 + 3
+    # The request's span sits on the stamps' clock and carries the
+    # gaps: no second clock, no arithmetic between two.
+    spans = {e["args"]["rid"]: e for e in trace.export_events()
+             if e["name"] == "serving.request"}
+    for r in reqs:
+        ev = spans[r.rid]
+        assert ev["ts"] == pytest.approx(r.t_submit * 1e6)
+        assert ev["dur"] == pytest.approx((r.t_done - r.t_submit) * 1e6)
+        assert len(ev["args"]["itl_ms"]) == 4
+        assert ev["args"]["itl_ms"] == pytest.approx(
+            [(b - a) * 1e3 for a, b in
+             zip(r.token_times, r.token_times[1:])], abs=1e-3)
+        assert ev["args"]["queue_ms"] == pytest.approx(
+            (r.t_admit - r.t_submit) * 1e3, abs=1e-3)
+
+
+def test_generate_returns_token_ms_one_offset_per_token():
+    cfg = TransformerConfig(vocab_size=256, d_model=64, n_heads=4,
+                            n_layers=2, d_ff=128, max_seq_len=64)
+    params = init_transformer(jax.random.PRNGKey(5), cfg)
+    engine = InferenceEngine(params, cfg, max_slots=2, page_size=8,
+                             capacity=32)
+    with LMServer(engine, port=0) as srv:
+        srv.start()
+        base = f"http://127.0.0.1:{srv.port}"
+        status, resp = _post(base + "/generate",
+                             {"tokens": [1, 2, 3], "max_tokens": 6})
+    assert status == 200
+    assert len(resp["token_ms"]) == len(resp["tokens"]) == 6
+    assert resp["token_ms"] == sorted(resp["token_ms"])
+    assert resp["token_ms"][0] == resp["ttft_ms"]
+    assert resp["token_ms"][-1] <= resp["total_ms"]
+
+
 # ---------------------------------------------------------------------------
 # HTTP front door on the shared exporter (route registry)
 # ---------------------------------------------------------------------------
