@@ -27,10 +27,12 @@ the model-agnostic FSDP/ZeRO-3 builder (:mod:`..parallel.fsdp`).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import topology as T
 from ..parallel.expert import local_experts, moe_layer
@@ -491,6 +493,87 @@ def cache_attention(q, k_view, v_view, q_pos):
     return out.astype(q.dtype)
 
 
+def view_attention(q, k, v, k_view, v_view, start_pos, q_pos):
+    """The view-dependent part of one serving layer: put the block's
+    new ``k``/``v`` rows (``[b, s, heads, head_dim]``) into this
+    layer's view (``[b, capacity, heads, head_dim]``) at
+    ``start_pos``, then :func:`cache_attention` over it."""
+
+    def put(view_b, new_b, start_b):
+        # Per-row scatter, NOT dynamic_update_slice: a slice window is
+        # clamped as a whole, so a decode block [token, dummy] landing
+        # at start == capacity-1 would shift back one position —
+        # overwriting the previous token's entry and leaving the dummy
+        # unmasked at capacity-1.  mode="drop" keeps every row at its
+        # true index and discards rows past the capacity.  (hvd-serve's
+        # scheduler evicts one step before that boundary; this keeps
+        # forward_step's own contract exact for any caller stepping at
+        # the final cached position.)
+        idx = jnp.clip(start_b, 0, None) + jnp.arange(
+            new_b.shape[0], dtype=jnp.int32)
+        return view_b.at[idx].set(new_b, mode="drop",
+                                  unique_indices=True)
+
+    k_full = jax.vmap(put)(k_view, k, start_pos)
+    v_full = jax.vmap(put)(v_view, v, start_pos)
+    return cache_attention(q, k_full, v_full, q_pos)
+
+
+def _step_layers(params, tokens, start_pos, cfg: TransformerConfig,
+                 attend, roll=False):
+    """The serving forward around its attention: ``attend(layer, q, k,
+    v, pos)`` is the one part that sees the cached keys and values
+    (:func:`forward_step` reads a dense view, :func:`forward_step_paged`
+    the page store).  ``roll`` walks the layers with ``lax.scan``, so
+    the program holds ONE layer (``layer`` is then traced): the same
+    operations in the same order, a fraction of the code to compile
+    and load."""
+    if cfg.num_experts > 0:
+        raise ValueError("the serving path currently supports dense FFN "
+                         "layers only (num_experts == 0)")
+    b, s = tokens.shape
+    h_n, d = cfg.n_heads, cfg.d_model
+    if d % h_n != 0:
+        raise ValueError(f"d_model {d} not divisible by n_heads {h_n}")
+    hd = d // h_n
+    pos = start_pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    # Inactive slots carry start_pos < 0; clamp the embedding lookup
+    # (their rows are masked/garbage anyway, but the gather index must
+    # stay in range).
+    x = (params["embed"][tokens]
+         + jnp.take(params["pos_embed"], jnp.clip(pos, 0, None), axis=0))
+    ax = ParallelAxes(data=None)
+
+    def layer(x, i, lp):
+        h = _layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
+        # Same fused [d, 3d] projection as the training forward.
+        qkv = jnp.dot(
+            h, jnp.concatenate([lp["wq"], lp["wk"], lp["wv"]], axis=-1),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+        q, k, v = (y.reshape(b, s, h_n, hd)
+                   for y in jnp.split(qkv, 3, axis=-1))
+        attn = attend(i, q, k, v, pos)
+        out = jnp.dot(attn.reshape(b, s, d), lp["wo"],
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+        x, _ = _ffn_block(x + out, lp, cfg, ax, jnp.zeros((), jnp.float32))
+        return x, (k, v)
+
+    if roll:
+        x, (k_new, v_new) = jax.lax.scan(
+            lambda x, il: layer(x, *il), x,
+            (jnp.arange(cfg.n_layers), params["layers"]))
+    else:
+        news = []
+        for i in range(cfg.n_layers):
+            x, new = layer(x, i, _index_layer(params["layers"], i))
+            news.append(new)
+        k_new, v_new = (jnp.stack(n) for n in zip(*news))
+    x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    logits = jnp.dot(x, params["unembed"],
+                     preferred_element_type=jnp.float32)
+    return logits, k_new, v_new
+
+
 def forward_step(params, tokens, start_pos, k_view, v_view,
                  cfg: TransformerConfig):
     """Cache-aware forward over ``tokens`` given already-cached context.
@@ -511,63 +594,79 @@ def forward_step(params, tokens, start_pos, k_view, v_view,
     new tokens' entries, for the caller to scatter back into its paged
     store (the view itself is a gather, not the storage).
     """
-    if cfg.num_experts > 0:
-        raise ValueError("the serving path currently supports dense FFN "
-                         "layers only (num_experts == 0)")
-    b, s = tokens.shape
-    h_n, d = cfg.n_heads, cfg.d_model
-    if d % h_n != 0:
-        raise ValueError(f"d_model {d} not divisible by n_heads {h_n}")
-    hd = d // h_n
     cap = k_view.shape[2]
     if cap > cfg.max_seq_len:
         raise ValueError(f"KV capacity {cap} exceeds cfg.max_seq_len "
                          f"{cfg.max_seq_len}")
-    pos = start_pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    # Inactive slots carry start_pos < 0; clamp the embedding lookup
-    # (their rows are masked/garbage anyway, but the gather index must
-    # stay in range).
-    x = (params["embed"][tokens]
-         + jnp.take(params["pos_embed"], jnp.clip(pos, 0, None), axis=0))
-    ax = ParallelAxes(data=None)
-    k_news, v_news = [], []
 
-    def put(view_b, new_b, start_b):
-        # Per-row scatter, NOT dynamic_update_slice: a slice window is
-        # clamped as a whole, so a decode block [token, dummy] landing
-        # at start == capacity-1 would shift back one position —
-        # overwriting the previous token's entry and leaving the dummy
-        # unmasked at capacity-1.  mode="drop" keeps every row at its
-        # true index and discards rows past the capacity.  (hvd-serve's
-        # scheduler evicts one step before that boundary; this keeps
-        # forward_step's own contract exact for any caller stepping at
-        # the final cached position.)
-        idx = jnp.clip(start_b, 0, None) + jnp.arange(
-            new_b.shape[0], dtype=jnp.int32)
-        return view_b.at[idx].set(new_b, mode="drop",
-                                  unique_indices=True)
+    def attend(i, q, k, v, pos):
+        return view_attention(q, k, v, k_view[i], v_view[i], start_pos,
+                              pos)
 
-    for i in range(cfg.n_layers):
-        lp = _index_layer(params["layers"], i)
-        h = _layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
-        # Same fused [d, 3d] projection as the training forward.
-        qkv = jnp.dot(
-            h, jnp.concatenate([lp["wq"], lp["wk"], lp["wv"]], axis=-1),
-            preferred_element_type=jnp.float32).astype(x.dtype)
-        q, k, v = (y.reshape(b, s, h_n, hd)
-                   for y in jnp.split(qkv, 3, axis=-1))
-        k_full = jax.vmap(put)(k_view[i], k, start_pos)
-        v_full = jax.vmap(put)(v_view[i], v, start_pos)
-        attn = cache_attention(q, k_full, v_full, pos)
-        out = jnp.dot(attn.reshape(b, s, d), lp["wo"],
-                      preferred_element_type=jnp.float32).astype(x.dtype)
-        x, _ = _ffn_block(x + out, lp, cfg, ax, jnp.zeros((), jnp.float32))
-        k_news.append(k)
-        v_news.append(v)
-    x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    logits = jnp.dot(x, params["unembed"],
-                     preferred_element_type=jnp.float32)
-    return logits, jnp.stack(k_news), jnp.stack(v_news)
+    return _step_layers(params, tokens, start_pos, cfg, attend)
+
+
+def view_rungs(page_size: int, pages_per_slot: int) -> tuple:
+    """The ladder of KV-view lengths, in TOKENS: powers of two from 8
+    pages up, ``pages_per_slot`` always last; fewer than 16 pages per
+    slot is the one full rung."""
+    rungs = []
+    n = 8
+    while pages_per_slot >= 16 and n < pages_per_slot:
+        rungs.append(n * page_size)
+        n *= 2
+    rungs.append(pages_per_slot * page_size)
+    return tuple(rungs)
+
+
+def view_rung(start_pos, rungs, width: int = 2):
+    """Index of the smallest rung that holds ``max(start_pos) + width``
+    tokens (the last one if none does: rows past a view drop).  Pure
+    and the same for the traced ``start_pos`` of a serving program and
+    for the host's numpy lengths; inactive slots (``< 0``) ask for
+    nothing."""
+    need = start_pos.max() + width
+    return (need > np.asarray(rungs[:-1], np.int32)).sum()
+
+
+def forward_step_paged(params, tokens, start_pos, k_pages, v_pages,
+                       table, cfg: TransformerConfig, rungs):
+    """:func:`forward_step` straight over the page store, attending the
+    live tokens and not the capacity: each layer gathers only the first
+    ``n`` pages of every sequence's ``table`` row into its view, ``n``
+    the smallest of ``rungs`` (:func:`view_rungs`) that covers the
+    batch's longest sequence plus this block (:func:`view_rung`, from
+    ``start_pos`` INSIDE the program, ``lax.switch`` around the layer's
+    gather, put and attention only — the projections, the FFN and the
+    unembedding exist once).  Masked positions contribute exact zeros
+    (:func:`cache_attention`), so every rung is bitwise the full view.
+    The layers are rolled into a ``lax.scan``: unrolled, the rungs'
+    branches of every layer made the executable four times the dense
+    one's and its load at start-up three seconds longer.
+
+    ``k_pages``/``v_pages``: ``[n_layers, n_pages, page_size, heads *
+    head_dim]``; ``table``: ``[b, pages_per_slot]`` int32.  Returns
+    what :func:`forward_step` returns.
+    """
+    ps, pps = k_pages.shape[2], table.shape[1]
+    if pps * ps > cfg.max_seq_len:
+        raise ValueError(f"KV capacity {pps * ps} exceeds "
+                         f"cfg.max_seq_len {cfg.max_seq_len}")
+    b, s = tokens.shape
+    rung = view_rung(start_pos, rungs, s)
+
+    def over(n_tokens, layer, q, k, v, pos):
+        rows = table[:, :n_tokens // ps]
+        k_view = k_pages[layer, rows].reshape(b, n_tokens, *q.shape[2:])
+        v_view = v_pages[layer, rows].reshape(b, n_tokens, *q.shape[2:])
+        return view_attention(q, k, v, k_view, v_view, start_pos, pos)
+
+    def attend(layer, q, k, v, pos):
+        return jax.lax.switch(rung,
+                              [partial(over, n, layer) for n in rungs],
+                              q, k, v, pos)
+
+    return _step_layers(params, tokens, start_pos, cfg, attend, roll=True)
 
 
 def _put_view(view, new, pos):

@@ -2,12 +2,18 @@
 over the paged KV cache, driven by the continuous-batching scheduler.
 
 Megakernel-style data plane (docs/inference.md): each serving phase is
-ONE compiled XLA program — page-table gather → cache-aware forward
-(:func:`..models.transformer.forward_step`) → scatter of the new KV
-entries back into the paged store — with the page arrays donated, so a
-decode iteration is a single dispatch whose working set updates in
-place.  Executables are built ahead of time (``jit(...).lower(...)
-.compile()``) and recorded in the PR-5 persistent-cache manifest under
+ONE compiled XLA program — gather of the pages it attends →
+cache-aware forward → scatter of the new KV entries back into the paged
+store — with the page arrays donated, so a decode iteration is a single
+dispatch whose working set updates in place.  Prefill, verify and
+propose gather every slot's whole page-table row into a dense
+capacity-long view (:func:`..models.transformer.forward_step`); decode
+gathers, layer by layer, only the leading pages of each row that the
+iteration's longest live sequence reaches — the smallest rung of a
+ladder of page counts, picked inside the program from its ``lengths``
+(:func:`..models.transformer.forward_step_paged`).  Executables are
+built ahead of time (``jit(...).lower(...).compile()``) and recorded
+in the PR-5 persistent-cache manifest under
 ``variant: "serving"`` (ops/megakernel.py ``record_manifest_entry``):
 :meth:`InferenceEngine.warm_start` rebuilds every recorded executable
 at startup — against a warm compile-cache directory
@@ -77,6 +83,10 @@ _M_PREFILLS = _telemetry.counter(
     "serving.prefills", "prefill executions (one per admission)")
 _M_DECODES = _telemetry.counter(
     "serving.decode_iterations", "batched decode iterations")
+_M_VIEW_TOKENS = _telemetry.counter(
+    "serving.decode_view_tokens", "tokens of KV view per slot that the "
+    "decode iterations attended (the ladder rung each one rode); over "
+    "serving.decode_iterations it is the mean view")
 _M_WARM = _telemetry.counter(
     "serving.warm_starts", "serving executables AOT-rebuilt at startup")
 _M_SPEC_PROPOSED = _telemetry.counter(
@@ -295,6 +305,10 @@ class InferenceEngine:
                          if b <= self.capacity]
         if self._buckets[-1] != self.capacity:
             self._buckets.append(self.capacity)
+        # The decode program's view ladder (tokens), from the cache's
+        # geometry alone.
+        self._rungs = _transformer.view_rungs(self.cache.page_size,
+                                              self.cache.pages_per_slot)
         self._exec: Dict[Tuple, Any] = {}
         self._last_token = np.zeros((max_slots,), np.int32)
         # The second-newest context token per slot — the catch-up
@@ -536,33 +550,33 @@ class InferenceEngine:
 
     def _decode_exec(self) -> Any:
         cfg, cache, B = self.cfg, self.cache, self.max_slots
-        ps, pps, n_pages = (cache.page_size, cache.pages_per_slot,
-                            cache.n_pages)
-        L, H = cfg.n_layers, cfg.n_heads
-        hd = cfg.d_model // H
+        ps, L, rungs = cache.page_size, cfg.n_layers, self._rungs
 
         def kernel(params, k_pages, v_pages, table, lengths, tokens):
-            k_view = k_pages[:, table].reshape(L, B, pps * ps, H, hd)
-            v_view = v_pages[:, table].reshape(L, B, pps * ps, H, hd)
             # Width-2 block: [token, dummy]; the dummy column keeps the
             # gemms off XLA:CPU's bitwise-divergent single-row path and
             # is never sampled nor scattered.  The scheduler evicts at
             # prompt+generated == capacity, so the deepest decode here
             # runs at length == capacity-2 and the block always fits
-            # the view; forward_step itself stays exact one position
-            # further (it drops, not clamps, a row past the capacity).
+            # the last rung; every other rung is picked to hold it.
             blk = jnp.stack([tokens, jnp.zeros_like(tokens)], axis=1)
-            logits, k_new, v_new = _transformer.forward_step(
-                params, blk, lengths, k_view, v_view, cfg)
+            logits, k_new, v_new = _transformer.forward_step_paged(
+                params, blk, lengths, k_pages, v_pages, table, cfg,
+                rungs)
+            # One row a slot, written where it lies: B in-place
+            # dynamic-update-slices.  (A scatter over the flattened
+            # store makes the TPU copy all of it into a layout of the
+            # scatter's own, and back.)
             pos = jnp.clip(lengths, 0, None)
-            page = table[jnp.arange(B), pos // ps]
-            flat = page * ps + pos % ps
-            kf = k_pages.reshape(L, n_pages * ps, H, hd)
-            vf = v_pages.reshape(L, n_pages * ps, H, hd)
-            kf = kf.at[:, flat].set(k_new[:, :, 0])
-            vf = vf.at[:, flat].set(v_new[:, :, 0])
-            return (logits[:, 0], kf.reshape(k_pages.shape),
-                    vf.reshape(v_pages.shape))
+            page, off = table[jnp.arange(B), pos // ps], pos % ps
+            zero = jnp.zeros((), jnp.int32)
+            for slot in range(B):
+                at = (zero, page[slot], off[slot], zero)
+                k_pages = jax.lax.dynamic_update_slice(
+                    k_pages, k_new[:, slot, 0].reshape(L, 1, 1, -1), at)
+                v_pages = jax.lax.dynamic_update_slice(
+                    v_pages, v_new[:, slot, 0].reshape(L, 1, 1, -1), at)
+            return logits[:, 0], k_pages, v_pages
 
         table, lengths = cache.device_tables()
         args = (self.params, cache.k_pages, cache.v_pages, table,
@@ -992,6 +1006,9 @@ class InferenceEngine:
             for slot, _ in active:
                 tokens[slot] = self._last_token[slot]
             tokens = self._rep(tokens)
+            # The rung the program is about to pick from ``lengths``.
+            view = self._rungs[_transformer.view_rung(
+                self.cache.lengths(), self._rungs)]
         with _R_LAUNCH(iter=it):
             compiled = self._decode_exec()
             with _oom.guard("serving/decode"):
@@ -1016,6 +1033,7 @@ class InferenceEngine:
             if self._multiprocess():
                 self._bcast({"tokens": fed, "evict": evicted})
         _M_DECODES.inc()
+        _M_VIEW_TOKENS.inc(view)
         _M_TOKEN_LAT.observe(last.t1 - first.t0)
         return logits_np
 

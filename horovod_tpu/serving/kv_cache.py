@@ -1,8 +1,11 @@
 """Paged KV cache: fixed-size pages, free-list recycling, TP sharding.
 
 Storage is two device arrays per engine —
-``k_pages``/``v_pages: [n_layers, n_pages, page_size, n_heads,
-head_dim]`` — plus a HOST page table (``[max_slots, pages_per_slot]``
+``k_pages``/``v_pages: [n_layers, n_pages, page_size, n_heads *
+head_dim]`` (a token's heads side by side in the minor dimension: with
+a 64-wide ``head_dim`` of its own there, half a lane row, the TPU lays
+the store out PAGES-minor and every gather first copies all of it) —
+plus a HOST page table (``[max_slots, pages_per_slot]``
 int32, numpy) mapping each decode slot's logical positions onto
 physical pages.  Pages are allocated on demand as a sequence grows and
 recycled through a free list the moment the scheduler evicts it, so
@@ -20,10 +23,10 @@ Nothing ever reads trash through an unmasked attention row (entry
 masked rows contribute exact zeros regardless of trash content — the
 bitwise contract does not depend on it.
 
-Tensor parallelism: the head axis is sharded over the mesh's ``model``
-axis with a ``NamedSharding`` — the SAME partition
-``parallel/tensor.py`` gives the training attention (heads
-column-parallel), so a model served on its training mesh reuses the
+Tensor parallelism: the minor (heads) dimension is sharded over the
+mesh's ``model`` axis with a ``NamedSharding``, whole heads a shard —
+the SAME partition ``parallel/tensor.py`` gives the training attention
+(heads column-parallel), so a model served on its training mesh reuses the
 training layout and GSPMD partitions prefill/decode along heads with
 no code change here.
 
@@ -143,7 +146,7 @@ class PagedKVCache:
         self._fingerprint = fingerprint.encode()
         self._ledger_category = ledger_category
 
-        shape = (n_layers, self.n_pages, page_size, n_heads, head_dim)
+        shape = (n_layers, self.n_pages, page_size, n_heads * head_dim)
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
         sh = self.page_sharding()
@@ -223,7 +226,7 @@ class PagedKVCache:
                 f"tensor-parallel degree {tp} must divide n_heads "
                 f"({self.n_heads}) to shard the KV head axis")
         return NamedSharding(self.mesh,
-                             P(None, None, None, self.model_axis, None))
+                             P(None, None, None, self.model_axis))
 
     # -- gauges ------------------------------------------------------------
     def _set_page_gauges_locked(self) -> None:
@@ -616,6 +619,12 @@ class PagedKVCache:
         the scheduler's page-budget gate consume."""
         with self._lock:
             return len(self._free) + len(self._lru)
+
+    def lengths(self) -> np.ndarray:
+        """Every slot's cached length (-1 inactive), as
+        :meth:`device_tables` would ship them now."""
+        with self._lock:
+            return self._lengths.copy()
 
     def table_row(self, slot: int) -> np.ndarray:
         """One slot's page-table row, ``[1, pages_per_slot]`` (a copy —

@@ -10,6 +10,7 @@ policy, and engine relaunches, which is what makes continuous batching
 and elastic resize observably side-effect-free.
 """
 
+import functools
 import json
 import os
 import threading
@@ -23,7 +24,8 @@ import pytest
 from horovod_tpu.models.transformer import (TransformerConfig,
                                             forward_step,
                                             init_transformer,
-                                            serving_forward)
+                                            serving_forward, view_rung,
+                                            view_rungs)
 from horovod_tpu.serving import (ContinuousBatchingScheduler,
                                  FinishReason, InferenceEngine, LMServer,
                                  PagedKVCache, Request)
@@ -565,6 +567,247 @@ def test_engine_capacity_finished_rollout_is_bitwise(capacity):
     assert req.finish_reason == FinishReason.CAPACITY
     assert len(prompt) + len(out) == eng.capacity
     assert out == reference_rollout(prompt, len(out), eng.capacity)
+
+
+# ---------------------------------------------------------------------------
+# Decode's view ladder: the program attends the smallest rung of pages
+# that covers the iteration's longest live sequence
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("page_size,pages_per_slot,want", [
+    (16, 64, (128, 256, 512, 1024)),
+    (8, 32, (64, 128, 256)),
+    (16, 16, (128, 256)),
+    (16, 24, (128, 256, 384)),  # pages_per_slot is always the last
+    (8, 15, (120,)),            # fewer than 16 pages: the one full rung
+    (16, 8, (128,)),
+    (8, 4, (32,)),
+])
+def test_view_rungs_follow_the_page_geometry(page_size, pages_per_slot,
+                                             want):
+    assert view_rungs(page_size, pages_per_slot) == want
+
+
+@pytest.mark.parametrize("lengths,want", [
+    ([126, 3, -1], 0),    # 126 + 2 == 128: the rung itself holds it
+    ([127, 3, -1], 1),    # one more does not
+    ([3, 254, 40], 1),    # one slot alone picks the rung
+    ([255, -1, -1], 2),
+    ([510, 510, 510], 2),
+    ([-1, 511, -1], 3),
+    ([1022, 0, 0], 3),    # the deepest decode the scheduler allows
+    ([1023, 0, 0], 3),    # past every rung: the last one (rows drop)
+    ([0, -1, -1], 0),
+    ([-1, -1, -1], 0),    # all slots inactive
+])
+def test_view_rung_is_the_smallest_that_holds_the_block(lengths, want):
+    rungs = (128, 256, 512, 1024)
+    host = np.asarray(lengths, np.int32)
+    assert int(view_rung(host, rungs)) == want
+    # The traced function is the same function.
+    traced = jax.jit(lambda x: view_rung(x, rungs))(jnp.asarray(host))
+    assert traced.dtype == jnp.int32 and int(traced) == want
+    assert int(view_rung(host, rungs[-1:])) == 0  # one rung: no choice
+
+
+LCFG = TransformerConfig(vocab_size=97, d_model=64, n_heads=4,
+                         n_layers=2, d_ff=128, max_seq_len=256)
+LPARAMS = init_transformer(jax.random.PRNGKey(5), LCFG)
+# The issue's three-rung engine, and a two-rung one whose last rung is
+# the second.  (A 16-long view sums in another order than every longer
+# one under XLA:CPU, so no rung here is shorter than 32 tokens.)
+LADDERS = {
+    "cap256-page8": dict(params=LPARAMS, cfg=LCFG, page_size=8,
+                         capacity=256),
+    "cap64-page4": dict(params=PARAMS, cfg=CFG, page_size=4,
+                        capacity=64),
+}
+
+
+class Ladder:
+    """An engine with its ladder, and the same engine forced to the
+    full view; shared by the cases of one geometry (a finished request
+    frees its slot; a prefix hit is bitwise invisible)."""
+
+    def __init__(self, params, cfg, page_size, capacity):
+        self.params, self.cfg = params, cfg
+        self.kw = dict(max_slots=3, page_size=page_size,
+                       capacity=capacity)
+        self.rungs = view_rungs(page_size, capacity // page_size)
+        assert len(self.rungs) >= 2 and self.rungs[-1] == capacity
+        self.ladder = self.make()
+        self.full = self.make(full=True)
+
+    def make(self, full=False):
+        eng = InferenceEngine(self.params, self.cfg, **self.kw)
+        assert eng._rungs == self.rungs
+        if full:
+            eng._rungs = eng._rungs[-1:]
+        eng.warm_start()
+        return eng
+
+    def prompt(self, seed, n):
+        return [int(t) for t in jax.random.randint(
+            jax.random.PRNGKey(seed), (n,), 0, self.cfg.vocab_size)]
+
+    def view(self, longest):
+        """What a decode whose longest sequence holds ``longest``
+        tokens has to ride: room for the [token, dummy] block."""
+        return next(r for r in self.rungs if r >= longest + 2)
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder(name):
+    return Ladder(**LADDERS[name])
+
+
+@pytest.fixture(params=sorted(LADDERS))
+def ladder(request):
+    return _ladder(request.param)
+
+
+def _view_counter():
+    import horovod_tpu.telemetry as telemetry
+
+    return telemetry.metrics().get("serving.decode_view_tokens",
+                                   {}).get("value", 0)
+
+
+def _rollout(eng, prompts, max_new):
+    """Drive ``prompts`` together; returns the requests and, for each
+    decode iteration, its ``{slot: logits row}``, the lengths it ran at
+    and its advance of ``serving.decode_view_tokens``."""
+    reqs = [eng.submit(list(p), max_new_tokens=n)
+            for p, n in zip(prompts, max_new)]
+    rows, lengths, views = [], [], []
+    orig = eng._decode_iteration
+
+    def wrapped(active):
+        before = _view_counter()
+        lengths.append(max(eng.cache.length(s) for s, _ in active))
+        logits = orig(active)
+        views.append(_view_counter() - before)
+        rows.append({slot: logits[slot].copy() for slot, _ in active})
+        return logits
+
+    eng._decode_iteration = wrapped
+    try:
+        eng.run_until_idle()
+    finally:
+        eng._decode_iteration = orig
+    return reqs, rows, lengths, views
+
+
+def _assert_ladder_is_bitwise(lad, prompts, max_new):
+    """Returns the requests and the view of every decode iteration."""
+    reqs, rows, lengths, views = _rollout(lad.ladder, prompts, max_new)
+    freqs, frows, _, fviews = _rollout(lad.full, prompts, max_new)
+    # The counter advances by the rung the longest live sequence needs.
+    assert views == [lad.view(n) for n in lengths]
+    assert fviews == [lad.rungs[-1]] * len(views)
+    # Rung by rung the logits are those of the full view...
+    assert len(rows) == len(frows)
+    for i, (a, b) in enumerate(zip(rows, frows)):
+        assert a.keys() == b.keys()
+        for slot in a:
+            assert a[slot].tobytes() == b[slot].tobytes(), (i, slot)
+    assert [r.result(0) for r in reqs] == [r.result(0) for r in freqs]
+    # ... and the tokens are the non-incremental forward's greedy
+    # ones.  (Its LOGITS are bitwise the engine's only while its own
+    # gemms are small under XLA:CPU, a dozen tokens, with the ladder
+    # as without it: test_engine_bitwise_vs_noncached_forward_through_
+    # executables holds that end.)
+    sf = jax.jit(serving_forward, static_argnums=(2, 3))
+    for req, prompt in zip(reqs, prompts):
+        gen = req.result(0)
+        seq = list(prompt) + gen
+        ref = np.asarray(sf(lad.params, jnp.asarray([seq], jnp.int32),
+                            lad.cfg, lad.ladder.capacity))
+        greedy = np.argmax(ref[0, len(prompt) - 1:-1], axis=-1)
+        assert gen == [int(t) for t in greedy]
+    return reqs, views
+
+
+@pytest.mark.parametrize("name,across", [
+    ("cap256-page8", "first"), ("cap256-page8", "second"),
+    ("cap256-page8", "both"), ("cap64-page4", "first")])
+def test_engine_rollout_across_a_rung_boundary_is_bitwise(name, across):
+    lad = _ladder(name)
+    lo, hi = {"first": (0, 1), "second": (1, 2), "both": (0, 2)}[across]
+    # The first decode runs at length == len(prompt), and the last
+    # length a rung r holds is r - 2: two iterations on the lowest
+    # rung, all of those between, two past the last boundary.
+    start = lad.rungs[lo] - 3
+    want = ([lad.rungs[lo]] * 2
+            + [r for r, below in zip(lad.rungs[lo + 1:hi],
+                                     lad.rungs[lo:hi])
+               for _ in range(r - below)]
+            + [lad.rungs[hi]] * 2)
+    _, views = _assert_ladder_is_bitwise(
+        lad, [lad.prompt(start, start)], [len(want) + 1])
+    assert views == want
+
+
+def test_engine_one_long_slot_alone_forces_the_higher_rung(ladder):
+    """Three slots, one of them past the first rung: every slot rides
+    the second rung while it lives, and the short ones drop back to
+    the first when it has finished."""
+    r0, r1 = ladder.rungs[:2]
+    prompts = [ladder.prompt(31, 5), ladder.prompt(32, r0 + 6),
+               ladder.prompt(33, 9)]
+    _, views = _assert_ladder_is_bitwise(ladder, prompts, [9, 4, 7])
+    assert views == [r1] * 3 + [r0] * 5
+
+
+def test_engine_ladder_rollout_that_finishes_at_capacity_is_bitwise(
+        ladder):
+    cap = ladder.rungs[-1]
+    prompt = ladder.prompt(41, cap - 6)
+    (req,), views = _assert_ladder_is_bitwise(ladder, [prompt], [99])
+    assert req.finish_reason == FinishReason.CAPACITY
+    assert len(prompt) + len(req.result(0)) == cap
+    assert views == [cap] * 5
+
+
+def test_engine_ladder_tensor_parallel_matches_single_device():
+    """The store's heads sharded over a model axis: each rung's gather
+    is on the page axis, inside the conditional, under GSPMD."""
+    from horovod_tpu.core.topology import make_mesh
+
+    lad = _ladder("cap256-page8")
+    r0, r1 = lad.rungs[:2]
+    mesh = make_mesh(data=1, model=2, devices=jax.devices()[:2])
+    tp = InferenceEngine(lad.params, lad.cfg, mesh=mesh, **lad.kw)
+    assert tp.cache.page_sharding() is not None
+    tp.warm_start()
+    prompt = lad.prompt(61, r0 - 3)
+    (req,), _, _, views = _rollout(tp, [prompt], [6])
+    (ref,), _, _, _ = _rollout(lad.ladder, [prompt], [6])
+    assert views == [r0] * 2 + [r1] * 3
+    assert req.result(0) == ref.result(0)
+
+
+def test_engine_ladder_is_one_decode_executable(ladder, tmp_path,
+                                                monkeypatch):
+    """The rung is picked inside the program: one decode executable
+    and one manifest entry, whatever rungs the traffic rode."""
+    assert ([k for k in ladder.ladder._exec if k[0] == "decode"]
+            == [("decode",)])
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    eng = ladder.make()
+    assert eng.health()[1]["executables"] == 1
+    r0, r1 = ladder.rungs[:2]
+    _, _, _, views = _rollout(eng, [ladder.prompt(51, r0 - 2)], [4])
+    assert views == [r0, r1, r1]
+    bucket = eng._bucket_for(r0 - 2)
+    assert sorted(eng._exec) == [("decode",), ("prefill", bucket)]
+    assert eng.health()[1]["executables"] == 2
+    man = json.loads(
+        (tmp_path / "megakernel_manifest.json").read_text())
+    kinds = [(e["kind"], e.get("bucket")) for e in man["entries"]
+             if e["variant"] == "serving"]
+    assert sorted(kinds, key=str) == [("decode", None),
+                                      ("prefill", bucket)]
 
 
 def test_engine_eos_and_sampling_determinism():
