@@ -3,7 +3,7 @@
     python chip_smoke.py            # on the chip (through the chip tool)
     python chip_smoke.py --dry-run  # tiny widths on the 8-device CPU mesh
 
-One process, no children, no ``JAX_PLATFORMS`` override.  Four legs run
+One process, no children, no ``JAX_PLATFORMS`` override.  Five legs run
 through the entry points a user calls, at full width per chip:
 
   A  ResNet-50 data-parallel trainer (the BASELINE.json workload):
@@ -21,6 +21,12 @@ through the entry points a user calls, at full width per chip:
      examples/serve_lm.py --serve builds them, /healthz, /generate over
      HTTP (two concurrent, one repeated), logits against
      serving_forward.
+  E  the latent-attention mixture of experts the benchmark serves
+     (benchmark/configs/axk1-ep16.json, one chip's share of a 16-chip
+     expert-parallel group): the cut model built from the seed, two
+     requests through InferenceEngine, and the LOGITS its own prefill
+     and decode executables produced through the latent paged cache
+     against the benchmark's plain float32 reference.
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
@@ -93,6 +99,13 @@ FLASH_LSE_ABS_TOL = 1e-3
 # float32 logits agree to bf16 rounding carried through the layers.
 # Measured on the v5e (PR 21): 1.0e-2 of the largest logit.
 SERVE_REL_TOL = 2.0 ** -5
+# Latent MoE: bf16 program against the float32 ``highest`` reference.
+# The root-mean-square error of the logits, over their spread, is what
+# bf16 rounding carried through 7 layers gives (measured on the v5e, PR
+# 27: 0.03-0.055; the reference itself computed in bf16 reads 0.028, in
+# fp8 0.25).  Single logits move by far more wherever a near-tied
+# router choice flips, so the largest error is reported, not judged.
+LATENT_RMS_REL_TOL = 0.12
 
 
 class LegFailed(Exception):
@@ -600,6 +613,89 @@ def leg_serve(w, wl):
     }
 
 
+def leg_latent_moe(dry):
+    """Leg E.  The benchmark's own configuration, builder and reference:
+    the cut model at the published widths on the chip, its toy fixture
+    in the dry run."""
+    import gc
+
+    import horovod_tpu as hvd
+    from benchmark import cells
+    from benchmark.builders.latent_moe import config_of, seeded_params
+    from horovod_tpu.serving import InferenceEngine
+
+    path = os.path.join(cells.HERE, *(
+        ("tests", "fixtures", "configs", "tiny-axk1.json") if dry
+        else ("configs", "axk1-ep16.json")))
+    with open(path) as f:
+        config = json.load(f)
+    m = config["model"]
+    ref = cells.load_module("refs", config["ref"])
+    cfg = config_of(m)
+    jax.clear_caches()
+    gc.collect()
+    t0 = time.perf_counter()
+    params = seeded_params(m, cfg, 2_400_000_027, ref)
+    engine = InferenceEngine(params, cfg, mesh=None,
+                             max_slots=4 if dry else 64,
+                             page_size=8 if dry else 16,
+                             capacity=256 if dry else 4096)
+    engine.warm_start()
+    setup_s = time.perf_counter() - t0
+    check(not engine.cache.prefix_enabled and len(engine.cache.pages) == 1,
+          "the latent model caches one store, prefix cache off")
+
+    rows = {}
+    orig_prefill, orig_decode = engine._prefill, engine._decode_iteration
+
+    def prefill(slot, req, prompt=None):
+        last = orig_prefill(slot, req, prompt)
+        rows.setdefault(req.rid, []).append(last.copy())
+        return last
+
+    def decode(active):
+        owners = {slot: req.rid for slot, req in active}
+        logits = orig_decode(active)
+        for slot, rid in owners.items():
+            rows[rid].append(logits[slot].copy())
+        return logits
+
+    engine._prefill, engine._decode_iteration = prefill, decode
+    rng = np.random.default_rng(27)
+    prompts = [[int(t) for t in rng.integers(0, m["vocab_size"], size=k)]
+               for k in ((40, 100) if dry else (700, 150))]
+    new = 8 if dry else 24
+    before = hvd.metrics()
+    t0 = time.perf_counter()
+    reqs = [engine.submit(p, max_new_tokens=new) for p in prompts]
+    engine.run_until_idle()
+    serve_s = time.perf_counter() - t0
+    after = hvd.metrics()
+    tokens = [r.result(0) for r in reqs]
+    check(all(len(t) == new for t in tokens), "every request served whole")
+    check(grew(before, after, "serving.moe_assignments") > 0,
+          "the decode program's expert counts reach the counters")
+    del engine
+    gc.collect()
+
+    want = ref.served_logits(m, params, [p + t for p, t in
+                                         zip(prompts, tokens)], "f32")
+    rms, worst = [], 0.0
+    for p, r, w in zip(prompts, reqs, want):
+        got = np.stack(rows[r.rid])
+        w = w[len(p) - 1:len(p) - 1 + new]
+        rms.append(float(np.sqrt(np.mean((got - w) ** 2)) / w.std()))
+        worst = max(worst, float(np.abs(got - w).max() / w.std()))
+    check(max(rms) <= LATENT_RMS_REL_TOL,
+          f"logits through the latent cache differ from the reference: "
+          f"rms/std {rms} > {LATENT_RMS_REL_TOL}")
+    return {"config": config["name"], "setup_s": round(setup_s, 1),
+            "serve_s": round(serve_s, 2), "logit_rms_over_std": rms,
+            "logit_max_over_std": worst,
+            "pairs_on_held_experts": grew(before, after,
+                                          "serving.moe_assignments")}
+
+
 # ---------------------------------------------------------------------------
 
 def main() -> int:
@@ -608,9 +704,9 @@ def main() -> int:
                     help="toy widths on whatever platform jax has; the "
                          "result line says ok=false and names the "
                          "platform (for the test suite, never a pass)")
-    ap.add_argument("--legs", default="ABCD",
+    ap.add_argument("--legs", default="ABCDE",
                     help="subset of legs to run while debugging, e.g. "
-                         "AD; anything short of all four is not a pass")
+                         "AD; anything short of all five is not a pass")
     args = ap.parse_args()
     dry = args.dry_run
 
@@ -664,7 +760,8 @@ def main() -> int:
     plan = (("A_resnet_dp", lambda: leg_resnet(w["resnet"], dry)),
             ("B_lm_pallas", lambda: leg_lm(w["lm"], w["flash"], dry)),
             ("C_eager", lambda: leg_eager(w["eager"])),
-            ("D_serve", lambda: leg_serve(w["serve"], w["lm"])))
+            ("D_serve", lambda: leg_serve(w["serve"], w["lm"])),
+            ("E_latent_moe", lambda: leg_latent_moe(dry)))
     for name, fn in plan:
         if name[0] not in args.legs.upper():
             continue

@@ -201,3 +201,130 @@ def moe_layer(x, params: dict, *, axis_name: str, num_experts: int,
                      combine.astype(jnp.float32))
     return MoEOutput(out.astype(x.dtype), aux.astype(jnp.float32),
                      dropped.astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel group, with no mesh axis at all
+# (docs/inference.md "Latent-attention mixture of experts").  The layer is
+# TOLD which experts it holds, routes over all of them, drops nothing, and
+# computes the part of the result its own experts give.  Work follows the
+# assignments: (token, expert) pairs are sorted by expert and run through
+# grouped matrix products (``jax.lax.ragged_dot``) in fixed-size chunks of
+# rows, as many chunks as the pairs on held experts need.
+# ---------------------------------------------------------------------------
+
+
+class HeldExpertsOutput(NamedTuple):
+    out: jnp.ndarray      # [tokens, d_model] float32: shared + held experts
+    counts: jnp.ndarray   # [experts_held] int32: assignments computed here
+
+
+def route_sigmoid_top_k(x, router, top_k: int, routed_scale: float = 1.0,
+                        norm_topk: bool = True):
+    """Sigmoid scores over ALL experts, the ``top_k`` largest, weights
+    ``g_e / sum of the chosen * routed_scale``.  The scores are computed
+    in float32 at the highest matmul precision: a near-tied choice should
+    flip on the hidden state's rounding, not on the router's own.
+    Returns ``(experts [t, k] int32, weights [t, k] float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    gate, idx = jax.lax.top_k(scores, top_k)
+    if norm_topk:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gate * routed_scale
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``down(silu(gate(x)) * up(x))``, float32 accumulation."""
+    g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
+def held_chunk_rows(tokens: int, top_k: int, experts_held: int,
+                    num_experts: int) -> int:
+    """Rows of one chunk of sorted assignments: twice what balanced
+    routing sends to the held experts, a power of two in [128, 2048] and
+    no more than the layer can ever see.  A skewed batch takes more
+    chunks, never fewer tokens."""
+    want = 2 * tokens * top_k * experts_held // num_experts
+    rows = 128
+    while rows < min(want, 2048):
+        rows *= 2
+    return max(8, min(rows, -(-tokens * top_k // 8) * 8))
+
+
+def moe_layer_held(x, params: dict, *, num_experts: int,
+                   expert_offset: int, top_k: int,
+                   routed_scale: float = 1.0, norm_topk: bool = True,
+                   token_mask=None,
+                   chunk_rows: Optional[int] = None) -> HeldExpertsOutput:
+    """The share of a sigmoid-routed SwiGLU expert layer that ONE member
+    of an expert-parallel group computes: no mesh axis, no exchange, no
+    capacity, no dropped token.
+
+    ``x``: ``[tokens, d_model]``.  ``params``: ``router [d, num_experts]``
+    (whole, as every member holds it), ``shared`` (``w_gate``/``w_up``
+    ``[d, f_s]``, ``w_down [f_s, d]``: counted once, by every member
+    alike), ``w_gate``/``w_up [experts_held, d, f]`` and ``w_down
+    [experts_held, f, d]``: experts ``expert_offset .. expert_offset +
+    experts_held`` of the ``num_experts``.  ``token_mask`` (``[tokens]``
+    bool) takes padding and idle rows out of the routing: they reach no
+    expert and count nowhere.  What the absent experts would add is left
+    out; the caller sends the partial result on.
+    """
+    t, d = x.shape
+    held = params["w_gate"].shape[0]
+    if not 0 <= expert_offset <= num_experts - held:
+        raise ValueError(
+            f"experts {expert_offset}..{expert_offset + held} are not "
+            f"among the router's {num_experts}")
+    idx, gate = route_sigmoid_top_k(x, params["router"], top_k,
+                                    routed_scale, norm_topk)
+    local = idx - expert_offset
+    here = (local >= 0) & (local < held)
+    if token_mask is not None:
+        here = here & token_mask[:, None]
+    # Assignments sorted by held expert; everything else sorts last.
+    flat = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_rows = ends[-1]
+
+    rows = chunk_rows or held_chunk_rows(t, top_k, held, num_experts)
+    padded = -(-t * top_k // rows) * rows
+    pad = padded - t * top_k
+    tok = jnp.pad((order // top_k).astype(jnp.int32), (0, pad))
+    w_sorted = jnp.pad(gate.reshape(-1)[order], (0, pad))
+
+    def chunk(c, acc):
+        base = c * rows
+        tok_c = jax.lax.dynamic_slice(tok, (base,), (rows,))
+        w_c = jax.lax.dynamic_slice(w_sorted, (base,), (rows,))
+        valid = base + jnp.arange(rows, dtype=jnp.int32) < n_rows
+        sizes = (jnp.clip(ends - base, 0, rows)
+                 - jnp.clip(starts - base, 0, rows)).astype(jnp.int32)
+        xs = x[tok_c]
+        g = jax.lax.ragged_dot(xs, params["w_gate"], sizes,
+                               preferred_element_type=jnp.float32)
+        u = jax.lax.ragged_dot(xs, params["w_up"], sizes,
+                               preferred_element_type=jnp.float32)
+        y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype),
+                               params["w_down"], sizes,
+                               preferred_element_type=jnp.float32)
+        # Rows past the chunk's last group hold whatever the grouped
+        # product left there: selected away, not multiplied away.
+        y = jnp.where(valid[:, None], y * w_c[:, None], 0.0)
+        return acc.at[tok_c].add(y)
+
+    with jax.named_scope("moe_routed"):
+        routed = jax.lax.fori_loop(0, (n_rows + rows - 1) // rows, chunk,
+                                   jnp.zeros((t, d), jnp.float32))
+    with jax.named_scope("moe_shared"):
+        s = params["shared"]
+        shared = swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
+    return HeldExpertsOutput(shared + routed, counts)

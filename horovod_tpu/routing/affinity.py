@@ -15,7 +15,7 @@ absorbs that page's token ids as little-endian int32 bytes and emits
 its digest — ``h_j`` commits to the model fingerprint AND every token
 of pages ``0..j``, so a hit on page ``j`` implies the whole prefix
 matches with no token comparison.  ``fingerprint`` is the engine's
-model-identity JSON (``serving/engine.py _model_dict``, sorted keys),
+model-identity JSON (``serving/models.py``'s ``identity()``, sorted keys),
 exported verbatim in ``/healthz`` so the router self-configures from
 the replicas it fronts.
 """

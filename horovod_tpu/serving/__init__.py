@@ -1,7 +1,7 @@
 """hvd-serve: continuous-batching inference over the training mesh.
 
 The serving runtime the north star's "heavy traffic from millions of
-users" scenario needs (ROADMAP open item 4; docs/inference.md).  Four
+users" scenario needs (ROADMAP open item 4; docs/inference.md).  Five
 pieces, each its own module:
 
 * :mod:`~horovod_tpu.serving.scheduler` — request queue + iteration-
@@ -13,6 +13,9 @@ pieces, each its own module:
   pages recycled through a free list, head axis sharded with the
   ``parallel/tensor.py`` tensor-parallel layout so serving reuses the
   training partition.
+* :mod:`~horovod_tpu.serving.models` — what the engine asks of a
+  model (cache entry, paged decode step over the view ladder, prefill
+  step, fingerprint), and the dense multi-head decoder's answers.
 * :mod:`~horovod_tpu.serving.engine` — prefill and decode compiled as
   donated AOT executables (megakernel-style: gather → forward →
   scatter in ONE program), recorded in the PR-5 persistent-cache

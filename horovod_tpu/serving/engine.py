@@ -5,8 +5,11 @@ Megakernel-style data plane (docs/inference.md): each serving phase is
 ONE compiled XLA program — gather of the pages it attends →
 cache-aware forward → scatter of the new KV entries back into the paged
 store — with the page arrays donated, so a decode iteration is a single
-dispatch whose working set updates in place.  Prefill, verify and
-propose gather every slot's whole page-table row into a dense
+dispatch whose working set updates in place.  The engine knows no
+model: what a token leaves in the cache and the step functions the
+executables wrap come from the model's serving protocol
+(serving/models.py; below, the dense multi-head decoder's).  Prefill,
+verify and propose gather every slot's whole page-table row into a dense
 capacity-long view (:func:`..models.transformer.forward_step`); decode
 gathers, layer by layer, only the leading pages of each row that the
 iteration's longest live sequence reaches — the smallest rung of a
@@ -45,6 +48,7 @@ from __future__ import annotations
 import json
 import os
 import time
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -65,6 +69,7 @@ from ..telemetry import flight as _flight
 from ..models import transformer as _transformer
 from ..ops import megakernel as _megakernel
 from .kv_cache import PagedKVCache
+from .models import serving_model as _serving_model
 from .scheduler import (ContinuousBatchingScheduler, FinishReason,
                         Request)
 
@@ -118,28 +123,24 @@ _R_SAMPLE = _trace.region("serve.sample", "serve", timed=True)
 _R_WARM_START = _trace.region("serve.warm_start", "init")
 
 
-def _model_dict(cfg) -> dict:
-    """One model-identity dict for every identity consumer — the
-    prefix-cache fingerprint, the manifest's model field, and the
-    draft identity on speculative entries.  A new config field that
-    changes compiled programs or KV content belongs HERE, once."""
-    return {
-        "vocab_size": cfg.vocab_size,
-        "d_model": cfg.d_model,
-        "n_heads": cfg.n_heads,
-        "n_layers": cfg.n_layers,
-        "d_ff": cfg.d_ff,
-        "max_seq_len": cfg.max_seq_len,
-        "num_experts": cfg.num_experts,
-        "dtype": jnp.dtype(cfg.dtype).name,
-    }
+def _make_cache(model, max_slots: int, pages_per_slot: int,
+                page_size: int, **kw) -> PagedKVCache:
+    """The paged store for what ``model`` caches."""
+    entry = model.cache_entry()
+    return PagedKVCache(entry["n_layers"], entry["n_heads"],
+                        entry["head_dim"], max_slots, pages_per_slot,
+                        page_size, dtype=model.cfg.dtype,
+                        entry_widths=entry["widths"], **kw)
 
 
 class InferenceEngine:
-    """Continuous-batching inference over one transformer LM.
+    """Continuous-batching inference over one decoder LM.
 
-    ``params``/``cfg`` are the training-side parameter pytree and
-    :class:`~horovod_tpu.models.transformer.TransformerConfig`.  With a
+    ``params``/``cfg`` are a parameter pytree and its config: the
+    training-side :class:`~horovod_tpu.models.transformer.
+    TransformerConfig`, or any config whose ``serving_model()`` supplies
+    the serving protocol (serving/models.py; ``models/latent_moe.py``'s
+    latent-attention mixture of experts is one).  With a
     ``mesh`` that has a ``model`` axis, the KV head axis and the
     attention/FFN compute shard over it exactly like the training
     forward (the ``parallel/tensor.py`` layout, via GSPMD).  Threading
@@ -180,6 +181,7 @@ class InferenceEngine:
         if cap < 2:
             raise ValueError("KV capacity must be >= 2")
         self.cfg = cfg
+        self.model = _serving_model(cfg)
         self.mesh = mesh
         self.eos_id = eos_id
         self.max_slots = max_slots
@@ -198,14 +200,28 @@ class InferenceEngine:
         if prefix_pages is None:
             prefix_pages = int(os.environ.get(
                 "HVD_TPU_PREFIX_PAGES", "0"))
-        fingerprint = json.dumps(_model_dict(cfg), sort_keys=True)
+        if prefix_cache and not self.model.prefix_cache:
+            _flight.record("serve_prefix_cache_off",
+                           self.model.prefix_cache_why)
+            prefix_cache, prefix_pages = False, 0
+        if draft is not None and not self.model.speculative:
+            raise ValueError(
+                f"{type(self.model).__name__} has no verify/propose "
+                f"programs: speculative decoding is not supported for "
+                f"this model")
+        fingerprint = json.dumps(self.model.identity(), sort_keys=True)
         # Exported verbatim in /healthz: the router tier keys its
         # prefix-affinity chain hashes off this (routing/affinity.py).
         self.fingerprint = fingerprint
-        self.cache = PagedKVCache(
-            cfg.n_layers, cfg.n_heads, cfg.d_model // cfg.n_heads,
-            max_slots, cap // page_size, page_size,
-            dtype=cfg.dtype, mesh=mesh, model_axis=model_axis,
+        if (not self.model.tensor_parallel and mesh is not None
+                and dict(mesh.shape).get(model_axis, 1) > 1):
+            raise ValueError(
+                f"{type(self.model).__name__} caches one entry all heads "
+                f"share: its store cannot be sharded over the "
+                f"'{model_axis}' axis (serve it with mesh=None)")
+        self.cache = _make_cache(
+            self.model, max_slots, cap // page_size, page_size,
+            mesh=mesh, model_axis=model_axis,
             prefix_cache=prefix_cache, prefix_pages=prefix_pages,
             fingerprint=fingerprint)
         self.capacity = self.cache.capacity
@@ -228,6 +244,7 @@ class InferenceEngine:
         self.spec_tokens = spec_tokens
         self._draft_params = None
         self._draft_cfg = None
+        self._draft_model = None
         self.draft_cache: Optional[PagedKVCache] = None
         if draft is not None:
             # Validated only when a draft is armed: without one the
@@ -247,19 +264,18 @@ class InferenceEngine:
                     f"draft max_seq_len {draft_cfg.max_seq_len} must "
                     f"cover the KV capacity {cap}")
             self._draft_cfg = draft_cfg
+            self._draft_model = _serving_model(draft_cfg)
             # The draft store rides the shared-prefix index too
             # (hvd-spec tail): a prompt-header hit skips the DRAFT
             # prefill as well as the target's.  Its chain hashes are
             # keyed by the DRAFT config's fingerprint — the two caches
             # hold different models' KV, so their indexes must never
             # collide on a shared token prefix.
-            self.draft_cache = PagedKVCache(
-                draft_cfg.n_layers, draft_cfg.n_heads,
-                draft_cfg.d_model // draft_cfg.n_heads,
-                max_slots, cap // page_size, page_size,
-                dtype=draft_cfg.dtype, mesh=mesh, model_axis=model_axis,
+            self.draft_cache = _make_cache(
+                self._draft_model, max_slots, cap // page_size, page_size,
+                mesh=mesh, model_axis=model_axis,
                 prefix_cache=prefix_cache,
-                fingerprint=json.dumps(_model_dict(draft_cfg),
+                fingerprint=json.dumps(self._draft_model.identity(),
                                        sort_keys=True),
                 ledger_category="serving.draft_kv")
             if mesh is not None and self.cache.page_sharding() is not None:
@@ -459,19 +475,15 @@ class InferenceEngine:
         try:
             from ..memory import ledger as _mem_ledger
 
-            per_device = (_mem_ledger.device_nbytes(self.cache.k_pages)
-                          + _mem_ledger.device_nbytes(
-                              self.cache.v_pages)
-                          + sum(_mem_ledger.device_nbytes(x) for x in
-                                jax.tree_util.tree_leaves(self.params)))
+            per_device = sum(
+                _mem_ledger.device_nbytes(x) for x in
+                self.cache.pages + tuple(
+                    jax.tree_util.tree_leaves(self.params)))
             if self.draft_cache is not None:
-                per_device += (
-                    _mem_ledger.device_nbytes(self.draft_cache.k_pages)
-                    + _mem_ledger.device_nbytes(
-                        self.draft_cache.v_pages)
-                    + sum(_mem_ledger.device_nbytes(x) for x in
-                          jax.tree_util.tree_leaves(
-                              self._draft_params)))
+                per_device += sum(
+                    _mem_ledger.device_nbytes(x) for x in
+                    self.draft_cache.pages + tuple(
+                        jax.tree_util.tree_leaves(self._draft_params)))
             _oom.preflight_warn(per_device, "serving.warm_start",
                                 "KV shard + replicated params "
                                 "(per-device bytes)")
@@ -489,7 +501,7 @@ class InferenceEngine:
     def _manifest_identity(self) -> dict:
         return {
             "variant": "serving",
-            "model": _model_dict(self.cfg),
+            "model": self.model.identity(),
             "slots": self.max_slots,
             "page_size": self.cache.page_size,
             "pages_per_slot": self.cache.pages_per_slot,
@@ -497,9 +509,9 @@ class InferenceEngine:
         }
 
     def _draft_model_dict(self) -> Optional[dict]:
-        if self._draft_cfg is None:
+        if self._draft_model is None:
             return None
-        return _model_dict(self._draft_cfg)
+        return self._draft_model.identity()
 
     def _record(self, kind: str, bucket: Optional[int]) -> None:
         entry = dict(self._manifest_identity())
@@ -511,16 +523,27 @@ class InferenceEngine:
         _megakernel.record_manifest_entry(entry, self._manifest_dir)
 
     # -- executables -------------------------------------------------------
-    def _aot(self, key: Tuple, fn, args: Tuple) -> Any:
-        """Compile ``fn`` for ``args``' shapes/shardings (donating the
-        page arrays at positions 1 and 2) and cache the executable."""
+    def _aot(self, key: Tuple, step, cache: PagedKVCache,
+             args: Tuple) -> Any:
+        """Compile the executable ``key``: ``step(params, pages, *rest)
+        -> (outs, pages)``, a step function of the model's, over
+        ``args = (params, *cache.pages, *rest)``' shapes/shardings, the
+        page arrays (positions 1 ..) donated; cache it.  The executable
+        takes ``args`` and returns ``(*outs, *pages)``."""
         compiled = self._exec.get(key)
         if compiled is not None:
             return compiled
+        n = len(cache.pages)
+        donated = tuple(range(1, 1 + n))
+
+        def fn(params, *rest):
+            outs, pages = step(params, rest[:n], *rest[n:])
+            return (*outs, *pages)
+
         avals = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding), args)
-        jfn = jax.jit(fn, donate_argnums=(1, 2))
+        jfn = jax.jit(fn, donate_argnums=donated)
         compiled = jfn.lower(*avals).compile()
         # hvd-mem: harvest compiled.memory_analysis() per serving
         # executable (prefill buckets + decode) into the planner's
@@ -529,13 +552,13 @@ class InferenceEngine:
         _mem_planner.record_compiled(label, compiled)
 
         # hvd-race donation sanitizer: every serving dispatch donates
-        # the page arrays (positions 1, 2); routing the executable
+        # the page arrays (positions 1 ..); routing the executable
         # through the registry turns a stale re-dispatch of donated
         # pages (a forgotten replace_pages) into a DonationError naming
         # this executable instead of XLA's opaque deletion error.
         def guarded(*call_args, _raw=compiled, _label=label):
             return _donation.guard_dispatch(_label, _raw, call_args,
-                                            (1, 2))
+                                            donated)
 
         self._exec[key] = guarded
         self._record(key[0], key[1] if len(key) > 1 else None)
@@ -549,39 +572,13 @@ class InferenceEngine:
         return a
 
     def _decode_exec(self) -> Any:
-        cfg, cache, B = self.cfg, self.cache, self.max_slots
-        ps, L, rungs = cache.page_size, cfg.n_layers, self._rungs
-
-        def kernel(params, k_pages, v_pages, table, lengths, tokens):
-            # Width-2 block: [token, dummy]; the dummy column keeps the
-            # gemms off XLA:CPU's bitwise-divergent single-row path and
-            # is never sampled nor scattered.  The scheduler evicts at
-            # prompt+generated == capacity, so the deepest decode here
-            # runs at length == capacity-2 and the block always fits
-            # the last rung; every other rung is picked to hold it.
-            blk = jnp.stack([tokens, jnp.zeros_like(tokens)], axis=1)
-            logits, k_new, v_new = _transformer.forward_step_paged(
-                params, blk, lengths, k_pages, v_pages, table, cfg,
-                rungs)
-            # One row a slot, written where it lies: B in-place
-            # dynamic-update-slices.  (A scatter over the flattened
-            # store makes the TPU copy all of it into a layout of the
-            # scatter's own, and back.)
-            pos = jnp.clip(lengths, 0, None)
-            page, off = table[jnp.arange(B), pos // ps], pos % ps
-            zero = jnp.zeros((), jnp.int32)
-            for slot in range(B):
-                at = (zero, page[slot], off[slot], zero)
-                k_pages = jax.lax.dynamic_update_slice(
-                    k_pages, k_new[:, slot, 0].reshape(L, 1, 1, -1), at)
-                v_pages = jax.lax.dynamic_update_slice(
-                    v_pages, v_new[:, slot, 0].reshape(L, 1, 1, -1), at)
-            return logits[:, 0], k_pages, v_pages
-
+        cache, B = self.cache, self.max_slots
         table, lengths = cache.device_tables()
-        args = (self.params, cache.k_pages, cache.v_pages, table,
-                lengths, self._rep(np.zeros((B,), np.int32)))
-        return self._aot(("decode",), kernel, args)
+        args = (self.params, *cache.pages, table, lengths,
+                self._rep(np.zeros((B,), np.int32)))
+        return self._aot(("decode",),
+                         partial(self.model.decode, rungs=self._rungs),
+                         cache, args)
 
     def _prefill_exec(self, bucket: int, draft: bool = False) -> Any:
         """Prefill executable, START-aware: ``start`` is the number of
@@ -591,43 +588,16 @@ class InferenceEngine:
         padded ``tokens`` block — the last real token's logits are what
         admission samples from.  ``draft=True`` builds the same program
         over the draft model/cache (cold draft prefill on admission)."""
-        cfg = self._draft_cfg if draft else self.cfg
+        model = self._draft_model if draft else self.model
         cache = self.draft_cache if draft else self.cache
         params = self._draft_params if draft else self.params
-        ps, pps, n_pages = (cache.page_size, cache.pages_per_slot,
-                            cache.n_pages)
-        cap = cache.capacity
-        L, H = cfg.n_layers, cfg.n_heads
-        hd = cfg.d_model // H
-
-        def kernel(params, k_pages, v_pages, table_row, start, n_valid,
-                   tokens):
-            k_view = k_pages[:, table_row].reshape(L, 1, pps * ps, H, hd)
-            v_view = v_pages[:, table_row].reshape(L, 1, pps * ps, H, hd)
-            logits, k_new, v_new = _transformer.forward_step(
-                params, tokens, start, k_view, v_view, cfg)
-            idx = start[0] + jnp.arange(bucket, dtype=jnp.int32)
-            # Positions past the capacity (a deep suffix's padding) and
-            # pad positions whose page is unmapped both land in trash
-            # page 0; real positions are mapped by construction.
-            page = jnp.where(
-                idx < cap,
-                table_row[0, jnp.clip(idx // ps, 0, pps - 1)], 0)
-            flat = page * ps + idx % ps
-            kf = k_pages.reshape(L, n_pages * ps, H, hd)
-            vf = v_pages.reshape(L, n_pages * ps, H, hd)
-            kf = kf.at[:, flat].set(k_new[:, 0])
-            vf = vf.at[:, flat].set(v_new[:, 0])
-            return (logits[0, n_valid[0] - 1],
-                    kf.reshape(k_pages.shape), vf.reshape(v_pages.shape))
-
-        args = (params, cache.k_pages, cache.v_pages,
-                self._rep(np.zeros((1, pps), np.int32)),
+        args = (params, *cache.pages,
+                self._rep(np.zeros((1, cache.pages_per_slot), np.int32)),
                 self._rep(np.zeros((1,), np.int32)),
                 self._rep(np.ones((1,), np.int32)),
                 self._rep(np.zeros((1, bucket), np.int32)))
         key = ("draft_prefill" if draft else "prefill", bucket)
-        return self._aot(key, kernel, args)
+        return self._aot(key, model.prefill, cache, args)
 
     def _verify_exec(self) -> Any:
         """The speculative-decoding verify program: ONE donated target
@@ -640,79 +610,27 @@ class InferenceEngine:
         entries are rolled back host-side (the write cursor simply does
         not advance over them) and overwritten by the next iteration's
         block before they could ever unmask."""
-        cfg, cache, B = self.cfg, self.cache, self.max_slots
+        cache, B = self.cache, self.max_slots
         W = self.spec_tokens + 1
-        ps, pps, n_pages = (cache.page_size, cache.pages_per_slot,
-                            cache.n_pages)
-        cap = cache.capacity
-        L, H = cfg.n_layers, cfg.n_heads
-        hd = cfg.d_model // H
-
-        def kernel(params, k_pages, v_pages, table, lengths, blocks):
-            k_view = k_pages[:, table].reshape(L, B, pps * ps, H, hd)
-            v_view = v_pages[:, table].reshape(L, B, pps * ps, H, hd)
-            logits, k_new, v_new = _transformer.forward_step(
-                params, blocks, lengths, k_view, v_view, cfg)
-            pos = (jnp.clip(lengths, 0, None)[:, None]
-                   + jnp.arange(W, dtype=jnp.int32)[None, :])
-            page = jnp.where(
-                pos < cap,
-                jnp.take_along_axis(table,
-                                    jnp.clip(pos // ps, 0, pps - 1),
-                                    axis=1), 0)
-            flat = page * ps + pos % ps
-            kf = k_pages.reshape(L, n_pages * ps, H, hd)
-            vf = v_pages.reshape(L, n_pages * ps, H, hd)
-            kf = kf.at[:, flat].set(k_new)
-            vf = vf.at[:, flat].set(v_new)
-            return (logits, kf.reshape(k_pages.shape),
-                    vf.reshape(v_pages.shape))
-
         table, lengths = cache.device_tables()
-        args = (self.params, cache.k_pages, cache.v_pages, table,
-                lengths, self._rep(np.zeros((B, W), np.int32)))
-        return self._aot(("verify", W), kernel, args)
+        args = (self.params, *cache.pages, table, lengths,
+                self._rep(np.zeros((B, W), np.int32)))
+        return self._aot(("verify", W), self.model.verify, cache, args)
 
     def _propose_exec(self) -> Any:
         """The draft's propose program: ONE donated dispatch unrolling
         ``spec_tokens`` greedy draft steps per slot
         (models/transformer.speculative_propose) and scattering the
         derived draft KV back into the draft's paged store."""
-        dcfg, dcache, B = self._draft_cfg, self.draft_cache, \
-            self.max_slots
+        dcache, B = self.draft_cache, self.max_slots
         m = self.spec_tokens
-        ps, pps, n_pages = (dcache.page_size, dcache.pages_per_slot,
-                            dcache.n_pages)
-        cap = dcache.capacity
-        L, H = dcfg.n_layers, dcfg.n_heads
-        hd = dcfg.d_model // H
-
-        def kernel(params, k_pages, v_pages, table, lengths, prev,
-                   pending):
-            k_view = k_pages[:, table].reshape(L, B, pps * ps, H, hd)
-            v_view = v_pages[:, table].reshape(L, B, pps * ps, H, hd)
-            sp = lengths - 1
-            proposals, kc, vc = _transformer.speculative_propose(
-                params, prev, pending, sp, k_view, v_view, dcfg, m)
-            pos = sp[:, None] + jnp.arange(m + 1, dtype=jnp.int32)[None]
-            page = jnp.where(
-                (pos >= 0) & (pos < cap),
-                jnp.take_along_axis(table,
-                                    jnp.clip(pos // ps, 0, pps - 1),
-                                    axis=1), 0)
-            flat = page * ps + jnp.where(pos >= 0, pos % ps, 0)
-            kf = k_pages.reshape(L, n_pages * ps, H, hd)
-            vf = v_pages.reshape(L, n_pages * ps, H, hd)
-            kf = kf.at[:, flat].set(kc)
-            vf = vf.at[:, flat].set(vc)
-            return (proposals, kf.reshape(k_pages.shape),
-                    vf.reshape(v_pages.shape))
-
         table, lengths = dcache.device_tables()
-        args = (self._draft_params, dcache.k_pages, dcache.v_pages,
-                table, lengths, self._rep(np.zeros((B,), np.int32)),
+        args = (self._draft_params, *dcache.pages, table, lengths,
+                self._rep(np.zeros((B,), np.int32)),
                 self._rep(np.zeros((B,), np.int32)))
-        return self._aot(("draft_propose", m), kernel, args)
+        return self._aot(("draft_propose", m),
+                         partial(self._draft_model.propose, m=m), dcache,
+                         args)
 
     def _bucket_for(self, n: int) -> int:
         n = max(2, min(n, self.capacity))
@@ -965,13 +883,13 @@ class InferenceEngine:
         tokens[0, :len(suffix)] = suffix
         compiled = self._prefill_exec(bucket)
         with _oom.guard(f"serving/prefill/{bucket}"):
-            last, kp, vp = compiled(
-                self.params, self.cache.k_pages, self.cache.v_pages,
+            last, *pages = compiled(
+                self.params, *self.cache.pages,
                 self._rep(self.cache.table_row(slot)),
                 self._rep(np.asarray([n_shared], np.int32)),
                 self._rep(np.asarray([len(suffix)], np.int32)),
                 self._rep(tokens))
-        self.cache.replace_pages(kp, vp)
+        self.cache.replace_pages(*pages)
         self.cache.publish_prefix(slot, prompt)
         if self._draft_params is not None:
             dshared = self.draft_cache.lookup_prefix(prompt)
@@ -983,14 +901,13 @@ class InferenceEngine:
             dtokens[0, :len(dsuffix)] = dsuffix
             dcompiled = self._prefill_exec(dbucket, draft=True)
             with _oom.guard(f"serving/draft_prefill/{dbucket}"):
-                _, dkp, dvp = dcompiled(
-                    self._draft_params, self.draft_cache.k_pages,
-                    self.draft_cache.v_pages,
+                _, *dpages = dcompiled(
+                    self._draft_params, *self.draft_cache.pages,
                     self._rep(self.draft_cache.table_row(slot)),
                     self._rep(np.asarray([dn_shared], np.int32)),
                     self._rep(np.asarray([len(dsuffix)], np.int32)),
                     self._rep(dtokens))
-            self.draft_cache.replace_pages(dkp, dvp)
+            self.draft_cache.replace_pages(*dpages)
             self.draft_cache.publish_prefix(slot, prompt)
         self._prev_token[slot] = prompt[-1]
         _M_PREFILLS.inc()
@@ -1007,20 +924,25 @@ class InferenceEngine:
                 tokens[slot] = self._last_token[slot]
             tokens = self._rep(tokens)
             # The rung the program is about to pick from ``lengths``.
-            view = self._rungs[_transformer.view_rung(
-                self.cache.lengths(), self._rungs)]
+            view = self.model.decode_view(self.cache.lengths(),
+                                          self._rungs)
         with _R_LAUNCH(iter=it):
             compiled = self._decode_exec()
             with _oom.guard("serving/decode"):
-                logits, kp, vp = compiled(
-                    self.params, self.cache.k_pages, self.cache.v_pages,
-                    table, lengths, tokens)
-            self.cache.replace_pages(kp, vp)
+                out = compiled(self.params, *self.cache.pages, table,
+                               lengths, tokens)
+            stores = len(self.cache.pages)
+            logits, extras = out[0], out[1:-stores]
+            self.cache.replace_pages(*out[-stores:])
         with _R_LOGITS_WAIT(iter=it):
             # The host blocked on the device: everything the iteration's
             # program takes shows here.
             logits_np = np.asarray(logits)
         with _R_SAMPLE(iter=it, slots=len(active)) as last:
+            if extras:
+                # What the program returned beside the logits (a model's
+                # own counts: they came with the same transfer).
+                self.model.observe_decode(extras)
             fed = {}
             evicted = []
             for slot, req in active:
@@ -1060,11 +982,10 @@ class InferenceEngine:
         dtable, dlengths = self.draft_cache.device_tables()
         compiled = self._propose_exec()
         with _oom.guard(f"serving/draft_propose/{self.spec_tokens}"):
-            proposals, dk, dv = compiled(
-                self._draft_params, self.draft_cache.k_pages,
-                self.draft_cache.v_pages, dtable, dlengths,
-                self._rep(prev), self._rep(pending))
-        self.draft_cache.replace_pages(dk, dv)
+            proposals, *dpages = compiled(
+                self._draft_params, *self.draft_cache.pages, dtable,
+                dlengths, self._rep(prev), self._rep(pending))
+        self.draft_cache.replace_pages(*dpages)
         props = np.asarray(proposals)
         W = self.spec_tokens + 1
         blocks = np.zeros((B, W), np.int32)
@@ -1074,10 +995,10 @@ class InferenceEngine:
         table, lengths = self.cache.device_tables()
         compiled = self._verify_exec()
         with _oom.guard(f"serving/verify/{W}"):
-            logits, kp, vp = compiled(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                table, lengths, self._rep(blocks))
-        self.cache.replace_pages(kp, vp)
+            logits, *pages = compiled(
+                self.params, *self.cache.pages, table, lengths,
+                self._rep(blocks))
+        self.cache.replace_pages(*pages)
         return props, np.asarray(logits)
 
     def _speculative_iteration(self, active) -> None:
@@ -1254,11 +1175,9 @@ class InferenceEngine:
                     tokens[slot] = self._last_token[slot]
                 compiled = self._decode_exec()
                 with _oom.guard("serving/decode"):
-                    _, kp, vp = compiled(
-                        self.params, self.cache.k_pages,
-                        self.cache.v_pages, table, lengths,
-                        self._rep(tokens))
-                self.cache.replace_pages(kp, vp)
+                    out = compiled(self.params, *self.cache.pages,
+                                   table, lengths, self._rep(tokens))
+                self.cache.replace_pages(*out[-len(self.cache.pages):])
             fed = self._bcast(None)
             if fed.get("abort"):
                 # Rank 0's decode/speculative iteration died before
@@ -1379,9 +1298,8 @@ class InferenceEngine:
             try:
                 compiled = self._prefill_exec(bucket)
                 with _oom.guard(f"serving/prefill/{bucket}"):
-                    _, kp, vp = compiled(
-                        self.params, self.cache.k_pages,
-                        self.cache.v_pages, self._rep(row),
+                    _, *pages = compiled(
+                        self.params, *self.cache.pages, self._rep(row),
                         self._rep(np.zeros((1,), np.int32)),
                         self._rep(np.asarray([n], np.int32)),
                         self._rep(toks))
@@ -1396,7 +1314,7 @@ class InferenceEngine:
                     f"dropping {n_pages}-page prefix seed: "
                     f"{type(e).__name__}: {e}")
                 continue
-            self.cache.replace_pages(kp, vp)
+            self.cache.replace_pages(*pages)
             seeded += self.cache.publish_ghost(row, tokens)
         return seeded
 

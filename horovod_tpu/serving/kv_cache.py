@@ -1,11 +1,14 @@
 """Paged KV cache: fixed-size pages, free-list recycling, TP sharding.
 
-Storage is two device arrays per engine —
-``k_pages``/``v_pages: [n_layers, n_pages, page_size, n_heads *
-head_dim]`` (a token's heads side by side in the minor dimension: with
-a 64-wide ``head_dim`` of its own there, half a lane row, the TPU lays
-the store out PAGES-minor and every gather first copies all of it) —
-plus a HOST page table (``[max_slots, pages_per_slot]``
+Storage is one device array for each part of the entry a model caches
+(``pages``; the model says what that is, serving/models.py).  A
+multi-head model caches two, ``k_pages``/``v_pages: [n_layers, n_pages,
+page_size, n_heads * head_dim]`` (a token's heads side by side in the
+minor dimension: with a 64-wide ``head_dim`` of its own there, half a
+lane row, the TPU lays the store out PAGES-minor and every gather first
+copies all of it); a latent-attention model ONE, ``[n_layers, n_pages,
+page_size, kv_rank + rope_dim]`` (``entry_widths``).  Beside them lives
+a HOST page table (``[max_slots, pages_per_slot]``
 int32, numpy) mapping each decode slot's logical positions onto
 physical pages.  Pages are allocated on demand as a sequence grows and
 recycled through a free list the moment the scheduler evicts it, so
@@ -119,7 +122,8 @@ class PagedKVCache:
                  model_axis: str = MODEL_AXIS,
                  prefix_cache: bool = False, prefix_pages: int = 0,
                  fingerprint: str = "",
-                 ledger_category: str = "serving.kv_pages") -> None:
+                 ledger_category: str = "serving.kv_pages",
+                 entry_widths: Optional[Sequence[int]] = None) -> None:
         if pages_per_slot < 1 or page_size < 1:
             raise ValueError("pages_per_slot and page_size must be >= 1")
         if prefix_pages < 0:
@@ -146,15 +150,18 @@ class PagedKVCache:
         self._fingerprint = fingerprint.encode()
         self._ledger_category = ledger_category
 
-        shape = (n_layers, self.n_pages, page_size, n_heads * head_dim)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
+        # The minor width of each store: keys and values of all heads
+        # unless the model caches something else (one latent entry).
+        self.entry_widths = (tuple(int(w) for w in entry_widths)
+                             if entry_widths is not None
+                             else (n_heads * head_dim,) * 2)
         sh = self.page_sharding()
-        if sh is not None:
-            k = jax.device_put(k, sh)
-            v = jax.device_put(v, sh)
-        self.k_pages = k
-        self.v_pages = v
+        pages = []
+        for width in self.entry_widths:
+            store = jnp.zeros((n_layers, self.n_pages, page_size, width),
+                              dtype)
+            pages.append(store if sh is None else jax.device_put(store, sh))
+        self.pages: Tuple = tuple(pages)
 
         self._lock = _lockorder.make_lock("serving.PagedKVCache._lock")
         self._free: List[int] = list(range(1, self.n_pages))
@@ -191,7 +198,7 @@ class PagedKVCache:
         # memory/planner.prefix_pages_bytes predicts with), so
         # plan-vs-ledger stays exact with a prefix reserve resident.
         self._ledger_key = id(self)
-        resident = _mem.resident_nbytes(k) + _mem.resident_nbytes(v)
+        resident = sum(_mem.resident_nbytes(x) for x in self.pages)
         # n_pages divides both factors of the array shape, so the
         # partition is exact integer arithmetic.
         self._page_resident_bytes = resident // self.n_pages
@@ -371,10 +378,11 @@ class PagedKVCache:
     # -- shared-prefix index -----------------------------------------------
     @property
     def page_global_bytes(self) -> int:
-        """GLOBAL logical KV bytes of one page (K + V, all layers) —
-        the byte model memory/planner.prefix_pages_bytes shares."""
-        return (2 * self.n_layers * self.page_size * self.n_heads
-                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+        """GLOBAL logical KV bytes of one page (every store, all
+        layers) — the byte model memory/planner.prefix_pages_bytes
+        shares."""
+        return (self.n_layers * self.page_size * sum(self.entry_widths)
+                * jnp.dtype(self.dtype).itemsize)
 
     def _chain_hashes(self, tokens: Sequence[int],
                       n_pages: int) -> List[bytes]:
@@ -647,8 +655,18 @@ class PagedKVCache:
             lengths = jax.device_put(lengths, rep)
         return table, lengths
 
-    def replace_pages(self, k_pages, v_pages) -> None:
+    def replace_pages(self, *pages) -> None:
         """Install the executables' donated-output page arrays (the old
         references were consumed by the dispatch)."""
-        self.k_pages = k_pages
-        self.v_pages = v_pages
+        if len(pages) != len(self.pages):
+            raise ValueError(f"{len(pages)} page arrays for a cache of "
+                             f"{len(self.pages)}")
+        self.pages = tuple(pages)
+
+    @property
+    def k_pages(self):
+        return self.pages[0]
+
+    @property
+    def v_pages(self):
+        return self.pages[1]
