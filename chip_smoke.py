@@ -70,7 +70,8 @@ CHIP = {
     # [batch, heads, seq, head_dim]; the streaming shape lies beyond
     # HVD_TPU_FLASH_RESIDENT_SEQ (4096) and is cut in heads so the dense
     # float32 reference (4 score-sized matrices) fits beside it.
-    "flash": dict(resident=(2, 12, 1024, 64), streaming=(1, 2, 8192, 64),
+    # The resident shape is the benchmark's gpt2m-train-1k cell's own.
+    "flash": dict(resident=(8, 16, 1024, 64), streaming=(1, 2, 8192, 64),
                   block=256),
     "eager": dict(fused=64, elems=1 << 16),
     "serve": dict(max_seq=1024, slots=8, prompts=(16, 48, 128), new=16),
@@ -80,7 +81,7 @@ DRY = {
     "lm": dict(vocab=256, d_model=64, heads=4, layers=2, d_ff=128,
                bf16=False, per_chip=2, seq=64, block=32, steps=4,
                long_seq=256, loss_chunk=64),
-    "flash": dict(resident=(1, 2, 64, 32), streaming=(1, 1, 256, 32),
+    "flash": dict(resident=(1, 2, 64, 64), streaming=(1, 1, 256, 32),
                   block=32),
     "eager": dict(fused=64, elems=64),
     "serve": dict(max_seq=128, slots=8, prompts=(16, 24, 48), new=8),
@@ -279,10 +280,14 @@ def _lm_train(cfg, mesh, ax, global_batch, seq, steps, want_kernel):
             None if step_s is None else round(step_s, 4))
 
 
-def _flash_check(shape, block, dry):
+def _flash_check(shape, block, dry, qkv_entry=False):
     """Kernel (o, lse) and (dq, dk, dv) against the dense math in
     float32 at ``highest`` precision, causal.  Returns the measured
-    relative errors."""
+    relative errors.  ``shape`` is ``[batch, heads, seq, head_dim]``;
+    with ``qkv_entry`` the kernels are the resident ones, reached as the
+    model reaches them: on the fused ``[batch, seq, 3 x heads x
+    head_dim]`` projection (``flash_attention_qkv``'s forward and
+    backward), else the ``[b, h, s, d]`` entry's."""
 
     from horovod_tpu.ops import flash_attention as F
 
@@ -294,6 +299,16 @@ def _flash_check(shape, block, dry):
 
     @jax.jit
     def kernel(q, k, v, g):
+        if qkv_entry:
+            b, h, s, _ = shape
+            check(F._resident_ok(h, shape[-1], s, s, 0),
+                  f"flash {shape}: not a shape of the resident kernels")
+            qkv = jnp.concatenate([F._to_rows(x) for x in (q, k, v)], -1)
+            o, res = F._flash_qkv_fwd(qkv, h, scale, True, interpret)
+            (dqkv,) = F._flash_qkv_bwd(h, scale, True, interpret, res,
+                                       F._to_rows(g))
+            return (F._to_heads(o, h), res[2].reshape(b, h, -1)[:, :, :s],
+                    *(F._to_heads(x, h) for x in jnp.split(dqkv, 3, -1)))
         o, lse = F._flash_forward(q, k, v, scale, True, block, block, 0,
                                   interpret)
         dq, dk, dv = F._flash_backward(
@@ -363,7 +378,8 @@ def leg_lm(w, wf, dry):
 
     info["flash_resident"] = dict(
         shape=wf["resident"], **_flash_check(wf["resident"],
-                                             wf["block"], dry))
+                                             wf["block"], dry,
+                                             qkv_entry=True))
     info["flash_streaming"] = dict(
         shape=wf["streaming"], **_flash_check(wf["streaming"],
                                               wf["block"], dry))

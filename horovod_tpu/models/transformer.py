@@ -40,7 +40,7 @@ from ..parallel.pipeline import gpipe
 from ..parallel.sequence import ring_attention
 from ..parallel.tensor import (column_parallel, local_shard, row_parallel,
                                tp_mlp)
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention_qkv
 
 
 @dataclass(frozen=True)
@@ -155,24 +155,28 @@ def _attention_block(x, lp, cfg: TransformerConfig, ax: ParallelAxes,
     heads_loc = cfg.n_heads // mp
     head_dim = d // cfg.n_heads
 
-    def split_heads(y):
-        return y.reshape(b, s_loc, heads_loc, head_dim).transpose(
-            0, 2, 1, 3)
-
     # One fused [d, 3*d_local] projection instead of three separate
     # gemms: XLA does not merge gemms horizontally, and the wider
     # matmul tiles the MXU better at transformer widths.
     qkv = column_parallel(h, jnp.concatenate([wq, wk, wv], axis=-1),
                           axis_name=ax.model or T.MODEL_AXIS)
-    q, k, v = (split_heads(y) for y in jnp.split(qkv, 3, axis=-1))
     if ax.seq is not None:
+        def split_heads(y):
+            return y.reshape(b, s_loc, heads_loc, head_dim).transpose(
+                0, 2, 1, 3)
+
+        q, k, v = (split_heads(y) for y in jnp.split(qkv, 3, axis=-1))
         attn = ring_attention(q, k, v, axis_name=ax.seq, causal=True,
                               block_q=cfg.block_q, block_k=cfg.block_k)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s_loc,
+                                                  heads_loc * head_dim)
     else:
-        attn = flash_attention(q, k, v, causal=True, block_q=cfg.block_q,
-                               block_k=cfg.block_k)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s_loc,
-                                              heads_loc * head_dim)
+        # The kernels read q, k and v out of the projection as it lies
+        # ([b, s, 3 x heads x head_dim]) wherever the shape allows; the
+        # blocks only drive the streaming kernels of long sequences.
+        attn = flash_attention_qkv(qkv, heads_loc, causal=True,
+                                   block_q=cfg.block_q,
+                                   block_k=cfg.block_k)
     if ax.model is not None:
         out = row_parallel(attn, wo, axis_name=ax.model)
     else:

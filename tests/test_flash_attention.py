@@ -147,3 +147,203 @@ def test_bfloat16_inputs():
     diff = jnp.max(jnp.abs(o.astype(jnp.float32)
                            - ref.astype(jnp.float32)))
     assert diff < 0.05  # bf16 mantissa tolerance
+
+
+# ---------------------------------------------------------------------------
+# The resident kernels on the model's own [b, s, heads x head_dim] layout
+# ---------------------------------------------------------------------------
+
+from horovod_tpu.ops import flash_attention as F  # noqa: E402
+
+
+def _fused(b, s, h, d, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jax.random.normal(ks[0], (b, s, 3 * h * d), dtype),
+            jax.random.normal(ks[1], (b, s, h * d), dtype))
+
+
+def _fused_reference(qkv, g, h, causal):
+    """o, lse and d(qkv) by the O(seq^2) reference, in float32."""
+    qkv, g = qkv.astype(jnp.float32), g.astype(jnp.float32)
+
+    def ref(qkv):
+        q, k, v = F._split_qkv(qkv, h)
+        return F._to_rows(mha_reference(q, k, v, causal=causal))
+
+    q, k, v = F._split_qkv(qkv, h)
+    _, lse = F._dense_forward(q, k, v, q.shape[-1] ** -0.5, causal, 0)
+    o, vjp = jax.vjp(ref, qkv)
+    return o, lse, vjp(g)[0]
+
+
+# head_dim 64: two heads to a 128-lane block; 128: one; 32: four.  300
+# tokens are padded to three 128-token blocks, walked as 128 x 128 tiles
+# (a loop, then the diagonal tile; padded keys in the last); 512 are one
+# 512-wide q tile against two 256-token k tiles in straight-line code.
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 0.06)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("h,d,s", [(2, 64, 512), (4, 64, 300),
+                                   (1, 128, 300), (4, 32, 128)])
+def test_qkv_layout_matches_reference(h, d, s, causal, dtype, tol):
+    qkv, g = _fused(2, s, h, d, dtype)
+    assert F._resident_ok(h, d, s, s, 0)
+    o, res = F._flash_qkv_fwd(qkv, h, d ** -0.5, causal, True)
+    lse = res[2]
+    (dqkv,) = F._flash_qkv_bwd(h, d ** -0.5, causal, True, res, g)
+    assert o.shape == g.shape and o.dtype == dtype
+    assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
+    ro, rlse, rd = _fused_reference(qkv, g, h, causal)
+    f32 = lambda x: x.astype(jnp.float32)
+    assert jnp.max(jnp.abs(f32(o) - ro)) < tol
+    # lse travels lane-dense, [b, lane blocks, heads a block, padded seq].
+    assert lse.shape[:3] == (2, h * d // 128, 128 // d)
+    got_lse = lse.reshape(2, h, -1)[:, :, :s]
+    assert jnp.max(jnp.abs(got_lse - rlse)) < (1e-4 if dtype == jnp.float32
+                                              else 0.05)
+    # q, k and v gradients, each against its own scale.
+    for got, want in zip(jnp.split(f32(dqkv), 3, -1), jnp.split(rd, 3, -1)):
+        assert jnp.max(jnp.abs(got - want)) < tol * max(
+            1.0, float(jnp.max(jnp.abs(want))))
+
+
+def test_qkv_entry_differentiates_like_the_heads_entry():
+    # The public entries agree: fused [b, s, 3hd] and [b, h, s, d] (whose
+    # resident shapes reach the same kernels through a thin wrapper).
+    qkv, g = _fused(1, 256, 2, 64, jnp.float32, seed=3)
+
+    def fused(qkv):
+        return jnp.sum(F.flash_attention_qkv(qkv, 2, causal=True) * g)
+
+    def heads(qkv):
+        q, k, v = F._split_qkv(qkv, 2)
+        return jnp.sum(F._to_rows(flash_attention(q, k, v, causal=True))
+                       * g)
+
+    a, b = jax.grad(fused)(qkv), jax.grad(heads)(qkv)
+    assert jnp.max(jnp.abs(a - b)) < 1e-5
+
+
+def test_fused_gradient_block_equals_three_outputs(monkeypatch):
+    # d(qkv) leaves the backward kernel as one [seq, dq | dk | dv] block
+    # held in VMEM over a batch element's cells; past the VMEM budget it
+    # leaves as three arrays and a concatenate.  Same numbers either way.
+    qkv, g = _fused(2, 256, 4, 64, jnp.float32, seed=7)
+    o, res = F._flash_qkv_fwd(qkv, 4, 0.125, True, True)
+    (in_place,) = F._flash_qkv_bwd(4, 0.125, True, True, res, g)
+    monkeypatch.setattr(F, "_FUSED_GRAD_VMEM", 0)
+    (joined,) = F._flash_qkv_bwd(4, 0.125, True, True, res, g)
+    assert in_place.shape == joined.shape == qkv.shape
+    assert jnp.array_equal(in_place, joined)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_equals_two_pass_math_on_same_residuals(causal):
+    # One walk over the tile pairs (S, P, dP, dS once; delta in-kernel)
+    # against the two-pass dense math, on the kernel's OWN o and lse.
+    b, s, h, d = 1, 256, 2, 64
+    qkv, g = _fused(b, s, h, d, jnp.float32, seed=5)
+    o, (_, _, lse) = F._flash_qkv_fwd(qkv, h, d ** -0.5, causal, True)
+    (dqkv,) = F._flash_qkv_bwd(h, d ** -0.5, causal, True, (qkv, o, lse), g)
+    q, k, v = F._split_qkv(qkv, h)
+    want = F._dense_backward(
+        (q, k, v, F._to_heads(o, h), lse.reshape(b, h, s)),
+        F._to_heads(g, h), sm_scale=d ** -0.5, causal=causal,
+        q_block_offset=0)
+    for got, ref in zip(jnp.split(dqkv, 3, -1), want):
+        assert jnp.max(jnp.abs(got - F._to_rows(ref))) < 2e-5
+
+
+def _transposed_activations(jaxpr, min_size):
+    """Every ``transpose`` of an array of at least min_size elements,
+    anywhere in the jaxpr (sub-jaxprs included)."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if (eqn.primitive.name == "transpose"
+                    and eqn.invars[0].aval.size >= min_size):
+                found.append(eqn.invars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("n_heads,path_taken", [(4, True), (3, False)])
+def test_attention_block_transposes_no_activation(n_heads, path_taken):
+    # 4 heads of 64: two lane blocks, the kernels read the projection in
+    # place.  3 heads of 64: no whole number of head pairs (an odd local
+    # head count under TP), so the [b, h, s, d] entry and its transposes.
+    from horovod_tpu.models.transformer import (ParallelAxes,
+                                                TransformerConfig,
+                                                _attention_block,
+                                                init_transformer)
+
+    cfg = TransformerConfig(vocab_size=64, d_model=64 * n_heads,
+                            n_heads=n_heads, n_layers=1, d_ff=128,
+                            max_seq_len=128, block_q=32, block_k=32)
+    lp = jax.tree_util.tree_map(
+        lambda leaf: leaf[0],
+        init_transformer(jax.random.PRNGKey(0), cfg)["layers"])
+    x = jnp.ones((2, 128, cfg.d_model), jnp.float32)
+    ax = ParallelAxes(data=None)
+
+    def loss(x, lp):
+        return jnp.sum(_attention_block(x, lp, cfg, ax, 0.0)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, lp)
+    # Activations are [2, 128, >= 192]; the weights' own transposes in the
+    # projections' backward ([d, d] matrices) are not activations' but are
+    # as large, so look at rank: an activation transpose is 4-d.
+    moved = [s for s in _transposed_activations(jaxpr, 2 * 128 * 64)
+             if len(s) == 4]
+    assert (moved == []) == path_taken, moved
+    assert F._resident_ok(n_heads, 64, 128, 128, 0) == path_taken
+
+
+# ---------------------------------------------------------------------------
+# The resident kernels through the TPU's own compiler, for a described v5e
+# (no chip: nothing runs; Mosaic refuses here what it would refuse there)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # An executable for a described chip cannot be read back from the
+    # session's persistent cache; keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+# gpt2m-train-1k's own shape; the resident limit (VMEM: the gradients leave
+# as three arrays there); one head of 128 to a lane block; a sequence that
+# is an odd number of 128-token blocks, in float32.
+@pytest.mark.parametrize("b,s,h,d,dtype", [
+    (8, 1024, 16, 64, jnp.bfloat16), (2, 4096, 16, 64, jnp.bfloat16),
+    (2, 2048, 8, 128, jnp.bfloat16), (2, 640, 4, 64, jnp.float32)])
+def test_resident_kernels_compile_for_the_v5e(v5e_chip, b, s, h, d, dtype):
+    qkv = jax.ShapeDtypeStruct((b, s, 3 * h * d), dtype, sharding=v5e_chip)
+    g = jax.ShapeDtypeStruct((b, s, h * d), dtype, sharding=v5e_chip)
+
+    def fwd_bwd(qkv, g):
+        o, res = F._flash_qkv_fwd(qkv, h, d ** -0.5, True, False)
+        return o, F._flash_qkv_bwd(h, d ** -0.5, True, False, res, g)
+
+    text = jax.jit(fwd_bwd).lower(qkv, g).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
