@@ -30,7 +30,7 @@ through the entry points a user calls, at full width per chip:
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
-whose ``device_kind`` is in bench.PEAK_BF16_FLOPS: a CPU fallback is an
+whose ``device_kind`` is in benchmark.device.PEAKS: a CPU fallback is an
 error, not a slower run.  On success stdout ends with two JSON lines:
 the report (versions, compile-cache counters, every leg's numbers,
 ``"claim": null``) and then, last, the verdict with exactly these keys:
@@ -726,12 +726,11 @@ def main() -> int:
     args = ap.parse_args()
     dry = args.dry_run
 
-    import bench
+    from benchmark import device as bench_device
 
     # A TPU whose device_kind is in the peak table, or no run at all.
-    found = bench.device_info() if dry else bench.require_tpu()
-    device = {"platform": found["platform"], "kind": found["device_kind"],
-              "count": found["count"]}
+    device = (bench_device.device_info() if dry
+              else bench_device.require_tpu(1))
     if dry:
         # Streaming kernels at a toy length, and the schedule a real
         # mesh of several chips selects (auto is off on a CPU mesh).

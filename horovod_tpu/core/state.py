@@ -452,7 +452,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def compile_cache_dir() -> Optional[str]:
     """Where the persistent XLA compilation cache — and the warm-start
     manifest that rides it — lives.  One rule for ``hvd.init()``,
-    ``bench.py`` and ``chip_smoke.py``: a ``JAX_COMPILATION_CACHE_DIR``
+    ``benchmark/`` and ``chip_smoke.py``: a ``JAX_COMPILATION_CACHE_DIR``
     placed from outside wins; otherwise ``<checkout>/.jax_cache``, a
     fixed path (a directory that moves never hits).  A CPU backend gets
     a cache only when one is placed from outside: XLA:CPU compiles in

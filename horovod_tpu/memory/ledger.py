@@ -221,8 +221,7 @@ ledger = MemoryLedger()
 
 def enabled() -> bool:
     """Accounting gate: the allocation sites check this (one flag read)
-    so the bench's telemetry-on/off A/B — the ≤5 % ledger-overhead
-    contract — measures the accounting too."""
+    so telemetry off turns the accounting off too."""
     return _telemetry.enabled()
 
 

@@ -396,8 +396,8 @@ def plan_dataplane(tensors: int, elems: int, world: int,
                    dtype: str = "float32",
                    fusion_threshold: Optional[int] = None,
                    capacity: Optional[int] = None) -> MemoryPlan:
-    """Plan for the dataplane steady state (``bench.py --mode
-    dataplane``'s workload): a ``tensors``-wide allreduce program.  The
+    """Plan for the dataplane steady state: a ``tensors``-wide
+    allreduce program.  The
     framework peak is the largest fusion group's launch footprint under
     the threshold partition (groups are filled greedily in submission
     order — the coordinator's plan_fusion policy)."""

@@ -381,8 +381,8 @@ def chained_lm_loss(cfg: TransformerConfig):
     parallel composition keeps :func:`make_loss_fn`; pipeline/expert
     axes have their own schedules).  Calling the returned object
     evaluates the identical monolithic loss, so ``HVD_TPU_OVERLAP=off``
-    differentiates the same math — the bitwise-identity contract of
-    ``bench.py --mode overlap``.  Pair with :func:`chained_lm_params`.
+    differentiates the same math — the identity contract of
+    tests/test_overlap.py.  Pair with :func:`chained_lm_params`.
     """
     from ..parallel.overlap import ChainedLoss
 

@@ -1567,7 +1567,8 @@ def _execute_response_inner(resp: Response, ops: List[_QueuedOp]) -> None:
                                            mesh, tl, hm, fmt)
                     continue
                 # Eager fallback (HVD_TPU_MEGAKERNEL=0): the per-tensor
-                # choreography — also the bench's comparison baseline.
+                # choreography — the reference tests/test_megakernel.py
+                # compares the fused path against.
                 avg = group[0].red_op == ReduceOp.AVERAGE
                 kernel = ks[_OP_KERNEL[group[0].red_op]
                             + ("_pr" if layout else "_rep")]
@@ -2415,8 +2416,7 @@ def _enqueue(x, op: RequestType, name: Optional[str],
         else process_set.process_set_id)
     handle = st.handle_manager.allocate(None, name=name)
     # Clock stamp gated like every other instrument: disabled telemetry
-    # must cost a flag check, and the bench's overhead A/B must compare
-    # against a leg that truly pays nothing.
+    # must cost a flag check and nothing else.
     qop = _QueuedOp(name=name, op=op, contrib=c, red_op=red_op,
                     root_rank=root_rank, handle=handle, nbytes=nbytes,
                     ps=process_set,

@@ -14,16 +14,16 @@ outside the framework's graph; here the transform is compiler-visible,
 so XLA's async collective scheduling overlaps the legs without any new
 runtime machinery.
 
-**Bitwise contract** (tests/test_fused.py, gated by ``bench.py --mode
-fused``): every fused primitive is bitwise-identical to its unfused
+**Bitwise contract** (tests/test_fused.py; the MoE round trip in
+tests/test_expert_parallel.py): every fused primitive is bitwise-identical to its unfused
 reference program.  Three facts make that possible without the PR-6
 pow2/ordered-sum discipline:
 
 * chunking runs along a **reduction-free** axis (GEMM rows, the MoE
   capacity axis) — each output element's contraction is computed by
   exactly one chunk, with the same K-axis accumulation order the
-  unfused GEMM uses (verified empirically per backend; the dispatch
-  gate in the bench re-checks it every run);
+  unfused GEMM uses (verified empirically per backend: the bitwise
+  tests re-check it every run);
 * ``psum`` / ``psum_scatter`` / ``all_gather`` are elementwise in the
   chunked axis — splitting rows never reorders any element's
   cross-replica reduction;
@@ -60,9 +60,8 @@ relaunched fleet warm-starts the same groups from the compile-cache
 directory, per-launch hvd-mem ledger charges via
 the planner's shared byte formula (:func:`..memory.planner.
 fused_group_bytes`), OOM-guarded dispatch, and the
-``fused.groups_compiled`` / ``fused.launches`` /
-``fused.exposed_comm_seconds`` telemetry documented in
-docs/metrics.md.
+``fused.groups_compiled`` / ``fused.launches`` telemetry documented
+in docs/metrics.md.
 
 Threading: everything here runs on the caller's (main/user) thread —
 module state is one counter-protected lock, and no method is entered
@@ -74,7 +73,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -101,11 +99,6 @@ _M_GROUPS = _telemetry.counter(
 _M_LAUNCHES = _telemetry.counter(
     "fused.launches",
     "fused-group executable dispatches")
-_M_EXPOSED = _telemetry.histogram(
-    "fused.exposed_comm_seconds", "seconds",
-    "communication seconds NOT hidden under producer compute in one "
-    "fused group (max(0, fused_total - compute_only) — the figure "
-    "bench.py --mode fused gates strictly below the unfused leg)")
 
 
 def fuse_mode() -> str:
@@ -358,38 +351,3 @@ class FusedProgram:
             finally:
                 if mem_on:
                     _mem.ledger.free("fused.launch", self._launch_bytes)
-
-
-def observe_exposed(seconds: float) -> None:
-    """Record one fused group's exposed-communication window
-    (``fused.exposed_comm_seconds``; bench.py --mode fused is the
-    measuring side)."""
-    if _telemetry.enabled():
-        _M_EXPOSED.observe(max(0.0, float(seconds)))
-
-
-def measure_exposed_comm(program: Callable, compute_only: Callable,
-                         args: tuple, *, cycles: int = 5) -> float:
-    """Median exposed-communication seconds of ``program`` over
-    ``compute_only`` (the same chunked producer computation with the
-    collective legs elided): ``max(0, total - compute)`` per cycle.
-
-    Both legs pay their dispatch and a full fence inside the measured
-    window — the idiom the pipeline bubble gate established so a
-    loaded box inflates both sides instead of faking an improvement.
-    Shared by ``bench.py --mode fused`` and the telemetry tests."""
-    def timed(fn):
-        lats = []
-        fn(*args)  # warm (compile outside the window)
-        for _ in range(cycles):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            lats.append(time.perf_counter() - t0)
-        lats.sort()
-        return lats[len(lats) // 2]
-
-    total = timed(program)
-    compute = timed(compute_only)
-    exposed = max(0.0, total - compute)
-    observe_exposed(exposed)
-    return exposed

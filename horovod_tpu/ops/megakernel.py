@@ -53,8 +53,8 @@ plan invalidation, and checkpoint-restorable
 
 Env contract (docs/performance.md):
   HVD_TPU_MEGAKERNEL=0           fall back to the per-tensor eager
-                                 executor (default on; the bench's
-                                 comparison baseline)
+                                 executor (default on; the tests'
+                                 bitwise reference)
   HVD_TPU_HIERARCHICAL=auto|on|off   see core/topology.py
   HVD_TPU_VIRTUAL_SLICES=<k>         see core/topology.py
   HVD_TPU_DCN_COMPRESS=none|bf16|fp16|int8|int4
@@ -216,8 +216,8 @@ class MegakernelStats:
     # what the collective's payload traversals would move uncompressed,
     # wire_bytes what they move in the launched kernels' wire formats
     # (codes + block scales; per-leg on hierarchical launches).  The
-    # ratio is surfaced as the compression.ratio gauge and in
-    # bench.py --mode dataplane's bytes-on-wire section.
+    # ratio is surfaced as the compression.ratio gauge
+    # (tests/test_megakernel.py bounds it per codec).
     logical_bytes: int = 0
     wire_bytes: int = 0
     quant_launches: int = 0

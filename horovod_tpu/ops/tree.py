@@ -1064,7 +1064,7 @@ class TreeWorkerTransport(T.WorkerTransport):
 
 
 # ---------------------------------------------------------------------------
-# Dryrun simulation (bench.py --mode control "tree" section + CI gate)
+# Dryrun simulation (tests/test_tree.py bounds the root's frames with it)
 # ---------------------------------------------------------------------------
 
 def steady_envelope(layout: TreeLayout, child: int, epoch: int,
@@ -1082,8 +1082,9 @@ def steady_envelope(layout: TreeLayout, child: int, epoch: int,
 def simulate_cycle_frames(world: int,
                           fanout: Optional[int] = None) -> Dict[str, int]:
     """Frame accounting for one steady-state negotiation cycle and one
-    metrics/trace pull, flat vs tree — the quantity the CI gate bounds
-    (rank-0 rx frames <= c * fanout * log_fanout(world))."""
+    metrics/trace pull, flat vs tree — the quantity
+    tests/test_tree.py bounds (rank-0 rx frames <= c * fanout *
+    log_fanout(world))."""
     layout = build_layout(world, fanout)
     root_children = len(layout.children(0))
     return {

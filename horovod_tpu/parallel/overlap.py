@@ -42,8 +42,7 @@ machinery instead of a new runtime:
   int8/int4 the per-bucket quantization blocks and EF residual keys,
   stay deterministic.
 
-**Identity contract** (tested in tests/test_overlap.py and gated by
-``bench.py --mode overlap``): the streamed schedule's parameters are
+**Identity contract** (tests/test_overlap.py): the streamed schedule's parameters are
 bitwise identical to the ``serial`` schedule's after any number of
 steps — the SAME per-bucket sub-programs dispatched strictly after the
 full backward, so only the interleaving differs.  That also covers the
@@ -154,10 +153,6 @@ _M_MP_BUCKETS = _telemetry.counter(
 _M_FALLBACKS = _telemetry.counter(
     "overlap.fallbacks",
     "overlap-mode steps that fell back to the monolithic path")
-_M_EXPOSED = _telemetry.histogram(
-    "overlap.exposed_comm_seconds", "seconds",
-    "host seconds completing bucket reductions after every backward "
-    "segment was dispatched — reduction work NOT hidden under backward")
 # Same registry entry as parallel/training.py / parallel/input.py: every
 # place the loop blocks feeds one histogram.
 _M_HOST_STALL = _telemetry.histogram(
@@ -170,7 +165,7 @@ _M_HOST_STALL = _telemetry.histogram(
 _R_BACKWARD = _trace.region("stream.backward", "stream")
 _R_SUBMIT = _trace.region("stream.submit", "stream")
 _R_DRAIN = _trace.region("stream.drain", "stream")
-_R_TAKE = _trace.region("stream.take", "stream", timed=True)
+_R_TAKE = _trace.region("stream.take", "stream")
 _R_APPLY = _trace.region("stream.apply", "stream")
 
 
@@ -924,11 +919,10 @@ class _OverlapStep:
             self._submit_segment(self._plan.segments[0], seg_leaves,
                                  handles, tl)
 
-        with _R_TAKE(handles=len(handles)) as took:
+        with _R_TAKE(handles=len(handles)):
             reduced = [C.take_async(h) for h in handles]
             if not stream:
                 jax.block_until_ready(reduced)
-        _M_EXPOSED.observe(took.seconds)
         with _R_APPLY():
             red_tree = jax.tree_util.tree_unflatten(self._treedef,
                                                     reduced)

@@ -35,9 +35,8 @@ GPipe's, but (a) in-flight activation memory is bounded by the stage
 depth instead of the microbatch count (``PipelinePlan.peak_activations``
 — the property the dryrun tests gate), and (b) each stage finishes its
 backwards EARLY (stage ``S-1`` first), so streamed gradient reduction
-overlaps the other stages' cooldown — ``bench.py --mode pipeline``
-gates the exposed-bubble seconds strictly below the GPipe-ordered leg
-at equal device work.
+overlaps the other stages' cooldown (what that buys on the chip: no
+cell yet, ROADMAP R3).
 
 Env contract (validated at ``hvd.init``; rides the control-plane HELLO
 env fingerprint — the schedule selects which compiled programs a rank
@@ -55,8 +54,7 @@ dispatches in which order, so it must be uniform fleet-wide):
       round-robin model chunks, shortening the per-chunk ramp so the
       flush bubble shrinks (gated structurally by the dryrun plan).
 
-**Bitwise contract** (tests/test_pipeline_parallel.py, gated by
-``bench.py --mode pipeline``): the 1F1B step's loss and parameters are
+**Bitwise contract** (tests/test_pipeline_parallel.py): the 1F1B step's loss and parameters are
 bitwise identical to the GPipe-ordered dispatch of the same per-stage
 programs — backwards execute in microbatch order at every stage under
 both schedules, so the gradient accumulation chains are the same
@@ -1014,8 +1012,7 @@ class _PipelineStep:
         # ready.  The GPipe-ordered leg pays its flush fence, the
         # serialized bucket dispatch AND the whole reduction inside
         # this window; the 1F1B leg's reductions were dispatched inside
-        # the schedule, so only the residual drain shows up —
-        # `bench.py --mode pipeline` gates 1f1b strictly below gpipe.
+        # the schedule, so only the residual drain shows up.
         t0 = time.perf_counter()
         if not stream:
             # GPipe-ordered comparator: reduction serialized after the

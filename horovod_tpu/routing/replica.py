@@ -12,9 +12,8 @@ returns ``(status, payload)`` for every call and raises
 differently (failover-and-retry vs mark-dead-and-backoff).
 
 Anything that implements this four-method surface can sit behind the
-router: :class:`HttpReplicaClient` for real fleets, the simulated
-replicas of ``bench.py --mode routing``, and the in-memory fakes of
-tests/test_routing.py.
+router: :class:`HttpReplicaClient` for real fleets and the in-memory
+fakes of tests/test_routing.py.
 """
 
 from __future__ import annotations

@@ -18,8 +18,7 @@ hashes the replica's ``PagedKVCache`` keys — affinity.py), and the
 penalty applies when the replica lacks KV headroom for the prompt's
 unshared pages.  Lowest score wins; ties break on replica name, so a
 given fleet snapshot always routes a prompt the same way
-(deterministic — the trace-replay gate of ``bench.py --mode routing``
-relies on it).
+(deterministic: tests/test_routing.py ``test_select_deterministic_tie_break``).
 
 Failover is drain-aware (docs/routing.md): a replica that answers 503
 mid-generation was elastically drained — its partial tokens are a

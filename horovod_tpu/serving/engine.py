@@ -24,8 +24,7 @@ at startup — against a warm compile-cache directory
 so a relaunched serving fleet reaches full token rate before its first
 request, and ``/healthz`` reports NOT_READY until it has.
 
-Bitwise contract (CI-gated by tests/test_serving.py and ``bench.py
---mode serving``): a prefill of the prompt followed by N single-token
+Bitwise contract (tests/test_serving.py): a prefill of the prompt followed by N single-token
 decode iterations reproduces, bit for bit, the logits of the
 non-incremental :func:`..models.transformer.serving_forward` of the
 same tokens — greedy generation is therefore exactly reproducible
@@ -683,10 +682,10 @@ class InferenceEngine:
         no batch boundary.  ``now`` gates admission on logical arrival
         stamps (trace replay); None admits anything queued.
         ``admit=False`` skips admission entirely — that is the whole
-        difference between this engine and a static batcher, and
-        exactly how ``bench.py --mode serving`` builds its baseline
-        (admit only at batch boundaries).  Returns whether any work
-        ran.
+        difference between this engine and a static batcher
+        (admit only at batch boundaries: the reference
+        tests/test_serving.py compares the completions against).
+        Returns whether any work ran.
 
         Multi-host: rank 0 (the only rank with a scheduler) broadcasts
         the admission plan, then post-prefill state, then the sampled

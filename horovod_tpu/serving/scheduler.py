@@ -19,9 +19,9 @@ a slot — there is no path that admits a later arrival first
 (tests/test_serving.py proves it on an adversarial trace).
 
 Arrivals may carry a logical ``arrival`` stamp: ``admit(now=i)`` only
-considers requests with ``arrival <= i``, so benches replay a seeded
-arrival trace deterministically with no wall-clock in the loop
-(bench.py --mode serving); the live server just submits with the
+considers requests with ``arrival <= i``, so a test replays a seeded
+arrival trace deterministically with no wall-clock in the loop; the
+live server just submits with the
 default ``arrival=0``.
 
 Telemetry (docs/metrics.md): ``serving.queue_depth`` /

@@ -140,7 +140,7 @@ def enabled() -> bool:
 
 def set_enabled(v: bool) -> None:
     """Master switch for the whole telemetry subsystem (registry AND
-    flight recorder) — the bench's overhead A/B.  Re-enabling restores
+    flight recorder).  Re-enabling restores
     the flight recorder's own env gate."""
     _default.set_enabled(v)
     flight.recorder.enabled = bool(v) and flight.flight_enabled_env()

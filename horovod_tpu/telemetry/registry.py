@@ -27,8 +27,7 @@ phases it measures):
   search, no unbounded label space.
 * **Cheap when off.**  ``HVD_TPU_METRICS=0`` (or
   ``set_enabled(False)``) turns every ``inc``/``observe``/``set`` into
-  a single flag check; the A/B is measured by ``bench.py --mode
-  control`` and recorded in the bench JSON (≤ 5 % gate).
+  a single flag check.
 
 Pull metrics (values that already exist as cheap stats structs —
 ``CacheStats``, ``MegakernelStats``, the handle pool depth) are read by

@@ -31,8 +31,7 @@ three pieces (docs/tracing.md):
 3. **Analysis** (:mod:`~horovod_tpu.trace.analyze`) — ``python -m
    horovod_tpu.trace <file>`` computes per-step critical-path
    attribution, names the straggler rank per cycle with its blame
-   category, and emits a human report + JSON (``bench.py``'s ``trace``
-   section).  :class:`~horovod_tpu.trace.watch.StragglerWatch` warns
+   category, and emits a human report + JSON.  :class:`~horovod_tpu.trace.watch.StragglerWatch` warns
    live when one rank's skew exceeds a threshold for N consecutive
    steps.
 
@@ -53,8 +52,7 @@ Hot-path budget mirrors the flight recorder's: recording a span is one
 flag check, two ``time.monotonic`` reads (taken by the caller, or by
 the region) and one ``deque.append`` (atomic in CPython — no lock).
 ``HVD_TPU_TRACE=0`` opts out; ``set_enabled(False)`` is the runtime
-switch the bench's overhead A/B flips (gated ≤ 5 % like telemetry
-was).
+switch (what tracing costs on the chip: PERF.md section 6, PR 24).
 
 Env contract:
   HVD_TPU_TRACE=0           disable span recording (default on)
@@ -174,9 +172,8 @@ def enabled() -> bool:
 
 
 def set_enabled(v: bool) -> None:
-    """Runtime switch for span recording (the bench overhead A/B flips
-    this exactly like ``telemetry.set_enabled``).  Re-enabling restores
-    the env gate."""
+    """Runtime switch for span recording, like
+    ``telemetry.set_enabled``.  Re-enabling restores the env gate."""
     _state.enabled = bool(v) and trace_enabled_env()
 
 

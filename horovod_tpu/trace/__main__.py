@@ -2,7 +2,7 @@
 
 Prints the human critical-path / straggler report (trace/analyze.py);
 ``--json`` additionally writes the machine report (``-`` for stdout —
-the form ``bench.py`` and the CI determinism gate consume).
+the form the CI determinism gate consumes).
 """
 
 from __future__ import annotations

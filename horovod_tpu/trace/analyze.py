@@ -23,8 +23,8 @@ each rank's spans into the classic legs —
 path** attribution.  Blame for a straggler is the category where its
 busy time most EXCEEDS the fleet median for the step, so "rank 5 was
 host-bound" emerges even when every rank also paid the same collective
-cost.  Output is a human report plus JSON (``--json``; ``bench.py``'s
-``trace`` section and the CI determinism gate consume it) — both are
+cost.  Output is a human report plus JSON (``--json``; the CI
+determinism gate consumes it) — both are
 pure functions of the input file, so two replays of one trace are
 byte-identical.
 """

@@ -3,8 +3,8 @@
 The rule table maps one :class:`WindowSnapshot` (the sensors' per-window
 diagnosis, sensors.py) to at most ONE :class:`Decision` per window.  The
 engine is deliberately free of wall clock and PRNG: feeding it the same
-snapshot sequence always yields the same decision sequence — the
-determinism gate ``bench.py --mode tuning`` replays.
+snapshot sequence always yields the same decision sequence
+(tests/test_tuning.py ``test_decision_sequence_is_deterministic``).
 
 Stability machinery (docs/tuning.md "Why the tuner won't thrash"):
 

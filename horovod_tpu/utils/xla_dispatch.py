@@ -8,8 +8,8 @@ back to N dispatches — so it is asserted, not assumed: this module
 counts *real* loaded-executable launches at jax's single dispatch choke
 point (``pxla.ExecuteReplicated.__call__`` executes every compiled
 program: jitted functions AND each eagerly-dispatched primitive), and
-the megakernel executor + ``bench.py --mode dataplane`` + the
-regression test in tests/test_megakernel.py read the counts.
+the megakernel executor + the regression tests in
+tests/test_megakernel.py read the counts.
 
 The patch is installed lazily and only when counting is enabled
 (``HVD_TPU_COUNT_DISPATCHES=1`` — set by tests/conftest.py for the
@@ -21,7 +21,7 @@ pay the per-dispatch bookkeeping.  Scopes come in two flavors:
   executor to attribute dispatches to one response execution even
   while user threads concurrently classify/place inputs.
 * ``record(all_threads=True)`` — global: counts every launch in the
-  process.  Used by the bench to measure a whole submit→drain→
+  process.  Used by the tests to count a whole submit→drain→
   synchronize cycle, wherever the drain happens to run.
 """
 
@@ -58,7 +58,7 @@ def _bump() -> None:
     if _global_scopes:
         # Benign cross-thread increment race (GIL-serialized bytecode
         # makes torn counts impossible; at worst two racing launches
-        # both land) — the bench opens exactly one global scope at a
+        # both land) — a test opens exactly one global scope at a
         # time around an otherwise-quiet process.
         for scope in _global_scopes:
             scope.count += 1
@@ -100,8 +100,8 @@ def exact_scope():
     ``pjit._get_fastpath_data`` to return None makes the C++ wrapper
     fall back to the Python dispatch path on every call — and clears
     the global C++ PjitFunction caches so previously-warmed functions
-    re-enter through it too.  Strictly a measurement mode (tests +
-    ``bench.py --mode dataplane`` dispatch counting): warm dispatch
+    re-enter through it too.  Strictly a measurement mode (the tests'
+    dispatch counting): warm dispatch
     gets slower while open, results are unchanged.  On exit the
     fastpath is restored (and the caches cleared again so the
     no-fastpath entries cannot linger).

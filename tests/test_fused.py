@@ -3,8 +3,7 @@
 
 The bitwise contract is the load-bearing one — every fused primitive
 must reproduce its unfused reference program's bytes exactly (chunking
-runs along reduction-free axes only; ``bench.py --mode fused`` re-gates
-the same contract plus the exposed-communication measurement).  The
+runs along reduction-free axes only).  The
 integration call sites have their own suites (test_tensor_parallel.py,
 test_expert_parallel.py, test_pipeline_parallel.py)."""
 
@@ -226,6 +225,31 @@ def test_all_gather_matmul_bitwise(chunks):
         x, w)
 
 
+def _psum_group(mesh):
+    """The jitted chunked matmul+psum group over ``mesh`` (4 chunks)."""
+    return jax.jit(jax.shard_map(
+        lambda x, w: F.matmul_psum(x, w, axis_name=MODEL_AXIS,
+                                   chunks=4, fuse=True),
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+        check_vma=False))
+
+
+def test_fused_group_is_one_dispatch():
+    """A chunked matmul+psum group stays ONE XLA executable launch:
+    the chunks are emitted inside the one program, not as launches."""
+    from horovod_tpu.utils import xla_dispatch
+
+    mesh = _mesh()
+    x = jnp.ones((16, 8), jnp.float32)
+    w = jnp.ones((8, 8), jnp.float32)
+    fn = _psum_group(mesh)
+    jax.block_until_ready(fn(x, w))
+    with xla_dispatch.exact_scope():
+        with xla_dispatch.record(all_threads=True) as scope:
+            jax.block_until_ready(fn(x, w))
+    assert scope.count == 1
+
+
 # ---------------------------------------------------------------------------
 # Host-side services: FusedProgram, manifest, ledger, telemetry
 # ---------------------------------------------------------------------------
@@ -235,11 +259,7 @@ def test_fused_program_compiles_once_and_matches_jit():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((16, 8)).astype(np.float32))
     w = jnp.asarray(rng.standard_normal((8, 8)).astype(np.float32))
-    fn = jax.jit(jax.shard_map(
-        lambda x, w: F.matmul_psum(x, w, axis_name=MODEL_AXIS,
-                                   chunks=4, fuse=True),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-        check_vma=False))
+    fn = _psum_group(mesh)
     g0 = F._M_GROUPS.value
     l0 = F._M_LAUNCHES.value
     prog = F.FusedProgram("test/psum", fn, mesh=mesh, chunks=4)
@@ -256,11 +276,7 @@ def test_fused_program_ledger_charge_is_scoped_to_the_launch():
     mesh = _mesh()
     x = jnp.ones((16, 8), jnp.float32)
     w = jnp.ones((8, 8), jnp.float32)
-    fn = jax.jit(jax.shard_map(
-        lambda x, w: F.matmul_psum(x, w, axis_name=MODEL_AXIS,
-                                   chunks=4, fuse=True),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-        check_vma=False))
+    fn = _psum_group(mesh)
     nbytes = planner.fused_group_bytes((16, 8), 4)
     led = ledger_mod.ledger
     led.set("fused.launch", 0)
@@ -300,18 +316,3 @@ def test_fused_group_bytes_formula():
     assert planner.fused_group_bytes((16, 8), 1) == (128 + 128) * 4
     assert planner.fused_group_bytes((16, 8), 4, dtype="bfloat16") \
         == (128 + 32) * 2
-
-
-def test_measure_exposed_comm_nonnegative_and_observed():
-    from horovod_tpu import telemetry as _telemetry
-
-    x = jnp.ones((64, 64), jnp.float32)
-    f = jax.jit(lambda x: x @ x)
-    before = _telemetry.registry().histogram(
-        "fused.exposed_comm_seconds").snapshot()["count"]
-    exposed = F.measure_exposed_comm(f, f, (x,), cycles=3)
-    assert exposed >= 0.0
-    if _telemetry.enabled():
-        after = _telemetry.registry().histogram(
-            "fused.exposed_comm_seconds").snapshot()["count"]
-        assert after == before + 1

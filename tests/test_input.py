@@ -241,6 +241,7 @@ def test_train_loop_prefetched_matches_synchronous(hvd):
         float(loss)
 
     # Overlapped leg.
+    staged0 = hvd.metrics().get("input.batches_staged", {}).get("value", 0)
     p_async, s_async = params0, opt.init(params0)
     with prefetch_to_device(data(), depth=2) as staged:
         for b in staged:
@@ -248,6 +249,8 @@ def test_train_loop_prefetched_matches_synchronous(hvd):
     barrier_fence(p_async)
     assert (np.asarray(p_sync["w"]).tobytes()
             == np.asarray(p_async["w"]).tobytes())
+    # ...and the batches really went through the stager.
+    assert hvd.metrics()["input.batches_staged"]["value"] - staged0 == 8
 
 
 def test_trainer_prefetch_and_log_every(hvd):

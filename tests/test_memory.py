@@ -524,8 +524,7 @@ def _within(pred: int, measured: int, pct: float = 15.0) -> bool:
 
 def test_dataplane_plan_matches_ledger_watermark(hvd):
     """Framework-owned prediction within ±15 % of the measured ledger
-    high-watermark for the dataplane workload (the acceptance gate;
-    bench.py --mode memory runs the same comparison)."""
+    high-watermark for the dataplane workload (the acceptance gate)."""
     tensors, elems = 8, 128
     n = hvd.size()
     rng = np.random.default_rng(3)

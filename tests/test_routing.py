@@ -7,8 +7,7 @@ ever diverge (dtype, page alignment, fingerprint seed), affinity
 routing silently goes cold with no error anywhere.  The rest covers the
 router's scoring/failover state machine and the fleet autoscaler over
 in-memory fake replicas (the same four-method client surface
-``bench.py --mode routing`` simulates and ``HttpReplicaClient``
-implements for real fleets).
+``HttpReplicaClient`` implements for real fleets).
 """
 
 import pytest
@@ -24,10 +23,10 @@ FP = "fp-router-test"
 
 
 def _complete(prompt, n):
-    """The rolling-hash completion oracle of bench.py --mode routing:
-    state is a pure fold over tokens-so-far, so a continuation from any
-    partial point reproduces the uninterrupted rollout exactly — the
-    same bitwise property the serving engine's greedy decode has."""
+    """A rolling-hash completion oracle: state is a pure fold over
+    tokens-so-far, so a continuation from any partial point reproduces
+    the uninterrupted rollout exactly — the same bitwise property the
+    serving engine's greedy decode has."""
     s = 0
     for t in prompt:
         s = (s * 1103515245 + int(t) + 12345) & 0x7FFFFFFF

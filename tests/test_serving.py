@@ -544,6 +544,45 @@ def test_engine_greedy_matches_reference_and_batch_invariance():
     assert [r.result(0) for r in reqs] == ref
 
 
+def test_engine_continuous_matches_static_batching_on_a_ragged_trace():
+    """Admission policy changes WHEN a request runs, never what it
+    generates: a seeded ragged-arrival trace admitted into any free
+    slot every iteration (continuous: sequences join a batch that is
+    mid-decode) and admitted only when every slot is empty (the static
+    batcher, ``step(admit=False)`` between boundaries) completes with
+    identical tokens."""
+    rng = np.random.default_rng(7)
+    trace, arrival = [], 0
+    for _ in range(8):
+        arrival += int(rng.integers(0, 2))
+        trace.append((
+            [int(t) for t in rng.integers(1, CFG.vocab_size,
+                                          size=int(rng.integers(3, 9)))],
+            int(rng.integers(2, 10)), arrival))
+
+    def run(continuous):
+        eng = make_engine()
+        eng.warm_start()
+        reqs = [eng.submit(list(p), max_new_tokens=n, arrival=a)
+                for p, n, a in trace]
+        it, joined = 0, 0
+        while not eng.scheduler.idle():
+            busy = eng.scheduler.occupancy()
+            eng.step(now=it, admit=continuous or busy == 0)
+            joined += bool(busy and eng.scheduler.occupancy() > busy)
+            it += 1
+        return [r.result(0) for r in reqs], it, joined
+
+    cont, cont_iters, cont_joined = run(True)
+    stat, stat_iters, stat_joined = run(False)
+    assert cont == stat
+    assert [len(g) for g in cont] == [n for _, n, _ in trace]
+    # The two schedules really differed: continuous admitted into a
+    # running batch and needed no more iterations than static.
+    assert cont_joined > 0 and stat_joined == 0
+    assert cont_iters <= stat_iters
+
+
 @pytest.mark.parametrize("capacity", [32, 64])
 def test_engine_capacity_finished_rollout_is_bitwise(capacity):
     """A CAPACITY-finished rollout (prompt + max_new_tokens over the
@@ -1550,8 +1589,7 @@ def test_engine_prefix_hit_is_bitwise_and_saves_prefill():
     header = list(range(1, 18))  # 17 tokens -> 2 full pages published
     ext = header + [40, 41, 42]
     # Ground truth: the non-incremental reference — a cache-off engine
-    # equals it by the standing contract (and bench.py's prefix_cache
-    # leg gates cache-on vs cache-off completions directly).
+    # equals it by the standing contract.
     a_off = reference_rollout(header, 5, 32)
     b_off = reference_rollout(ext, 5, 32)
 

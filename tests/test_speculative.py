@@ -19,7 +19,7 @@ from horovod_tpu.models.transformer import (TransformerConfig,
                                             init_transformer,
                                             serving_forward)
 from horovod_tpu.serving import InferenceEngine, Request
-from horovod_tpu.serving import harness as _harness
+from horovod_tpu.utils import xla_dispatch
 
 CFG = TransformerConfig(vocab_size=97, d_model=64, n_heads=4, n_layers=2,
                         d_ff=128, max_seq_len=64)
@@ -32,15 +32,53 @@ DRAFT_CFG = TransformerConfig(vocab_size=97, d_model=32, n_heads=2,
 DRAFT = init_transformer(jax.random.PRNGKey(9), DRAFT_CFG)
 
 
+def _zeroed_layers(params):
+    """Every layer's residual contribution zeroed (attention and FFN
+    output projections): the logits are ``ln_f(embed + pos) @ unembed``
+    whatever the depth or width."""
+    layers = dict(params["layers"])
+    for k in ("wo", "w_out", "b_out"):
+        layers[k] = jnp.zeros_like(layers[k])
+    return dict(params, layers=layers)
+
+
 def agreement_pair():
-    """(target, draft) with deterministic acceptance 1.0 — the shared
-    serving.harness construction (ONE implementation with the bench's
-    CI gate)."""
+    """(target, draft) whose greedy argmax agrees at every position:
+    both models' layers are zeroed and the draft shares the target's
+    embed/pos/ln_f/unembed, so acceptance is deterministically 1.0
+    while the draft still pays only its own, smaller, layer stack."""
     tcfg = CFG
     dcfg = TransformerConfig(vocab_size=97, d_model=64, n_heads=4,
                              n_layers=1, d_ff=32, max_seq_len=64)
-    tparams, dparams = _harness.agreement_pair(tcfg, dcfg)
+    tparams = _zeroed_layers(init_transformer(jax.random.PRNGKey(0), tcfg))
+    dparams = _zeroed_layers(init_transformer(jax.random.PRNGKey(1), dcfg))
+    for k in ("embed", "pos_embed", "ln_f", "unembed"):
+        dparams[k] = tparams[k]
     return (tparams, tcfg), (dparams, dcfg)
+
+
+def count_spec_dispatches(engine):
+    """``(propose_calls, verify_calls, eager_dispatches)`` of ONE
+    steady-state speculative iteration of ``engine`` (slots active)."""
+    keys = (("draft_propose", engine.spec_tokens),
+            ("verify", engine.spec_tokens + 1))
+    saved = {k: engine._exec[k] for k in keys}
+    calls = dict.fromkeys(keys, 0)
+
+    def counted(key):
+        def call(*a):
+            calls[key] += 1
+            return saved[key](*a)
+        return call
+
+    engine._exec.update({k: counted(k) for k in keys})
+    try:
+        with xla_dispatch.exact_scope():
+            with xla_dispatch.record(all_threads=True) as scope:
+                engine.step()
+    finally:
+        engine._exec.update(saved)
+    return calls[keys[0]], calls[keys[1]], scope.count
 
 
 def make_engine(params=PARAMS, cfg=CFG, **kw):
@@ -161,7 +199,7 @@ def test_spec_steady_state_is_one_propose_one_verify_dispatch():
     for p in ([1, 2, 3], [4, 5, 6, 7]):
         eng.submit(list(p), max_new_tokens=8)
     eng.step()  # admissions + prefills + first block
-    proposes, verifies, eager = _harness.count_spec_dispatches(eng)
+    proposes, verifies, eager = count_spec_dispatches(eng)
     assert (proposes, verifies) == (1, 1), (proposes, verifies)
     assert eager == 0, (
         f"{eager} eager dispatches leaked out of the speculative "

@@ -71,15 +71,15 @@ def test_layout_slice_major_ordering(monkeypatch):
     assert rest == sorted(rest, key=lambda r: (r // 4, r))
 
 
-def test_root_frames_drop_from_linear_to_fanout_log():
-    for world in (64, 256, 1024):
-        stats = tree.simulate_cycle_frames(world, 8)
-        flat = stats["flat_frames_per_cycle"]
-        got = stats["tree_frames_per_cycle"]
-        bound = 2 * 8 * max(1, math.ceil(math.log(world, 8)))
-        assert got <= bound, (world, got, bound)
-        assert got < flat / 4
-        assert stats["tree_frames_per_pull"] == got
+@pytest.mark.parametrize("world", [64, 256, 1024])
+def test_root_frames_drop_from_linear_to_fanout_log(world):
+    stats = tree.simulate_cycle_frames(world, 8)
+    flat = stats["flat_frames_per_cycle"]
+    got = stats["tree_frames_per_cycle"]
+    bound = 2 * 8 * max(1, math.ceil(math.log(world, 8)))
+    assert got <= bound, (world, got, bound)
+    assert got < flat / 4
+    assert stats["tree_frames_per_pull"] == got
 
 
 def test_tree_active_modes(monkeypatch):

@@ -231,8 +231,8 @@ def test_pinned_knob_is_never_touched():
 
 def test_decision_sequence_is_deterministic():
     """Same seeded snapshot sequence through two fresh engines: the
-    decision sequences are identical — the replay gate bench.py --mode
-    tuning enforces end to end."""
+    decision sequences are identical: the engine reads no clock and
+    draws no random number."""
     feed = ([snap(i, DCN_LEGS) for i in range(3)]
             + [snap(i, GAP_LEGS, straggler_rank=1) for i in range(3, 6)]
             + [snap(i, spec_acceptance=0.1) for i in range(6, 10)])
@@ -246,6 +246,31 @@ def test_decision_sequence_is_deterministic():
     first = run()
     assert first  # the feed produces decisions
     assert run() == first
+
+
+def test_closed_loop_reaches_the_hand_tuned_knobs_and_rests():
+    """The rules composed: each decision is applied to the knobs the
+    NEXT window's legs are synthesized from.  Started mis-tuned (no
+    DCN compression, in-flight depth 1, six speculative tokens on a
+    draft accepted 3 times in 10), the default-configured engine
+    walks every ladder to its hand-tuned end and then stays silent."""
+    dcn_us = {"none": 60e3, "bf16": 30e3, "int8": 14e3, "int4": 11e3}
+    gap_us = {1: 40e3, 2: 24e3, 4: 14e3, 8: 2e3}
+    knobs = dict(DEFAULT_KNOBS, **{KNOB_MAX_INFLIGHT: 1,
+                                   KNOB_SPEC_TOKENS: 6})
+    eng = PolicyEngine()
+    for w in range(80):
+        d = eng.step(WindowSnapshot(
+            index=w, knobs=dict(knobs), spec_acceptance=0.3,
+            headroom_frac=0.5, headroom_bytes=8 << 30,
+            legs={"dispatch": 10e3, "host": 1e3,
+                  "dcn": dcn_us[knobs[KNOB_DCN_COMPRESS]],
+                  "dispatch-gap": gap_us[knobs[KNOB_MAX_INFLIGHT]]}))
+        if d is not None:
+            knobs[d.knob] = d.value
+    assert (knobs[KNOB_DCN_COMPRESS], knobs[KNOB_MAX_INFLIGHT],
+            knobs[KNOB_SPEC_TOKENS]) == ("int4", 8, 1)
+    assert max(d.window for d in eng.decisions) <= 60
 
 
 # ---------------------------------------------------------------------------
