@@ -14,7 +14,12 @@ capacity-long view (:func:`..models.transformer.forward_step`); decode
 gathers, layer by layer, only the leading pages of each row that the
 iteration's longest live sequence reaches — the smallest rung of a
 ladder of page counts, picked inside the program from its ``lengths``
-(:func:`..models.transformer.forward_step_paged`).  Executables are
+(:func:`..models.transformer.forward_step_paged`).  The decode
+loop runs one iteration ahead (:meth:`InferenceEngine._decode_iteration`):
+the executable takes the greedy token itself and hands it to the next
+launch on the device, so the host prepares and launches iteration i+1
+while the device runs i, and fetches ``[slots] int32`` a pass, not the
+logits.  Executables are
 built ahead of time (``jit(...).lower(...).compile()``) and recorded
 in the PR-5 persistent-cache manifest under
 ``variant: "serving"`` (ops/megakernel.py ``record_manifest_entry``):
@@ -87,6 +92,11 @@ _M_PREFILLS = _telemetry.counter(
     "serving.prefills", "prefill executions (one per admission)")
 _M_DECODES = _telemetry.counter(
     "serving.decode_iterations", "batched decode iterations")
+_M_AHEAD = _telemetry.counter(
+    "serving.decode_ahead", "decode iterations launched while the "
+    "previous one's tokens were still unfetched (the loop ran one ahead); "
+    "over serving.decode_iterations it is the share of the loop that "
+    "hid the host behind the device")
 _M_VIEW_TOKENS = _telemetry.counter(
     "serving.decode_view_tokens", "tokens of KV view per slot that the "
     "decode iterations attended (the ladder rung each one rode); over "
@@ -108,16 +118,18 @@ _M_SPEC_RATE = _telemetry.gauge(
 # the serve-loop thread and all carrying ``iter``, the engine's own
 # iteration counter.  Per iteration and per prefill, never per slot or
 # per token.  ``serve.tables`` .. ``serve.sample`` tile one decode
-# iteration: serving.token_seconds reads the first one's start and the
-# last one's end, so they are timed (a speculative iteration starts at
-# its launch).
+# pass: serving.token_seconds reads the first one's start and the last
+# one's end, so they are timed (a speculative iteration starts at its
+# launch; a pass that only retires the iteration in flight at its
+# wait).  Run ahead, the tables and the launch are the NEXT iteration's
+# and the wait and the sampling the one before's.
 _R_ITERATION = _trace.region("serve.iteration", "serve")
 _R_ADMIT = _trace.region("serve.admit", "serve")
 _R_PREFILL = _trace.region("serve.prefill", "serve")
 _R_ENSURE = _trace.region("serve.ensure", "serve")
 _R_TABLES = _trace.region("serve.tables", "serve", timed=True)
 _R_LAUNCH = _trace.region("serve.launch", "serve", timed=True)
-_R_LOGITS_WAIT = _trace.region("serve.logits_wait", "serve")
+_R_LOGITS_WAIT = _trace.region("serve.logits_wait", "serve", timed=True)
 _R_SAMPLE = _trace.region("serve.sample", "serve", timed=True)
 _R_WARM_START = _trace.region("serve.warm_start", "init")
 
@@ -130,6 +142,21 @@ def _make_cache(model, max_slots: int, pages_per_slot: int,
                         entry["head_dim"], max_slots, pages_per_slot,
                         page_size, dtype=model.cfg.dtype,
                         entry_widths=entry["widths"], **kw)
+
+
+class _Flight:
+    """One decode iteration from its plan to its retirement: who rides
+    it, the view it attends, the tables it is launched with and, once
+    launched, the program's outputs, still on the device."""
+
+    __slots__ = ("riders", "view", "inputs", "tokens", "logits", "extras")
+
+    def __init__(self, riders: Dict[int, Request], view, inputs) -> None:
+        self.riders = riders    # slot -> the request decoding there
+        self.view = view
+        self.inputs = inputs    # (table, lengths, override or None)
+        self.tokens = self.logits = None
+        self.extras: Tuple = ()
 
 
 class InferenceEngine:
@@ -226,13 +253,12 @@ class InferenceEngine:
         self.capacity = self.cache.capacity
         self.scheduler = ContinuousBatchingScheduler(max_slots,
                                                      self.capacity)
-        if mesh is not None and self.cache.page_sharding() is not None:
-            rep = NamedSharding(mesh, P())
-            params = jax.tree_util.tree_map(
-                lambda x: jax.device_put(jnp.asarray(x), rep), params)
-        else:
-            params = jax.tree_util.tree_map(jnp.asarray, params)
-        self.params = params
+        # Where the replicated parameters and the tiny control arrays
+        # live under a sharded store (None: wherever jax puts them).
+        self._replicated = (
+            NamedSharding(mesh, P()) if mesh is not None
+            and self.cache.page_sharding() is not None else None)
+        self.params = jax.tree_util.tree_map(self._rep, params)
         # Speculative decoding (hvd-spec): a draft model over the same
         # mesh proposes spec_tokens greedy tokens per iteration; ONE
         # donated verify executable runs the target over the block and
@@ -277,14 +303,7 @@ class InferenceEngine:
                 fingerprint=json.dumps(self._draft_model.identity(),
                                        sort_keys=True),
                 ledger_category="serving.draft_kv")
-            if mesh is not None and self.cache.page_sharding() is not None:
-                rep = NamedSharding(mesh, P())
-                draft_params = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(jnp.asarray(x), rep),
-                    draft_params)
-            else:
-                draft_params = jax.tree_util.tree_map(jnp.asarray,
-                                                      draft_params)
+            draft_params = jax.tree_util.tree_map(self._rep, draft_params)
             self._draft_params = draft_params
             # hvd-mem: the draft's replicated parameters are a
             # framework-resident cost the planner's --draft-layers
@@ -325,6 +344,13 @@ class InferenceEngine:
         self._rungs = _transformer.view_rungs(self.cache.page_size,
                                               self.cache.pages_per_slot)
         self._exec: Dict[Tuple, Any] = {}
+        # The decode loop runs one iteration ahead (_decode_iteration):
+        # the iteration launched and not yet fetched, and the two token
+        # vectors a launch takes when the host has nothing to say: no
+        # previous tokens (a start), no override (a steady pass).
+        self._inflight: Optional[_Flight] = None
+        self._no_tokens = self._rep(np.zeros((max_slots,), np.int32))
+        self._no_override = self._rep(np.full((max_slots,), -1, np.int32))
         self._last_token = np.zeros((max_slots,), np.int32)
         # The second-newest context token per slot — the catch-up
         # column of the draft's propose block (see
@@ -564,20 +590,39 @@ class InferenceEngine:
         return guarded
 
     def _rep(self, x) -> jnp.ndarray:
-        """Tiny control array → device, replicated under a mesh."""
+        """Array → device, replicated under a sharded store."""
         a = jnp.asarray(x)
-        if self.mesh is not None and self.cache.page_sharding() is not None:
-            a = jax.device_put(a, NamedSharding(self.mesh, P()))
+        if self._replicated is not None:
+            a = jax.device_put(a, self._replicated)
         return a
 
+    def _decode_step(self, params, pages, table, lengths, prev, override):
+        """The model's decode program with the token chosen in it: the
+        input token a slot is ``override`` where the host gave one (a
+        newly admitted slot's first token, from its prefill) and else
+        ``prev``, the token the previous program chose, which never
+        left the device; the output leads with the greedy choice
+        ``[slots] int32`` (the first index among ties, as ``np.argmax``)
+        and keeps the logits and the model's extras behind it."""
+        tokens = jnp.where(override >= 0, override, prev)
+        outs, pages = self.model.decode(params, pages, table, lengths,
+                                        tokens, rungs=self._rungs)
+        chosen = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
+        if self._replicated is not None:
+            # It is the next call's ``prev``: the layout that was
+            # compiled for.
+            chosen = jax.lax.with_sharding_constraint(chosen,
+                                                      self._replicated)
+        return (chosen, *outs), pages
+
     def _decode_exec(self) -> Any:
-        cache, B = self.cache, self.max_slots
-        table, lengths = cache.device_tables()
-        args = (self.params, *cache.pages, table, lengths,
-                self._rep(np.zeros((B,), np.int32)))
-        return self._aot(("decode",),
-                         partial(self.model.decode, rungs=self._rungs),
-                         cache, args)
+        compiled = self._exec.get(("decode",))
+        if compiled is not None:
+            return compiled
+        table, lengths = self.cache.device_tables()
+        args = (self.params, *self.cache.pages, table, lengths,
+                self._no_tokens, self._no_override)
+        return self._aot(("decode",), self._decode_step, self.cache, args)
 
     def _prefill_exec(self, bucket: int, draft: bool = False) -> Any:
         """Prefill executable, START-aware: ``start`` is the number of
@@ -753,6 +798,12 @@ class InferenceEngine:
                 self._speculative_iteration(active)
             else:
                 self._decode_iteration(active)
+        elif self._inflight is not None:
+            # Every rider of the iteration in flight ended under it (an
+            # ``eos_id``, a cancellation, a drain): nobody wants its
+            # tokens.
+            self._retired(self._inflight)
+            self._inflight = None
         return bool(admitted or active)
 
     def _admit(self, now: Optional[int]) -> List[Tuple[int, Request]]:
@@ -912,51 +963,180 @@ class InferenceEngine:
         _M_PREFILLS.inc()
         return np.asarray(last)
 
-    def _decode_iteration(self, active) -> np.ndarray:
-        """One batched decode over ``active``; the caller (step) has
-        already run ``cache.ensure`` for every slot."""
+    def _runs_ahead(self, active) -> bool:
+        """Whether a pass may launch the next iteration before the host
+        holds this one's tokens.  It follows from the requests alive:
+        every one greedy (a sampled token is a host draw from the
+        logits row, keyed ``(seed, position)``), no draft (its greedy
+        slots ride propose/verify), one process (``follow`` mirrors
+        rank 0 token by token)."""
+        return (self._draft_params is None
+                and all(req.temperature <= 0.0 for _, req in active)
+                and not self._multiprocess())
+
+    def _continuing(self, active,
+                    behind: Dict[int, Request]) -> Dict[int, Request]:
+        """Who rides the iteration launched behind the one ``behind``
+        rode, that one's tokens unseen: every request alive but those
+        its token finishes by ``max_new_tokens`` or capacity — a count,
+        which the host knows without the token.  An ``eos_id`` it
+        cannot know: such a request rides once more (``_retire``)."""
+        riders = {}
+        for slot, req in active:
+            if behind.get(slot) is req:
+                n = len(req.generated) + 1
+                if (n >= req.max_new_tokens
+                        or len(req.prompt) + n >= self.capacity):
+                    continue
+            riders[slot] = req
+        return riders
+
+    def _plan(self, riders: Dict[int, Request],
+              behind: Optional[Dict[int, Request]]) -> Optional[_Flight]:
+        """The host's half of one launch: the tables ``riders`` decode
+        at, on the device.  ``behind`` is who rode the iteration whose
+        tokens the host has not fetched (None: it holds every token).
+        A request of ``behind`` decodes one past its cached length (its
+        page mapped here, which may raise: before any launch of the
+        pass) and takes its token from that program's output; every
+        other rider gets the host's token through the override.
+        Whoever does not ride — finishing under ``behind``, or freed by
+        a drain meanwhile — is shipped as an empty slot: length -1, row
+        on the trash page.  Returns None with nobody left to ride."""
+        behind = behind or {}
+        cached = self.cache.lengths()
+        for slot, req in riders.items():
+            if behind.get(slot) is req:
+                self.cache.ensure(slot, int(cached[slot]) + 1)
+        table, cached = self.cache.host_tables()
+        lengths = np.full_like(cached, -1)
+        override = None
+        riding = {}
+        for slot, req in riders.items():
+            if cached[slot] < 0:
+                continue
+            riding[slot] = req
+            if behind.get(slot) is req:
+                lengths[slot] = cached[slot] + 1
+            else:
+                lengths[slot] = cached[slot]
+                if override is None:
+                    override = np.full_like(cached, -1)
+                override[slot] = self._last_token[slot]
+        if not riding:
+            return None
+        table[lengths < 0] = 0
+        # The rung the program is about to pick from ``lengths``.
+        return _Flight(
+            riding, self.model.decode_view(lengths, self._rungs),
+            (self._rep(table), self._rep(lengths),
+             None if override is None else self._rep(override)))
+
+    def _launch(self, flight: _Flight, prev: Optional[_Flight]) -> _Flight:
+        """Enqueue a planned iteration behind ``prev`` (None: behind
+        nothing the host has not fetched)."""
+        (table, lengths, override), flight.inputs = flight.inputs, None
+        compiled = self._decode_exec()
+        with _oom.guard("serving/decode"):
+            out = compiled(
+                self.params, *self.cache.pages, table, lengths,
+                self._no_tokens if prev is None else prev.tokens,
+                self._no_override if override is None else override)
+        stores = len(self.cache.pages)
+        self.cache.replace_pages(*out[-stores:])
+        flight.tokens, flight.logits = out[0], out[1]
+        flight.extras = out[2:-stores]
+        if prev is not None:
+            _M_AHEAD.inc()
+        return flight
+
+    def _decode_iteration(self, active):
+        """One decode pass over ``active``; the caller (step) has
+        already run ``cache.ensure`` for every slot.  The loop is
+        pipelined one deep: with an iteration in flight, the pass
+        plans and launches the NEXT one (``serve.tables``,
+        ``serve.launch``) and only then fetches and feeds the tokens of
+        the one in flight (``serve.logits_wait``, ``serve.sample``), so
+        the host's share of an iteration runs under the device's.  A
+        pass with nothing in flight launches two and feeds the first;
+        a pass that may not run ahead (:meth:`_runs_ahead`) launches at
+        most the one it feeds.  Either way every rider of the retired
+        iteration is fed exactly one token: ``step()``'s contract.
+
+        What a launch needs of the iteration before it is one integer a
+        slot, and that stays on the device (:meth:`_decode_step`).
+        What the host cannot know ahead it handles late: a request
+        that ends under the iteration in flight by ``eos_id`` (or a
+        cancellation, or a drain) has ridden it; its token is dropped
+        and its slot was freed at its end, which is safe because the
+        device runs programs in launch order — a freed page is only
+        written again by a program enqueued after the stale one.
+
+        Returns the retired iteration's logits, still on the device:
+        rows for whoever asks."""
         it = self._iter
-        with _R_TABLES(iter=it) as first:
-            table, lengths = self.cache.device_tables()
-            tokens = np.zeros((self.max_slots,), np.int32)
-            for slot, _ in active:
-                tokens[slot] = self._last_token[slot]
-            tokens = self._rep(tokens)
-            # The rung the program is about to pick from ``lengths``.
-            view = self.model.decode_view(self.cache.lengths(),
-                                          self._rungs)
-        with _R_LAUNCH(iter=it):
-            compiled = self._decode_exec()
-            with _oom.guard("serving/decode"):
-                out = compiled(self.params, *self.cache.pages, table,
-                               lengths, tokens)
-            stores = len(self.cache.pages)
-            logits, extras = out[0], out[1:-stores]
-            self.cache.replace_pages(*out[-stores:])
-        with _R_LOGITS_WAIT(iter=it):
-            # The host blocked on the device: everything the iteration's
-            # program takes shows here.
-            logits_np = np.asarray(logits)
-        with _R_SAMPLE(iter=it, slots=len(active)) as last:
-            if extras:
+        flight, self._inflight = self._inflight, None
+        behind = dict(active) if flight is None else flight.riders
+        ahead = (self._continuing(active, behind)
+                 if self._runs_ahead(active) else {})
+        first = None
+        if flight is None or ahead:
+            with _R_TABLES(iter=it) as first:
+                start = self._plan(behind, None) if flight is None else None
+                ahead = self._plan(ahead, behind) if ahead else None
+            with _R_LAUNCH(iter=it):
+                if start is not None:
+                    flight = self._launch(start, None)
+                if ahead is not None and flight is not None:
+                    self._inflight = self._launch(ahead, flight)
+        if flight is None:
+            return None     # a drain freed every slot under the pass
+        return self._retire(flight, first)
+
+    def _retire(self, flight: _Flight, first):
+        """Fetch ``flight``'s tokens and feed them.  ``first`` is the
+        pass's ``serve.tables`` region, None where it launched
+        nothing."""
+        it = self._iter
+        with _R_LOGITS_WAIT(iter=it) as wait:
+            # The host blocked on the device: ``[slots] int32``, and
+            # the logits only for a slot that samples (its pass
+            # launched nothing ahead).
+            tokens = np.asarray(flight.tokens)
+            rows = None
+            if any(req.temperature > 0.0
+                   for req in flight.riders.values()):
+                rows = np.asarray(flight.logits)
+        with _R_SAMPLE(iter=it, slots=len(flight.riders)) as last:
+            if flight.extras:
                 # What the program returned beside the logits (a model's
-                # own counts: they came with the same transfer).
-                self.model.observe_decode(extras)
+                # own counts).
+                self.model.observe_decode(flight.extras)
             fed = {}
             evicted = []
-            for slot, req in active:
+            for slot, req in flight.riders.items():
+                if req.finish_reason is not None:
+                    # Ended while the iteration flew: the token is
+                    # dropped, and the slot may be another request's.
+                    continue
                 self.cache.advance(slot)  # the input token's KV landed
-                token = self._sample(req, logits_np[slot])
+                token = (int(tokens[slot]) if req.temperature <= 0.0
+                         else self._sample(req, rows[slot]))
                 fed[slot] = token
                 self._feed(slot, req, token)
                 if self.cache.length(slot) < 0:
                     evicted.append(slot)
             if self._multiprocess():
                 self._bcast({"tokens": fed, "evict": evicted})
+        self._retired(flight)
+        _M_TOKEN_LAT.observe(last.t1 - (wait if first is None else first).t0)
+        return flight.logits
+
+    @staticmethod
+    def _retired(flight: _Flight) -> None:
+        """Count an iteration the device ran, fed or dropped."""
         _M_DECODES.inc()
-        _M_VIEW_TOKENS.inc(view)
-        _M_TOKEN_LAT.observe(last.t1 - first.t0)
-        return logits_np
+        _M_VIEW_TOKENS.inc(flight.view)
 
     def _prefill_and_sample(self, slot: int, req: Request) -> None:
         with _R_PREFILL(iter=self._iter, rid=req.rid,
@@ -1175,7 +1355,8 @@ class InferenceEngine:
                 compiled = self._decode_exec()
                 with _oom.guard("serving/decode"):
                     out = compiled(self.params, *self.cache.pages,
-                                   table, lengths, self._rep(tokens))
+                                   table, lengths, self._rep(tokens),
+                                   self._no_override)
                 self.cache.replace_pages(*out[-len(self.cache.pages):])
             fed = self._bcast(None)
             if fed.get("abort"):
@@ -1377,6 +1558,9 @@ class InferenceEngine:
         with self._drain_lock:
             drained, pending = self._drain_and_finish(
                 FinishReason.ERROR)
+            # The poisoned step may be the iteration in flight: its
+            # outputs are no input for the next launch.
+            self._inflight = None
             if not self._drained:
                 self.scheduler.resume()
         return drained + pending

@@ -641,12 +641,17 @@ class PagedKVCache:
             return self._table[slot:slot + 1].copy()
 
     # -- device views ------------------------------------------------------
+    def host_tables(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(page_table, lengths) as of ONE lock hold, copies: what the
+        serve loop edits into the tables of an iteration it launches
+        ahead of the host's own state."""
+        with self._lock:
+            return self._table.copy(), self._lengths.copy()
+
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(page_table, lengths) as device arrays for the executables
         (replicated under a mesh — they are tiny)."""
-        with self._lock:
-            table_np = self._table.copy()
-            lengths_np = self._lengths.copy()
+        table_np, lengths_np = self.host_tables()
         table = jnp.asarray(table_np)
         lengths = jnp.asarray(lengths_np)
         if self.mesh is not None and self.page_sharding() is not None:

@@ -15,8 +15,9 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
 ``decode(params, pages, table, lengths, tokens, rungs) -> (outs, pages)``
     One token a slot over the paged store, the view rung picked inside
     the program; writes the new entries back.  ``outs[0]`` is ``logits
-    [slots, vocab]``; anything after it comes back to the host with the
-    logits and goes to ``observe_decode`` inside ``serve.sample``.
+    [slots, vocab]``, which stay on the device unless a slot samples
+    (the engine's executable takes their argmax itself); anything after
+    it is fetched and goes to ``observe_decode`` inside ``serve.sample``.
 ``decode_view(lengths, rungs) -> tokens``
     The view a slot the decode program attends at these host lengths
     (the rung it is about to pick, by the same pure function): what

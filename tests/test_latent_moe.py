@@ -138,6 +138,45 @@ def test_every_rung_was_ridden_by_the_longest_group_alone():
     assert seen == [64, (128 + 7 * 64) / 8, (256 + 7 * 64) / 8]
 
 
+def test_run_ahead_loop_equals_the_loop_held_at_depth_0(monkeypatch):
+    """The decode loop one iteration ahead (ISSUE 30) over this model's
+    program (one store, the expert counts beside the logits):
+    staggered admissions and finishes serve the same tokens and feed
+    the same expert counters as the loop that fetches before it
+    launches; ``serving.decode_ahead`` moves only when it runs
+    ahead."""
+    eng = engine()
+    trace = [(prompt(400 + i, n), new, at) for i, (n, new, at) in enumerate(
+        [(20, 7, 0), (70, 2, 0), (9, 5, 1), (33, 1, 2), (140, 6, 2),
+         (12, 4, 6), (66, 3, 6)])]
+    names = ("serving.decode_ahead", "serving.decode_iterations",
+             "serving.moe_assignments", "serving.tokens_generated")
+
+    def replay():
+        before = [counter(n) for n in names]
+        reqs = [eng.submit(list(p), max_new_tokens=n, arrival=a)
+                for p, n, a in trace]
+        it = 0
+        while not eng.scheduler.idle():
+            eng.step(now=it)
+            it += 1
+        assert eng.cache.free_pages() == eng.cache.total_pages
+        return ([r.result(0) for r in reqs],
+                [counter(n) - b for n, b in zip(names, before)])
+
+    ahead, (n_ahead, n_iter, assigned, tokens) = replay()
+    monkeypatch.setattr(eng, "_runs_ahead", lambda active: False)
+    held, (h_ahead, h_iter, h_assigned, h_tokens) = replay()
+    assert ahead == held and [len(t) for t in ahead] == [
+        n for _, n, _ in trace]
+    assert h_ahead == 0 and 0.5 * n_iter < n_ahead < n_iter
+    # Every token but a prefill's came from one slot of one decode
+    # iteration, through top_k experts of each expert layer, of which
+    # this share holds some: the counters saw the same slots.
+    assert tokens == h_tokens == sum(n for _, n, _ in trace)
+    assert assigned == h_assigned > 0
+
+
 @pytest.mark.parametrize("lengths,want", [
     ([-1] * 8, [0, 0, 0]),
     ([10, -1, 200, 64, 63, -1, 127, 5], [2, 1, 0]),

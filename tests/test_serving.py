@@ -895,6 +895,233 @@ def test_engine_one_dispatch_per_decode_iteration():
     eng.run_until_idle()
 
 
+# ---------------------------------------------------------------------------
+# The decode loop runs one iteration ahead (ISSUE 30): the token is chosen
+# in the program and stays on the device, the host feeds one pass behind
+# ---------------------------------------------------------------------------
+
+def _counters(*names):
+    import horovod_tpu.telemetry as telemetry
+
+    snap = telemetry.metrics()
+    return [snap.get("serving." + n, {}).get("value", 0) for n in names]
+
+
+def _hold_at_depth_0(eng):
+    """The loop as it was: every pass fetches what it launched."""
+    eng._runs_ahead = lambda active: False
+    return eng
+
+
+RAGGED = [  # (prompt, max_new_tokens, arrival): joins and leaves mid-flight
+    ([5, 3, 8], 9, 0), ([1, 2, 3, 4, 5, 6], 2, 0), ([9, 9, 2, 6], 6, 1),
+    ([7, 1], 1, 2), ([4, 4, 4, 4, 4], 7, 3), ([2, 7, 1, 8, 2, 8], 4, 3),
+    ([6, 6], 5, 9), ([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], 8, 9)]
+
+
+def _replay(eng, trace=RAGGED):
+    """Drive a trace by its arrival stamps; also holds step()'s
+    contract on the way: a request that was decoding before a pass
+    has exactly one more token after it."""
+    reqs = [eng.submit(list(p), max_new_tokens=n, arrival=a)
+            for p, n, a in trace]
+    it = 0
+    while not eng.scheduler.idle():
+        before = {id(r): (r, len(r.generated))
+                  for _, r in eng.scheduler.active()}
+        eng.step(now=it)
+        for r, had in before.values():
+            assert len(r.generated) == had + 1, (it, r.rid)
+        it += 1
+    return [r.result(0) for r in reqs], it
+
+
+def test_run_ahead_loop_equals_the_loop_held_at_depth_0():
+    """Staggered admissions and finishes (by count, at a prefill, into a
+    slot another request just left): the same tokens, pass for pass, as
+    the loop that fetches before it launches, and as the
+    non-incremental forward; and ``serving.decode_ahead`` counts the
+    launches made with the previous tokens unfetched — none at depth
+    0."""
+    names = ("decode_ahead", "decode_iterations", "tokens_generated",
+             "prefills")
+    eng = make_engine()
+    eng.warm_start()
+    c0 = _counters(*names)
+    ahead, ahead_passes = _replay(eng)
+    c1 = _counters(*names)
+    held, held_passes = _replay(_hold_at_depth_0(make_engine()))
+    c2 = _counters(*names)
+    assert ahead == held
+    assert ahead == [reference_rollout(p, n, eng.capacity)
+                     for p, n, _ in RAGGED]
+    d_ahead, d_iter, d_tok, d_pre = (b - a for a, b in zip(c0, c1))
+    h_ahead, h_iter, h_tok, h_pre = (b - a for a, b in zip(c1, c2))
+    assert (d_tok, d_pre) == (h_tok, h_pre) == (
+        sum(n for _, n, _ in RAGGED), len(RAGGED))
+    # No ``eos_id`` here, so the host knew every last iteration by
+    # count: nothing was launched in vain.  A request admitted behind an
+    # iteration in flight decodes one pass later than at depth 0, so
+    # the two loops may differ by a pass or an iteration, not by more.
+    assert h_ahead == 0 and h_iter == held_passes
+    assert d_iter <= h_iter + 3 and ahead_passes <= held_passes + 3
+    assert 0.6 * d_iter < d_ahead < d_iter
+    assert eng.cache.free_pages() == eng.cache.total_pages
+    assert eng._inflight is None
+
+
+def test_run_ahead_counts_one_start_a_pipeline():
+    """One request alone, n tokens: the prefill's, then n - 1 decode
+    iterations of which all but the first were launched ahead; the
+    first pass launches two and feeds one, the last launches none."""
+    eng = make_engine()
+    eng.warm_start()
+    calls = []
+    compiled = eng._exec[("decode",)]
+    eng._exec[("decode",)] = lambda *a: (calls.append(1) or compiled(*a))
+    a0, i0 = _counters("decode_ahead", "decode_iterations")
+    req = eng.submit([5, 3, 8], max_new_tokens=6)
+    per_pass = []
+    while not eng.scheduler.idle():
+        n = len(calls)
+        eng.step()
+        per_pass.append(len(calls) - n)
+    a1, i1 = _counters("decode_ahead", "decode_iterations")
+    assert per_pass == [2, 1, 1, 1, 0]
+    assert (i1 - i0, a1 - a0) == (5, 4)
+    assert req.result(0) == reference_rollout([5, 3, 8], 6, eng.capacity)
+
+
+def test_eos_under_an_iteration_in_flight_drops_its_token():
+    """``eos_id`` is what the host cannot count: the request has ridden
+    the next iteration when its last token arrives.  That token is
+    dropped, the slot freed, and the next admission takes the SAME
+    pages (one slot, four pages: it needs them all) while the stale
+    program may still be writing into one; its completion is a fresh
+    engine's."""
+    first, long_prompt = [1, 2, 3, 4, 5, 6], list(range(1, 27))
+    ref = reference_rollout(first, 12, 32)
+    k = next(i for i in range(2, 12) if ref[i] not in ref[:i])
+    eng = make_engine(max_slots=1, prefix_cache=False)
+    eng.warm_start()
+    i0, = _counters("decode_iterations")
+    a = eng.submit(list(first), max_new_tokens=12, eos_id=ref[k])
+    b = eng.submit(list(long_prompt), max_new_tokens=5)
+    held = set()
+    while not a.done.is_set():
+        eng.step()
+        held = {int(p) for p in eng.cache.table_row(0)[0] if p} or held
+    assert held
+    assert a.result(0) == ref[:k + 1]
+    assert a.finish_reason == FinishReason.EOS
+    # It rode the iteration still in flight; nothing else does.
+    assert list(eng._inflight.riders.values()) == [a]
+    assert eng.cache.free_pages() == eng.cache.total_pages
+    eng.step()      # admits b behind the stale iteration
+    assert held < {int(p) for p in eng.cache.table_row(0)[0]}
+    eng.run_until_idle()
+    i1, = _counters("decode_iterations")
+    # a's k fed iterations and the dropped one, then b's.
+    assert i1 - i0 == (k + 1) + 4
+    fresh = make_engine(max_slots=1, prefix_cache=False)
+    fresh.warm_start()
+    assert b.result(0) == fresh.generate(list(long_prompt),
+                                         max_new_tokens=5)
+    assert b.result(0) == reference_rollout(long_prompt, 5, 32)
+    assert eng.cache.free_pages() == eng.cache.total_pages
+
+
+def _sampled_reference(prompt, n, temperature, seed, capacity):
+    """The sampled rollout of ``InferenceEngine._sample`` over the
+    non-incremental forward: the draw keyed ``(seed, position)``."""
+    sf = jax.jit(serving_forward, static_argnums=(2, 3))
+    seq, out = list(prompt), []
+    for i in range(n):
+        row = np.asarray(sf(PARAMS, jnp.asarray([seq], jnp.int32), CFG,
+                            capacity))[0, -1]
+        z = (row - row.max()) / temperature
+        p = np.exp(z)
+        p /= p.sum()
+        tok = int(np.random.default_rng((seed, i)).choice(len(p), p=p))
+        out.append(tok)
+        seq.append(tok)
+    return out
+
+
+def test_a_sampled_request_alive_holds_the_pass_synchronous():
+    """A ``temperature > 0`` token is a host draw from the logits row:
+    while such a request is alive no iteration is launched ahead
+    (``serving.decode_ahead`` stands still), its rollout is the one
+    its ``(seed, position)`` gives, and the greedy request beside it
+    is untouched.  Admitted behind an iteration in flight, it makes
+    that pass retire without launching."""
+    eng = make_engine()
+    eng.warm_start()
+    greedy = eng.submit([5, 3, 8], max_new_tokens=12)
+    eng.step()
+    eng.step()
+    assert eng._inflight is not None
+    a0, = _counters("decode_ahead")
+    hot = eng.submit([1, 2, 3, 4], max_new_tokens=4, temperature=0.8,
+                     seed=11)
+    n = len(greedy.generated)
+    eng.step()                      # prefill; the flight retires
+    assert eng._inflight is None
+    assert (len(greedy.generated), len(hot.generated)) == (n + 1, 1)
+    while not hot.done.is_set():
+        eng.step()
+        assert eng._inflight is None
+    assert _counters("decode_ahead") == [a0]
+    eng.run_until_idle()            # alone again, the loop runs ahead
+    assert _counters("decode_ahead")[0] > a0
+    assert hot.result(0) == _sampled_reference([1, 2, 3, 4], 4, 0.8, 11,
+                                               eng.capacity)
+    assert greedy.result(0) == reference_rollout([5, 3, 8], 12,
+                                                 eng.capacity)
+    held = _hold_at_depth_0(make_engine())
+    assert hot.result(0) == held.generate(
+        [1, 2, 3, 4], max_new_tokens=4, temperature=0.8, seed=11)
+
+
+@pytest.mark.parametrize("how", ["cancel", "drain", "abort_all"])
+def test_evictions_under_an_iteration_in_flight(how):
+    """Slots freed at a pass boundary while the device still runs the
+    iteration they rode (the donation sanitizer is armed suite-wide):
+    the cache comes back whole, the survivors' tokens are the
+    reference's, and the freed pages serve the next requests."""
+    prompts = [[5, 3, 8], [1, 2, 3, 4, 5, 6], [9, 9, 2, 6]]
+    ref = [reference_rollout(p, 10, 32) for p in prompts]
+    eng = make_engine()
+    eng.warm_start()
+    reqs = [eng.submit(list(p), max_new_tokens=10) for p in prompts]
+    eng.step()
+    eng.step()
+    assert [r.rid for r in eng._inflight.riders.values()] == [
+        r.rid for r in reqs]
+    if how == "cancel":
+        assert eng.abort_request(reqs[1]) == "active"
+        eng.step()
+        assert reqs[1].finish_reason == FinishReason.CLIENT_DISCONNECT
+        assert len(reqs[1].generated) == 3      # its token was dropped
+        eng.run_until_idle()
+        assert [reqs[0].result(0), reqs[2].result(0)] == [ref[0], ref[2]]
+    elif how == "drain":
+        exported = eng.drain()
+        assert eng.cache.free_pages() == eng.cache.total_pages
+        assert [len(d["generated_prefix"]) for d in exported] == [3] * 3
+        again = eng.import_requests(exported)
+        eng.run_until_idle()
+        assert [r.result(0) for r in again] == ref
+    else:
+        assert {r.rid for r in eng.abort_all()} == {r.rid for r in reqs}
+        assert eng._inflight is None
+        assert eng.cache.free_pages() == eng.cache.total_pages
+    assert [eng.generate(list(p), max_new_tokens=10)
+            for p in prompts] == ref
+    assert eng.cache.free_pages() == eng.cache.total_pages
+    assert eng._inflight is None
+
+
 def test_engine_tensor_parallel_matches_single_device():
     from horovod_tpu.core.topology import make_mesh
 
