@@ -3,7 +3,7 @@
     python chip_smoke.py            # on the chip (through the chip tool)
     python chip_smoke.py --dry-run  # tiny widths on the 8-device CPU mesh
 
-One process, no children, no ``JAX_PLATFORMS`` override.  Five legs run
+One process, no children, no ``JAX_PLATFORMS`` override.  Six legs run
 through the entry points a user calls, at full width per chip:
 
   A  ResNet-50 data-parallel trainer (the BASELINE.json workload):
@@ -27,6 +27,11 @@ through the entry points a user calls, at full width per chip:
      requests through InferenceEngine, and the LOGITS its own prefill
      and decode executables produced through the latent paged cache
      against the benchmark's plain float32 reference.
+  F  the decoder-hybrid-decoder the benchmark serves
+     (benchmark/configs/phi4-mini-flash.json, whole on one chip:
+     state-space, window, full, gated-memory and cross layers): the same
+     comparison through its paged layer and its five per-slot stores,
+     the scan kernel compiled.
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
@@ -107,6 +112,11 @@ SERVE_REL_TOL = 2.0 ** -5
 # fp8 0.25).  Single logits move by far more wherever a near-tied
 # router choice flips, so the largest error is reported, not judged.
 LATENT_RMS_REL_TOL = 0.12
+# Hybrid state-space decoder: the same comparison through 32 layers with
+# no router to flip a choice (measured on the v5e, PR 31: 0.027, 0.030;
+# the largest single error 0.17 of the spread).  The latent model's
+# limit: its readings say where bf16 ends and fp8 begins.
+HYBRID_RMS_REL_TOL = 0.12
 
 
 class LegFailed(Exception):
@@ -629,37 +639,36 @@ def leg_serve(w, wl):
     }
 
 
-def leg_latent_moe(dry):
-    """Leg E.  The benchmark's own configuration, builder and reference:
-    the cut model at the published widths on the chip, its toy fixture
-    in the dry run."""
+def _leg_served_model(dry, builder, fixture, config_file, seed, engine_kw,
+                      lengths, new, tol, stores_ok):
+    """A configuration of the benchmark through ``InferenceEngine``: the
+    model built from the seed by the benchmark's own builder, two requests,
+    and the LOGITS the engine's own prefill and decode executables
+    produced against the benchmark's plain float32 reference, by their
+    root-mean-square over the reference's spread.  ``stores_ok(engine)``
+    says what the cache manager must hold, and why."""
     import gc
 
     import horovod_tpu as hvd
     from benchmark import cells
-    from benchmark.builders.latent_moe import config_of, seeded_params
     from horovod_tpu.serving import InferenceEngine
 
     path = os.path.join(cells.HERE, *(
-        ("tests", "fixtures", "configs", "tiny-axk1.json") if dry
-        else ("configs", "axk1-ep16.json")))
+        ("tests", "fixtures", "configs", fixture) if dry
+        else ("configs", config_file)))
     with open(path) as f:
         config = json.load(f)
     m = config["model"]
     ref = cells.load_module("refs", config["ref"])
-    cfg = config_of(m)
+    cfg = builder.config_of(m)
     jax.clear_caches()
     gc.collect()
     t0 = time.perf_counter()
-    params = seeded_params(m, cfg, 2_400_000_027, ref)
-    engine = InferenceEngine(params, cfg, mesh=None,
-                             max_slots=4 if dry else 64,
-                             page_size=8 if dry else 16,
-                             capacity=256 if dry else 4096)
+    params = builder.seeded_params(m, cfg, seed, ref)
+    engine = InferenceEngine(params, cfg, mesh=None, **engine_kw)
     engine.warm_start()
     setup_s = time.perf_counter() - t0
-    check(not engine.cache.prefix_enabled and len(engine.cache.pages) == 1,
-          "the latent model caches one store, prefix cache off")
+    check(*stores_ok(engine))
 
     rows = {}
     orig_prefill, orig_decode = engine._prefill, engine._decode_iteration
@@ -673,14 +682,13 @@ def leg_latent_moe(dry):
         owners = {slot: req.rid for slot, req in active}
         logits = orig_decode(active)
         for slot, rid in owners.items():
-            rows[rid].append(logits[slot].copy())
+            rows[rid].append(np.asarray(logits[slot]))
         return logits
 
     engine._prefill, engine._decode_iteration = prefill, decode
-    rng = np.random.default_rng(27)
+    rng = np.random.default_rng(seed % 1000)
     prompts = [[int(t) for t in rng.integers(0, m["vocab_size"], size=k)]
-               for k in ((40, 100) if dry else (700, 150))]
-    new = 8 if dry else 24
+               for k in lengths]
     before = hvd.metrics()
     t0 = time.perf_counter()
     reqs = [engine.submit(p, max_new_tokens=new) for p in prompts]
@@ -689,8 +697,7 @@ def leg_latent_moe(dry):
     after = hvd.metrics()
     tokens = [r.result(0) for r in reqs]
     check(all(len(t) == new for t in tokens), "every request served whole")
-    check(grew(before, after, "serving.moe_assignments") > 0,
-          "the decode program's expert counts reach the counters")
+    peak = peak_bytes()
     del engine
     gc.collect()
 
@@ -702,14 +709,57 @@ def leg_latent_moe(dry):
         w = w[len(p) - 1:len(p) - 1 + new]
         rms.append(float(np.sqrt(np.mean((got - w) ** 2)) / w.std()))
         worst = max(worst, float(np.abs(got - w).max() / w.std()))
-    check(max(rms) <= LATENT_RMS_REL_TOL,
-          f"logits through the latent cache differ from the reference: "
-          f"rms/std {rms} > {LATENT_RMS_REL_TOL}")
+    check(max(rms) <= tol,
+          f"logits through the engine's stores differ from the reference: "
+          f"rms/std {rms} > {tol}")
     return {"config": config["name"], "setup_s": round(setup_s, 1),
             "serve_s": round(serve_s, 2), "logit_rms_over_std": rms,
-            "logit_max_over_std": worst,
-            "pairs_on_held_experts": grew(before, after,
-                                          "serving.moe_assignments")}
+            "logit_max_over_std": worst, "peak_bytes": peak}, before, after
+
+
+def leg_latent_moe(dry):
+    """Leg E.  The benchmark's own configuration, builder and reference:
+    the cut model at the published widths on the chip, its toy fixture
+    in the dry run."""
+    from benchmark.builders import latent_moe
+
+    out, before, after = _leg_served_model(
+        dry, latent_moe, "tiny-axk1.json", "axk1-ep16.json", 2_400_000_027,
+        dict(max_slots=4 if dry else 64, page_size=8 if dry else 16,
+             capacity=256 if dry else 4096),
+        (40, 100) if dry else (700, 150), 8 if dry else 24,
+        LATENT_RMS_REL_TOL,
+        lambda e: (not e.cache.prefix_enabled and len(e.cache.pages) == 1,
+                   "the latent model caches one store, prefix cache off"))
+    pairs = grew(before, after, "serving.moe_assignments")
+    check(pairs > 0, "the decode program's expert counts reach the counters")
+    return dict(out, pairs_on_held_experts=pairs)
+
+
+def leg_hybrid_ssm(dry):
+    """Leg F.  The decoder-hybrid-decoder of benchmark/configs/
+    phi4-mini-flash.json, whole and uncut on the chip (its toy fixture in
+    the dry run): one request longer than the window and one shorter, so
+    the rings wrap in the prefill of one and under the decode of
+    neither; on the chip the scan kernel runs compiled."""
+    from benchmark.builders import hybrid_ssm
+
+    out, before, after = _leg_served_model(
+        dry, hybrid_ssm, "tiny-phi4flash.json", "phi4-mini-flash.json",
+        3_100_000_031,
+        dict(max_slots=4 if dry else 64, page_size=4 if dry else 16,
+             capacity=128 if dry else 6144),
+        (40, 6) if dry else (700, 150), 8 if dry else 24,
+        HYBRID_RMS_REL_TOL,
+        lambda e: (not e.cache.prefix_enabled and len(e.cache.pages) == 2
+                   and len(e.cache.slot_state) == 5
+                   and e.cache.n_layers == 1,
+                   "one paged layer and five per-slot stores, prefix "
+                   "cache off"))
+    shared = grew(before, after, "serving.shared_kv_tokens")
+    check(shared > 0 and grew(before, after, "serving.state_slot_resets") == 2,
+          "the cache manager counts what it holds and replaces")
+    return dict(out, shared_kv_tokens=shared)
 
 
 # ---------------------------------------------------------------------------
@@ -720,9 +770,9 @@ def main() -> int:
                     help="toy widths on whatever platform jax has; the "
                          "result line says ok=false and names the "
                          "platform (for the test suite, never a pass)")
-    ap.add_argument("--legs", default="ABCDE",
+    ap.add_argument("--legs", default="ABCDEF",
                     help="subset of legs to run while debugging, e.g. "
-                         "AD; anything short of all five is not a pass")
+                         "AD; anything short of all six is not a pass")
     args = ap.parse_args()
     dry = args.dry_run
 
@@ -776,7 +826,8 @@ def main() -> int:
             ("B_lm_pallas", lambda: leg_lm(w["lm"], w["flash"], dry)),
             ("C_eager", lambda: leg_eager(w["eager"])),
             ("D_serve", lambda: leg_serve(w["serve"], w["lm"])),
-            ("E_latent_moe", lambda: leg_latent_moe(dry)))
+            ("E_latent_moe", lambda: leg_latent_moe(dry)),
+            ("F_hybrid_ssm", lambda: leg_hybrid_ssm(dry)))
     for name, fn in plan:
         if name[0] not in args.legs.upper():
             continue

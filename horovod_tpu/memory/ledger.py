@@ -70,6 +70,7 @@ CATEGORIES = (
     "serving.prefix_pages",
     "serving.draft_kv",
     "serving.draft_params",
+    "serving.slot_state",
     "input.prefetch",
     "pipeline.activations",
     "checkpoint.snapshots",

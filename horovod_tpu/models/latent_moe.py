@@ -511,6 +511,8 @@ class LatentMoEServing:
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False    # one latent "head": nothing to shard
+    tensor_parallel_why = "caches one entry all heads share"
+    slot_state = False         # no per-slot store beside the pages
     prefix_cache = False       # the prefill attends its own block only
     prefix_cache_why = ("the latent prefill attends its own block only: "
                         "a suffix prefill over cached latent pages is "

@@ -101,6 +101,10 @@ _M_VIEW_TOKENS = _telemetry.counter(
     "serving.decode_view_tokens", "tokens of KV view per slot that the "
     "decode iterations attended (the ladder rung each one rode); over "
     "serving.decode_iterations it is the mean view")
+_M_PREFILL_TOKENS = _telemetry.counter(
+    "serving.prefill_tokens", "real prompt tokens the admission prefills "
+    "ran through the model (a prefix-cache hit's shared tokens and a "
+    "bucket's padding are not among them)")
 _M_WARM = _telemetry.counter(
     "serving.warm_starts", "serving executables AOT-rebuilt at startup")
 _M_SPEC_PROPOSED = _telemetry.counter(
@@ -138,10 +142,14 @@ def _make_cache(model, max_slots: int, pages_per_slot: int,
                 page_size: int, **kw) -> PagedKVCache:
     """The paged store for what ``model`` caches."""
     entry = model.cache_entry()
-    return PagedKVCache(entry["n_layers"], entry["n_heads"],
-                        entry["head_dim"], max_slots, pages_per_slot,
-                        page_size, dtype=model.cfg.dtype,
-                        entry_widths=entry["widths"], **kw)
+    cache = PagedKVCache(entry["n_layers"], entry["n_heads"],
+                         entry["head_dim"], max_slots, pages_per_slot,
+                         page_size, dtype=model.cfg.dtype,
+                         entry_widths=entry["widths"],
+                         slot_stores=entry.get("slot_stores", ()), **kw)
+    if cache.slot_state:
+        model.observe_stores(cache.slot_store_bytes())
+    return cache
 
 
 class _Flight:
@@ -149,11 +157,14 @@ class _Flight:
     it, the view it attends, the tables it is launched with and, once
     launched, the program's outputs, still on the device."""
 
-    __slots__ = ("riders", "view", "inputs", "tokens", "logits", "extras")
+    __slots__ = ("riders", "view", "lengths", "inputs", "tokens", "logits",
+                 "extras")
 
-    def __init__(self, riders: Dict[int, Request], view, inputs) -> None:
+    def __init__(self, riders: Dict[int, Request], view, lengths,
+                 inputs) -> None:
         self.riders = riders    # slot -> the request decoding there
         self.view = view
+        self.lengths = lengths  # the host's, as launched (-1: not riding)
         self.inputs = inputs    # (table, lengths, override or None)
         self.tokens = self.logits = None
         self.extras: Tuple = ()
@@ -242,9 +253,10 @@ class InferenceEngine:
         if (not self.model.tensor_parallel and mesh is not None
                 and dict(mesh.shape).get(model_axis, 1) > 1):
             raise ValueError(
-                f"{type(self.model).__name__} caches one entry all heads "
-                f"share: its store cannot be sharded over the "
-                f"'{model_axis}' axis (serve it with mesh=None)")
+                f"{type(self.model).__name__} "
+                f"{self.model.tensor_parallel_why}: its store cannot be "
+                f"sharded over the '{model_axis}' axis (serve it with "
+                f"mesh=None)")
         self.cache = _make_cache(
             self.model, max_slots, cap // page_size, page_size,
             mesh=mesh, model_axis=model_axis,
@@ -502,7 +514,7 @@ class InferenceEngine:
 
             per_device = sum(
                 _mem_ledger.device_nbytes(x) for x in
-                self.cache.pages + tuple(
+                self.cache.arrays + tuple(
                     jax.tree_util.tree_leaves(self.params)))
             if self.draft_cache is not None:
                 per_device += sum(
@@ -552,13 +564,14 @@ class InferenceEngine:
              args: Tuple) -> Any:
         """Compile the executable ``key``: ``step(params, pages, *rest)
         -> (outs, pages)``, a step function of the model's, over
-        ``args = (params, *cache.pages, *rest)``' shapes/shardings, the
-        page arrays (positions 1 ..) donated; cache it.  The executable
-        takes ``args`` and returns ``(*outs, *pages)``."""
+        ``args = (params, *cache.arrays, *rest)``' shapes/shardings, the
+        cache's arrays (positions 1 ..: pages, then per-slot stores)
+        donated; cache it.  The executable takes ``args`` and returns
+        ``(*outs, *arrays)``."""
         compiled = self._exec.get(key)
         if compiled is not None:
             return compiled
-        n = len(cache.pages)
+        n = len(cache.arrays)
         donated = tuple(range(1, 1 + n))
 
         def fn(params, *rest):
@@ -620,7 +633,7 @@ class InferenceEngine:
         if compiled is not None:
             return compiled
         table, lengths = self.cache.device_tables()
-        args = (self.params, *self.cache.pages, table, lengths,
+        args = (self.params, *self.cache.arrays, table, lengths,
                 self._no_tokens, self._no_override)
         return self._aot(("decode",), self._decode_step, self.cache, args)
 
@@ -635,11 +648,14 @@ class InferenceEngine:
         model = self._draft_model if draft else self.model
         cache = self.draft_cache if draft else self.cache
         params = self._draft_params if draft else self.params
-        args = (params, *cache.pages,
+        args = (params, *cache.arrays,
                 self._rep(np.zeros((1, cache.pages_per_slot), np.int32)),
                 self._rep(np.zeros((1,), np.int32)),
                 self._rep(np.ones((1,), np.int32)),
                 self._rep(np.zeros((1, bucket), np.int32)))
+        if model.slot_state:
+            # A model with per-slot stores is told WHICH slot it fills.
+            args += (self._rep(np.zeros((1,), np.int32)),)
         key = ("draft_prefill" if draft else "prefill", bucket)
         return self._aot(key, model.prefill, cache, args)
 
@@ -932,14 +948,17 @@ class InferenceEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(suffix)] = suffix
         compiled = self._prefill_exec(bucket)
+        which = ((self._rep(np.asarray([slot], np.int32)),)
+                 if self.model.slot_state else ())
         with _oom.guard(f"serving/prefill/{bucket}"):
             last, *pages = compiled(
-                self.params, *self.cache.pages,
+                self.params, *self.cache.arrays,
                 self._rep(self.cache.table_row(slot)),
                 self._rep(np.asarray([n_shared], np.int32)),
                 self._rep(np.asarray([len(suffix)], np.int32)),
-                self._rep(tokens))
+                self._rep(tokens), *which)
         self.cache.replace_pages(*pages)
+        _M_PREFILL_TOKENS.inc(len(suffix))
         self.cache.publish_prefix(slot, prompt)
         if self._draft_params is not None:
             dshared = self.draft_cache.lookup_prefix(prompt)
@@ -1028,7 +1047,7 @@ class InferenceEngine:
         table[lengths < 0] = 0
         # The rung the program is about to pick from ``lengths``.
         return _Flight(
-            riding, self.model.decode_view(lengths, self._rungs),
+            riding, self.model.decode_view(lengths, self._rungs), lengths,
             (self._rep(table), self._rep(lengths),
              None if override is None else self._rep(override)))
 
@@ -1039,10 +1058,10 @@ class InferenceEngine:
         compiled = self._decode_exec()
         with _oom.guard("serving/decode"):
             out = compiled(
-                self.params, *self.cache.pages, table, lengths,
+                self.params, *self.cache.arrays, table, lengths,
                 self._no_tokens if prev is None else prev.tokens,
                 self._no_override if override is None else override)
-        stores = len(self.cache.pages)
+        stores = len(self.cache.arrays)
         self.cache.replace_pages(*out[-stores:])
         flight.tokens, flight.logits = out[0], out[1]
         flight.extras = out[2:-stores]
@@ -1132,11 +1151,15 @@ class InferenceEngine:
         _M_TOKEN_LAT.observe(last.t1 - (wait if first is None else first).t0)
         return flight.logits
 
-    @staticmethod
-    def _retired(flight: _Flight) -> None:
+    def _retired(self, flight: _Flight) -> None:
         """Count an iteration the device ran, fed or dropped."""
         _M_DECODES.inc()
         _M_VIEW_TOKENS.inc(flight.view)
+        observe = getattr(self.model, "observe_launch", None)
+        if observe is not None:
+            # What the model itself counts of an iteration, from the
+            # lengths it was launched with.
+            observe(flight.lengths)
 
     def _prefill_and_sample(self, slot: int, req: Request) -> None:
         with _R_PREFILL(iter=self._iter, rid=req.rid,
@@ -1354,10 +1377,10 @@ class InferenceEngine:
                     tokens[slot] = self._last_token[slot]
                 compiled = self._decode_exec()
                 with _oom.guard("serving/decode"):
-                    out = compiled(self.params, *self.cache.pages,
+                    out = compiled(self.params, *self.cache.arrays,
                                    table, lengths, self._rep(tokens),
                                    self._no_override)
-                self.cache.replace_pages(*out[-len(self.cache.pages):])
+                self.cache.replace_pages(*out[-len(self.cache.arrays):])
             fed = self._bcast(None)
             if fed.get("abort"):
                 # Rank 0's decode/speculative iteration died before

@@ -17,6 +17,21 @@ maps fresh pages and the old values become unreachable (masked by
 :func:`..models.transformer.cache_attention` long before they are
 overwritten).
 
+**Per-slot stores** (``slot_stores``): what a model keeps of a sequence
+that is no function of position lives beside the pages in the SAME
+manager, one array ``[layers, max_slots, *shape]`` a store, indexed by
+the decode slot and not by the page table: a window layer's ring of the
+last ``window`` keys or values (``kind: "window"``: its bytes a slot are
+bounded by the window, whatever ``capacity`` is), recurrent state
+(``kind: "state"``) and room the decode program works in and keeps
+across iterations so that it is never re-allocated nor zeroed (``kind:
+"scratch"``; a dimension given as ``"capacity"`` is the slot's).  They
+are fixed at construction, so admission headroom counts pages alone; a
+slot's rows are REPLACED whole by the prefill that fills it (recurrent
+state has no mask that could hide an evicted sequence's), so
+``free_slot`` has nothing of theirs to recycle but the slot itself.  The executables take and return ``arrays`` (pages,
+then per-slot stores), all donated.
+
 Page 0 is the reserved *trash* page: unmapped table entries point at
 it, so the executables' scatters of padded/inactive positions land
 somewhere harmless instead of needing per-position predication.
@@ -98,6 +113,10 @@ _M_PREFIX_BYTES = _telemetry.counter(
     "serving.prefix_bytes_saved",
     "KV bytes NOT recomputed thanks to prefix-cache hits (global "
     "logical bytes of the shared pages)")
+_M_STATE_RESETS = _telemetry.counter(
+    "serving.state_slot_resets",
+    "slots whose per-slot stores (recurrent state, window rings) an "
+    "admission's prefill replaced whole")
 _M_PREFIX_HITS_DRAFT = _telemetry.counter(
     "serving.prefix_hits_draft",
     "admissions whose speculative DRAFT prefill mapped cached prefix "
@@ -123,7 +142,8 @@ class PagedKVCache:
                  prefix_cache: bool = False, prefix_pages: int = 0,
                  fingerprint: str = "",
                  ledger_category: str = "serving.kv_pages",
-                 entry_widths: Optional[Sequence[int]] = None) -> None:
+                 entry_widths: Optional[Sequence[int]] = None,
+                 slot_stores: Sequence[dict] = ()) -> None:
         if pages_per_slot < 1 or page_size < 1:
             raise ValueError("pages_per_slot and page_size must be >= 1")
         if prefix_pages < 0:
@@ -162,6 +182,17 @@ class PagedKVCache:
                               dtype)
             pages.append(store if sh is None else jax.device_put(store, sh))
         self.pages: Tuple = tuple(pages)
+        # Per-slot stores (module docstring): ``{"name", "kind", "shape",
+        # "dtype"}`` each, ``shape`` led by the store's own layer count.
+        self.slot_stores = tuple(dict(s) for s in slot_stores)
+        if self.slot_stores and sh is not None:
+            raise ValueError("per-slot stores are not written for a "
+                             "sharded model axis")
+        self.slot_state: Tuple = tuple(
+            jnp.zeros((s["shape"][0], max_slots,
+                       *(self.capacity if d == "capacity" else d
+                         for d in s["shape"][1:])), s["dtype"])
+            for s in self.slot_stores)
 
         self._lock = _lockorder.make_lock("serving.PagedKVCache._lock")
         self._free: List[int] = list(range(1, self.n_pages))
@@ -212,6 +243,13 @@ class PagedKVCache:
                                   prefix_resident, key=self._ledger_key)
         weakref.finalize(self, _mem.ledger.free, self._ledger_category,
                          key=self._ledger_key)
+        if self.slot_state:
+            if _mem.enabled():
+                _mem.ledger.alloc("serving.slot_state",
+                                  sum(self.slot_store_bytes().values()),
+                                  key=self._ledger_key)
+            weakref.finalize(self, _mem.ledger.free, "serving.slot_state",
+                             key=self._ledger_key)
         if prefix_resident:
             weakref.finalize(self, _mem.ledger.free,
                              "serving.prefix_pages",
@@ -269,6 +307,10 @@ class PagedKVCache:
             self._lengths[slot] = 0
             self._ensure_locked(slot, n_tokens - 1)
             self._lengths[slot] = n_tokens
+            if self.slot_state:
+                # The prefill this admission runs replaces the slot's
+                # rows of every per-slot store.
+                _M_STATE_RESETS.inc()
             if prefix_pages:
                 # Split by store: the target's hits stay on the
                 # historical serving.prefix_hits family; a DRAFT
@@ -660,13 +702,29 @@ class PagedKVCache:
             lengths = jax.device_put(lengths, rep)
         return table, lengths
 
-    def replace_pages(self, *pages) -> None:
-        """Install the executables' donated-output page arrays (the old
-        references were consumed by the dispatch)."""
-        if len(pages) != len(self.pages):
-            raise ValueError(f"{len(pages)} page arrays for a cache of "
-                             f"{len(self.pages)}")
-        self.pages = tuple(pages)
+    @property
+    def arrays(self) -> Tuple:
+        """Every device array of the cache, as the executables take and
+        return them: the page arrays, then the per-slot stores."""
+        return self.pages + self.slot_state
+
+    def slot_store_bytes(self) -> Dict[str, int]:
+        """Resident bytes of the per-slot stores by ``kind``."""
+        out: Dict[str, int] = {}
+        for spec, x in zip(self.slot_stores, self.slot_state):
+            out[spec["kind"]] = (out.get(spec["kind"], 0)
+                                 + _mem.resident_nbytes(x))
+        return out
+
+    def replace_pages(self, *arrays) -> None:
+        """Install the executables' donated outputs, :attr:`arrays` in
+        their order (the old references were consumed by the dispatch)."""
+        n = len(self.pages)
+        if len(arrays) != n + len(self.slot_state):
+            raise ValueError(f"{len(arrays)} page arrays for a cache of "
+                             f"{n + len(self.slot_state)}")
+        self.pages = tuple(arrays[:n])
+        self.slot_state = tuple(arrays[n:])
 
     @property
     def k_pages(self):

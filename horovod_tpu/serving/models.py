@@ -12,6 +12,16 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
     What a token leaves in the cache: ``n_layers``, ``n_heads``,
     ``head_dim`` and ``widths``, the minor width of each store
     (:class:`~horovod_tpu.serving.kv_cache.PagedKVCache` ``entry_widths``).
+``cache_entry()["slot_stores"]`` (optional), ``slot_state``
+    Per-slot stores beside the pages, ``{"name", "kind", "shape",
+    "dtype"}`` each (``kind``: ``"window"`` a ring of the last positions,
+    ``"state"`` recurrent state, ``"scratch"`` room a program keeps across
+    iterations; ``shape`` led by the store's own layer count, a dimension
+    ``"capacity"`` the slot's): the cache manager holds one array
+    ``[layers, max_slots, *shape]`` a store and tells the model their
+    bytes by kind once (``observe_stores``).  ``pages`` below is then
+    ``cache.arrays``: the page arrays, then these.  A model with
+    ``slot_state = True`` is told which slot a prefill fills.
 ``decode(params, pages, table, lengths, tokens, rungs) -> (outs, pages)``
     One token a slot over the paged store, the view rung picked inside
     the program; writes the new entries back.  ``outs[0]`` is ``logits
@@ -22,19 +32,25 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
     The view a slot the decode program attends at these host lengths
     (the rung it is about to pick, by the same pure function): what
     ``serving.decode_view_tokens`` counts.
-``prefill(params, pages, table_row, start, n_valid, tokens) -> (outs, pages)``
+``prefill(params, pages, table_row, start, n_valid, tokens[, slot]) -> (outs, pages)``
     A padded prompt block from ``start`` cached positions; ``outs`` is
-    ``(last,)``, the last real token's logits.
+    ``(last,)``, the last real token's logits.  ``slot [1]`` only where
+    ``slot_state``: the slot's rows of the per-slot stores are REPLACED
+    by what the prompt leaves (recurrent state has no mask that could
+    hide an evicted sequence's).
+``observe_launch(lengths)`` (optional)
+    Called once a retired decode iteration with the host lengths it was
+    launched at: what the model itself counts of an iteration.
 ``verify`` / ``propose``
     The speculative programs, ``(outs, pages)`` like the others; a model
     with ``speculative = False`` has none and the engine refuses a draft
     for it.
-``tensor_parallel``, ``prefix_cache`` (+ ``prefix_cache_why``)
+``tensor_parallel`` (+ ``tensor_parallel_why``), ``prefix_cache`` (+ ``prefix_cache_why``)
     Whether the store may be sharded over a ``model`` axis, and whether
     a suffix prefill over cached prefix pages is exact for this model.
 
 A config object that has a ``serving_model()`` method supplies its own
-(``models/latent_moe.py``); every other config is the dense multi-head
+(``models/latent_moe.py``, ``models/hybrid_ssm.py``); every other config is the dense multi-head
 decoder of ``models/transformer.py``, :class:`DenseLM`, whose programs
 are operation for operation the ones the engine built itself before.
 """
@@ -60,6 +76,7 @@ class DenseLM:
     tensor_parallel = True
     prefix_cache = True
     prefix_cache_why = ""
+    slot_state = False          # no per-slot store: pages are all it keeps
 
     def __init__(self, cfg) -> None:
         self.cfg = cfg

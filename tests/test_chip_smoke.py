@@ -1,6 +1,6 @@
 """chip_smoke.py: the script the driver runs on the chip.  Here, on the
 CPU, it must refuse — quickly, naming the platform, with no result
-line — and its five legs must run at toy widths through the explicit
+line — and its six legs must run at toy widths through the explicit
 dry run, whose result line can never be read as a pass on the chip."""
 
 import json
@@ -44,7 +44,7 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert out["n"] == 8 and out["claim"] is None
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
-                         "D_serve", "E_latent_moe"}
+                         "D_serve", "E_latent_moe", "F_hybrid_ssm"}
     # The dry run forces the schedule several real chips select.
     assert legs["A_resnet_dp"]["schedule"] == "stream"
     assert legs["A_resnet_dp"]["overlap_fallbacks"] == 0
@@ -57,6 +57,9 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert legs["E_latent_moe"]["config"] == "tiny-axk1"
     assert max(legs["E_latent_moe"]["logit_rms_over_std"]) < 1e-4
     assert legs["E_latent_moe"]["pairs_on_held_experts"] > 0
+    assert legs["F_hybrid_ssm"]["config"] == "tiny-phi4flash"
+    assert max(legs["F_hybrid_ssm"]["logit_rms_over_std"]) < 1e-4
+    assert legs["F_hybrid_ssm"]["shared_kv_tokens"] > 0
 
 
 # ---------------------------------------------------------------------------

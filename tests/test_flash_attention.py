@@ -347,3 +347,23 @@ def test_resident_kernels_compile_for_the_v5e(v5e_chip, b, s, h, d, dtype):
 
     text = jax.jit(fwd_bwd).lower(qkv, g).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+# The selective-scan kernel (ops/ssm_scan.py) at the widths
+# `phi4flash-serve-reason` prefills at: the longest bucket, a short one, and
+# the shortest (padded to one 8-step chunk).  Here and not in a file of its
+# own: a second file's fixture could not describe the chip while this one
+# holds libtpu.
+@pytest.mark.parametrize("t", [2048, 64, 2])
+def test_scan_kernel_compiles_for_the_v5e(v5e_chip, t):
+    from horovod_tpu.ops import ssm_scan as S
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    text = jax.jit(
+        lambda x, dt, b, c, a, s0, n: S._pallas_scan(x, dt, b, c, a, s0, n,
+                                                     False)
+    ).lower(sd(t, 5120), sd(t, 5120), sd(t, 16), sd(t, 16), sd(16, 5120),
+            sd(16, 5120), sd(dtype=jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "ssm_scan" in text
