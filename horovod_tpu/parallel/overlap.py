@@ -70,10 +70,13 @@ compression/topology knobs — the knob selects which compiled programs
 a rank runs, so it must be uniform fleet-wide):
 
   HVD_TPU_OVERLAP=auto|on|off|serial
-      auto (default): overlap on real accelerator meshes with >1
-      replica; off on CPU/virtual-device meshes (where the
-      single-program static step is already optimal and tests pin
-      behavior explicitly).
+      auto (default): off wherever this process drives every device
+      of the step's mesh — one chip, the chips of one host under one
+      process, CPU/virtual-device meshes: the single-program step
+      with the in-program bucketed psum has one dispatch a step and
+      nothing for the host to pace (measured on four chips: PERF.md
+      section 6, PR 32).  Streaming only on accelerator meshes that
+      span processes.
       on: bucketed-backward streaming dispatch.
       serial: the same bucketed sub-programs with hard fences —
       reduction strictly after backward (the measurement/identity
@@ -194,10 +197,14 @@ def validate_env() -> None:
 
 def resolve_mode(override: Optional[str], mesh) -> str:
     """Resolve the step builder's overlap schedule: ``"stream"``,
-    ``"serial"`` or ``"off"``.  ``auto`` enables streaming only on real
-    accelerator meshes with more than one replica — on CPU/virtual
-    meshes the monolithic single-program step is already optimal and
-    the dynamic path's per-bucket control plane would be pure cost."""
+    ``"serial"`` or ``"off"``.  ``auto`` decides from the mesh alone:
+    where this process addresses every device of it (one chip, one
+    host's chips under one process, any CPU/virtual mesh) the
+    monolithic single-program step wins — one dispatch a step with
+    the psum inside it, where the stream schedule's program boundaries
+    and per-tensor submissions are host time the device waits for.
+    Only an accelerator mesh that spans processes keeps the stream
+    schedule."""
     mode = (override or overlap_mode()).strip().lower()
     if mode == "1":
         mode = "on"
@@ -210,6 +217,9 @@ def resolve_mode(override: Optional[str], mesh) -> str:
         try:
             devs = list(mesh.devices.flat)
             if len(devs) < 2 or devs[0].platform == "cpu":
+                return "off"
+            me = jax.process_index()
+            if all(d.process_index == me for d in devs):
                 return "off"
         except Exception:  # noqa: BLE001 — exotic mesh: stay monolithic
             return "off"

@@ -255,7 +255,8 @@ def _make_step(loss_fn, optimizer, mesh, average, fusion_threshold,
 
     ``overlap`` (default: the ``HVD_TPU_OVERLAP`` env knob) selects the
     backward/communication-overlap schedule (parallel/overlap.py):
-    ``off`` keeps this monolithic single-program step; ``on``/``serial``
+    ``off`` keeps this monolithic single-program step (``auto``'s
+    answer wherever this process owns the whole mesh); ``on``/``serial``
     build the bucketed-backward path whose gradient buckets ride the
     dynamic megakernel executor per bucket.
     """
@@ -397,13 +398,17 @@ def make_train_step(
         the whole-gradient ppermute ladder into the step.
       overlap: backward/communication-overlap schedule —
         ``auto``/``on``/``off``/``serial``; defaults to the
-        ``HVD_TPU_OVERLAP`` env knob (parallel/overlap.py).
+        ``HVD_TPU_OVERLAP`` env knob (parallel/overlap.py).  ``auto``
+        is ``off`` wherever this process drives every device of the
+        mesh (one chip, one host's chips, CPU meshes) and the stream
+        schedule only on an accelerator mesh that spans processes.
 
     Returns:
       ``step(params, opt_state, batch) -> (params, opt_state, loss[, aux])``
-      — one compiled SPMD program (overlap off), or the bucketed-backward
-      sub-program pipeline (overlap on; same gradients, identity contract
-      in parallel/overlap.py);
+      — one compiled SPMD program with the in-program bucketed psum
+      (overlap off: what ``auto`` builds on a mesh one process owns),
+      or the bucketed-backward sub-program pipeline (overlap on; same
+      gradients, identity contract in parallel/overlap.py);
       batch's leading axis must be divisible by the replica count.
     """
     return _make_step(loss_fn, optimizer, mesh, average, fusion_threshold,

@@ -45,7 +45,8 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
                          "D_serve", "E_latent_moe", "F_hybrid_ssm"}
-    # The dry run forces the schedule several real chips select.
+    # The dry run forces the stream schedule (auto is the one-program
+    # step on a mesh one process owns): no other leg runs it.
     assert legs["A_resnet_dp"]["schedule"] == "stream"
     assert legs["A_resnet_dp"]["overlap_fallbacks"] == 0
     assert legs["A_resnet_dp"]["overlap_buckets"] > 0

@@ -656,26 +656,140 @@ def test_init_rejects_malformed_overlap_env(monkeypatch):
         H.init(devices=jax.devices())
 
 
-def test_auto_resolution_per_mesh_platform(monkeypatch):
-    """auto = streaming only on real multi-replica accelerator meshes;
-    CPU/virtual meshes keep the monolithic program (their shared thread
-    pool has no comm/compute concurrency to exploit)."""
+def _fake_mesh(platform, procs, drop=()):
+    """A mesh-shaped stand-in: one device per entry of ``procs`` (its
+    ``process_index``), without the attributes ``drop`` names."""
     from types import SimpleNamespace
 
+    devs = [SimpleNamespace(**{
+        k: v for k, v in (("platform", platform), ("process_index", proc))
+        if k not in drop}) for proc in procs]
+    return SimpleNamespace(devices=np.asarray(devs))
+
+
+_MESHES = {
+    "cpu8": lambda: _fake_mesh("cpu", [0] * 8),
+    "tpu1": lambda: _fake_mesh("tpu", [0]),
+    "tpu8_one_process": lambda: _fake_mesh("tpu", [jax.process_index()] * 8),
+    "tpu8_two_processes": lambda: _fake_mesh("tpu", [0] * 4 + [1] * 4),
+    "tpu8_no_process_index": lambda: _fake_mesh(
+        "tpu", [0] * 8, drop=("process_index",)),
+    "no_platform": lambda: _fake_mesh("tpu", [0] * 8, drop=("platform",)),
+}
+
+
+@pytest.mark.parametrize("override,mesh,want", [
+    # auto decides from the mesh alone: the one-program step wherever
+    # this process drives every device of it (ISSUE 32: four chips under
+    # one process lost a fifth of their rate to the host-paced stream
+    # schedule); streaming only where the mesh spans processes.
+    (None, "cpu8", "off"),
+    (None, "tpu1", "off"),                  # nothing to reduce
+    (None, "tpu8_one_process", "off"),
+    (None, "tpu8_two_processes", "stream"),
+    (None, "tpu8_no_process_index", "off"),  # exotic mesh: stay monolithic
+    (None, "no_platform", "off"),
+    ("auto", "tpu8_one_process", "off"),
+    ("auto", "tpu8_two_processes", "stream"),
+    # Explicit values win on every mesh.
+    ("on", "cpu8", "stream"),
+    ("on", "tpu8_one_process", "stream"),
+    ("on", "tpu8_two_processes", "stream"),
+    ("1", "tpu8_one_process", "stream"),
+    ("serial", "cpu8", "serial"),
+    ("serial", "tpu8_one_process", "serial"),
+    ("serial", "tpu8_two_processes", "serial"),
+    ("off", "cpu8", "off"),
+    ("off", "tpu8_one_process", "off"),
+    ("off", "tpu8_two_processes", "off"),
+    ("0", "tpu8_two_processes", "off"),
+])
+def test_auto_resolution_per_mesh_platform(monkeypatch, override, mesh, want):
     monkeypatch.delenv(OV.OVERLAP_ENV, raising=False)
-    cpu_mesh = SimpleNamespace(devices=np.asarray(
-        [SimpleNamespace(platform="cpu")] * 8))
-    tpu_mesh = SimpleNamespace(devices=np.asarray(
-        [SimpleNamespace(platform="tpu")] * 8))
-    one_tpu = SimpleNamespace(devices=np.asarray(
-        [SimpleNamespace(platform="tpu")]))
-    assert OV.resolve_mode(None, cpu_mesh) == "off"
-    assert OV.resolve_mode(None, tpu_mesh) == "stream"
-    assert OV.resolve_mode(None, one_tpu) == "off"  # nothing to reduce
-    assert OV.resolve_mode("on", cpu_mesh) == "stream"  # explicit wins
-    assert OV.resolve_mode("serial", tpu_mesh) == "serial"
+    assert OV.resolve_mode(override, _MESHES[mesh]()) == want
+
+
+@pytest.mark.parametrize("mesh", ["cpu8", "tpu8_one_process",
+                                  "tpu8_two_processes"])
+def test_env_knob_is_the_default_and_unknown_values_raise(monkeypatch, mesh):
+    monkeypatch.setenv(OV.OVERLAP_ENV, "serial")
+    assert OV.resolve_mode(None, _MESHES[mesh]()) == "serial"
+    assert OV.resolve_mode("off", _MESHES[mesh]()) == "off"  # argument wins
     with pytest.raises(ValueError, match="overlap"):
-        OV.resolve_mode("diagonal", cpu_mesh)
+        OV.resolve_mode("diagonal", _MESHES[mesh]())
+
+
+# ---------------------------------------------------------------------------
+# The stateful variant under auto: one program, no bucket dispatched
+# ---------------------------------------------------------------------------
+
+def _stateful_loss(params, model_state, batch):
+    """A normalising layer with running statistics: the smallest loss
+    that returns a new model state (``make_train_step_with_state``)."""
+    x, y = batch
+    h = x @ params["w1"] + params["b1"]
+    mean = jnp.mean(h, axis=0)
+    h = jnp.tanh(h - mean)
+    pred = h @ params["w2"] + params["b2"]
+    new_state = {"mean": 0.9 * model_state["mean"] + 0.1 * mean}
+    return jnp.mean((pred - y) ** 2), new_state
+
+
+def _stateful_first_step(hvd, overlap):
+    from horovod_tpu.parallel.training import make_train_step_with_state
+
+    params = _plain_params(jax.random.PRNGKey(0))
+    stats = {"mean": jnp.zeros((_DIM,))}
+    batch = _batch(hvd, jax.random.PRNGKey(1))
+    opt = optax.sgd(0.1, momentum=0.9)
+    step = make_train_step_with_state(
+        _stateful_loss, opt, donate=False, fusion_threshold=_THRESHOLD,
+        **({} if overlap is None else {"overlap": overlap}))
+    out = step(params, stats, opt.init(params), batch)
+    jax.block_until_ready(jax.tree_util.tree_leaves(out))
+    return step, out
+
+
+def test_auto_stateful_step_is_one_program_and_dispatches_no_bucket(
+        hvd, monkeypatch):
+    """What ``resnet50-dp4`` builds (``make_train_step_with_state``,
+    the schedule left to the program) on a mesh this process owns: the
+    ``step/monolithic`` region, no ``step/stream``, and neither
+    ``overlap.buckets_dispatched`` nor ``overlap.fallbacks`` moves."""
+    import horovod_tpu as H
+    import horovod_tpu.trace as trace
+
+    monkeypatch.delenv(OV.OVERLAP_ENV, raising=False)
+    assert OV.resolve_mode(None, hvd.mesh()) == "off"
+    before = H.metrics()
+    step, _out = _stateful_first_step(hvd, None)
+    after = H.metrics()
+    assert not hasattr(step, "overlap_active")
+    names = {e["name"] for e in _step_spans(trace.current_step())}
+    assert "step/monolithic" in names
+    assert not {"step/stream", "step/serial"} & names
+
+    def moved(name, field="value"):
+        return (after.get(name, {}).get(field, 0)
+                - before.get(name, {}).get(field, 0))
+
+    assert moved("trace.span_seconds.step/monolithic", "count") == 1
+    assert moved("trace.span_seconds.step/stream", "count") == 0
+    assert moved("overlap.buckets_dispatched") == 0
+    assert moved("overlap.fallbacks") == 0
+
+
+def test_auto_stateful_first_sgd_step_bitwise_the_stream_steps(
+        hvd, monkeypatch):
+    """The identity contract for the stateful variant: the first SGD
+    step of the one-program step ``auto`` builds is bitwise the
+    ``overlap="on"`` step's — parameters, model state, momentum, loss."""
+    monkeypatch.delenv(OV.OVERLAP_ENV, raising=False)
+    step_on, out_on = _stateful_first_step(hvd, "on")
+    _step, out_auto = _stateful_first_step(hvd, None)
+    assert step_on.overlap_active and step_on.bucket_count >= 2
+    assert float(out_on[3]) == float(out_auto[3])
+    assert _leaves_equal(out_on[:3], out_auto[:3])
 
 
 def test_overlap_knob_in_hello_env_fingerprint(monkeypatch):
