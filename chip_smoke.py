@@ -3,7 +3,7 @@
     python chip_smoke.py            # on the chip (through the chip tool)
     python chip_smoke.py --dry-run  # tiny widths on the 8-device CPU mesh
 
-One process, no children, no ``JAX_PLATFORMS`` override.  Six legs run
+One process, no children, no ``JAX_PLATFORMS`` override.  Seven legs run
 through the entry points a user calls, at full width per chip:
 
   A  ResNet-50 data-parallel trainer (the BASELINE.json workload):
@@ -32,6 +32,12 @@ through the entry points a user calls, at full width per chip:
      state-space, window, full, gated-memory and cross layers): the same
      comparison through its paged layer and its five per-slot stores,
      the scan kernel compiled.
+  G  the shortcut-connected mixture of experts the benchmark serves
+     (benchmark/configs/longcat-flash-omni-ep32.json, one chip's share
+     of a 32-chip expert-parallel group: two latent attentions and two
+     dense feed-forwards a layer, zero-compute experts beside the held
+     ones): the same comparison through two cache layers a decoder
+     layer, and the pairs that went to zero-compute experts counted.
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
@@ -112,6 +118,11 @@ SERVE_REL_TOL = 2.0 ** -5
 # fp8 0.25).  Single logits move by far more wherever a near-tied
 # router choice flips, so the largest error is reported, not judged.
 LATENT_RMS_REL_TOL = 0.12
+# Shortcut MoE: the same comparison through 4 layers of two attentions,
+# two dense feed-forwards and one expert layer each (measured on the v5e,
+# PR 33: 0.028, 0.031; the largest single error 0.30 of the spread).  The
+# latent model's limit, for the same reason.
+SHORTCUT_RMS_REL_TOL = 0.12
 # Hybrid state-space decoder: the same comparison through 32 layers with
 # no router to flip a choice (measured on the v5e, PR 31: 0.027, 0.030;
 # the largest single error 0.17 of the spread).  The latent model's
@@ -771,6 +782,36 @@ def leg_hybrid_ssm(dry):
     return dict(out, shared_kv_tokens=shared)
 
 
+def leg_shortcut_moe(dry):
+    """Leg G.  The shortcut-connected mixture of experts of
+    benchmark/configs/longcat-flash-omni-ep32.json, the cut model at the
+    published widths on the chip (its toy fixture in the dry run): the
+    one latent store holds two layers a decoder layer, and the decode
+    program's counts of pairs on zero-compute experts and of all pairs
+    reach the counters."""
+    from benchmark.builders import shortcut_moe
+
+    out, before, after = _leg_served_model(
+        dry, shortcut_moe, "tiny-longcat.json",
+        "longcat-flash-omni-ep32.json", 3_300_000_033,
+        dict(max_slots=4 if dry else 128, page_size=8 if dry else 16,
+             capacity=256 if dry else 2048),
+        (40, 100) if dry else (700, 150), 8 if dry else 24,
+        SHORTCUT_RMS_REL_TOL,
+        lambda e: (not e.cache.prefix_enabled and len(e.cache.pages) == 1
+                   and e.cache.n_layers == 2 * len(e.params["layers"]),
+                   "one latent store, two layers of it a decoder layer, "
+                   "prefix cache off"))
+    routed = grew(before, after, "serving.moe_routed_pairs")
+    zero = grew(before, after, "serving.moe_zero_assignments")
+    held = grew(before, after, "serving.moe_assignments")
+    check(0 < zero < routed and 0 < held and zero + held <= routed,
+          "the decode program's counts of zero-compute and of all pairs "
+          "reach the counters")
+    return dict(out, pairs_on_held_experts=held, pairs_on_zero_experts=zero,
+                pairs_routed=routed)
+
+
 # ---------------------------------------------------------------------------
 
 def main() -> int:
@@ -779,9 +820,9 @@ def main() -> int:
                     help="toy widths on whatever platform jax has; the "
                          "result line says ok=false and names the "
                          "platform (for the test suite, never a pass)")
-    ap.add_argument("--legs", default="ABCDEF",
+    ap.add_argument("--legs", default="ABCDEFG",
                     help="subset of legs to run while debugging, e.g. "
-                         "AD; anything short of all six is not a pass")
+                         "AD; anything short of all seven is not a pass")
     args = ap.parse_args()
     dry = args.dry_run
 
@@ -837,7 +878,8 @@ def main() -> int:
             ("C_eager", lambda: leg_eager(w["eager"])),
             ("D_serve", lambda: leg_serve(w["serve"], w["lm"])),
             ("E_latent_moe", lambda: leg_latent_moe(dry)),
-            ("F_hybrid_ssm", lambda: leg_hybrid_ssm(dry)))
+            ("F_hybrid_ssm", lambda: leg_hybrid_ssm(dry)),
+            ("G_shortcut_moe", lambda: leg_shortcut_moe(dry)))
     for name, fn in plan:
         if name[0] not in args.legs.upper():
             continue

@@ -97,6 +97,10 @@ class LatentMoEConfig:
     mscale_all_dim: float = 1.0
     original_max_position_embeddings: int = 4096
     max_position_embeddings: int = 131072
+    # Queries and latent rescaled after their norms by sqrt(hidden /
+    # rank) (published by the configs that do it; this family's do not).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     dtype: object = jnp.bfloat16
     # The share held here.
     experts_held: int = 192
@@ -109,6 +113,11 @@ class LatentMoEConfig:
     @property
     def n_moe_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the paged store: one latent attention a layer."""
+        return self.num_hidden_layers
 
     @property
     def entry_width(self) -> int:
@@ -175,11 +184,23 @@ def rope(x, pos, cfg: LatentMoEConfig):
                            axis=-1).astype(x.dtype)
 
 
-def rmsnorm(x, w, eps: float, dtype):
-    """Computed in float32 whatever comes in, handed on as ``dtype``."""
+def rmsnorm(x, w, eps: float, dtype, scale=None):
+    """Computed in float32 whatever comes in, handed on as ``dtype``;
+    ``scale`` multiplies the normed value before it is rounded."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(dtype)
+    y = y * w.astype(jnp.float32)
+    return (y if scale is None else y * scale).astype(dtype)
+
+
+def lora_scales(cfg) -> tuple:
+    """``(queries, latent)``: ``sqrt(hidden / rank)`` where the config
+    says the bottleneck's output is rescaled after its norm, else
+    ``None``."""
+    return ((cfg.hidden_size / cfg.q_lora_rank) ** 0.5
+            if cfg.mla_scale_q_lora else None,
+            (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
+            if cfg.mla_scale_kv_lora else None)
 
 
 # -- parameters ---------------------------------------------------------------
@@ -249,16 +270,19 @@ def mla_project(h, ap, cfg: LatentMoEConfig, pos):
     h_n, nope, rp = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
     dt = h.dtype
+    q_scale, kv_scale = lora_scales(cfg)
     # The bottlenecks' outputs reach their norms and the rotation in
     # float32; only matmul operands and the cache entry are rounded.
     c_q = rmsnorm(jnp.dot(h, ap["w_dq"],
                           preferred_element_type=jnp.float32),
                   ap["q_norm"], cfg.rms_norm_eps, dt)
-    q = jnp.dot(c_q, ap["w_uq"], preferred_element_type=jnp.float32
-                ).reshape(b, s, h_n, nope + rp)
+    q = jnp.dot(c_q, ap["w_uq"], preferred_element_type=jnp.float32)
+    if q_scale is not None:
+        q = q * q_scale
+    q = q.reshape(b, s, h_n, nope + rp)
     ckv = jnp.dot(h, ap["w_dkv"], preferred_element_type=jnp.float32)
     c = rmsnorm(ckv[..., :cfg.kv_lora_rank], ap["kv_norm"],
-                cfg.rms_norm_eps, dt)
+                cfg.rms_norm_eps, dt, kv_scale)
     k_rope = rope(ckv[..., cfg.kv_lora_rank:], pos, cfg).astype(dt)
     fill = jnp.zeros((b, s, cfg.entry_width - cfg.kv_lora_rank - rp), dt)
     return (q[..., :nope].astype(dt),
@@ -368,13 +392,13 @@ def _moe_layer(x, lp, cfg, pos, attend, token_mask):
     b, s, d = x.shape
     h = rmsnorm(x, lp["ffn_norm"], cfg.rms_norm_eps,
                 cfg.dtype).reshape(b * s, d)
-    y, counts = moe_layer_held(
+    held = moe_layer_held(
         h, lp, num_experts=cfg.n_routed_experts,
         expert_offset=cfg.expert_offset, top_k=cfg.num_experts_per_tok,
         routed_scale=cfg.routed_scaling_factor,
         norm_topk=cfg.norm_topk_prob,
         token_mask=None if token_mask is None else token_mask.reshape(-1))
-    return x + y.reshape(b, s, d), entry, counts
+    return x + held.out.reshape(b, s, d), entry, held.counts
 
 
 def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask):
@@ -459,21 +483,20 @@ def group_rungs(lengths, rungs, groups) -> list:
     return out
 
 
-def decode_step(params, tokens, lengths, store, table,
-                cfg: LatentMoEConfig, rungs):
-    """One token a slot over the paged store, attending the live tokens
-    and not the capacity.  The slots are sorted by length and attended in
-    :func:`slot_groups`; each layer gathers, for a group, the first ``n``
-    pages of its slots' ``table`` rows, ``n`` the smallest of ``rungs``
-    that holds the group's longest sequence and its new token, picked
-    INSIDE the program from ``lengths`` (``lax.switch`` around the gather
-    and the attention only).  ``tokens [slots]``; ``lengths [slots]`` (-1
-    idle: such a slot reaches no expert, attends nothing and sorts
-    last); ``store [layers, pages, page, width]``.  Returns ``(logits
-    [slots, vocab], entries [layers, slots, width], counts [expert
-    layers, held])``."""
+def ladder_attend(lengths, store, table, cfg, rungs):
+    """The decode step's attention over the paged store, attending the
+    live tokens and not the capacity.  The slots are sorted by length and
+    attended in :func:`slot_groups`; each cache layer gathers, for a
+    group, the first ``n`` pages of its slots' ``table`` rows, ``n`` the
+    smallest of ``rungs`` that holds the group's longest sequence and its
+    new token, picked INSIDE the program from ``lengths`` (``lax.switch``
+    around the gather and the attention only).  ``lengths [slots]`` (-1
+    idle: such a slot attends nothing and sorts last); ``store [cache
+    layers, pages, page, width]``.  Returns ``(attend, pos)``:
+    ``attend(layer, q_nope, q_rope, entry, ap)`` with ``layer`` the index
+    into the store, and the new tokens' positions ``[slots, 1]``."""
     ps = store.shape[2]
-    b = tokens.shape[0]
+    b = lengths.shape[0]
     pos = jnp.clip(lengths, 0, None)[:, None]
     groups = slot_groups(b)
     order = jnp.argsort(-lengths)
@@ -498,6 +521,17 @@ def decode_step(params, tokens, lengths, store, table,
         # Back into slot order.
         return jnp.concatenate(outs)[jnp.argsort(order)]
 
+    return attend, pos
+
+
+def decode_step(params, tokens, lengths, store, table,
+                cfg: LatentMoEConfig, rungs):
+    """One token a slot over the paged store through
+    :func:`ladder_attend`.  ``tokens [slots]``; ``lengths [slots]`` (-1
+    idle: such a slot reaches no expert).  Returns ``(logits [slots,
+    vocab], entries [layers, slots, width], counts [expert layers,
+    held])``."""
+    attend, pos = ladder_attend(lengths, store, table, cfg, rungs)
     logits, entries, counts = _layers(params, tokens[:, None], pos, cfg,
                                       attend, lengths[:, None] >= 0)
     return logits[:, 0], entries[:, :, 0], counts
@@ -507,7 +541,12 @@ def decode_step(params, tokens, lengths, store, table,
 
 class LatentMoEServing:
     """The serving protocol (serving/models.py) for this model: ONE
-    store, ``[layers, pages, page, entry_width]``."""
+    store, ``[cache layers, pages, page, entry_width]``.  A sibling
+    family on the same store (models/shortcut_moe.py) gives its own step
+    functions, ``(logits, entries, *extras)`` each, and identity."""
+
+    decode_step = staticmethod(decode_step)
+    prefill_step = staticmethod(prefill_step)
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False    # one latent "head": nothing to shard
@@ -551,13 +590,13 @@ class LatentMoEServing:
         the entry."""
         w = self.cfg.entry_width
         _M_ENTRY_BYTES.set(w * jnp.dtype(self.cfg.dtype).itemsize)
-        return {"n_layers": self.cfg.num_hidden_layers, "n_heads": 1,
+        return {"n_layers": self.cfg.cache_layers, "n_heads": 1,
                 "head_dim": w, "widths": (w,)}
 
     def decode(self, params, pages, table, lengths, tokens, rungs):
         (store,) = pages
         ps = store.shape[2]
-        logits, entries, counts = decode_step(
+        logits, entries, *extras = self.decode_step(
             params, tokens, lengths, store, table, self.cfg, rungs)
         # One row a slot, written where it lies (see DenseLM.decode).
         pos = jnp.clip(lengths, 0, None)
@@ -568,13 +607,14 @@ class LatentMoEServing:
             store = jax.lax.dynamic_update_slice(
                 store, entries[:, slot][:, None, None, :],
                 (zero, page[slot], off[slot], zero))
-        return (logits, counts), (store,)
+        return (logits, *extras), (store,)
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens):
         """``start`` is always 0 here (``prefix_cache`` is off)."""
         (store,) = pages
         ps, bucket = store.shape[2], tokens.shape[1]
-        logits, entries, _ = prefill_step(params, tokens, n_valid, self.cfg)
+        logits, entries, *_ = self.prefill_step(params, tokens, n_valid,
+                                                self.cfg)
         # A page at a time, written where it lies.  (A scatter over the
         # flattened store makes the TPU copy all of it into a layout of
         # the scatter's own, and back.)  Pages past the prompt are not

@@ -216,7 +216,10 @@ def moe_layer(x, params: dict, *, axis_name: str, num_experts: int,
 
 class HeldExpertsOutput(NamedTuple):
     out: jnp.ndarray      # [tokens, d_model] float32: shared + held experts
+    #                       + the zero-compute experts' ``w x``
     counts: jnp.ndarray   # [experts_held] int32: assignments computed here
+    zero_pairs: jnp.ndarray    # int32: pairs that went to zero-compute experts
+    routed_pairs: jnp.ndarray  # int32: all pairs routed (live tokens x top_k)
 
 
 def route_sigmoid_top_k(x, router, top_k: int, routed_scale: float = 1.0,
@@ -232,6 +235,22 @@ def route_sigmoid_top_k(x, router, top_k: int, routed_scale: float = 1.0,
     gate, idx = jax.lax.top_k(scores, top_k)
     if norm_topk:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gate * routed_scale
+
+
+def route_softmax_top_k(x, router, bias, top_k: int,
+                        routed_scale: float = 1.0):
+    """Softmax scores over ALL of the router's outputs; the ``top_k``
+    largest of ``scores + bias`` are CHOSEN (``bias [outputs]``: a
+    trained score-correction buffer that balances the load) and weighted
+    by the UNBIASED scores ``* routed_scale``, not renormalised.  float32
+    at the highest matmul precision, as :func:`route_sigmoid_top_k`.
+    Returns ``(experts [t, k] int32, weights [t, k] float32)``."""
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
     return idx.astype(jnp.int32), gate * routed_scale
 
 
@@ -260,29 +279,44 @@ def moe_layer_held(x, params: dict, *, num_experts: int,
                    expert_offset: int, top_k: int,
                    routed_scale: float = 1.0, norm_topk: bool = True,
                    token_mask=None,
-                   chunk_rows: Optional[int] = None) -> HeldExpertsOutput:
-    """The share of a sigmoid-routed SwiGLU expert layer that ONE member
-    of an expert-parallel group computes: no mesh axis, no exchange, no
+                   chunk_rows: Optional[int] = None,
+                   routing=None,
+                   zero_experts: int = 0) -> HeldExpertsOutput:
+    """The share of a SwiGLU expert layer that ONE member of an
+    expert-parallel group computes: no mesh axis, no exchange, no
     capacity, no dropped token.
 
     ``x``: ``[tokens, d_model]``.  ``params``: ``router [d, num_experts]``
-    (whole, as every member holds it), ``shared`` (``w_gate``/``w_up``
+    (whole, as every member holds it), ``w_gate``/``w_up [experts_held,
+    d, f]`` and ``w_down [experts_held, f, d]``: experts ``expert_offset
+    .. expert_offset + experts_held`` of the router's ``num_experts``
+    outputs, and, where the model has one, ``shared`` (``w_gate``/``w_up``
     ``[d, f_s]``, ``w_down [f_s, d]``: counted once, by every member
-    alike), ``w_gate``/``w_up [experts_held, d, f]`` and ``w_down
-    [experts_held, f, d]``: experts ``expert_offset .. expert_offset +
-    experts_held`` of the ``num_experts``.  ``token_mask`` (``[tokens]``
-    bool) takes padding and idle rows out of the routing: they reach no
-    expert and count nowhere.  What the absent experts would add is left
-    out; the caller sends the partial result on.
+    alike).  ``routing(x) -> (experts [t, top_k], weights [t, top_k])``
+    is the caller's (:func:`route_softmax_top_k` with a choice bias);
+    left out, it is :func:`route_sigmoid_top_k` over ``params["router"]``
+    with ``routed_scale`` and ``norm_topk``.  The LAST ``zero_experts``
+    of the router's outputs are zero-compute experts: they hold no
+    weights, a chosen one returns its input, so the layer adds ``w x``
+    where the token lives (every member alike: counted once when shares
+    are added).  ``token_mask`` (``[tokens]`` bool) takes padding and
+    idle rows out of the routing: they reach no expert and count
+    nowhere.  What the absent experts would add is left out; the caller
+    sends the partial result on.
     """
     t, d = x.shape
     held = params["w_gate"].shape[0]
-    if not 0 <= expert_offset <= num_experts - held:
+    if not 0 <= expert_offset <= num_experts - zero_experts - held:
         raise ValueError(
             f"experts {expert_offset}..{expert_offset + held} are not "
-            f"among the router's {num_experts}")
-    idx, gate = route_sigmoid_top_k(x, params["router"], top_k,
-                                    routed_scale, norm_topk)
+            f"among the router's {num_experts - zero_experts}"
+            + (f" (its last {zero_experts} outputs compute nothing)"
+               if zero_experts else ""))
+    if routing is None:
+        idx, gate = route_sigmoid_top_k(x, params["router"], top_k,
+                                        routed_scale, norm_topk)
+    else:
+        idx, gate = routing(x)
     local = idx - expert_offset
     here = (local >= 0) & (local < held)
     if token_mask is not None:
@@ -324,7 +358,19 @@ def moe_layer_held(x, params: dict, *, num_experts: int,
     with jax.named_scope("moe_routed"):
         routed = jax.lax.fori_loop(0, (n_rows + rows - 1) // rows, chunk,
                                    jnp.zeros((t, d), jnp.float32))
-    with jax.named_scope("moe_shared"):
-        s = params["shared"]
-        shared = swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
-    return HeldExpertsOutput(shared + routed, counts)
+    out = routed
+    if "shared" in params:
+        with jax.named_scope("moe_shared"):
+            s = params["shared"]
+            shared = swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
+        out = shared + routed
+    live = (jnp.ones((t,), bool) if token_mask is None else token_mask)
+    zero_pairs = jnp.zeros((), jnp.int32)
+    if zero_experts:
+        to_zero = (idx >= num_experts - zero_experts) & live[:, None]
+        with jax.named_scope("moe_zero"):
+            w_zero = jnp.sum(jnp.where(to_zero, gate, 0.0), axis=-1)
+            out = out + x.astype(jnp.float32) * w_zero[:, None]
+        zero_pairs = jnp.sum(to_zero, dtype=jnp.int32)
+    return HeldExpertsOutput(out, counts, zero_pairs,
+                             jnp.sum(live, dtype=jnp.int32) * top_k)

@@ -44,7 +44,8 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert out["n"] == 8 and out["claim"] is None
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
-                         "D_serve", "E_latent_moe", "F_hybrid_ssm"}
+                         "D_serve", "E_latent_moe", "F_hybrid_ssm",
+                         "G_shortcut_moe"}
     # The dry run forces the stream schedule (auto is the one-program
     # step on a mesh one process owns): no other leg runs it.
     assert legs["A_resnet_dp"]["schedule"] == "stream"
@@ -61,6 +62,10 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert legs["F_hybrid_ssm"]["config"] == "tiny-phi4flash"
     assert max(legs["F_hybrid_ssm"]["logit_rms_over_std"]) < 1e-4
     assert legs["F_hybrid_ssm"]["shared_kv_tokens"] > 0
+    assert legs["G_shortcut_moe"]["config"] == "tiny-longcat"
+    assert max(legs["G_shortcut_moe"]["logit_rms_over_std"]) < 1e-4
+    assert 0 < legs["G_shortcut_moe"]["pairs_on_zero_experts"] < legs[
+        "G_shortcut_moe"]["pairs_routed"]
 
 
 # ---------------------------------------------------------------------------
