@@ -587,9 +587,9 @@ def leg_serve(w, wl):
         rows.append(np.array(logits[active[0][0]], np.float32))
         return logits
 
-    def pf(slot, r, prompt=None):
-        out = orig_pf(slot, r, prompt)
-        rows.append(np.array(out, np.float32))
+    def pf(slot, r, *a, **kw):
+        out = orig_pf(slot, r, *a, **kw)
+        rows.append(np.array(out[2], np.float32))  # (token, tokens, last)
         return out
 
     engine._decode_iteration, engine._prefill = dec, pf
@@ -693,10 +693,10 @@ def _leg_served_model(dry, builder, fixture, config_file, seed, engine_kw,
     rows = {}
     orig_prefill, orig_decode = engine._prefill, engine._decode_iteration
 
-    def prefill(slot, req, prompt=None):
-        last = orig_prefill(slot, req, prompt)
-        rows.setdefault(req.rid, []).append(last.copy())
-        return last
+    def prefill(slot, req, *a, **kw):
+        out = orig_prefill(slot, req, *a, **kw)
+        rows.setdefault(req.rid, []).append(np.asarray(out[2]))
+        return out
 
     def decode(active):
         owners = {slot: req.rid for slot, req in active}

@@ -19,7 +19,11 @@ loop runs one iteration ahead (:meth:`InferenceEngine._decode_iteration`):
 the executable takes the greedy token itself and hands it to the next
 launch on the device, so the host prepares and launches iteration i+1
 while the device runs i, and fetches ``[slots] int32`` a pass, not the
-logits.  Executables are
+logits.  An admission joins that pipeline
+(:meth:`InferenceEngine._prefill_step`): the prefill executable takes
+the first token itself and sets it in the token vector the next launch
+reads, so the decode behind a prefill is queued before the host has
+seen the token.  Executables are
 built ahead of time (``jit(...).lower(...).compile()``) and recorded
 in the PR-5 persistent-cache manifest under
 ``variant: "serving"`` (ops/megakernel.py ``record_manifest_entry``):
@@ -97,6 +101,12 @@ _M_AHEAD = _telemetry.counter(
     "previous one's tokens were still unfetched (the loop ran one ahead); "
     "over serving.decode_iterations it is the share of the loop that "
     "hid the host behind the device")
+_M_PREFILL_AHEAD = _telemetry.counter(
+    "serving.prefill_ahead", "admission prefills whose successor decode "
+    "was launched before the host fetched the prefill's token (the first "
+    "token went from the prefill program to the decode on the device); "
+    "over serving.prefills it is the share of admissions that did not "
+    "drain the decode loop")
 _M_VIEW_TOKENS = _telemetry.counter(
     "serving.decode_view_tokens", "tokens of KV view per slot that the "
     "decode iterations attended (the ladder rung each one rode); over "
@@ -126,7 +136,10 @@ _M_SPEC_RATE = _telemetry.gauge(
 # one's end, so they are timed (a speculative iteration starts at its
 # launch; a pass that only retires the iteration in flight at its
 # wait).  Run ahead, the tables and the launch are the NEXT iteration's
-# and the wait and the sampling the one before's.
+# and the wait and the sampling the one before's.  ``serve.prefill`` is
+# what the loop spends on an admission: its enqueue and, where the first
+# token stayed on the device for the decode queued behind it, a second
+# region of the name around the fetch of that token.
 _R_ITERATION = _trace.region("serve.iteration", "serve")
 _R_ADMIT = _trace.region("serve.admit", "serve")
 _R_PREFILL = _trace.region("serve.prefill", "serve")
@@ -157,15 +170,16 @@ class _Flight:
     it, the view it attends, the tables it is launched with and, once
     launched, the program's outputs, still on the device."""
 
-    __slots__ = ("riders", "view", "lengths", "inputs", "tokens", "logits",
-                 "extras")
+    __slots__ = ("riders", "view", "lengths", "inputs", "fresh", "tokens",
+                 "logits", "extras")
 
     def __init__(self, riders: Dict[int, Request], view, lengths,
-                 inputs) -> None:
+                 inputs, fresh: int = 0) -> None:
         self.riders = riders    # slot -> the request decoding there
         self.view = view
         self.lengths = lengths  # the host's, as launched (-1: not riding)
         self.inputs = inputs    # (table, lengths, override or None)
+        self.fresh = fresh      # riders whose first token the host lacks
         self.tokens = self.logits = None
         self.extras: Tuple = ()
 
@@ -361,6 +375,13 @@ class InferenceEngine:
         # vectors a launch takes when the host has nothing to say: no
         # previous tokens (a start), no override (a steady pass).
         self._inflight: Optional[_Flight] = None
+        # An admission rides that pipeline (_admit_prefill): the
+        # prefills whose first token is still on the device, (slot,
+        # request, token, bucket) in admission order, and the token
+        # vector the last of them returned, which the next launch
+        # takes for its ``prev``.
+        self._fresh: List[Tuple[int, Request, Any, int]] = []
+        self._carry = None
         self._no_tokens = self._rep(np.zeros((max_slots,), np.int32))
         self._no_override = self._rep(np.full((max_slots,), -1, np.int32))
         self._last_token = np.zeros((max_slots,), np.int32)
@@ -637,27 +658,55 @@ class InferenceEngine:
                 self._no_tokens, self._no_override)
         return self._aot(("decode",), self._decode_step, self.cache, args)
 
+    def _prefill_step(self, params, pages, table, start, n_valid, tokens,
+                      prev, slot):
+        """The model's prefill program with the first token chosen in
+        it, as :meth:`_decode_step` chooses the later ones: the output
+        leads with the greedy choice from the last real token's row
+        (``int32``, the first index among ties, as ``np.argmax``), then
+        ``prev`` (the ``[slots] int32`` the iteration in flight chose)
+        with entry ``slot`` set to it, which the next decode launch
+        takes for its own ``prev``: the token reaches the decode queued
+        behind the prefill without the host.  The row and the model's
+        other outputs follow.  A model with per-slot stores is told
+        WHICH slot it fills."""
+        which = (slot,) if self.model.slot_state else ()
+        outs, pages = self.model.prefill(params, pages, table, start,
+                                         n_valid, tokens, *which)
+        token = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
+        merged = prev.at[slot[0]].set(token)
+        if self._replicated is not None:
+            merged = jax.lax.with_sharding_constraint(merged,
+                                                      self._replicated)
+        return (token, merged, *outs), pages
+
     def _prefill_exec(self, bucket: int, draft: bool = False) -> Any:
         """Prefill executable, START-aware: ``start`` is the number of
         already-cached positions (0 for a cold prefill; the shared
         prefix length on a prefix-cache hit, so only the suffix runs
         through the model), ``n_valid`` the real token count in the
         padded ``tokens`` block — the last real token's logits are what
-        admission samples from.  ``draft=True`` builds the same program
-        over the draft model/cache (cold draft prefill on admission)."""
-        model = self._draft_model if draft else self.model
+        admission samples from, in the program (:meth:`_prefill_step`).
+        ``draft=True`` builds the draft model's own prefill over its
+        cache (cold draft prefill on admission): nobody samples from
+        it."""
+        key = ("draft_prefill" if draft else "prefill", bucket)
+        compiled = self._exec.get(key)
+        if compiled is not None:
+            # Before the example arguments are built: they are copies
+            # to the device, 4-5 ms of an admission's enqueue on the chip.
+            return compiled
         cache = self.draft_cache if draft else self.cache
-        params = self._draft_params if draft else self.params
-        args = (params, *cache.arrays,
+        args = (self._draft_params if draft else self.params,
+                *cache.arrays,
                 self._rep(np.zeros((1, cache.pages_per_slot), np.int32)),
                 self._rep(np.zeros((1,), np.int32)),
                 self._rep(np.ones((1,), np.int32)),
                 self._rep(np.zeros((1, bucket), np.int32)))
-        if model.slot_state:
-            # A model with per-slot stores is told WHICH slot it fills.
-            args += (self._rep(np.zeros((1,), np.int32)),)
-        key = ("draft_prefill" if draft else "prefill", bucket)
-        return self._aot(key, model.prefill, cache, args)
+        if draft:
+            return self._aot(key, self._draft_model.prefill, cache, args)
+        args += (self._no_tokens, self._rep(np.zeros((1,), np.int32)))
+        return self._aot(key, self._prefill_step, cache, args)
 
     def _verify_exec(self) -> Any:
         """The speculative-decoding verify program: ONE donated target
@@ -737,8 +786,10 @@ class InferenceEngine:
     # -- the continuous-batching iteration --------------------------------
     def step(self, now: Optional[int] = None, admit: bool = True) -> bool:
         """ONE iteration: admit into free slots (prefill each new
-        sequence and sample its first token from the prefill logits —
-        TTFT pays no decode-batching delay), then one batched decode
+        sequence and take its first token from the prefill logits —
+        TTFT pays no decode-batching delay; where the loop runs ahead
+        the token is taken in the program and fed once the decode
+        behind it is launched), then one batched decode
         over every active slot — sequences finish and admit mid-stream,
         no batch boundary.  ``now`` gates admission on logical arrival
         stamps (trace replay); None admits anything queued.
@@ -765,8 +816,12 @@ class InferenceEngine:
             self._bcast({"stop": False,
                          "admit": [(slot, list(req.prompt))
                                    for slot, req in admitted]})
+        # The admissions join the run-ahead pipeline where the decode
+        # loop itself may run ahead, by its rule, over everyone alive
+        # with them.
+        ahead = bool(admitted) and self._runs_ahead(self.scheduler.active())
         for slot, req in admitted:
-            self._prefill_and_sample(slot, req)
+            self._admit_prefill(slot, req, ahead)
         # Clean abort of disconnected clients' slots (hvd-chaos): the
         # eviction happens HERE, at the iteration boundary on the
         # serve-loop thread — the only thread that may free KV slots —
@@ -820,6 +875,10 @@ class InferenceEngine:
             # tokens.
             self._retired(self._inflight)
             self._inflight = None
+        # The prefills ran behind the iteration the pass retired: their
+        # tokens come last.  Every first token is the host's now.
+        self._feed_fresh()
+        self._carry = None
         return bool(admitted or active)
 
     def _admit(self, now: Optional[int]) -> List[Tuple[int, Request]]:
@@ -924,10 +983,15 @@ class InferenceEngine:
         return reason
 
     def _prefill(self, slot: int, req: Request,
-                 prompt: Optional[List[int]] = None) -> np.ndarray:
-        """Admission prefill.  With a prefix-cache hit the shared pages
-        map copy-free and ONLY the suffix runs through the model (the
-        KV a suffix prefill derives is bitwise-identical to a cold
+                 prompt: Optional[List[int]] = None, prev=None) -> Tuple:
+        """Admission prefill: enqueues the program and returns its
+        outputs on the device, ``(token, tokens, last)``: the greedy
+        first token, ``prev`` (the token vector of the iteration in
+        flight; None: nothing the next launch needs) with this slot's
+        entry set to it, and the last real token's logits row; whoever
+        fetches one waits for the program.  With a prefix-cache hit the
+        shared pages map copy-free and ONLY the suffix runs through the
+        model (the KV a suffix prefill derives is bitwise-identical to a cold
         full prefill's: every gemm is row-wise over M>=2 blocks, the
         same discipline the prefill+decode ≡ non-incremental contract
         already rides).  The completed prompt's full pages publish into
@@ -948,15 +1012,15 @@ class InferenceEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(suffix)] = suffix
         compiled = self._prefill_exec(bucket)
-        which = ((self._rep(np.asarray([slot], np.int32)),)
-                 if self.model.slot_state else ())
         with _oom.guard(f"serving/prefill/{bucket}"):
-            last, *pages = compiled(
+            token, merged, last, *pages = compiled(
                 self.params, *self.cache.arrays,
                 self._rep(self.cache.table_row(slot)),
                 self._rep(np.asarray([n_shared], np.int32)),
                 self._rep(np.asarray([len(suffix)], np.int32)),
-                self._rep(tokens), *which)
+                self._rep(tokens),
+                self._no_tokens if prev is None else prev,
+                self._rep(np.asarray([slot], np.int32)))
         self.cache.replace_pages(*pages)
         _M_PREFILL_TOKENS.inc(len(suffix))
         self.cache.publish_prefix(slot, prompt)
@@ -980,11 +1044,12 @@ class InferenceEngine:
             self.draft_cache.publish_prefix(slot, prompt)
         self._prev_token[slot] = prompt[-1]
         _M_PREFILLS.inc()
-        return np.asarray(last)
+        return token, merged, last
 
     def _runs_ahead(self, active) -> bool:
         """Whether a pass may launch the next iteration before the host
-        holds this one's tokens.  It follows from the requests alive:
+        holds this one's tokens, and an admission the decode behind its
+        prefill.  It follows from the requests alive:
         every one greedy (a sampled token is a host draw from the
         logits row, keyed ``(seed, position)``), no draft (its greedy
         slots ride propose/verify), one process (``follow`` mirrors
@@ -994,16 +1059,19 @@ class InferenceEngine:
                 and not self._multiprocess())
 
     def _continuing(self, active,
-                    behind: Dict[int, Request]) -> Dict[int, Request]:
-        """Who rides the iteration launched behind the one ``behind``
-        rode, that one's tokens unseen: every request alive but those
-        its token finishes by ``max_new_tokens`` or capacity — a count,
-        which the host knows without the token.  An ``eos_id`` it
-        cannot know: such a request rides once more (``_retire``)."""
+                    *unseen: Dict[int, Request]) -> Dict[int, Request]:
+        """Who rides an iteration launched with tokens the host has not
+        seen: each of ``unseen`` names the requests one such token is
+        due (the riders of the iteration in flight; the prefills whose
+        first token is on the device).  Every request alive rides but
+        those these tokens finish by ``max_new_tokens`` or capacity — a
+        count, which the host knows without the token.  An ``eos_id``
+        it cannot know: such a request rides once more (``_retire``)."""
         riders = {}
         for slot, req in active:
-            if behind.get(slot) is req:
-                n = len(req.generated) + 1
+            due = sum(u.get(slot) is req for u in unseen)
+            if due:
+                n = len(req.generated) + due
                 if (n >= req.max_new_tokens
                         or len(req.prompt) + n >= self.capacity):
                     continue
@@ -1011,14 +1079,18 @@ class InferenceEngine:
         return riders
 
     def _plan(self, riders: Dict[int, Request],
-              behind: Optional[Dict[int, Request]]) -> Optional[_Flight]:
+              behind: Optional[Dict[int, Request]],
+              fresh: Dict[int, Request]) -> Optional[_Flight]:
         """The host's half of one launch: the tables ``riders`` decode
         at, on the device.  ``behind`` is who rode the iteration whose
         tokens the host has not fetched (None: it holds every token).
         A request of ``behind`` decodes one past its cached length (its
         page mapped here, which may raise: before any launch of the
-        pass) and takes its token from that program's output; every
-        other rider gets the host's token through the override.
+        pass) and takes its token from that program's output; a request
+        of ``fresh`` (its prefill's token unfetched) decodes at its
+        cached length and takes the token its prefill set in the
+        launch's ``prev``; every other rider gets the host's token
+        through the override.
         Whoever does not ride — finishing under ``behind``, or freed by
         a drain meanwhile — is shipped as an empty slot: length -1, row
         on the trash page.  Returns None with nobody left to ride."""
@@ -1031,17 +1103,21 @@ class InferenceEngine:
         lengths = np.full_like(cached, -1)
         override = None
         riding = {}
+        n_fresh = 0
         for slot, req in riders.items():
             if cached[slot] < 0:
                 continue
             riding[slot] = req
             if behind.get(slot) is req:
                 lengths[slot] = cached[slot] + 1
-            else:
-                lengths[slot] = cached[slot]
-                if override is None:
-                    override = np.full_like(cached, -1)
-                override[slot] = self._last_token[slot]
+                continue
+            lengths[slot] = cached[slot]
+            if fresh.get(slot) is req:
+                n_fresh += 1
+                continue
+            if override is None:
+                override = np.full_like(cached, -1)
+            override[slot] = self._last_token[slot]
         if not riding:
             return None
         table[lengths < 0] = 0
@@ -1049,17 +1125,21 @@ class InferenceEngine:
         return _Flight(
             riding, self.model.decode_view(lengths, self._rungs), lengths,
             (self._rep(table), self._rep(lengths),
-             None if override is None else self._rep(override)))
+             None if override is None else self._rep(override)), n_fresh)
 
     def _launch(self, flight: _Flight, prev: Optional[_Flight]) -> _Flight:
         """Enqueue a planned iteration behind ``prev`` (None: behind
-        nothing the host has not fetched)."""
+        no iteration the host has not fetched).  The first launch after
+        an admission that rides takes the token vector its prefill
+        returned: ``prev``'s with the first tokens set."""
         (table, lengths, override), flight.inputs = flight.inputs, None
+        tokens, self._carry = self._carry, None
+        if tokens is None:
+            tokens = self._no_tokens if prev is None else prev.tokens
         compiled = self._decode_exec()
         with _oom.guard("serving/decode"):
             out = compiled(
-                self.params, *self.cache.arrays, table, lengths,
-                self._no_tokens if prev is None else prev.tokens,
+                self.params, *self.cache.arrays, table, lengths, tokens,
                 self._no_override if override is None else override)
         stores = len(self.cache.arrays)
         self.cache.replace_pages(*out[-stores:])
@@ -1067,6 +1147,8 @@ class InferenceEngine:
         flight.extras = out[2:-stores]
         if prev is not None:
             _M_AHEAD.inc()
+        if flight.fresh:
+            _M_PREFILL_AHEAD.inc(flight.fresh)
         return flight
 
     def _decode_iteration(self, active):
@@ -1082,6 +1164,15 @@ class InferenceEngine:
         most the one it feeds.  Either way every rider of the retired
         iteration is fed exactly one token: ``step()``'s contract.
 
+        The pass's admissions ride the first launch, their first tokens
+        unfetched (:meth:`_admit_prefill`), and the host takes results
+        in the order the device gives them: behind an iteration in
+        flight the prefills ran after it, so their tokens are fed after
+        its retirement (``_step``); with nothing in flight they ran
+        before the iteration the pass retires, so they are fed here,
+        before it, and that iteration is timed from its wait: its
+        tables and launch ran under the prefill.
+
         What a launch needs of the iteration before it is one integer a
         slot, and that stays on the device (:meth:`_decode_step`).
         What the host cannot know ahead it handles late: a request
@@ -1094,20 +1185,27 @@ class InferenceEngine:
         Returns the retired iteration's logits, still on the device:
         rows for whoever asks."""
         it = self._iter
+        fresh = {slot: req for slot, req, _, _ in self._fresh}
         flight, self._inflight = self._inflight, None
-        behind = dict(active) if flight is None else flight.riders
-        ahead = (self._continuing(active, behind)
+        starts = flight is None
+        behind = (self._continuing(active, fresh) if starts
+                  else flight.riders)
+        ahead = (self._continuing(active, behind, fresh)
                  if self._runs_ahead(active) else {})
         first = None
-        if flight is None or ahead:
+        if starts or ahead:
             with _R_TABLES(iter=it) as first:
-                start = self._plan(behind, None) if flight is None else None
-                ahead = self._plan(ahead, behind) if ahead else None
+                start = self._plan(behind, None, fresh) if starts else None
+                ahead = (self._plan(ahead, behind, {} if starts else fresh)
+                         if ahead else None)
             with _R_LAUNCH(iter=it):
                 if start is not None:
                     flight = self._launch(start, None)
                 if ahead is not None and flight is not None:
                     self._inflight = self._launch(ahead, flight)
+        if starts and fresh:
+            self._feed_fresh()
+            first = None
         if flight is None:
             return None     # a drain freed every slot under the pass
         return self._retire(flight, first)
@@ -1161,12 +1259,44 @@ class InferenceEngine:
             # lengths it was launched with.
             observe(flight.lengths)
 
-    def _prefill_and_sample(self, slot: int, req: Request) -> None:
+    def _admit_prefill(self, slot: int, req: Request, ahead: bool) -> None:
+        """One admission's prefill.  ``ahead``: the program is only
+        enqueued, behind the iteration in flight and the pass's earlier
+        admissions; its token stays on the device, set in the vector
+        the pass's first launch takes (:meth:`_prefill_step`), and the
+        host feeds it when it has launched (:meth:`_feed_fresh`).
+        Else the host waits for the logits row here and draws from
+        it."""
         with _R_PREFILL(iter=self._iter, rid=req.rid,
                         prompt_tokens=len(req.prompt)) as r:
-            last = self._prefill(slot, req)
+            if ahead:
+                prev = self._carry
+                if prev is None and self._inflight is not None:
+                    prev = self._inflight.tokens
+                token, self._carry, _ = self._prefill(slot, req, prev=prev)
+                self._fresh.append((slot, req, token,
+                                    self._prefill_bucket))
+            else:
+                last = np.asarray(self._prefill(slot, req)[2])
+                self._feed(slot, req, self._sample(req, last))
             r.note(bucket=self._prefill_bucket)
-            self._feed(slot, req, self._sample(req, last))
+
+    def _feed_fresh(self) -> None:
+        """Fetch and feed the first tokens the pass's prefills left on
+        the device, in admission order; here the host first holds them,
+        so here they are stamped.  A request that ended meanwhile (a
+        cancellation, a drain) has its token dropped, as ``_retire``
+        drops a decode's; an error of the prefill program surfaces at
+        the fetch, under its executable's name."""
+        while self._fresh:
+            slot, req, token, bucket = self._fresh.pop(0)
+            if req.finish_reason is not None:
+                continue
+            with _R_PREFILL(iter=self._iter, rid=req.rid,
+                            prompt_tokens=len(req.prompt), bucket=bucket):
+                with _oom.guard(f"serving/prefill/{bucket}"):
+                    token = int(np.asarray(token))
+                self._feed(slot, req, token)
 
     # -- speculative decoding ---------------------------------------------
     def _spec_dispatch(self, slots: Sequence[int]):
@@ -1501,11 +1631,14 @@ class InferenceEngine:
             try:
                 compiled = self._prefill_exec(bucket)
                 with _oom.guard(f"serving/prefill/{bucket}"):
-                    _, *pages = compiled(
+                    # Nobody decodes behind a ghost row: the token
+                    # lands in a vector nobody reads.
+                    _, _, _, *pages = compiled(
                         self.params, *self.cache.pages, self._rep(row),
                         self._rep(np.zeros((1,), np.int32)),
                         self._rep(np.asarray([n], np.int32)),
-                        self._rep(toks))
+                        self._rep(toks), self._no_tokens,
+                        self._rep(np.zeros((1,), np.int32)))
             except Exception as e:  # noqa: BLE001 — seeding is an
                 # optimization: one failed chain must neither strand
                 # its ghost pages (the sizing invariant would silently
@@ -1581,9 +1714,12 @@ class InferenceEngine:
         with self._drain_lock:
             drained, pending = self._drain_and_finish(
                 FinishReason.ERROR)
-            # The poisoned step may be the iteration in flight: its
-            # outputs are no input for the next launch.
+            # The poisoned step may be the iteration in flight or an
+            # admission's prefill: their outputs are no input for the
+            # next launch.
             self._inflight = None
+            self._fresh.clear()
+            self._carry = None
             if not self._drained:
                 self.scheduler.resume()
         return drained + pending

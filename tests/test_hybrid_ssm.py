@@ -306,10 +306,10 @@ def rollout(eng, prompts, max_new):
     rows = {r.rid: [] for r in reqs}
     orig_prefill, orig_retire = eng._prefill, eng._retire
 
-    def prefill(slot, req, prompt=None):
-        last = orig_prefill(slot, req, prompt)
-        rows[req.rid].append(last.copy())
-        return last
+    def prefill(slot, req, *a, **kw):
+        out = orig_prefill(slot, req, *a, **kw)
+        rows[req.rid].append(np.asarray(out[2]))  # (token, tokens, last)
+        return out
 
     def retire(flight, first):
         owners = {slot: req.rid for slot, req in flight.riders.items()

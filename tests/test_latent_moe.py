@@ -76,11 +76,11 @@ def rollout(eng, prompts, max_new):
     slot_of = {}
     orig_prefill, orig_decode = eng._prefill, eng._decode_iteration
 
-    def prefill(slot, req, prompt=None):
-        last = orig_prefill(slot, req, prompt)
+    def prefill(slot, req, *a, **kw):
+        out = orig_prefill(slot, req, *a, **kw)
         slot_of[slot] = req.rid
-        rows[req.rid].append(last.copy())
-        return last
+        rows[req.rid].append(np.asarray(out[2]))  # (token, tokens, last)
+        return out
 
     def decode(active):
         owners = {slot: req.rid for slot, req in active}

@@ -508,9 +508,9 @@ def test_engine_bitwise_vs_noncached_forward_through_executables():
         rows.append(logits[active[0][0]].copy())
         return logits
 
-    def wrapped_pf(slot, r, prompt=None):
-        out = orig_pf(slot, r, prompt)
-        pf.append(out.copy())
+    def wrapped_pf(slot, r, *a, **kw):
+        out = orig_pf(slot, r, *a, **kw)
+        pf.append(np.asarray(out[2]))   # (token, tokens, last)
         return out
 
     eng._decode_iteration = wrapped_dec
@@ -1122,6 +1122,252 @@ def test_evictions_under_an_iteration_in_flight(how):
     assert eng._inflight is None
 
 
+# ---------------------------------------------------------------------------
+# An admission joins the run-ahead pipeline (ISSUE 34): the prefill
+# program takes the first token itself and sets it in the token vector the
+# next decode launch reads, so that decode is queued before the host has
+# seen the token
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _hybrid():
+    """A ``slot_state`` model at toy size (models/hybrid_ssm.py: its
+    prefill is told its slot and replaces per-slot stores)."""
+    from benchmark import cells
+    from benchmark.builders.hybrid_ssm import config_of
+
+    with open(os.path.join(cells.HERE, "tests", "fixtures", "configs",
+                           "tiny-phi4flash.json")) as f:
+        model = json.load(f)["model"]
+    ref = cells.load_module("refs", "phi4-mini-flash")
+    return ref.init_params(model, 11), config_of(model)
+
+
+@functools.lru_cache(maxsize=None)
+def _admission_engine(model):
+    """One engine a model for the cases below: a case runs on it twice,
+    as it is and held synchronous."""
+    if model == "dense":
+        eng = make_engine()
+    else:
+        eng = InferenceEngine(*_hybrid(), max_slots=3, page_size=4,
+                              capacity=32)
+        assert eng.model.slot_state
+    eng.warm_start()
+    return eng
+
+
+def _tokens(seed, n):
+    return [int(t) for t in
+            np.random.default_rng(seed).integers(1, 96, size=n)]
+
+
+P0, P1, P2 = _tokens(340, 5), _tokens(341, 9), _tokens(342, 3)
+
+# case -> (trace of (prompt, max_new_tokens, arrival), prefills that ride)
+ADMISSIONS = {
+    # (a) behind an iteration in flight
+    "in_flight": ([(P0, 8, 0), (P1, 5, 2)], 2),
+    # (b) a start: nothing in flight, nobody alive
+    "start": ([(P1, 5, 0)], 1),
+    # (c) two in one step, behind an iteration and at a start
+    "two_in_flight": ([(P0, 8, 0), (P1, 5, 2), (P2, 4, 2)], 3),
+    "two_at_a_start": ([(P1, 5, 0), (P2, 4, 0)], 2),
+    # (d) the first token is the last, by count: it does not ride
+    "one_token": ([(P0, 6, 0), (P1, 1, 2), (P2, 1, 12)], 1),
+    # (e) the first token is ``eos_id``: it has ridden, and is dropped;
+    # the next admission takes its slot
+    "eos_first": ([(P0, 8, 0), (P1, 6, 2), (P2, 4, 4)], 3),
+    "eos_first_at_a_start": ([(P1, 6, 0), (P2, 4, 3)], 2),
+    # (f) cancelled between its prefill's enqueue and its fetch (it
+    # never rides), and between the launch behind it and the fetch (it
+    # has ridden)
+    "cancel_at_enqueue": ([(P0, 8, 0), (P1, 6, 2), (P2, 4, 5)], 2),
+    "cancel_after_launch": ([(P0, 8, 0), (P1, 6, 2), (P2, 4, 5)], 3),
+}
+
+
+def _drive(eng, trace, eos=None, cancel=None):
+    """Replay ``trace``; P1's request ends by ``eos`` where given, and is
+    cancelled where ``cancel`` says.  Returns the requests and the
+    counters' deltas (prefills, prefill_ahead, tokens)."""
+    names = ("prefills", "prefill_ahead", "tokens_generated")
+    c0 = _counters(*names)
+    reqs = [eng.submit(list(p), max_new_tokens=n, arrival=a,
+                       eos_id=eos if p is P1 else None)
+            for p, n, a in trace]
+    victim = next((r for r in reqs if r.prompt == P1), None)
+    orig_prefill, orig_launch = eng._prefill, eng._launch
+
+    def prefill(slot, req, *a, **kw):
+        out = orig_prefill(slot, req, *a, **kw)
+        if req is victim:
+            assert eng.abort_request(req) == "active"
+        return out
+
+    def launch(flight, prev):
+        out = orig_launch(flight, prev)
+        if victim in flight.riders.values() and not victim.cancel_reason:
+            assert eng.abort_request(victim) == "active"
+        return out
+
+    if cancel == "cancel_at_enqueue":
+        eng._prefill = prefill
+    elif cancel == "cancel_after_launch":
+        eng._launch = launch
+    try:
+        it = 0
+        while not eng.scheduler.idle():
+            eng.step(now=it)
+            # Every first token is the host's when a pass ends.
+            assert eng._fresh == [] and eng._carry is None
+            it += 1
+        eng.step()      # a stale iteration in flight is dropped
+    finally:
+        eng._prefill, eng._launch = orig_prefill, orig_launch
+    assert eng._inflight is None
+    assert eng.cache.free_pages() == eng.cache.total_pages
+    return reqs, [b - a for a, b in zip(c0, _counters(*names))]
+
+
+@pytest.mark.parametrize("case", sorted(ADMISSIONS))
+@pytest.mark.parametrize("model", ["dense", "hybrid"])
+def test_admission_ahead_equals_the_synchronous_admission(model, case,
+                                                          monkeypatch):
+    """Greedy completions token for token those of the admission that
+    waits for its logits row, ``serving.prefill_ahead`` counting the
+    prefills whose successor decode was launched with their token
+    unfetched (all of them on an all-greedy trace where none ends at
+    its first token by count), the cache whole afterwards."""
+    eng = _admission_engine(model)
+    trace, rode = ADMISSIONS[case]
+    cancel = case if case.startswith("cancel") else None
+    eos = None
+    if case.startswith("eos"):
+        eos = eng.generate(list(P1), max_new_tokens=1)[0]
+    ahead, (n_pre, n_ahead, n_tok) = _drive(eng, trace, eos, cancel)
+    monkeypatch.setattr(eng, "_runs_ahead", lambda active: False)
+    held, (h_pre, h_ahead, h_tok) = _drive(eng, trace, eos, cancel)
+    assert (n_pre, h_pre) == (len(trace),) * 2
+    assert (n_ahead, h_ahead) == (rode, 0)
+    for a, h, (p, n, _) in zip(ahead, held, trace):
+        if cancel and p is P1:
+            # Cancelled under its prefill, its first token is dropped
+            # where the synchronous admission had fed it already;
+            # cancelled under the decode behind it, that decode's is
+            # (held at depth 0, the pass had fed it too).
+            fed = 0 if cancel == "cancel_at_enqueue" else 1
+            assert a.generated == h.generated[:fed]
+            assert len(h.generated) == 1 + fed
+            assert a.finish_reason == h.finish_reason \
+                == FinishReason.CLIENT_DISCONNECT
+        elif eos is not None and p is P1:
+            assert a.generated == h.generated == [eos]
+            assert a.finish_reason == h.finish_reason == FinishReason.EOS
+        else:
+            assert a.result(0) == h.result(0) and len(a.generated) == n
+            if model == "dense":
+                assert a.result(0) == reference_rollout(p, n, eng.capacity)
+    if not cancel:
+        assert n_tok == h_tok == sum(len(r.generated) for r in held)
+    if case in ("in_flight", "start", "two_in_flight", "two_at_a_start"):
+        assert n_ahead == n_pre
+
+
+def test_a_sampled_request_alive_holds_the_admission_synchronous():
+    """``_runs_ahead``'s rule over the admitted request and everyone
+    alive: a sampled request (its token is a host draw from the row) is
+    admitted synchronously, and so is a greedy one admitted while it is
+    alive; ``serving.prefill_ahead`` stands still.  Alone again, the
+    next admission rides."""
+    eng = make_engine()
+    eng.warm_start()
+    greedy = eng.submit([5, 3, 8], max_new_tokens=14)
+    eng.step()
+    eng.step()
+    p0, a0 = _counters("prefills", "prefill_ahead")
+    hot = eng.submit([1, 2, 3, 4], max_new_tokens=5, temperature=0.8,
+                     seed=11)
+    eng.step()
+    assert len(hot.generated) == 1 and eng._inflight is None
+    late = eng.submit([9, 9, 2, 6], max_new_tokens=3)
+    eng.step()
+    assert not hot.done.is_set() and len(late.generated) == 2
+    assert _counters("prefills", "prefill_ahead") == [p0 + 2, a0]
+    while not (hot.done.is_set() and late.done.is_set()):
+        eng.step()
+    last = eng.submit([7, 1], max_new_tokens=3)
+    eng.run_until_idle()
+    assert _counters("prefills", "prefill_ahead") == [p0 + 3, a0 + 1]
+    assert hot.result(0) == _sampled_reference([1, 2, 3, 4], 5, 0.8, 11,
+                                               eng.capacity)
+    for req, (p, n) in ((greedy, ([5, 3, 8], 14)), (late, ([9, 9, 2, 6], 3)),
+                        (last, ([7, 1], 3))):
+        assert req.result(0) == reference_rollout(p, n, eng.capacity)
+
+
+def test_a_prefix_hits_suffix_prefill_rides_as_a_cold_one_does():
+    """The suffix prefill over cached prefix pages is the same program
+    at another ``start``: its token too goes to the decode behind it on
+    the device."""
+    header = list(range(1, 18))  # 17 tokens -> 2 full pages published
+    ext = header + [40, 41, 42]
+    eng = make_engine(prefix_cache=True)
+    eng.warm_start()
+    names = ("prefills", "prefill_ahead", "prefill_tokens", "prefix_hits")
+    c0 = _counters(*names)
+    assert eng.generate(list(header), max_new_tokens=5) \
+        == reference_rollout(header, 5, 32)
+    c1 = _counters(*names)
+    assert [b - a for a, b in zip(c0, c1)] == [1, 1, 17, 0]
+    assert eng.generate(list(ext), max_new_tokens=5) \
+        == reference_rollout(ext, 5, 32)
+    assert [b - a for a, b in zip(c1, _counters(*names))] == [1, 1, 4, 1]
+
+
+def test_a_prefill_error_surfaces_at_the_fetch_under_its_name(monkeypatch):
+    """The prefill is only enqueued at admission, so what its program
+    raises arrives with its token: still under ``serving/prefill/
+    <bucket>``, and ``abort_all`` still leaves an engine that serves."""
+    from horovod_tpu.memory import oom
+
+    named = []
+    monkeypatch.setattr(oom, "oom_event",
+                        lambda name, exc, predicted=None: named.append(name))
+
+    class Poisoned:
+        def __array__(self, *a, **kw):
+            raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory while "
+                               "trying to allocate 123 bytes")
+
+    eng = make_engine()
+    eng.warm_start()
+    a = eng.submit([5, 3, 8], max_new_tokens=8)
+    eng.step()
+    eng.step()
+    orig = eng._prefill
+
+    def prefill(slot, req, *args, **kw):
+        _, tokens, last = orig(slot, req, *args, **kw)
+        return Poisoned(), tokens, last
+
+    eng._prefill = prefill
+    b = eng.submit([1, 2, 3, 4], max_new_tokens=4)
+    n = len(a.generated)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        eng.step()
+    eng._prefill = orig
+    assert named == ["serving/prefill/4"]
+    # The iteration in flight was retired before the fetch.
+    assert len(a.generated) == n + 1 and b.generated == []
+    assert {r.rid for r in eng.abort_all()} == {a.rid, b.rid}
+    assert eng._fresh == [] and eng._carry is None
+    assert eng._inflight is None
+    assert eng.cache.free_pages() == eng.cache.total_pages
+    assert eng.generate([1, 2, 3, 4], max_new_tokens=4) \
+        == reference_rollout([1, 2, 3, 4], 4, eng.capacity)
+
+
 def test_engine_tensor_parallel_matches_single_device():
     from horovod_tpu.core.topology import make_mesh
 
@@ -1192,9 +1438,13 @@ def _iteration_spans(it):
 
 def test_engine_iteration_emits_serve_regions_with_one_iter():
     """One engine iteration: serve.iteration > serve.admit,
-    serve.prefill (one per admission), serve.ensure, serve.tables,
-    serve.launch, serve.logits_wait, serve.sample — all with the
-    engine's own ``iter`` (ISSUE 24)."""
+    serve.prefill, serve.ensure, serve.tables, serve.launch,
+    serve.logits_wait, serve.sample — all with the engine's own
+    ``iter`` (ISSUE 24).  ``serve.prefill`` opens twice an admission
+    that rides ahead (ISSUE 34): around its enqueue and around the
+    fetch of its token, which comes after the launch of the decode
+    behind it, and after the retirement of the iteration the prefill
+    was queued behind."""
     import horovod_tpu.telemetry as telemetry
     import horovod_tpu.trace as trace
 
@@ -1221,25 +1471,54 @@ def test_engine_iteration_emits_serve_regions_with_one_iter():
     for name, evs in by_name.items():
         if name == "serve.iteration":
             continue
-        assert len(evs) == (2 if name == "serve.prefill" else 1), name
+        assert len(evs) == (4 if name == "serve.prefill" else 1), name
         for e in evs:
             assert e["args"]["parent"] == "serve.iteration", name
             assert whole["ts"] <= e["ts"] and (
                 whole["ts"] + whole["dur"] >= e["ts"] + e["dur"]), name
-    assert {e["args"]["rid"] for e in by_name["serve.prefill"]} \
-        == {r.rid for r in reqs}
+    end = lambda e: e["ts"] + e["dur"]      # noqa: E731
+    prefills = sorted(by_name["serve.prefill"], key=lambda e: e["ts"])
+    assert [e["args"]["rid"] for e in prefills] \
+        == [r.rid for r in reqs] * 2
     assert all(e["args"]["prompt_tokens"] == 3 and e["args"]["bucket"] >= 3
-               for e in by_name["serve.prefill"])
-    # The decode iteration's four regions tile it: serving.token_seconds
-    # is fed from the first one's start and the last one's end.
-    tables, sample = by_name["serve.tables"][0], by_name["serve.sample"][0]
+               for e in prefills)
+    # A start: both prefills enqueued, the two decodes launched behind
+    # them, THEN the first tokens fetched, then the first decode's.
+    (tables,), (launch,) = by_name["serve.tables"], by_name["serve.launch"]
+    (wait,), (sample,) = by_name["serve.logits_wait"], by_name["serve.sample"]
+    assert end(prefills[1]) <= tables["ts"] and end(tables) <= launch["ts"]
+    assert end(launch) <= prefills[2]["ts"]
+    assert end(prefills[3]) <= wait["ts"] and end(wait) <= sample["ts"]
+    assert [len(r.generated) for r in reqs] == [2, 2]
+    # The decode iteration's regions tile it: serving.token_seconds is
+    # fed from the first one's start and the last one's end; the tables
+    # and the launch of a pass that fed first tokens ran under the
+    # prefills, so it is timed from its wait.
     took = after["serving.token_seconds"]["sum"] \
         - before["serving.token_seconds"]["sum"]
-    assert took == pytest.approx(
-        (sample["ts"] + sample["dur"] - tables["ts"]) / 1e6)
+    assert took == pytest.approx((end(sample) - wait["ts"]) / 1e6)
     assert after["trace.span_seconds.serve.iteration"]["count"] \
         - before["trace.span_seconds.serve.iteration"]["count"] == 1
-    # The next iteration carries the next iter.
+    # The next iteration carries the next iter.  Admitted behind the
+    # iteration in flight, a request's token is fetched after that
+    # iteration's retirement, and the pass is timed from its tables.
+    late = eng.submit([7, 2, 3], max_new_tokens=4)
+    before = telemetry.metrics()
+    eng.step()
+    after = telemetry.metrics()
+    by_name = {}
+    for e in _iteration_spans(eng._iter):
+        by_name.setdefault(e["name"], []).append(e)
+    enqueue, fetch = sorted(by_name["serve.prefill"], key=lambda e: e["ts"])
+    assert enqueue["args"]["rid"] == fetch["args"]["rid"] == late.rid
+    (tables,), (launch,) = by_name["serve.tables"], by_name["serve.launch"]
+    (sample,) = by_name["serve.sample"]
+    assert end(enqueue) <= tables["ts"] and end(launch) <= fetch["ts"]
+    assert end(sample) <= fetch["ts"]
+    assert [len(r.generated) for r in reqs + [late]] == [3, 3, 1]
+    took = after["serving.token_seconds"]["sum"] \
+        - before["serving.token_seconds"]["sum"]
+    assert took == pytest.approx((end(sample) - tables["ts"]) / 1e6)
     eng.step()
     assert {e["name"] for e in _iteration_spans(eng._iter)} >= {
         "serve.iteration", "serve.sample"}
