@@ -117,6 +117,9 @@ _M_PREFILL_TOKENS = _telemetry.counter(
     "bucket's padding are not among them)")
 _M_WARM = _telemetry.counter(
     "serving.warm_starts", "serving executables AOT-rebuilt at startup")
+_M_TABLES_BYTES = _telemetry.counter(
+    "serving.tables_h2d_bytes", "bytes of page table, lengths and token "
+    "override the decode passes copied to the device (serve.tables)")
 _M_SPEC_PROPOSED = _telemetry.counter(
     "serving.spec_proposed", "draft tokens proposed per speculative "
     "iteration (spec_tokens per active greedy slot)")
@@ -140,7 +143,7 @@ _M_SPEC_RATE = _telemetry.gauge(
 # what the loop spends on an admission: its enqueue and, where the first
 # token stayed on the device for the decode queued behind it, a second
 # region of the name around the fetch of that token.
-_R_ITERATION = _trace.region("serve.iteration", "serve")
+_R_ITERATION = _trace.region("serve.iteration", "serve", timed=True)
 _R_ADMIT = _trace.region("serve.admit", "serve")
 _R_PREFILL = _trace.region("serve.prefill", "serve")
 _R_ENSURE = _trace.region("serve.ensure", "serve")
@@ -149,6 +152,26 @@ _R_LAUNCH = _trace.region("serve.launch", "serve", timed=True)
 _R_LOGITS_WAIT = _trace.region("serve.logits_wait", "serve", timed=True)
 _R_SAMPLE = _trace.region("serve.sample", "serve", timed=True)
 _R_WARM_START = _trace.region("serve.warm_start", "init")
+
+# What a pass of the serve loop was, named at its end (``_step``) from
+# what it found and what it left: ``start`` — nothing was in flight, the
+# pipeline starts (two launches where the loop may run ahead; behind an
+# admission in the cells, but a loop that resumes after a ``sync``
+# stretch starts without one); ``admission`` — an iteration was in
+# flight and at least one prefill was enqueued behind it; ``steady`` —
+# one in flight, no prefill, the next one launched; ``retire`` — nothing
+# launched, the iteration in flight retired; ``sync`` — depth 0: the
+# loop may not run ahead (``_runs_ahead``), or the iteration was
+# speculative.  A pass with nothing to do has no kind.  The kind goes
+# on the ``serve.iteration`` span, and the pass's seconds (the region's
+# own clock reads) into the kind's histogram;
+# ``serving.token_seconds`` holds every kind in one.
+PASS_KINDS = ("start", "admission", "steady", "retire", "sync")
+_M_PASS = {
+    kind: _telemetry.histogram(
+        "serving.pass_seconds." + kind, "seconds",
+        f"one serve.iteration of a {kind} pass of the serve loop")
+    for kind in PASS_KINDS}
 
 
 def _make_cache(model, max_slots: int, pages_per_slot: int,
@@ -170,16 +193,20 @@ class _Flight:
     it, the view it attends, the tables it is launched with and, once
     launched, the program's outputs, still on the device."""
 
-    __slots__ = ("riders", "view", "lengths", "inputs", "fresh", "tokens",
-                 "logits", "extras")
+    __slots__ = ("riders", "view", "lengths", "inputs", "fresh", "sent",
+                 "tokens", "logits", "extras")
 
     def __init__(self, riders: Dict[int, Request], view, lengths,
-                 inputs, fresh: int = 0) -> None:
+                 inputs, fresh: int, sent: Tuple) -> None:
         self.riders = riders    # slot -> the request decoding there
         self.view = view
         self.lengths = lengths  # the host's, as launched (-1: not riding)
         self.inputs = inputs    # (table, lengths, override or None)
         self.fresh = fresh      # riders whose first token the host lacks
+        # What the plan cost: (bytes copied to the device, pages mapped
+        # for riders one past their cached length, seconds in the
+        # copies' calls).
+        self.sent = sent
         self.tokens = self.logits = None
         self.extras: Tuple = ()
 
@@ -391,6 +418,11 @@ class InferenceEngine:
         self._prev_token = np.zeros((max_slots,), np.int32)
         self._spec_proposed = 0
         self._iter = 0              # serve.* regions' ``iter``
+        # Admission prefills the loop has waited out: counted where the
+        # host takes (or drops) the first token.  A request's tokens
+        # are stamped with it, so two stamps tell how many prefills the
+        # gap between them held.
+        self._prefills_waited = 0
         self._prefill_bucket = 0    # the last admission prefill's
         self._spec_accepted = 0
         self._ready = False
@@ -804,12 +836,21 @@ class InferenceEngine:
         tokens, so :meth:`follow` on worker ranks mirrors the cache and
         runs the identical executables in the same order."""
         self._iter += 1
-        with _R_ITERATION(iter=self._iter):
-            return self._step(now, admit)
+        with _R_ITERATION(iter=self._iter) as r:
+            ran, kind = self._step(now, admit, r)
+        if kind is not None:
+            _M_PASS[kind].observe(r.seconds)
+        return ran
 
-    def _step(self, now: Optional[int], admit: bool) -> bool:
+    def _step(self, now: Optional[int], admit: bool,
+              whole) -> Tuple[bool, Optional[str]]:
+        """The pass inside its ``serve.iteration`` region ``whole``,
+        which takes the pass's kind and the requests it admitted.
+        Returns whether any work ran, and the kind (PASS_KINDS; None:
+        there was nothing to do)."""
         mp = self._multiprocess()
         it = self._iter
+        flying = self._inflight is not None
         with _R_ADMIT(iter=it):
             admitted = self._admit(now) if admit else []
         if mp:
@@ -822,6 +863,7 @@ class InferenceEngine:
         ahead = bool(admitted) and self._runs_ahead(self.scheduler.active())
         for slot, req in admitted:
             self._admit_prefill(slot, req, ahead)
+        fresh = bool(self._fresh)   # prefills enqueued, tokens unfetched
         # Clean abort of disconnected clients' slots (hvd-chaos): the
         # eviction happens HERE, at the iteration boundary on the
         # serve-loop thread — the only thread that may free KV slots —
@@ -875,11 +917,31 @@ class InferenceEngine:
             # tokens.
             self._retired(self._inflight)
             self._inflight = None
+        # What the pass was.  It ran ahead if it launched the next
+        # iteration or enqueued a prefill; where it did neither, an
+        # admission was synchronous, and without one the rule says.
+        launched = self._inflight is not None
+        if not (active or flying or admitted):
+            kind = None
+        elif spec or not (launched or fresh
+                          or (not admitted and self._runs_ahead(active))):
+            kind = "sync"
+        elif not flying:
+            kind = "start"
+        elif fresh:
+            kind = "admission"
+        else:
+            kind = "steady" if launched else "retire"
         # The prefills ran behind the iteration the pass retired: their
         # tokens come last.  Every first token is the host's now.
         self._feed_fresh()
         self._carry = None
-        return bool(admitted or active)
+        if kind is not None and _trace.enabled():
+            # A tuple: the span buffer keeps it, and the collector does
+            # not track a tuple of ints (nor the empty one).
+            whole.note(kind=kind,
+                       admitted=tuple(req.rid for _, req in admitted))
+        return bool(admitted or active), kind
 
     def _admit(self, now: Optional[int]) -> List[Tuple[int, Request]]:
         """Headroom-gated admission: the scheduler prices each
@@ -901,6 +963,7 @@ class InferenceEngine:
             t_admit = time.monotonic()
             for _, req in admitted:
                 req.t_admit = t_admit
+                req.admit_iter = self._iter
                 if req.t_submit:
                     _M_QUEUE_WAIT.observe(t_admit - req.t_submit)
         return admitted
@@ -950,12 +1013,14 @@ class InferenceEngine:
         stamp = time.monotonic()
         if not req.generated:
             req.t_first_token = stamp
+            req.first_iter = self._iter
             _M_TTFT.observe(stamp - req.t_submit)
         _M_TOKENS.inc()
         # expect=req: a concurrent drain may have evicted the slot
         # mid-iteration — the token is then discarded (the exported
         # continuation reproduces it) instead of poisoning the step.
-        reason = self.scheduler.feed(slot, token, expect=req, stamp=stamp)
+        reason = self.scheduler.feed(slot, token, expect=req, stamp=stamp,
+                                     prefills=self._prefills_waited)
         if reason is not None:
             req.t_done = stamp
             self._free_slot(slot)  # idempotent vs the drain
@@ -966,8 +1031,12 @@ class InferenceEngine:
                 # next to training cycles in the fleet trace.  It
                 # starts in the past on another thread, so it is a
                 # plain span; ``itl_ms`` are the request's inter-token
-                # gaps, ``queue_ms`` its wait for a slot.
-                times = req.token_times
+                # gaps and ``itl_admissions`` the prefills the loop
+                # waited out inside each, ``queue_ms`` its wait for a
+                # slot; ``rid`` with the three ``*_iter`` joins it to
+                # its ``serve.prefill`` regions and to the passes that
+                # served it.
+                times, waited = req.token_times, req.token_prefills
                 _trace.span(
                     "serving.request", "serving", req.t_submit, stamp,
                     args={"rid": req.rid,
@@ -976,8 +1045,13 @@ class InferenceEngine:
                           "queue_ms": round(
                               (req.t_admit - req.t_submit) * 1e3, 3)
                           if req.t_admit else None,
+                          "admit_iter": req.admit_iter,
+                          "first_iter": req.first_iter,
+                          "last_iter": self._iter,
                           "itl_ms": [round((b - a) * 1e3, 3) for a, b
-                                     in zip(times, times[1:])]})
+                                     in zip(times, times[1:])],
+                          "itl_admissions": [b - a for a, b
+                                             in zip(waited, waited[1:])]})
         else:
             self._last_token[slot] = token
         return reason
@@ -1096,9 +1170,10 @@ class InferenceEngine:
         on the trash page.  Returns None with nobody left to ride."""
         behind = behind or {}
         cached = self.cache.lengths()
+        mapped = 0
         for slot, req in riders.items():
             if behind.get(slot) is req:
-                self.cache.ensure(slot, int(cached[slot]) + 1)
+                mapped += self.cache.ensure(slot, int(cached[slot]) + 1)
         table, cached = self.cache.host_tables()
         lengths = np.full_like(cached, -1)
         override = None
@@ -1121,11 +1196,30 @@ class InferenceEngine:
         if not riding:
             return None
         table[lengths < 0] = 0
+        t0 = time.monotonic()
+        inputs = (self._rep(table), self._rep(lengths),
+                  None if override is None else self._rep(override))
+        copy_s = time.monotonic() - t0
+        nbytes = table.nbytes + lengths.nbytes + (
+            0 if override is None else override.nbytes)
         # The rung the program is about to pick from ``lengths``.
         return _Flight(
             riding, self.model.decode_view(lengths, self._rungs), lengths,
-            (self._rep(table), self._rep(lengths),
-             None if override is None else self._rep(override)), n_fresh)
+            inputs, n_fresh, (nbytes, mapped, copy_s))
+
+    @staticmethod
+    def _note_sent(tables, *flights: Optional[_Flight]) -> None:
+        """What the pass's plans sent to the device, on its
+        ``serve.tables`` span and in the counter; a start's two plans
+        add up."""
+        sent = [f.sent for f in flights if f is not None]
+        if not sent:
+            return
+        nbytes, mapped, copy_s = map(sum, zip(*sent))
+        _M_TABLES_BYTES.inc(nbytes)
+        if _trace.enabled():
+            tables.note(h2d_bytes=nbytes, pages_mapped=mapped,
+                        copy_ms=round(copy_s * 1e3, 3))
 
     def _launch(self, flight: _Flight, prev: Optional[_Flight]) -> _Flight:
         """Enqueue a planned iteration behind ``prev`` (None: behind
@@ -1198,6 +1292,7 @@ class InferenceEngine:
                 start = self._plan(behind, None, fresh) if starts else None
                 ahead = (self._plan(ahead, behind, {} if starts else fresh)
                          if ahead else None)
+                self._note_sent(first, start, ahead)
             with _R_LAUNCH(iter=it):
                 if start is not None:
                     flight = self._launch(start, None)
@@ -1278,6 +1373,7 @@ class InferenceEngine:
                                     self._prefill_bucket))
             else:
                 last = np.asarray(self._prefill(slot, req)[2])
+                self._prefills_waited += 1
                 self._feed(slot, req, self._sample(req, last))
             r.note(bucket=self._prefill_bucket)
 
@@ -1290,6 +1386,9 @@ class InferenceEngine:
         the fetch, under its executable's name."""
         while self._fresh:
             slot, req, token, bucket = self._fresh.pop(0)
+            # Dropped or fed, its program ran before whatever the loop
+            # fetches next.
+            self._prefills_waited += 1
             if req.finish_reason is not None:
                 continue
             with _R_PREFILL(iter=self._iter, rid=req.rid,

@@ -326,8 +326,9 @@ class PagedKVCache:
                     _M_PREFIX_HITS_DRAFT.inc()
             self._set_page_gauges_locked()
 
-    def ensure(self, slot: int, pos: int) -> None:
-        """Map pages so position ``pos`` of ``slot`` is writable.
+    def ensure(self, slot: int, pos: int) -> int:
+        """Map pages so position ``pos`` of ``slot`` is writable;
+        returns how many it mapped.
         A no-op on a freed slot: the serve loop reads ``length`` and
         calls this as two separate lock holds, so a drain landing
         between them must not map pages into the freed slot — its own
@@ -335,8 +336,8 @@ class PagedKVCache:
         page leak), and ``begin_slot`` zeroes the row on reuse."""
         with self._lock:
             if self._lengths[slot] < 0:
-                return
-            self._ensure_locked(slot, pos)
+                return 0
+            return self._ensure_locked(slot, pos)
 
     def _alloc_page_locked(self) -> int:
         """One allocatable page: free list first, then the LRU of
@@ -374,15 +375,18 @@ class PagedKVCache:
         else:
             self._refcount[page] = rc
 
-    def _ensure_locked(self, slot: int, pos: int) -> None:
+    def _ensure_locked(self, slot: int, pos: int) -> int:
         if pos >= self.capacity:
             raise ValueError(
                 f"position {pos} exceeds per-slot capacity "
                 f"{self.capacity}")
+        mapped = 0
         for p in range(pos // self.page_size + 1):
             if self._table[slot, p] == 0:
                 self._table[slot, p] = self._alloc_page_locked()
+                mapped += 1
         self._set_page_gauges_locked()
+        return mapped
 
     def advance(self, slot: int) -> int:
         """One decoded token was written at the current length; map the

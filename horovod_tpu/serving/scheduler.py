@@ -82,6 +82,12 @@ class Request:
     ``time.monotonic`` stamps the engine fills: the TTFT, queue-wait
     and per-token histograms, ``/generate``'s ``token_ms`` and the
     ``serving.request`` span all read this one clock.
+    ``token_prefills`` stands beside ``token_times``: the engine's
+    count of admission prefills waited out, as of each token, so the
+    difference of two neighbours is how many prefills that gap held.
+    ``admit_iter``/``first_iter`` are the engine's ``iter`` at
+    admission and at the first token: with ``rid`` they join the
+    request to the ``serve.*`` spans of the passes that served it.
     """
 
     prompt: List[int]
@@ -108,6 +114,9 @@ class Request:
     t_first_token: float = 0.0
     t_done: float = 0.0
     token_times: List[float] = field(default_factory=list)
+    token_prefills: List[int] = field(default_factory=list)
+    admit_iter: int = -1
+    first_iter: int = -1
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         """Block until eviction; returns the generated tokens (with any
@@ -207,9 +216,10 @@ class ContinuousBatchingScheduler:
 
     def feed(self, slot: int, token: int,
              expect: Optional[Request] = None,
-             stamp: float = 0.0) -> Optional[str]:
+             stamp: float = 0.0, prefills: int = 0) -> Optional[str]:
         """Record one sampled token for ``slot`` (and ``stamp``, the
-        engine's clock read for it, beside it).  Returns None while
+        engine's clock read for it, and ``prefills``, its count of
+        prefills waited out so far, beside it).  Returns None while
         the sequence continues, or the :class:`FinishReason` when this
         token finished it (the slot is then already free — iteration-
         level eviction).
@@ -228,6 +238,7 @@ class ContinuousBatchingScheduler:
                 raise ValueError(f"slot {slot} is not active")
             req.generated.append(token)
             req.token_times.append(stamp)
+            req.token_prefills.append(prefills)
             reason = None
             if req.eos_id is not None and token == req.eos_id:
                 reason = FinishReason.EOS

@@ -14,6 +14,7 @@ import functools
 import json
 import os
 import threading
+import types
 import urllib.request
 
 import jax
@@ -1525,6 +1526,298 @@ def test_engine_iteration_emits_serve_regions_with_one_iter():
     assert not [e for e in _iteration_spans(eng._iter)
                 if e["name"] == "serve.prefill"]
     eng.run_until_idle()
+
+
+# ---------------------------------------------------------------------------
+# What each pass was and what each token waited for (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+NOTED = {"kind", "admitted", "h2d_bytes", "pages_mapped", "copy_ms"}
+# One pass a line: what is submitted before it, and the kind it must be.
+SCRIPT = [
+    ("a", "start"),         # nothing in flight: two launches, a's 2 tokens
+    (None, "steady"),
+    (None, "steady"),
+    ("b", "admission"),     # b's prefill behind the iteration in flight
+    (None, "steady"),
+    (None, "steady"),       # b's third token: done
+    ("hot", "sync"),        # sampled: admitted synchronously, a retired
+    (None, "sync"),
+    (None, "sync"),         # hot's third token: done
+    (None, "start"),        # a alone again, nothing in flight
+    (None, "retire"),       # a's twelfth token
+]
+SUBMIT = {"a": dict(prompt=[5, 3, 8], max_new_tokens=12),
+          "b": dict(prompt=[1, 2, 3, 4], max_new_tokens=3),
+          "hot": dict(prompt=[9, 9, 2], max_new_tokens=3, temperature=0.8,
+                      seed=11)}
+
+
+@functools.lru_cache(maxsize=None)
+def _moe():
+    """A model whose decode program returns counts beside the logits
+    (``observe_decode``: models/latent_moe.py) at toy size."""
+    from benchmark import cells
+    from benchmark.builders.latent_moe import config_of
+
+    with open(os.path.join(cells.HERE, "tests", "fixtures", "configs",
+                           "tiny-axk1.json")) as f:
+        model = json.load(f)["model"]
+    ref = cells.load_module("refs", "axk1-ep16")
+    return ref.init_params(model, 7), config_of(model)
+
+
+def _added(before, after):
+    """What a run added: the observations of each kind's histogram, and
+    the ``serve.iteration`` regions closed."""
+    count = lambda snap, name: snap.get(name, {}).get("count", 0)  # noqa: E731
+    names = ["serving.pass_seconds." + k
+             for k in ("start", "admission", "steady", "retire", "sync")]
+    iterations = "trace.span_seconds.serve.iteration"
+    return ({n: count(after, n) - count(before, n) for n in names},
+            count(after, iterations) - count(before, iterations))
+
+
+def _play(eng):
+    """SCRIPT on ``eng``: the requests by name, and the registry before
+    and after."""
+    import horovod_tpu.telemetry as telemetry
+
+    before = telemetry.metrics()
+    reqs = {}
+    for who, _ in SCRIPT:
+        if who:
+            reqs[who] = eng.submit(**SUBMIT[who])
+        eng.step()
+    assert eng.scheduler.idle() and eng._inflight is None
+    return reqs, before, telemetry.metrics()
+
+
+@functools.lru_cache(maxsize=None)
+def _scripted(model):
+    """SCRIPT traced, then the same with tracing off, on one engine."""
+    import horovod_tpu.trace as trace
+
+    if model == "dense":
+        eng = make_engine()
+    else:
+        eng = InferenceEngine(*_moe(), max_slots=3, page_size=8,
+                              capacity=64)
+        assert hasattr(eng.model, "observe_decode")
+    eng.warm_start()
+    for kw in SUBMIT.values():      # compiles
+        eng.generate(kw["prompt"], max_new_tokens=2)
+    trace.set_enabled(True)
+    trace.clear()
+    first_iter = eng._iter + 1
+    reqs, before, after = _play(eng)
+    counts, iterations = _added(before, after)
+    events = [e for e in trace.export_events() if e.get("ph") == "X"]
+    noted = []
+    orig = trace._Off.note
+    trace._Off.note = lambda self, **kw: noted.append(set(kw))
+    trace.set_enabled(False)
+    trace.clear()
+    try:
+        off_reqs, *off = _play(eng)
+        off_events = trace.export_events()
+    finally:
+        trace._Off.note = orig
+        trace.set_enabled(True)
+    off_counts, off_iterations = _added(*off)
+    return types.SimpleNamespace(
+        eng=eng, reqs=reqs, counts=counts, iterations=iterations,
+        events=events, first_iter=first_iter, before=before, after=after,
+        off_reqs=off_reqs, off_counts=off_counts, off_events=off_events,
+        off_iterations=off_iterations, noted=noted)
+
+
+def _named(events, name):
+    return sorted((e for e in events if e["name"] == name),
+                  key=lambda e: e["ts"])
+
+
+PASS_MODELS = pytest.mark.parametrize("model", ["dense", "moe"])
+
+
+@PASS_MODELS
+def test_every_pass_has_a_kind_on_its_span_and_in_one_histogram(model):
+    """The kinds of SCRIPT, pass for pass, on the ``serve.iteration``
+    spans with the requests each admitted; one observation a pass in
+    the kind's member of ``serving.pass_seconds``, of the region's own
+    seconds; the counts add up to ``serve.iteration``'s."""
+    run = _scripted(model)
+    kinds = [k for _, k in SCRIPT]
+    passes = _named(run.events, "serve.iteration")
+    assert [e["args"]["kind"] for e in passes] == kinds
+    assert [e["args"]["admitted"] for e in passes] == [
+        (run.reqs[who].rid,) if who else () for who, _ in SCRIPT]
+    assert [e["args"]["iter"] for e in passes] == list(
+        range(run.first_iter, run.first_iter + len(SCRIPT)))
+    tables = {e["args"]["iter"]: e for e in _named(run.events,
+                                                   "serve.tables")}
+    # A pass that only retires plans nothing, and neither does the one
+    # that admits synchronously behind an iteration in flight.
+    assert [e["args"]["iter"] in tables for e in passes] == [
+        k != "retire" and who != "hot" for who, k in SCRIPT]
+    for kind in set(kinds):
+        assert run.counts["serving.pass_seconds." + kind] \
+            == kinds.count(kind), kind
+    assert sum(v for k, v in run.counts.items()
+               if k.startswith("serving.pass_seconds.")) \
+        == run.iterations == len(SCRIPT)
+    # The region's own clock reads: the one admission pass.
+    (adm,) = [e for e in passes if e["args"]["kind"] == "admission"]
+    name = "serving.pass_seconds.admission"
+    took = run.after[name]["sum"] - run.before.get(name, {}).get("sum", 0.0)
+    assert took == pytest.approx(adm["dur"] / 1e6)
+
+
+@PASS_MODELS
+def test_every_gap_counts_the_prefills_it_waited_out(model):
+    """``itl_admissions`` beside ``itl_ms``: a prefill enqueued behind
+    the iteration in flight is fetched after that iteration's feed, so
+    it lies in the gap that ENDS in the pass after the admission's; a
+    synchronous one in the gap that ends in its own pass.  Over a
+    request they add up to the first tokens other requests got between
+    its first and its last."""
+    run = _scripted(model)
+    spans = {e["args"]["rid"]: e["args"]
+             for e in _named(run.events, "serving.request")}
+    reqs = list(run.reqs.values())
+    assert sorted(spans) == sorted(r.rid for r in reqs)
+    for r in reqs:
+        args = spans[r.rid]
+        held = args["itl_admissions"]
+        assert len(held) == len(args["itl_ms"]) == len(r.generated) - 1
+        assert sum(held) == sum(
+            r.t_first_token < o.t_first_token <= r.t_done for o in reqs)
+        assert len(r.token_prefills) == len(r.token_times)
+    a = spans[run.reqs["a"].rid]["itl_admissions"]
+    # a's tokens: 2 in the start, 1 a pass; b's prefill is fetched at
+    # the end of pass 4 (gap 5 -> 6), hot's inside pass 7 (gap 7 -> 8).
+    assert a == [0, 0, 0, 0, 1, 0, 1, 0, 0, 0, 0]
+
+
+@PASS_MODELS
+def test_a_request_joins_its_prefill_regions_and_its_passes(model):
+    """``rid`` with ``admit_iter``, ``first_iter`` and ``last_iter``:
+    the request's ``serve.prefill`` regions lie in the iterations from
+    its admission to its first token, and each of those iterations
+    names it under ``admitted`` or served it."""
+    run = _scripted(model)
+    passes = {e["args"]["iter"]: e["args"]
+              for e in _named(run.events, "serve.iteration")}
+    for who, r in run.reqs.items():
+        (args,) = [e["args"] for e in _named(run.events, "serving.request")
+                   if e["args"]["rid"] == r.rid]
+        assert args["admit_iter"] <= args["first_iter"] <= args["last_iter"]
+        assert (args["admit_iter"], args["first_iter"]) == (
+            r.admit_iter, r.first_iter)
+        assert r.rid in passes[args["admit_iter"]]["admitted"]
+        assert args["last_iter"] in passes
+        prefills = [e["args"]["iter"]
+                    for e in _named(run.events, "serve.prefill")
+                    if e["args"]["rid"] == r.rid]
+        assert len(prefills) == (1 if who == "hot" else 2)
+        assert all(args["admit_iter"] <= it <= args["first_iter"]
+                   for it in prefills)
+
+
+@PASS_MODELS
+def test_the_tables_span_says_what_it_copied(model):
+    """A steady pass sends the page table and the lengths; a start
+    plans twice; a rider whose token only the host holds adds the
+    override.  The counter takes what the spans say."""
+    run = _scripted(model)
+    kind_of = {e["args"]["iter"]: e["args"]["kind"]
+               for e in _named(run.events, "serve.iteration")}
+    table, lengths = run.eng.cache.host_tables()
+    two = table.nbytes + lengths.nbytes
+    tables = _named(run.events, "serve.tables")
+    for e in tables:
+        args = e["args"]
+        assert NOTED - {"kind", "admitted"} <= set(args)
+        kind = kind_of[args["iter"]]
+        if kind in ("steady", "admission"):
+            assert args["h2d_bytes"] == two
+        elif kind == "start":
+            assert args["h2d_bytes"] in (2 * two, 2 * two + lengths.nbytes)
+        else:   # depth 0: every rider's token goes through the override
+            assert args["h2d_bytes"] == two + lengths.nbytes
+        assert 0.0 <= args["copy_ms"] <= e["dur"] / 1e3 + 1e-3
+    # a passes a page boundary (3 prompt tokens, 12 more, pages of 8),
+    # mapped by the plan that runs ahead of it.
+    assert sum(e["args"]["pages_mapped"] for e in tables) >= 1
+    counter = "serving.tables_h2d_bytes"
+    assert run.after[counter]["value"] - run.before.get(
+        counter, {}).get("value", 0) == sum(e["args"]["h2d_bytes"]
+                                            for e in tables)
+
+
+@PASS_MODELS
+def test_tracing_off_builds_no_argument_and_serves_the_same_tokens(model):
+    """``HVD_TPU_TRACE=0`` (here its runtime switch): no span, none of
+    this issue's arguments built, the served tokens the same; the
+    histograms a kind are the registry's and count on, from the two
+    clock reads a ``timed`` region still takes."""
+    run = _scripted(model)
+    assert run.off_events == []
+    assert not [kw & NOTED for kw in run.noted if kw & NOTED]
+    for who, r in run.reqs.items():
+        assert run.off_reqs[who].result(0) == r.result(0)
+        assert np.diff(run.off_reqs[who].token_prefills).tolist() \
+            == np.diff(r.token_prefills).tolist()
+    assert run.off_counts == run.counts
+    assert run.off_iterations == 0
+
+
+@PASS_MODELS
+def test_the_readers_read_what_the_loop_wrote(model):
+    """benchmark/metrics' readers of the kinds on the scripted run's own
+    counters and spans (CPU: the arithmetic, never a speed)."""
+    from benchmark import cells
+
+    run = _scripted(model)
+    rec = types.SimpleNamespace(
+        counters_before=run.before, counters_after=run.after,
+        requests=list(run.reqs.values()), peaks=None)
+    rec.counter_delta = lambda name, field="value": (
+        run.after.get(name, {}).get(field, 0)
+        - run.before.get(name, {}).get(field, 0))
+    read = lambda name, **kw: cells.load_module(  # noqa: E731
+        "metrics", name).read(rec, **kw)
+    passes = _named(run.events, "serve.iteration")
+    ms = lambda kind: [e["dur"] / 1e3 for e in passes  # noqa: E731
+                       if e["args"]["kind"] == kind]
+    assert read("steady_pass_ms") == pytest.approx(np.mean(ms("steady")))
+    assert read("admission_pass_ms") == pytest.approx(
+        np.mean(ms("admission")))
+    assert read("admission_time_pct") == pytest.approx(
+        100.0 * sum(ms("admission") + ms("start"))
+        / sum(e["dur"] / 1e3 for e in passes))
+    # The passes that admitted and planned: a's start and b's admission
+    # (hot's synchronous one planned nothing; the second start admitted
+    # nobody).
+    carried = {e["args"]["iter"] for e in passes if e["args"]["admitted"]}
+    tables = _named(run.events, "serve.tables")
+    after = [e["dur"] / 1e3 for e in tables if e["args"]["iter"] in carried]
+    assert len(after) == 2
+    assert read("tables_after_admission_ms", events=run.events) \
+        == pytest.approx(np.mean(after))
+    assert read("tables_h2d_kb_per_pass") == pytest.approx(
+        sum(e["args"]["h2d_bytes"] for e in tables) / 1e3 / len(tables))
+    spans = _named(run.events, "serving.request")
+    owed = [sum(g for g, n in zip(e["args"]["itl_ms"],
+                                  e["args"]["itl_admissions"]) if n)
+            / (e["args"]["tokens"] - 1) for e in spans]
+    assert max(owed) > 0.0 and min(owed) == 0.0
+    from benchmark import loadgen
+
+    assert read("tpot_admission_p90_ms", spans=spans) == pytest.approx(
+        loadgen.percentile(owed, 90))
+    # No peak table on the CPU: the roofline reads nothing, and says so.
+    assert read("steady_decode_hbm_roofline") is None
 
 
 def test_request_token_times_one_stamp_per_token_on_one_clock():
