@@ -3,7 +3,7 @@
     python chip_smoke.py            # on the chip (through the chip tool)
     python chip_smoke.py --dry-run  # tiny widths on the 8-device CPU mesh
 
-One process, no children, no ``JAX_PLATFORMS`` override.  Seven legs run
+One process, no children, no ``JAX_PLATFORMS`` override.  Eight legs run
 through the entry points a user calls, at full width per chip:
 
   A  ResNet-50 data-parallel trainer (the BASELINE.json workload):
@@ -38,6 +38,13 @@ through the entry points a user calls, at full width per chip:
      dense feed-forwards a layer, zero-compute experts beside the held
      ones): the same comparison through two cache layers a decoder
      layer, and the pairs that went to zero-compute experts counted.
+  H  the Mamba-2 hybrid the benchmark serves
+     (benchmark/configs/granite-4.0-h-micro.json, whole on one chip: 36
+     state-space layers of a matrix state a head, 4 grouped-query
+     attention layers): the same comparison through four paged layers
+     and three per-slot stores, a prompt longer than two chunks of the
+     scan, both kernels of ops/ssd.py compiled, the state a decode
+     iteration moves counted.
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
@@ -128,6 +135,10 @@ SHORTCUT_RMS_REL_TOL = 0.12
 # the largest single error 0.17 of the spread).  The latent model's
 # limit: its readings say where bf16 ends and fp8 begins.
 HYBRID_RMS_REL_TOL = 0.12
+# Mamba-2 hybrid: the same comparison through 40 layers, 36 of them the
+# chunked recurrence over the prompt and the one-step kernel in decode
+# (no router either).  The latent model's limit, for the same reason.
+MAMBA2_RMS_REL_TOL = 0.12
 
 
 class LegFailed(Exception):
@@ -812,6 +823,34 @@ def leg_shortcut_moe(dry):
                 pairs_routed=routed)
 
 
+def leg_mamba2_hybrid(dry):
+    """Leg H.  The Mamba-2 hybrid of benchmark/configs/
+    granite-4.0-h-micro.json, whole and uncut on the chip (its toy
+    fixture in the dry run): one prompt that crosses two edges of the
+    chunked scan and one inside its first chunk, then decode through the
+    one-step kernel with 62 of the 64 slots idle, whose state the kernel
+    must neither move nor count."""
+    from benchmark.builders import mamba2_hybrid
+
+    out, before, after = _leg_served_model(
+        dry, mamba2_hybrid, "tiny-granite4h.json", "granite-4.0-h-micro.json",
+        3_900_000_039,
+        dict(max_slots=4 if dry else 64, page_size=4 if dry else 16,
+             capacity=128 if dry else 3072),
+        (40, 6) if dry else (700, 150), 8 if dry else 24,
+        MAMBA2_RMS_REL_TOL,
+        lambda e: (not e.cache.prefix_enabled and len(e.cache.pages) == 2
+                   and len(e.cache.slot_state) == 3
+                   and e.cache.n_layers == e.model.n_attention,
+                   "a paged layer an attention layer and three per-slot "
+                   "stores, prefix cache off"))
+    moved = grew(before, after, "serving.state_bytes_moved")
+    check(moved > 0 and grew(before, after, "serving.shared_kv_tokens") > 0
+          and grew(before, after, "serving.state_slot_resets") == 2,
+          "the model counts what a decode iteration attends and moves")
+    return dict(out, state_bytes_moved=moved)
+
+
 # ---------------------------------------------------------------------------
 
 def main() -> int:
@@ -820,9 +859,9 @@ def main() -> int:
                     help="toy widths on whatever platform jax has; the "
                          "result line says ok=false and names the "
                          "platform (for the test suite, never a pass)")
-    ap.add_argument("--legs", default="ABCDEFG",
+    ap.add_argument("--legs", default="ABCDEFGH",
                     help="subset of legs to run while debugging, e.g. "
-                         "AD; anything short of all seven is not a pass")
+                         "AD; anything short of all eight is not a pass")
     args = ap.parse_args()
     dry = args.dry_run
 
@@ -879,7 +918,8 @@ def main() -> int:
             ("D_serve", lambda: leg_serve(w["serve"], w["lm"])),
             ("E_latent_moe", lambda: leg_latent_moe(dry)),
             ("F_hybrid_ssm", lambda: leg_hybrid_ssm(dry)),
-            ("G_shortcut_moe", lambda: leg_shortcut_moe(dry)))
+            ("G_shortcut_moe", lambda: leg_shortcut_moe(dry)),
+            ("H_mamba2_hybrid", lambda: leg_mamba2_hybrid(dry)))
     for name, fn in plan:
         if name[0] not in args.legs.upper():
             continue

@@ -33,7 +33,7 @@ import json
 import math
 import os
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..analysis import lockorder as _lockorder
 
@@ -48,7 +48,9 @@ _DTYPE_BYTES = {
 def dtype_bytes(dtype) -> int:
     """Item size without importing jax (the CLI must answer on a box
     with nothing initialized); jax/numpy dtypes resolve via their
-    itemsize, strings via the table."""
+    itemsize (a scalar type such as ``jnp.float32`` via its ``dtype``),
+    strings via the table."""
+    dtype = getattr(dtype, "dtype", dtype)
     itemsize = getattr(dtype, "itemsize", None)
     if itemsize:
         return int(itemsize)
@@ -101,6 +103,21 @@ def kv_cache_bytes(n_layers: int, n_heads: int, head_dim: int,
     n_pages = 1 + max_slots * pages_per_slot
     return (2 * n_layers * n_pages * page_size * n_heads * head_dim
             * dtype_bytes(dtype))
+
+
+def slot_store_bytes(slot_stores: Sequence[dict], max_slots: int,
+                     capacity: int) -> int:
+    """The per-slot stores a serving model declares beside its pages
+    (``cache_entry()["slot_stores"]``, serving/models.py: window rings,
+    recurrent state, scratch), one array ``[layers, max_slots, *shape]``
+    each, a dimension ``"capacity"`` the slot's: byte for byte the
+    ``serving.slot_state`` ledger category the cache manager charges.
+    They are fixed at ``max_slots``, so a what-if over slots moves them
+    in proportion, and where they outweigh the pages (a matrix state a
+    head) they decide how many slots fit."""
+    return sum(max_slots * dtype_bytes(s["dtype"]) * int(math.prod(
+        capacity if d == "capacity" else d for d in s["shape"]))
+        for s in slot_stores)
 
 
 def prefix_pages_bytes(n_layers: int, n_heads: int, head_dim: int,
@@ -476,9 +493,12 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
                  draft_layers: int = 0,
                  draft_d_ff: Optional[int] = None,
                  vocab_size: int = 256,
-                 capacity: Optional[int] = None) -> MemoryPlan:
+                 capacity: Optional[int] = None,
+                 slot_stores: Sequence[dict] = ()) -> MemoryPlan:
     """Plan for the serving engine: the paged KV store (the dominant
-    framework buffer) plus replicated params.  The KV what-ifs —
+    framework buffer unless ``slot_stores``, a model's per-slot stores
+    as its ``cache_entry()`` declares them, outweigh it:
+    :func:`slot_store_bytes`) plus replicated params.  The KV what-ifs —
     slots, pages per slot, page size — are the router tier's capacity
     question (ROADMAP item 2).  hvd-spec what-ifs: ``--prefix-pages``
     prices a dedicated shared-prefix reserve
@@ -495,6 +515,9 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
     framework = {"serving.kv_pages": kv}
     facts = {"kv_capacity_tokens": max_slots * pages_per_slot
              * page_size}
+    if slot_stores:
+        framework["serving.slot_state"] = slot_store_bytes(
+            slot_stores, max_slots, pages_per_slot * page_size)
     if prefix_pages:
         framework["serving.prefix_pages"] = prefix_pages_bytes(
             n_layers, n_heads, head_dim, prefix_pages, page_size,

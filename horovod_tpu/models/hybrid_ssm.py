@@ -52,10 +52,10 @@ from .. import telemetry as _telemetry
 from ..ops.ssm_scan import ssm_scan, ssm_step
 
 _M_SHARED_KV = _telemetry.counter(
-    "serving.shared_kv_tokens", "cached positions of the one shared "
-    "key/value store the decode iterations attended, summed over slots "
-    "and iterations (by the host's lengths; every reader layer attends "
-    "them once)")
+    "serving.shared_kv_tokens", "cached positions of the paged store the "
+    "decode iterations attended, summed over slots and iterations (by "
+    "the host's lengths; every layer that reads the store attends them "
+    "once)")
 _M_WINDOW = _telemetry.counter(
     "serving.window_tokens", "positions of the window stores the decode "
     "iterations attended, summed over slots and iterations (at most the "
@@ -405,17 +405,19 @@ def chunk_index(table, cached, chunk: int, page_size: int):
     return pages, mask, owner, mine, ends[-1]
 
 
-def fill_view(view, k_pages, v_pages, pages, used, block: int):
-    """Gather the chunks in use into ``view [2, chunks, chunk, kv_width]``
-    (keys, values), ``block`` chunks at a time, as many blocks as hold
-    them: a loop whose trip count follows the load, writing in place.
-    What lies past them is left as it is (stale rows are masked)."""
+def fill_view(view, k_pages, v_pages, pages, used, block: int,
+              layer: int = 0):
+    """Gather the chunks in use of paged layer ``layer`` into ``view [2,
+    chunks, chunk, kv_width]`` (keys, values), ``block`` chunks at a time,
+    as many blocks as hold them: a loop whose trip count follows the load,
+    writing in place.  What lies past them is left as it is (stale rows
+    are masked)."""
     per = pages.shape[1]
 
     def body(i, view):
         at = i * block
         these = jax.lax.dynamic_slice(pages, (at, 0), (block, per))
-        both = jnp.stack([k_pages[0, these], v_pages[0, these]])
+        both = jnp.stack([k_pages[layer, these], v_pages[layer, these]])
         return jax.lax.dynamic_update_slice(
             view, both.reshape(2, block, -1, view.shape[-1]),
             (0, at, 0, 0))

@@ -45,7 +45,7 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
                          "D_serve", "E_latent_moe", "F_hybrid_ssm",
-                         "G_shortcut_moe"}
+                         "G_shortcut_moe", "H_mamba2_hybrid"}
     # The dry run forces the stream schedule (auto is the one-program
     # step on a mesh one process owns): no other leg runs it.
     assert legs["A_resnet_dp"]["schedule"] == "stream"
@@ -66,6 +66,9 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert max(legs["G_shortcut_moe"]["logit_rms_over_std"]) < 1e-4
     assert 0 < legs["G_shortcut_moe"]["pairs_on_zero_experts"] < legs[
         "G_shortcut_moe"]["pairs_routed"]
+    assert legs["H_mamba2_hybrid"]["config"] == "tiny-granite4h"
+    assert max(legs["H_mamba2_hybrid"]["logit_rms_over_std"]) < 1e-4
+    assert legs["H_mamba2_hybrid"]["state_bytes_moved"] > 0
 
 
 # ---------------------------------------------------------------------------

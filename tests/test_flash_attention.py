@@ -367,3 +367,53 @@ def test_scan_kernel_compiles_for_the_v5e(v5e_chip, t):
     ).lower(sd(t, 5120), sd(t, 5120), sd(t, 16), sd(t, 16), sd(16, 5120),
             sd(16, 5120), sd(dtype=jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and "ssm_scan" in text
+
+
+# The two kernels of ops/ssd.py at the widths `granite4h-serve-sessions`
+# runs them at (here for the same reason as the scan kernel above).  The
+# chunked scan at the longest bucket, a short one and the shortest; the
+# one-step kernel over the cell's WHOLE state store (36 layers x 64 slots
+# x 2 MiB), two layers in a row, which must go in and come out as one
+# buffer: the compiled program aliases all 4.83 GB of it and keeps under a
+# megabyte of temporaries.
+@pytest.mark.parametrize("t", [2048, 64, 2])
+def test_ssd_chunk_scan_compiles_for_the_v5e(v5e_chip, t):
+    from horovod_tpu.ops import ssd
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    bf = jnp.bfloat16
+    text = jax.jit(
+        lambda x, dt, a, b, c, s0, n: ssd._pallas_chunk_scan(
+            x, dt, a, b, c, s0, n, 256, False)
+    ).lower(sd(t, 64, 64, dtype=bf), sd(t, 64), sd(64), sd(t, 128, dtype=bf),
+            sd(t, 128, dtype=bf), sd(32, 128, 128),
+            sd(dtype=jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "ssd_chunk_scan" in text
+
+
+def test_ssd_step_compiles_for_the_v5e_and_never_copies_the_store(v5e_chip):
+    from horovod_tpu.ops import ssd
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(store, x, dt, a, b, c, d, alive):
+        for layer in (0, 35):
+            decay, dtx = ssd._step_operands(x, dt, a, store.shape[1:])
+            y, store = ssd._pallas_step(store, decay, dtx, b, c, alive,
+                                        layer, False)
+        return y, store
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(two_layers, donate_argnums=(0,)).lower(
+        sd(36, 64, 32, 128, 128), sd(64, 64, 64, dtype=bf), sd(64, 64),
+        sd(64), sd(64, 128, dtype=bf), sd(64, 128, dtype=bf), sd(64),
+        sd(64, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "ssd_step" in text
+    memory = compiled.memory_analysis()
+    store = 36 * 64 * 32 * 128 * 128 * 4
+    assert memory.alias_size_in_bytes >= store
+    assert memory.temp_size_in_bytes < 1 << 20
