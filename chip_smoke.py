@@ -3,7 +3,7 @@
     python chip_smoke.py            # on the chip (through the chip tool)
     python chip_smoke.py --dry-run  # tiny widths on the 8-device CPU mesh
 
-One process, no children, no ``JAX_PLATFORMS`` override.  Eight legs run
+One process, no children, no ``JAX_PLATFORMS`` override.  Nine legs run
 through the entry points a user calls, at full width per chip:
 
   A  ResNet-50 data-parallel trainer (the BASELINE.json workload):
@@ -45,6 +45,12 @@ through the entry points a user calls, at full width per chip:
      and three per-slot stores, a prompt longer than two chunks of the
      scan, both kernels of ops/ssd.py compiled, the state a decode
      iteration moves counted.
+  I  the kernel that walks the page table over the latent store
+     (ops/latent_paged_attention.py) against its twin, the view ladder,
+     at the shapes of the two cells that decode through it (64 heads,
+     640-wide entries, 16-token pages; 47 of 128 and 14 of 64 slots
+     alive): the same attention, and the kernel's time beside what its
+     live bytes need at the HBM peak.
 
 The run fails at the first leg that fails, names it, prints no result
 line and exits non-zero.  It fails before any leg unless jax found a TPU
@@ -135,6 +141,10 @@ SHORTCUT_RMS_REL_TOL = 0.12
 # the largest single error 0.17 of the spread).  The latent model's
 # limit: its readings say where bf16 ends and fp8 begins.
 HYBRID_RMS_REL_TOL = 0.12
+# Paged latent attention against the view ladder, bfloat16 entries: both
+# round the probabilities and the attended latent to bfloat16, at other
+# points of the sum; a few units in the last place of the largest output.
+PAGED_REL_TOL = 2.0 ** -6
 # Mamba-2 hybrid: the same comparison through 40 layers, 36 of them the
 # chunked recurrence over the prompt and the one-step kernel in decode
 # (no router either).  The latent model's limit, for the same reason.
@@ -851,6 +861,96 @@ def leg_mamba2_hybrid(dry):
     return dict(out, state_bytes_moved=moved)
 
 
+def leg_latent_paged_attn(dry):
+    """Leg I.  One decode step's attention over every cache layer of a
+    seeded store through ``latent_moe.paged_attend`` (the kernel; in the
+    dry run its interpreter) and through ``view_ladder_attend`` (the
+    twin), at the two latent cells' shapes and the slots alive that
+    PERF.md gives for them, lengths drawn from the cells' mixes."""
+    from horovod_tpu.models import latent_moe as lm
+    from horovod_tpu.models.transformer import view_rungs
+    from horovod_tpu.ops import latent_paged_attention as lpa
+
+    cfg = (lm.LatentMoEConfig(num_attention_heads=8, kv_lora_rank=96,
+                              qk_rope_head_dim=32, qk_nope_head_dim=16,
+                              v_head_dim=16, dtype=jnp.float32)
+           if dry else lm.LatentMoEConfig())
+    page = 8 if dry else 16
+    shapes = ({"toy": dict(slots=8, pps=32, layers=2, alive=3, median=60)}
+              if dry else
+              {"longcat-serve-turns": dict(slots=128, pps=128, layers=8,
+                                           alive=47, median=440),
+               "axk1-serve-decode": dict(slots=64, pps=256, layers=7,
+                                         alive=14, median=610)})
+    h, w, dt = cfg.num_attention_heads, cfg.entry_width, cfg.dtype
+    out = {}
+    for name, c in shapes.items():
+        slots, pps, layers = c["slots"], c["pps"], c["layers"]
+        rng = np.random.RandomState(40)
+        lengths = np.full(slots, -1, np.int32)
+        lengths[rng.choice(slots, c["alive"], replace=False)] = np.clip(
+            rng.lognormal(np.log(c["median"]), 0.8, c["alive"]), 1,
+            pps * page - 1)
+        ks = jax.random.split(jax.random.PRNGKey(40), 5)
+        # A slot's pages in order, the slots' runs shuffled: what the
+        # free list hands a fresh engine.
+        table = jnp.asarray(1 + rng.permutation(slots)[:, None] * pps
+                            + np.arange(pps)[None, :], jnp.int32)
+        args = (jnp.asarray(lengths),
+                jax.random.normal(ks[0], (layers, slots * pps + 1, page, w),
+                                  dt), table,
+                jax.random.normal(ks[1], (slots, 1, h, cfg.qk_nope_head_dim),
+                                  dt),
+                jax.random.normal(ks[2], (slots, 1, h, cfg.qk_rope_head_dim),
+                                  dt),
+                jax.random.normal(ks[3], (slots, 1, w), dt),
+                # The weights an ARGUMENT, as the engine's are: closed
+                # over, XLA folds their slices into every branch of the
+                # ladder's conditionals (gigabytes of constants).
+                {"w_ukv": (jax.random.normal(
+                    ks[4], (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim
+                                                   + cfg.v_head_dim)))
+                           * 0.04).astype(dt)})
+        rungs = view_rungs(page, pps)
+
+        def every_layer(make):
+            def f(lengths, store, table, q_nope, q_rope, entry, ap):
+                attend = make(lengths, store, table)
+                return jnp.stack([attend(layer, q_nope, q_rope, entry, ap)
+                                  for layer in range(layers)])
+            return jax.jit(f)
+
+        kernel = every_layer(lambda n, s, t: lm.paged_attend(
+            n, s, t, cfg, True if dry else None))
+        twin = every_layer(lambda n, s, t: lm.view_ladder_attend(
+            n, s, t, cfg, rungs))
+        got = kernel(*args).astype(jnp.float32)
+        want = twin(*args).astype(jnp.float32)
+        on = lengths >= 0
+        err = float(jnp.max(jnp.abs(got - want)[:, on]))
+        top = float(jnp.max(jnp.abs(want)))
+        check(err <= (1e-5 if dry else PAGED_REL_TOL) * top,
+              f"{name}: kernel and view ladder differ by {err} of {top}")
+        check(not np.asarray(got)[:, ~on].any(),
+              f"{name}: an idle slot's attention is not zero")
+        live_bytes = (int(lengths[on].sum()) * w * jnp.dtype(dt).itemsize
+                      * layers)
+        out[name] = {
+            "alive": int(on.sum()), "live_tokens": int(lengths[on].sum()),
+            "kernel_tokens_a_layer": lpa.tokens_read(lengths, page),
+            "ladder_tokens_a_layer": int(lm.view_ladder_tokens(lengths,
+                                                               rungs)),
+            "max_err_over_max": err / top,
+            "live_bytes_at_819_gb_s_ms": round(live_bytes / 819e9 * 1e3, 4)}
+        if not dry:       # a time off the chip is no time
+            out[name].update(
+                kernel_ms=round(timed_steps(lambda: kernel(*args), 5) * 1e3,
+                                3),
+                view_ladder_ms=round(timed_steps(lambda: twin(*args), 5)
+                                     * 1e3, 3))
+    return out
+
+
 # ---------------------------------------------------------------------------
 
 def main() -> int:
@@ -859,9 +959,9 @@ def main() -> int:
                     help="toy widths on whatever platform jax has; the "
                          "result line says ok=false and names the "
                          "platform (for the test suite, never a pass)")
-    ap.add_argument("--legs", default="ABCDEFGH",
+    ap.add_argument("--legs", default="ABCDEFGHI",
                     help="subset of legs to run while debugging, e.g. "
-                         "AD; anything short of all eight is not a pass")
+                         "AD; anything short of all nine is not a pass")
     args = ap.parse_args()
     dry = args.dry_run
 
@@ -919,7 +1019,8 @@ def main() -> int:
             ("E_latent_moe", lambda: leg_latent_moe(dry)),
             ("F_hybrid_ssm", lambda: leg_hybrid_ssm(dry)),
             ("G_shortcut_moe", lambda: leg_shortcut_moe(dry)),
-            ("H_mamba2_hybrid", lambda: leg_mamba2_hybrid(dry)))
+            ("H_mamba2_hybrid", lambda: leg_mamba2_hybrid(dry)),
+            ("I_latent_paged_attn", lambda: leg_latent_paged_attn(dry)))
     for name, fn in plan:
         if name[0] not in args.legs.upper():
             continue

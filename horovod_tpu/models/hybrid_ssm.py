@@ -776,7 +776,7 @@ class HybridSSMServing:
         _M_STATE_BYTES.set(nbytes.get("state", 0))
         _M_WINDOW_BYTES.set(nbytes.get("window", 0))
 
-    def decode_view(self, lengths, rungs) -> float:
+    def decode_view(self, lengths, rungs, page_size=None) -> float:
         """Positions of shared view a slot the decode program gathers at
         these (host) lengths: the chunk list's rung, over the slots.  The
         engine's ladder (``rungs``) is a slot's and only its last rung,

@@ -23,8 +23,10 @@ attends over the cached entries themselves, one 576-wide "head" shared
 by all query heads.
 
 :class:`LatentMoEServing` is what ``serving.InferenceEngine`` asks for
-the cache entry, the paged decode step over the view ladder, the prefill
-step and the fingerprint (serving/models.py has the protocol).
+the cache entry, the paged decode step (:func:`ladder_attend`: on the TPU
+a kernel that walks the page table, ``ops/latent_paged_attention.py``;
+elsewhere its twin, the view ladder), the prefill step and the
+fingerprint (serving/models.py has the protocol).
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry as _telemetry
+from ..ops import latent_paged_attention as _paged
 from ..parallel.expert import moe_layer_held, swiglu
 from .transformer import view_rung
 
@@ -63,6 +66,10 @@ _M_ENTRY_BYTES = _telemetry.gauge(
 CACHE_LANE = 128
 # Queries of one block of the prefill's attention.
 PREFILL_Q_BLOCK = 256
+# ``ops/latent_paged_attention.py``'s ``interpret``: None is the rule (the
+# kernel on the TPU, the view ladder elsewhere); a test sets True to run
+# the kernel in the interpreter through the model.
+PAGED_INTERPRET = None
 
 
 @dataclass(frozen=True)
@@ -338,22 +345,40 @@ def mla_rebuilt_attention(q_nope, q_rope, entry, ap, cfg: LatentMoEConfig):
     return jnp.concatenate(outs, axis=1).astype(dt).reshape(b, s, -1)
 
 
+def _absorbed_query(q_nope, q_rope, width: int, ap, cfg: LatentMoEConfig):
+    """``W_uk`` absorbed into the query (a head's ``nope -> kv_rank``),
+    the rotated part beside it, zeros up to an entry's ``width``: ``[b,
+    s, heads, width]``, so the scores contract whole rows."""
+    b, s, h_n, _ = q_nope.shape
+    dt = q_nope.dtype
+    w_uk, _ = _w_ukv(ap, cfg)
+    q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_uk,
+                       preferred_element_type=jnp.float32).astype(dt)
+    fill = jnp.zeros((b, s, h_n, width - q_lat.shape[-1]
+                      - q_rope.shape[-1]), dt)
+    return jnp.concatenate([q_lat, q_rope, fill], axis=-1)
+
+
+def _absorbed_output(o_lat, ap, cfg: LatentMoEConfig):
+    """``W_uv`` applied to the attended latent ``[b, s, heads, kv_rank]``:
+    ``[b, s, heads * v_dim]``."""
+    _, w_uv = _w_ukv(ap, cfg)
+    b, s = o_lat.shape[:2]
+    return jnp.einsum("bshc,chv->bshv", o_lat, w_uv,
+                      preferred_element_type=jnp.float32
+                      ).astype(o_lat.dtype).reshape(b, s, -1)
+
+
 def mla_absorbed_attention(q_nope, q_rope, view, q_pos, ap,
                            cfg: LatentMoEConfig):
     """Attention over cached entries themselves: ``W_uk`` absorbed into
-    the query (a head's ``nope -> kv_rank``), ``W_uv`` into the output.
-    ``view [b, n, entry_width]`` holds position ``j`` at row ``j``; row
-    ``j`` takes part in query ``(b, i)`` iff ``j <= q_pos[b, i]``.  The
-    query is padded like the entry, so the scores contract whole rows
-    (the zeros add nothing).  Returns ``[b, s, heads * v_dim]``."""
-    b, s, h_n, _ = q_nope.shape
+    the query, ``W_uv`` into the output.  ``view [b, n, entry_width]``
+    holds position ``j`` at row ``j``; row ``j`` takes part in query ``(b,
+    i)`` iff ``j <= q_pos[b, i]``.  The query is padded like the entry,
+    so the scores contract whole rows (the zeros add nothing).  Returns
+    ``[b, s, heads * v_dim]``."""
     dt = q_nope.dtype
-    w_uk, w_uv = _w_ukv(ap, cfg)
-    q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_uk,
-                       preferred_element_type=jnp.float32).astype(dt)
-    fill = jnp.zeros((b, s, h_n, view.shape[-1] - q_lat.shape[-1]
-                      - q_rope.shape[-1]), dt)
-    q = jnp.concatenate([q_lat, q_rope, fill], axis=-1)
+    q = _absorbed_query(q_nope, q_rope, view.shape[-1], ap, cfg)
     scores = jnp.einsum("bshc,bnc->bhsn", q, view,
                         preferred_element_type=jnp.float32
                         ) * softmax_scale(cfg)
@@ -363,9 +388,7 @@ def mla_absorbed_attention(q_nope, q_rope, view, q_pos, ap,
     o_lat = jnp.einsum("bhsn,bnc->bshc", p.astype(dt),
                        view[..., :cfg.kv_lora_rank],
                        preferred_element_type=jnp.float32).astype(dt)
-    return jnp.einsum("bshc,chv->bshv", o_lat, w_uv,
-                      preferred_element_type=jnp.float32
-                      ).astype(dt).reshape(b, s, -1)
+    return _absorbed_output(o_lat, ap, cfg)
 
 
 # -- layers -------------------------------------------------------------------
@@ -483,22 +506,50 @@ def group_rungs(lengths, rungs, groups) -> list:
     return out
 
 
-def ladder_attend(lengths, store, table, cfg, rungs):
-    """The decode step's attention over the paged store, attending the
-    live tokens and not the capacity.  The slots are sorted by length and
+def view_ladder_tokens(lengths, rungs) -> int:
+    """Tokens of view the ladder gathers in one cache layer at these
+    (host) lengths: its groups' rungs times their sizes."""
+    groups = slot_groups(len(lengths))
+    return sum(size * rungs[int(i)] for size, i in zip(
+        groups, group_rungs(lengths, rungs, groups)))
+
+
+def paged_kernel_runs() -> bool:
+    """Whether the decode program attends through the kernel that walks
+    the page table: read off the backend the program is built for
+    (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
+    return _paged.use_kernel(PAGED_INTERPRET)
+
+
+def paged_attend(lengths, store, table, cfg, interpret=None):
+    """The decode step's ``attend`` through the kernel
+    (``ops/latent_paged_attention.py``): the live slots' own pages, read
+    where they lie; the absorptions stay the matmuls they are, outside
+    it."""
+    order, n_live = _paged.live_first(lengths)
+
+    def attend(layer, q_nope, q_rope, entry, ap):
+        q = _absorbed_query(q_nope, q_rope, store.shape[-1], ap, cfg)
+        o_lat = _paged.latent_paged_attention(
+            q[:, 0], entry[:, 0], store, table, lengths, layer,
+            scale=softmax_scale(cfg), kv_rank=cfg.kv_lora_rank,
+            order=order, n_live=n_live, interpret=interpret)
+        return _absorbed_output(o_lat[:, None], ap, cfg)
+
+    return attend
+
+
+def view_ladder_attend(lengths, store, table, cfg, rungs):
+    """The decode step's ``attend`` over gathered views, the kernel's twin
+    off the TPU.  The slots are sorted by length (idle ones last) and
     attended in :func:`slot_groups`; each cache layer gathers, for a
     group, the first ``n`` pages of its slots' ``table`` rows, ``n`` the
     smallest of ``rungs`` that holds the group's longest sequence and its
     new token, picked INSIDE the program from ``lengths`` (``lax.switch``
-    around the gather and the attention only).  ``lengths [slots]`` (-1
-    idle: such a slot attends nothing and sorts last); ``store [cache
-    layers, pages, page, width]``.  Returns ``(attend, pos)``:
-    ``attend(layer, q_nope, q_rope, entry, ap)`` with ``layer`` the index
-    into the store, and the new tokens' positions ``[slots, 1]``."""
+    around the gather and the attention only)."""
     ps = store.shape[2]
-    b = lengths.shape[0]
-    pos = jnp.clip(lengths, 0, None)[:, None]
-    groups = slot_groups(b)
+    pos = jnp.clip(lengths, 0, None)
+    groups = slot_groups(lengths.shape[0])
     order = jnp.argsort(-lengths)
     picked = group_rungs(lengths, rungs, groups)
     bounds = np.cumsum((0,) + groups)
@@ -508,7 +559,7 @@ def ladder_attend(lengths, store, table, cfg, rungs):
         ``n_tokens``."""
         pages = table[rows, :n_tokens // ps]
         view = store[layer, pages].reshape(rows.shape[0], n_tokens, -1)
-        view = view.at[jnp.arange(rows.shape[0]), pos[rows, 0]].set(
+        view = view.at[jnp.arange(rows.shape[0]), pos[rows]].set(
             entry[rows, 0], mode="drop")
         return mla_absorbed_attention(q_nope[rows], q_rope[rows], view,
                                       lengths[rows, None], ap, cfg)
@@ -521,7 +572,21 @@ def ladder_attend(lengths, store, table, cfg, rungs):
         # Back into slot order.
         return jnp.concatenate(outs)[jnp.argsort(order)]
 
-    return attend, pos
+    return attend
+
+
+def ladder_attend(lengths, store, table, cfg, rungs):
+    """The decode step's attention over the paged store, attending the
+    live tokens and not the capacity: on the TPU :func:`paged_attend`,
+    elsewhere :func:`view_ladder_attend` (:func:`paged_kernel_runs`).
+    ``lengths [slots]`` (-1 idle: such a slot attends nothing); ``store
+    [cache layers, pages, page, width]``.  Returns ``(attend, pos)``:
+    ``attend(layer, q_nope, q_rope, entry, ap)`` with ``layer`` the index
+    into the store, and the new tokens' positions ``[slots, 1]``."""
+    attend = (paged_attend(lengths, store, table, cfg, PAGED_INTERPRET)
+              if paged_kernel_runs()
+              else view_ladder_attend(lengths, store, table, cfg, rungs))
+    return attend, jnp.clip(lengths, 0, None)[:, None]
 
 
 def decode_step(params, tokens, lengths, store, table,
@@ -577,13 +642,16 @@ class LatentMoEServing:
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
-    def decode_view(self, lengths, rungs) -> float:
-        """Tokens of view a slot the decode program attends at these
-        (host) lengths: its groups' rungs, weighted by their sizes."""
-        groups = slot_groups(len(lengths))
-        picked = group_rungs(lengths, rungs, groups)
-        return sum(size * rungs[int(i)] for size, i in zip(groups, picked)
-                   ) / len(lengths)
+    def decode_view(self, lengths, rungs, page_size=None) -> float:
+        """Tokens of the store a slot the decode program attends in one
+        cache layer at these (host) lengths, by the rule the program
+        follows: where the kernel runs, what it copies (the live lengths
+        rounded up to the page, an idle slot nothing); on the ladder its
+        groups' rungs, weighted by their sizes."""
+        read = (_paged.tokens_read(lengths, page_size)
+                if paged_kernel_runs()
+                else view_ladder_tokens(lengths, rungs))
+        return read / len(lengths)
 
     def cache_entry(self) -> dict:
         """One store; to the cache it is one key/value head as wide as

@@ -556,7 +556,7 @@ class Mamba2HybridServing:
         """Bytes of the per-slot stores by kind, once at build."""
         _M_STATE_BYTES.set(nbytes.get("state", 0))
 
-    def decode_view(self, lengths, rungs) -> float:
+    def decode_view(self, lengths, rungs, page_size=None) -> float:
         """Positions of paged view a slot the decode program gathers (for
         EACH attention layer) at these (host) lengths: the chunk list's
         rung, over the slots."""

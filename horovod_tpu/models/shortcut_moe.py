@@ -278,8 +278,9 @@ def prefill_step(params, tokens, n_valid, cfg: ShortcutMoEConfig):
 
 def decode_step(params, tokens, lengths, store, table,
                 cfg: ShortcutMoEConfig, rungs):
-    """One token a slot over the paged store through the shared view
-    ladder (``latent_moe.ladder_attend``), two cache layers a decoder
+    """One token a slot over the paged store through the latent
+    family's attention (``latent_moe.ladder_attend``: the paged kernel on
+    the TPU, the view ladder elsewhere), two cache layers a decoder
     layer.  Returns ``(logits [slots, vocab], entries [cache layers,
     slots, width], counts [layers, held], zero_pairs [layers],
     routed_pairs [layers])``."""

@@ -109,7 +109,8 @@ _M_PREFILL_AHEAD = _telemetry.counter(
     "drain the decode loop")
 _M_VIEW_TOKENS = _telemetry.counter(
     "serving.decode_view_tokens", "tokens of KV view per slot that the "
-    "decode iterations attended (the ladder rung each one rode); over "
+    "decode iterations attended (the ladder rung each one rode; where a "
+    "kernel reads the pages in place, what it copied); over "
     "serving.decode_iterations it is the mean view")
 _M_PREFILL_TOKENS = _telemetry.counter(
     "serving.prefill_tokens", "real prompt tokens the admission prefills "
@@ -1202,9 +1203,10 @@ class InferenceEngine:
         copy_s = time.monotonic() - t0
         nbytes = table.nbytes + lengths.nbytes + (
             0 if override is None else override.nbytes)
-        # The rung the program is about to pick from ``lengths``.
+        # The view the program is about to attend at ``lengths``.
         return _Flight(
-            riding, self.model.decode_view(lengths, self._rungs), lengths,
+            riding, self.model.decode_view(lengths, self._rungs,
+                                           self.cache.page_size), lengths,
             inputs, n_fresh, (nbytes, mapped, copy_s))
 
     @staticmethod

@@ -28,9 +28,10 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
     [slots, vocab]``, which stay on the device unless a slot samples
     (the engine's executable takes their argmax itself); anything after
     it is fetched and goes to ``observe_decode`` inside ``serve.sample``.
-``decode_view(lengths, rungs) -> tokens``
+``decode_view(lengths, rungs, page_size) -> tokens``
     The view a slot the decode program attends at these host lengths
-    (the rung it is about to pick, by the same pure function): what
+    (the rung it is about to pick, by the same pure function; for a
+    program that reads the pages in place, the tokens it copies): what
     ``serving.decode_view_tokens`` counts.
 ``prefill(params, pages, table_row, start, n_valid, tokens[, slot]) -> (outs, pages)``
     A padded prompt block from ``start`` cached positions; ``outs`` is
@@ -95,7 +96,7 @@ class DenseLM:
             "dtype": jnp.dtype(cfg.dtype).name,
         }
 
-    def decode_view(self, lengths, rungs) -> int:
+    def decode_view(self, lengths, rungs, page_size=None) -> int:
         """One rung for every slot, holding the [token, dummy] block."""
         return rungs[_transformer.view_rung(lengths, rungs)]
 

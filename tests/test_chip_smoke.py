@@ -1,6 +1,6 @@
 """chip_smoke.py: the script the driver runs on the chip.  Here, on the
 CPU, it must refuse — quickly, naming the platform, with no result
-line — and its six legs must run at toy widths through the explicit
+line — and its nine legs must run at toy widths through the explicit
 dry run, whose result line can never be read as a pass on the chip."""
 
 import json
@@ -45,7 +45,8 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     legs = out["legs"]
     assert set(legs) == {"A_resnet_dp", "B_lm_pallas", "C_eager",
                          "D_serve", "E_latent_moe", "F_hybrid_ssm",
-                         "G_shortcut_moe", "H_mamba2_hybrid"}
+                         "G_shortcut_moe", "H_mamba2_hybrid",
+                         "I_latent_paged_attn"}
     # The dry run forces the stream schedule (auto is the one-program
     # step on a mesh one process owns): no other leg runs it.
     assert legs["A_resnet_dp"]["schedule"] == "stream"
@@ -69,6 +70,10 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert legs["H_mamba2_hybrid"]["config"] == "tiny-granite4h"
     assert max(legs["H_mamba2_hybrid"]["logit_rms_over_std"]) < 1e-4
     assert legs["H_mamba2_hybrid"]["state_bytes_moved"] > 0
+    toy = legs["I_latent_paged_attn"]["toy"]
+    assert toy["alive"] == 3 and toy["max_err_over_max"] < 1e-5
+    assert (toy["live_tokens"] <= toy["kernel_tokens_a_layer"]
+            < toy["ladder_tokens_a_layer"])
 
 
 # ---------------------------------------------------------------------------

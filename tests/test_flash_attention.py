@@ -417,3 +417,37 @@ def test_ssd_step_compiles_for_the_v5e_and_never_copies_the_store(v5e_chip):
     store = 36 * 64 * 32 * 128 * 128 * 4
     assert memory.alias_size_in_bytes >= store
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+# The paged latent-attention kernel (ops/latent_paged_attention.py) at the
+# two cells' shapes (here for the same reason as the scan kernel above):
+# `longcat-serve-turns` (128 slots x 128 pages, 8 cache layers) and
+# `axk1-serve-decode` (64 x 256, 7), two layers in a row over the WHOLE
+# store, which stays an argument: nothing of its 2.7 / 2.35 GB is copied,
+# and neither a sort, a gather nor a conditional is left around the call.
+@pytest.mark.parametrize("slots,pps,layers", [(128, 128, 8), (64, 256, 7)])
+def test_latent_paged_attention_compiles_for_the_v5e(v5e_chip, slots, pps,
+                                                     layers):
+    from horovod_tpu.ops import latent_paged_attention as lpa
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(q, entry, store, table, lengths):
+        order, n_live = lpa.live_first(lengths)
+        return sum(lpa.latent_paged_attention(
+            q, entry, store, table, lengths, layer, scale=0.07, kv_rank=512,
+            order=order, n_live=n_live, interpret=False).astype(jnp.float32)
+            for layer in (0, layers - 1))
+
+    compiled = jax.jit(two_layers).lower(
+        sd(slots, 64, 640), sd(slots, 640),
+        sd(layers, slots * pps + 1, 16, 640),
+        sd(slots, pps, dtype=jnp.int32),
+        sd(slots, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "latent_paged_attn" in text
+    assert not any(op in text for op in (" sort(", " gather(",
+                                         " conditional("))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
