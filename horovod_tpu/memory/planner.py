@@ -105,18 +105,65 @@ def kv_cache_bytes(n_layers: int, n_heads: int, head_dim: int,
             * dtype_bytes(dtype))
 
 
+def ring_entries(window: int, page_size: int) -> int:
+    """Entries of a window group's ring of pages (serving/kv_cache.py
+    "Layer groups"): the pages a window can straddle, ``ceil(window /
+    page_size) + 1``."""
+    return -(-window // page_size) + 1
+
+
+def group_entries(group: dict, pages_per_slot: int, page_size: int) -> int:
+    """Page-table entries a slot of one layer group of the paged store:
+    every page of the slot for the full group, the ring for a group that
+    declares a ``window``."""
+    window = group.get("window")
+    return ring_entries(window, page_size) if window else pages_per_slot
+
+
+def size_page_pools(groups: Sequence[dict], token_bytes: int,
+                    page_size: int, pages_per_slot: int, max_slots: int,
+                    budget_bytes: int,
+                    expected_tokens: Optional[int] = None
+                    ) -> Tuple[int, ...]:
+    """Split ``budget_bytes`` of device memory into the page pools of a
+    store's layer groups: allocatable pages a group, the full group
+    first.  ``token_bytes``: what one position leaves in ONE layer (all
+    its stores).  The rule: every pool holds the SAME number of sequences
+    of ``expected_tokens`` positions (left out: the slot's capacity), a
+    sequence taking ``ceil(expected / page_size)`` pages of the full
+    group and no more than its ring of a window group, so that neither
+    pool runs dry first under that traffic; a pool never exceeds what
+    every slot at its largest could map.  The trash page of each group
+    comes out of the budget too."""
+    entries = [group_entries(g, pages_per_slot, page_size) for g in groups]
+    page_bytes = [g["n_layers"] * page_size * token_bytes for g in groups]
+    want = -(-(expected_tokens or pages_per_slot * page_size) // page_size)
+    a_seq = [min(want, e) for e in entries]
+    left = budget_bytes - sum(page_bytes)
+    n_seq = left / sum(n * b for n, b in zip(a_seq, page_bytes))
+    pools = tuple(min(int(n_seq * n), max_slots * e)
+                  for n, e in zip(a_seq, entries))
+    if any(p < e for p, e in zip(pools, entries)):
+        raise ValueError(
+            f"{budget_bytes} bytes hold pools of {pools} pages: less than "
+            f"one slot at its largest ({entries} entries)")
+    return pools
+
+
 def slot_store_bytes(slot_stores: Sequence[dict], max_slots: int,
-                     capacity: int) -> int:
+                     capacity: int, view_tokens: int = 0) -> int:
     """The per-slot stores a serving model declares beside its pages
     (``cache_entry()["slot_stores"]``, serving/models.py: window rings,
     recurrent state, scratch), one array ``[layers, max_slots, *shape]``
-    each, a dimension ``"capacity"`` the slot's: byte for byte the
+    each, a dimension ``"capacity"`` the slot's and ``"view"``
+    ``view_tokens`` (``PagedKVCache.view_tokens``): byte for byte the
     ``serving.slot_state`` ledger category the cache manager charges.
     They are fixed at ``max_slots``, so a what-if over slots moves them
     in proportion, and where they outweigh the pages (a matrix state a
     head) they decide how many slots fit."""
+    named = {"capacity": capacity, "view": view_tokens}
     return sum(max_slots * dtype_bytes(s["dtype"]) * int(math.prod(
-        capacity if d == "capacity" else d for d in s["shape"]))
+        named.get(d, d) for d in s["shape"]))
         for s in slot_stores)
 
 
@@ -494,7 +541,10 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
                  draft_d_ff: Optional[int] = None,
                  vocab_size: int = 256,
                  capacity: Optional[int] = None,
-                 slot_stores: Sequence[dict] = ()) -> MemoryPlan:
+                 slot_stores: Sequence[dict] = (),
+                 groups: Sequence[dict] = (),
+                 pool_pages: Optional[Sequence[int]] = None,
+                 view_tokens: int = 0) -> MemoryPlan:
     """Plan for the serving engine: the paged KV store (the dominant
     framework buffer unless ``slot_stores``, a model's per-slot stores
     as its ``cache_entry()`` declares them, outweigh it:
@@ -509,15 +559,32 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
     with) plus its replicated parameters
     (:func:`_transformer_param_bytes`, exact for ``init_transformer``
     trees; draft ``d_model = n_heads * head_dim``, ``d_ff`` defaults
-    to ``4 * d_model``, positions sized to the KV capacity)."""
-    kv = kv_cache_bytes(n_layers, n_heads, head_dim, max_slots,
-                        pages_per_slot, page_size, dtype)
+    to ``4 * d_model``, positions sized to the KV capacity).  A store
+    with layer ``groups`` (``cache_entry()["groups"]``, each ``{"name",
+    "n_layers"[, "window"]}``; ``n_layers`` is then unused) is priced a
+    group at a time: ``pool_pages`` allocatable pages each
+    (:func:`size_page_pools`; left out, every slot at its largest) plus
+    its trash page."""
+    if groups:
+        pools = pool_pages or [
+            max_slots * group_entries(g, pages_per_slot, page_size)
+            for g in groups]
+        token = 2 * n_heads * head_dim * dtype_bytes(dtype)
+        kv = sum(g["n_layers"] * (1 + p) * page_size * token
+                 for g, p in zip(groups, pools))
+        facts = {"kv_capacity_tokens": pools[0] * page_size,
+                 "pool_pages": {g["name"]: int(p)
+                                for g, p in zip(groups, pools)}}
+    else:
+        kv = kv_cache_bytes(n_layers, n_heads, head_dim, max_slots,
+                            pages_per_slot, page_size, dtype)
+        facts = {"kv_capacity_tokens": max_slots * pages_per_slot
+                 * page_size}
     framework = {"serving.kv_pages": kv}
-    facts = {"kv_capacity_tokens": max_slots * pages_per_slot
-             * page_size}
     if slot_stores:
         framework["serving.slot_state"] = slot_store_bytes(
-            slot_stores, max_slots, pages_per_slot * page_size)
+            slot_stores, max_slots, pages_per_slot * page_size,
+            view_tokens)
     if prefix_pages:
         framework["serving.prefix_pages"] = prefix_pages_bytes(
             n_layers, n_heads, head_dim, prefix_pages, page_size,

@@ -254,6 +254,27 @@ def route_softmax_top_k(x, router, bias, top_k: int,
     return idx.astype(jnp.int32), gate * routed_scale
 
 
+def route_sigmoid_bias_top_k(x, router, bias, top_k: int,
+                             routed_scale: float = 1.0,
+                             norm_topk: bool = True):
+    """Sigmoid scores over ALL of the router's outputs; the ``top_k``
+    largest of ``scores + bias`` are CHOSEN (``bias [outputs]``: the
+    load-balancing buffer) and weighted by the UNBIASED scores,
+    normalised over the chosen (``/ (their sum + 1e-20)``, with
+    ``norm_topk``) and ``* routed_scale``: the bias moves the choice and
+    never the weight.  float32 at the highest matmul precision, as
+    :func:`route_sigmoid_top_k`.  Returns ``(experts [t, k] int32,
+    weights [t, k] float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gate * routed_scale
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """``down(silu(gate(x)) * up(x))``, float32 accumulation."""
     g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)

@@ -176,14 +176,30 @@ _M_PASS = {
 
 
 def _make_cache(model, max_slots: int, pages_per_slot: int,
-                page_size: int, **kw) -> PagedKVCache:
-    """The paged store for what ``model`` caches."""
+                page_size: int, kv_pool_bytes: Optional[int] = None,
+                kv_expected_tokens: Optional[int] = None,
+                **kw) -> PagedKVCache:
+    """The paged store for what ``model`` caches.  ``kv_pool_bytes``: the
+    bytes its page pools may take together, split over its layer groups
+    by ``memory/planner.size_page_pools`` for sequences of
+    ``kv_expected_tokens`` positions (left out: every slot at capacity,
+    and no byte budget at all means every group holds that)."""
     entry = model.cache_entry()
+    groups = entry.get("groups", ())
+    pools = None
+    if kv_pool_bytes is not None:
+        pools = _mem_planner.size_page_pools(
+            groups or ({"name": "full", "n_layers": entry["n_layers"]},),
+            sum(entry["widths"]) * jnp.dtype(model.cfg.dtype).itemsize,
+            page_size, pages_per_slot, max_slots, kv_pool_bytes,
+            expected_tokens=kv_expected_tokens)
     cache = PagedKVCache(entry["n_layers"], entry["n_heads"],
                          entry["head_dim"], max_slots, pages_per_slot,
                          page_size, dtype=model.cfg.dtype,
                          entry_widths=entry["widths"],
-                         slot_stores=entry.get("slot_stores", ()), **kw)
+                         slot_stores=entry.get("slot_stores", ()),
+                         groups=groups, pool_pages=pools,
+                         view_chunk=entry.get("view_chunk", 0), **kw)
     if cache.slot_state:
         model.observe_stores(cache.slot_store_bytes())
     return cache
@@ -243,7 +259,9 @@ class InferenceEngine:
                  prefix_cache: Optional[bool] = None,
                  prefix_pages: Optional[int] = None,
                  draft: Optional[Tuple[Any, Any]] = None,
-                 spec_tokens: Optional[int] = None) -> None:
+                 spec_tokens: Optional[int] = None,
+                 kv_pool_bytes: Optional[int] = None,
+                 kv_expected_tokens: Optional[int] = None) -> None:
         cap = capacity if capacity is not None else cfg.max_seq_len
         cap = min(cap, cfg.max_seq_len)
         cap -= cap % page_size
@@ -303,7 +321,8 @@ class InferenceEngine:
             self.model, max_slots, cap // page_size, page_size,
             mesh=mesh, model_axis=model_axis,
             prefix_cache=prefix_cache, prefix_pages=prefix_pages,
-            fingerprint=fingerprint)
+            fingerprint=fingerprint, kv_pool_bytes=kv_pool_bytes,
+            kv_expected_tokens=kv_expected_tokens)
         self.capacity = self.cache.capacity
         self.scheduler = ContinuousBatchingScheduler(max_slots,
                                                      self.capacity)
@@ -732,7 +751,7 @@ class InferenceEngine:
         cache = self.draft_cache if draft else self.cache
         args = (self._draft_params if draft else self.params,
                 *cache.arrays,
-                self._rep(np.zeros((1, cache.pages_per_slot), np.int32)),
+                self._rep(np.zeros((1, cache.table_width), np.int32)),
                 self._rep(np.zeros((1,), np.int32)),
                 self._rep(np.ones((1,), np.int32)),
                 self._rep(np.zeros((1, bucket), np.int32)))
@@ -956,9 +975,9 @@ class InferenceEngine:
         headroom — but it keeps overcommitted or future configs
         honest (the pricing is pure: no refcounts move here)."""
         admitted = self.scheduler.admit(
-            now, page_budget=self.cache.free_pages(),
-            pages_needed=lambda req:
-                self.cache.admission_cost(req.prompt))
+            now, page_budget=self.cache.headroom(),
+            pages_needed=lambda req: self.cache.admission_need(
+                req.prompt, req.max_new_tokens))
         if admitted:
             # The slot is granted: one stamp for the whole admission.
             t_admit = time.monotonic()
@@ -1081,7 +1100,10 @@ class InferenceEngine:
         n = len(prompt)
         shared = self.cache.lookup_prefix(prompt)
         n_shared = len(shared) * self.cache.page_size
-        self.cache.begin_slot(slot, n, prefix_pages=shared)
+        self.cache.begin_slot(slot, n, prefix_pages=shared,
+                              reserve_tokens=min(
+                                  n + req.max_new_tokens - len(req.generated),
+                                  self.capacity))
         suffix = prompt[n_shared:]
         bucket = self._prefill_bucket = self._bucket_for(len(suffix))
         tokens = np.zeros((1, bucket), np.int32)

@@ -32,6 +32,30 @@ state has no mask that could hide an evicted sequence's), so
 ``free_slot`` has nothing of theirs to recycle but the slot itself.  The executables take and return ``arrays`` (pages,
 then per-slot stores), all donated.
 
+**Layer groups** (``groups``): a model whose layers do not all keep the
+same positions declares more than one paged group, each with its layer
+count, its own page arrays ``[group layers, group pages, page_size,
+width]``, its own page table and its own free list.  The first group is
+the ``full`` one: every position of a sequence, ``pages_per_slot``
+entries a slot, everything above.  A group that declares a ``window``
+keeps only what a window layer can still attend: its table has ``R =
+ceil(window / page_size) + 1`` entries a slot, used as a ring of PAGES
+(logical page ``j`` lies in entry ``j mod R``).  A page is taken from the
+group's free list when the sequence first reaches it, never more than
+``R`` a slot; from logical page ``R`` on the entry's page is written over
+in place (what it held has left the window by then), and all go back at
+``free_slot``.  The executables see ONE table, the groups' side by side
+(``table_width`` columns), and every group's arrays in ``arrays``.
+
+**Page pools** (``pool_pages``): left out, every group holds all its
+slots at their largest (``max_slots x entries``), and a free slot implies
+room.  Given (``memory/planner.size_page_pools`` splits a byte budget),
+the pools are smaller than that and admission RESERVES: ``begin_slot``
+is told how far the sequence may grow, ``headroom`` is each pool's free
+pages less what live slots have reserved and not yet mapped, and
+``admission_need`` prices a request in every pool, so no sequence that
+was admitted runs out of pages as it decodes.
+
 Page 0 is the reserved *trash* page: unmapped table entries point at
 it, so the executables' scatters of padded/inactive positions land
 somewhere harmless instead of needing per-position predication.
@@ -80,6 +104,7 @@ from ..analysis import lockorder as _lockorder
 from ..analysis import races as _races
 from ..core.topology import MODEL_AXIS
 from ..memory import ledger as _mem
+from ..memory import planner as _planner
 from ..routing.affinity import chain_hashes as _chain_hash_scheme
 
 # hvd-mem satellite: free-page headroom next to serving.batch_occupancy
@@ -117,10 +142,71 @@ _M_STATE_RESETS = _telemetry.counter(
     "serving.state_slot_resets",
     "slots whose per-slot stores (recurrent state, window rings) an "
     "admission's prefill replaced whole")
+_M_RING_REUSED = _telemetry.counter(
+    "serving.window_pages_reused",
+    "window-group ring entries written over in place: logical pages a "
+    "sequence reached past its ring's length, which took no new page")
 _M_PREFIX_HITS_DRAFT = _telemetry.counter(
     "serving.prefix_hits_draft",
     "admissions whose speculative DRAFT prefill mapped cached prefix "
     "pages copy-free (the target's hits stay in serving.prefix_hits)")
+
+
+class _WindowGroup:
+    """One window group's host state (module docstring, "Layer groups"):
+    its ring table, the highest logical page each slot has reached, its
+    free list and its page arrays.  All guarded by the cache's lock."""
+
+    def __init__(self, name: str, n_layers: int, window: int,
+                 page_size: int, max_slots: int,
+                 pool_pages: Optional[int]) -> None:
+        if window < 1:
+            raise ValueError(f"group {name!r}: window must be >= 1")
+        self.name = name
+        self.n_layers = n_layers
+        self.window = window
+        self.entries = _planner.ring_entries(window, page_size)
+        self.total_pages = (max_slots * self.entries if pool_pages is None
+                            else int(pool_pages))
+        self.free: List[int] = list(range(1, self.total_pages + 1))
+        self.table = np.zeros((max_slots, self.entries), np.int32)
+        self.top = np.full((max_slots,), -1, np.int64)
+        self.pages: Tuple = ()
+
+
+def _group_gauges(name: str, total_pages: int) -> Tuple:
+    """A layer group's gauges ``(used, peak)``: pages its live slots map
+    now, and the most they mapped at once; the pool's size is set here."""
+    _telemetry.gauge(
+        f"serving.kv_group_pages_total.{name}",
+        f"allocatable pages of the {name!r} layer group's pool"
+    ).set(total_pages)
+    return (_telemetry.gauge(
+                f"serving.kv_group_pages_used.{name}",
+                f"pages of the {name!r} layer group mapped by live slots"),
+            _telemetry.gauge(
+                f"serving.kv_group_pages_peak.{name}",
+                f"the most pages of the {name!r} layer group live slots "
+                f"have mapped at once since the store was built"))
+
+
+def view_tokens_per_slot(pools: Sequence[Tuple[int, int]], page_size: int,
+                         max_slots: int, chunk: int) -> int:
+    """Positions a slot of a ``"view"`` scratch store (``[.., max_slots,
+    "view", ..]``): room for the longest list of ``chunk``-position chunks
+    any ONE group can hand a decode launch.  ``pools``: ``(allocatable
+    pages, table entries a slot)`` a group.  No launch gathers more
+    chunks of a group than its pool holds plus one part-filled chunk a
+    slot, nor more than every slot's whole table (``a_slot`` chunks each;
+    the list is gathered ``a_slot`` chunks at a time, so it is rounded up
+    to that)."""
+    most = 0
+    for pages, entries in pools:
+        a_slot = -(-entries * page_size // chunk)
+        top = min(max_slots * a_slot,
+                  -(-pages * page_size // chunk) + max_slots)
+        most = max(most, -(-top // a_slot) * a_slot)
+    return -(-most // max_slots) * chunk
 
 
 @_races.race_checked
@@ -143,9 +229,28 @@ class PagedKVCache:
                  fingerprint: str = "",
                  ledger_category: str = "serving.kv_pages",
                  entry_widths: Optional[Sequence[int]] = None,
-                 slot_stores: Sequence[dict] = ()) -> None:
+                 slot_stores: Sequence[dict] = (),
+                 groups: Sequence[dict] = (),
+                 pool_pages: Optional[Sequence[int]] = None,
+                 view_chunk: int = 0) -> None:
         if pages_per_slot < 1 or page_size < 1:
             raise ValueError("pages_per_slot and page_size must be >= 1")
+        # Layer groups (module docstring): ``{"name", "n_layers"[,
+        # "window"]}`` each, the full group first; none: one full group
+        # of ``n_layers``.  ``pool_pages``: allocatable pages a group.
+        groups = tuple(dict(g) for g in groups) or (
+            {"name": "full", "n_layers": n_layers},)
+        if groups[0].get("window") or not all(
+                g.get("window") for g in groups[1:]):
+            raise ValueError("the first layer group keeps every position "
+                             "and every other one declares a window")
+        if pool_pages is not None and len(pool_pages) != len(groups):
+            raise ValueError(f"{len(pool_pages)} page pools for "
+                             f"{len(groups)} layer groups")
+        if prefix_cache and (len(groups) > 1 or pool_pages is not None):
+            raise ValueError("the shared-prefix index is not written for "
+                             "window groups nor for reserved pools")
+        n_layers = groups[0]["n_layers"]
         if prefix_pages < 0:
             raise ValueError(f"prefix_pages must be >= 0, got "
                              f"{prefix_pages}")
@@ -162,7 +267,9 @@ class PagedKVCache:
         # live slots.
         self.prefix_enabled = bool(prefix_cache)
         self.prefix_pages = int(prefix_pages) if prefix_cache else 0
-        self.n_pages = (1 + max_slots * pages_per_slot
+        self._pooled = pool_pages is not None
+        self.n_pages = (1 + (pool_pages[0] if self._pooled
+                             else max_slots * pages_per_slot)
                         + self.prefix_pages)
         self.dtype = dtype
         self.mesh = mesh
@@ -182,6 +289,40 @@ class PagedKVCache:
                               dtype)
             pages.append(store if sh is None else jax.device_put(store, sh))
         self.pages: Tuple = tuple(pages)
+        self.group_names = tuple(g["name"] for g in groups)
+        self._extra: Tuple[_WindowGroup, ...] = tuple(
+            _WindowGroup(g["name"], g["n_layers"], g["window"], page_size,
+                         max_slots,
+                         pool_pages[i] if self._pooled else None)
+            for i, g in enumerate(groups[1:], start=1))
+        if self._extra and sh is not None:
+            raise ValueError("window groups are not written for a sharded "
+                             "model axis")
+        for g in self._extra:
+            g.pages = tuple(
+                jnp.zeros((g.n_layers, g.total_pages + 1, page_size, width),
+                          dtype) for width in self.entry_widths)
+        # Pages a live slot has reserved (a cache with pool_pages) and
+        # mapped, a row a group.
+        # guarded_by: _lock
+        self._reserved = np.zeros((len(groups), max_slots), np.int64)
+        # guarded_by: _lock
+        self._mapped = np.zeros((len(groups), max_slots), np.int64)
+        self.table_width = pages_per_slot + sum(g.entries
+                                                for g in self._extra)
+        self.view_tokens = view_tokens_per_slot(
+            [(self.n_pages - 1 - self.prefix_pages, pages_per_slot)]
+            + [(g.total_pages, g.entries) for g in self._extra],
+            page_size, max_slots, view_chunk) if view_chunk else 0
+        # Gauges a group, of a store with more than one (the full group's
+        # are serving.kv_pages_* as ever).
+        self._group_gauges = [
+            _group_gauges(name, total) for name, total in zip(
+                self.group_names,
+                [self.n_pages - 1] + [g.total_pages for g in self._extra])
+        ] if self._extra else []
+        # guarded_by: _lock
+        self._peak = np.zeros((len(groups),), np.int64)
         # Per-slot stores (module docstring): ``{"name", "kind", "shape",
         # "dtype"}`` each, ``shape`` led by the store's own layer count.
         self.slot_stores = tuple(dict(s) for s in slot_stores)
@@ -190,8 +331,8 @@ class PagedKVCache:
                              "sharded model axis")
         self.slot_state: Tuple = tuple(
             jnp.zeros((s["shape"][0], max_slots,
-                       *(self.capacity if d == "capacity" else d
-                         for d in s["shape"][1:])), s["dtype"])
+                       *(self._slot_dim(d) for d in s["shape"][1:])),
+                      s["dtype"])
             for s in self.slot_stores)
 
         self._lock = _lockorder.make_lock("serving.PagedKVCache._lock")
@@ -234,6 +375,8 @@ class PagedKVCache:
         # partition is exact integer arithmetic.
         self._page_resident_bytes = resident // self.n_pages
         prefix_resident = self._page_resident_bytes * self.prefix_pages
+        resident += sum(_mem.resident_nbytes(x)
+                        for g in self._extra for x in g.pages)
         if _mem.enabled():
             _mem.ledger.alloc(self._ledger_category,
                               resident - prefix_resident,
@@ -254,6 +397,14 @@ class PagedKVCache:
             weakref.finalize(self, _mem.ledger.free,
                              "serving.prefix_pages",
                              key=self._ledger_key)
+
+    def _slot_dim(self, d) -> int:
+        """A per-slot store's dimension: a number, ``"capacity"`` (the
+        slot's positions) or ``"view"`` (:func:`view_tokens_per_slot`)."""
+        if d == "view" and not self.view_tokens:
+            raise ValueError('a "view" dimension needs view_chunk')
+        return {"capacity": self.capacity,
+                "view": self.view_tokens}.get(d, d)
 
     # -- sharding ----------------------------------------------------------
     def page_sharding(self) -> Optional[NamedSharding]:
@@ -280,15 +431,25 @@ class PagedKVCache:
         # category) must not clobber them.
         if self._ledger_category != "serving.kv_pages":
             return
+        if self._group_gauges:
+            used = self._mapped.sum(axis=1)
+            np.maximum(self._peak, used, out=self._peak)
+            for (now, peak), u, p in zip(self._group_gauges, used,
+                                         self._peak):
+                now.set(int(u))
+                peak.set(int(p))
         _M_KV_FREE.set(len(self._free) + len(self._lru))
         _M_KV_RECLAIM.set(len(self._lru))
         _M_PREFIX_CACHED.set(len(self._page_hash))
 
     # -- page management ---------------------------------------------------
     def begin_slot(self, slot: int, n_tokens: int,
-                   prefix_pages: Sequence[int] = ()) -> None:
+                   prefix_pages: Sequence[int] = (),
+                   reserve_tokens: Optional[int] = None) -> None:
         """Map pages for a freshly admitted sequence's first
         ``n_tokens`` positions (the prompt) and set its length.
+        ``reserve_tokens``: how many positions the sequence may come to
+        hold, which a cache with ``pool_pages`` reserves in every pool.
         ``prefix_pages`` (from :meth:`lookup_prefix`) are mapped
         COPY-FREE as the leading read-only pages: each gets a
         reference (it leaves the reclaimable LRU while mapped) and
@@ -305,6 +466,18 @@ class PagedKVCache:
                 self._table[slot, j] = int(page)
                 self._ref_page_locked(int(page))
             self._lengths[slot] = 0
+            self._mapped[:, slot] = 0
+            self._mapped[0, slot] = len(prefix_pages)
+            last = (n_tokens - 1) // self.page_size
+            for g in self._extra:
+                # A prompt longer than the window leaves its last
+                # ``entries`` pages' worth: the pages before them are
+                # never mapped.
+                g.table[slot] = 0
+                g.top[slot] = max(last - g.entries, -1)
+            if self._pooled:
+                self._reserved[:, slot] = self._pages_for(
+                    max(n_tokens, reserve_tokens or 0))
             self._ensure_locked(slot, n_tokens - 1)
             self._lengths[slot] = n_tokens
             if self.slot_state:
@@ -381,10 +554,30 @@ class PagedKVCache:
                 f"position {pos} exceeds per-slot capacity "
                 f"{self.capacity}")
         mapped = 0
-        for p in range(pos // self.page_size + 1):
+        top = pos // self.page_size
+        for p in range(top + 1):
             if self._table[slot, p] == 0:
                 self._table[slot, p] = self._alloc_page_locked()
                 mapped += 1
+        self._mapped[0, slot] += mapped
+        for i, g in enumerate(self._extra, start=1):
+            # Only the logical pages the slot has not reached yet: a
+            # fresh page while the ring has an empty entry, else the
+            # entry's own page, written over in place.
+            for j in range(int(g.top[slot]) + 1, top + 1):
+                e = j % g.entries
+                if g.table[slot, e] != 0:
+                    _M_RING_REUSED.inc()
+                    continue
+                if not g.free:
+                    raise RuntimeError(
+                        f"layer group {g.name!r} out of pages: its pool "
+                        f"is smaller than the live slots' windows "
+                        f"(admission reserves against this)")
+                g.table[slot, e] = g.free.pop(0)
+                self._mapped[i, slot] += 1
+                mapped += 1
+            g.top[slot] = max(int(g.top[slot]), top)
         self._set_page_gauges_locked()
         return mapped
 
@@ -418,8 +611,60 @@ class PagedKVCache:
                     else:
                         self._free.append(page)
             self._table[slot] = 0
+            for g in self._extra:
+                g.free.extend(int(p) for p in g.table[slot] if p != 0)
+                g.table[slot] = 0
+                g.top[slot] = -1
+            self._mapped[:, slot] = 0
+            self._reserved[:, slot] = 0
             self._lengths[slot] = -1
             self._set_page_gauges_locked()
+
+    # -- admission headroom ------------------------------------------------
+    def _pages_for(self, n_tokens: int) -> np.ndarray:
+        """Pages a sequence of ``n_tokens`` positions maps in each group:
+        all of them in the full group, at most the ring in a window one."""
+        pages = -(-n_tokens // self.page_size)
+        return np.asarray([pages] + [min(pages, g.entries)
+                                     for g in self._extra], np.int64)
+
+    def headroom(self) -> np.ndarray:
+        """Pages each group's pool can still promise an admission, the
+        full group first: its free pages (:meth:`free_pages`) and, of a
+        cache with ``pool_pages``, less what the live slots have reserved
+        and not mapped yet."""
+        with self._lock:
+            free = np.asarray([len(self._free) + len(self._lru)]
+                              + [len(g.free) for g in self._extra],
+                              np.int64)
+            if self._pooled:
+                free -= np.clip(self._reserved - self._mapped, 0,
+                                None).sum(axis=1)
+            return free
+
+    def admission_need(self, tokens: Sequence[int],
+                       max_new_tokens: int = 0) -> np.ndarray:
+        """What admitting this prompt takes of :meth:`headroom`, a group
+        an entry: the prompt's pages (:meth:`admission_cost` in the full
+        group); of a cache with ``pool_pages`` the pages the sequence may
+        come to hold with ``max_new_tokens`` more, which ``begin_slot``
+        reserves."""
+        if self._pooled:
+            return self._pages_for(min(len(tokens) + max_new_tokens,
+                                       self.capacity))
+        need = self._pages_for(len(tokens))
+        need[0] = self.admission_cost(tokens)
+        return need
+
+    def group_pages(self) -> Dict[str, Tuple[int, int]]:
+        """``name -> (pages mapped by live slots, allocatable pages)``
+        a layer group."""
+        with self._lock:
+            used = self._mapped.sum(axis=1)
+            totals = [self.total_pages] + [g.total_pages
+                                           for g in self._extra]
+            return {name: (int(u), int(t)) for name, u, t
+                    in zip(self.group_names, used, totals)}
 
     # -- shared-prefix index -----------------------------------------------
     @property
@@ -684,7 +929,7 @@ class PagedKVCache:
         """One slot's page-table row, ``[1, pages_per_slot]`` (a copy —
         the live table may be mutated by a concurrent eviction)."""
         with self._lock:
-            return self._table[slot:slot + 1].copy()
+            return self._wide_locked(slice(slot, slot + 1))
 
     # -- device views ------------------------------------------------------
     def host_tables(self) -> Tuple[np.ndarray, np.ndarray]:
@@ -692,7 +937,16 @@ class PagedKVCache:
         serve loop edits into the tables of an iteration it launches
         ahead of the host's own state."""
         with self._lock:
-            return self._table.copy(), self._lengths.copy()
+            return self._wide_locked(slice(None)), self._lengths.copy()
+
+    def _wide_locked(self, rows) -> np.ndarray:
+        """Rows of the table as the executables take it, a copy: the
+        full group's entries, then each window group's ring."""
+        if not self._extra:
+            return self._table[rows].copy()
+        return np.concatenate([self._table[rows]]
+                              + [g.table[rows] for g in self._extra],
+                              axis=1)
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(page_table, lengths) as device arrays for the executables
@@ -709,8 +963,10 @@ class PagedKVCache:
     @property
     def arrays(self) -> Tuple:
         """Every device array of the cache, as the executables take and
-        return them: the page arrays, then the per-slot stores."""
-        return self.pages + self.slot_state
+        return them: the page arrays (group after group), then the
+        per-slot stores."""
+        return self.pages + tuple(
+            x for g in self._extra for x in g.pages) + self.slot_state
 
     def slot_store_bytes(self) -> Dict[str, int]:
         """Resident bytes of the per-slot stores by ``kind``."""
@@ -724,11 +980,13 @@ class PagedKVCache:
         """Install the executables' donated outputs, :attr:`arrays` in
         their order (the old references were consumed by the dispatch)."""
         n = len(self.pages)
-        if len(arrays) != n + len(self.slot_state):
+        if len(arrays) != n * (1 + len(self._extra)) + len(self.slot_state):
             raise ValueError(f"{len(arrays)} page arrays for a cache of "
-                             f"{n + len(self.slot_state)}")
+                             f"{len(self.arrays)}")
         self.pages = tuple(arrays[:n])
-        self.slot_state = tuple(arrays[n:])
+        for i, g in enumerate(self._extra, start=1):
+            g.pages = tuple(arrays[i * n:(i + 1) * n])
+        self.slot_state = tuple(arrays[n * (1 + len(self._extra)):])
 
     @property
     def k_pages(self):

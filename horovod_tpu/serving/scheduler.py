@@ -37,6 +37,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+import numpy as np
+
 from .. import telemetry as _telemetry
 from ..analysis import lockorder as _lockorder
 from ..analysis import races as _races
@@ -176,7 +178,9 @@ class ContinuousBatchingScheduler:
         ``page_budget``/``pages_needed`` gate admission on KV headroom
         (hvd-spec satellite): before a request takes a slot, its
         prefill's fresh-page demand (``pages_needed(req)`` — net of
-        any shared prefix pages) is charged against the budget.  A
+        any shared prefix pages) is charged against the budget; both
+        may be vectors, an entry a page pool (a cache with layer
+        groups), and a request fits when it fits EVERY pool.  A
         head-of-queue request that does not fit STOPS admission for
         this iteration — strictly FIFO, so deferral cannot reorder
         completions — and counts ``serving.admission_deferred``; the
@@ -197,11 +201,11 @@ class ContinuousBatchingScheduler:
                 if free and (now is None or req.arrival <= now):
                     if budget is not None and pages_needed is not None:
                         need = pages_needed(req)
-                        if need > budget:
+                        if np.any(need > budget):
                             deferred = True
                             keep.append(req)
                             continue
-                        budget -= need
+                        budget = budget - need
                     slot = free.pop(0)
                     self._slots[slot] = req
                     admitted.append((slot, req))

@@ -304,6 +304,72 @@ def test_attention_block_transposes_no_activation(n_heads, path_taken):
 
 
 # ---------------------------------------------------------------------------
+# Grouped queries inside a window: the streaming forward a serving model's
+# prompt takes (models/afmoe.py)
+# ---------------------------------------------------------------------------
+
+def _gqa_reference(q, k, v, window):
+    """Exact: every query head over its key/value head, causal, at most
+    the ``window`` newest keys."""
+    h, s, d = q.shape
+    r = h // k.shape[0]
+    kk, vv = jnp.repeat(k, r, axis=0), jnp.repeat(v, r, axis=0)
+    scores = jnp.einsum("hqd,hkd->hqk", q, kk) * d ** -0.5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = j <= i
+    if window:
+        seen = seen & (i - j < window)
+    return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1), vv)
+
+
+# Heads in groups of 2, 6 and 1; no window, a window inside one block, one
+# that spans blocks and is no multiple of them, one wider than the sequence;
+# unequal blocks; a sequence that is no whole number of blocks.
+@pytest.mark.parametrize("h,g,s,d,window,bq,bk", [
+    (4, 2, 64, 16, 0, 16, 16), (4, 2, 64, 16, 24, 16, 16),
+    (6, 1, 80, 16, 17, 16, 32), (4, 4, 50, 8, 5, 16, 16),
+    (8, 2, 128, 32, 40, 32, 16), (4, 2, 48, 16, 500, 16, 16),
+    (2, 1, 96, 16, 32, 32, 32)])
+def test_grouped_queries_in_a_window_match_the_exact_attention(
+        h, g, s, d, window, bq, bk):
+    from horovod_tpu.ops.flash_attention import gqa_window_attention
+
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(s + window), 3)
+    q = jax.random.normal(kq, (h, s, d), jnp.float32)
+    k = jax.random.normal(kk, (g, s, d), jnp.float32)
+    v = jax.random.normal(kv, (g, s, d), jnp.float32)
+    o = gqa_window_attention(q, k, v, window=window, block_q=bq,
+                             block_k=bk, interpret=True)
+    assert jnp.max(jnp.abs(o - _gqa_reference(q, k, v, window))) < TOL
+
+
+def test_key_blocks_outside_the_window_are_never_read():
+    """Keys a whole block or more behind every query's window are NaN:
+    a block the kernel fetched and masked would still poison the sums
+    (0 x NaN); one it skips cannot."""
+    from horovod_tpu.ops.flash_attention import gqa_window_attention
+
+    h, g, s, d, window, block = 4, 2, 128, 16, 16, 16
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (h, s, d), jnp.float32)
+    k = jax.random.normal(kk, (g, s, d), jnp.float32)
+    v = jax.random.normal(kv, (g, s, d), jnp.float32)
+    want = _gqa_reference(q, k, v, window)[:, -block:]
+    # The last block of queries sees keys s - block - window + 1 .. s - 1.
+    dead = s - block - window + 1
+    dead -= dead % block
+    k = k.at[:, :dead].set(jnp.nan)
+    v = v.at[:, :dead].set(jnp.nan)
+    o = gqa_window_attention(q, k, v, window=window, block_q=block,
+                             block_k=block, interpret=True)[:, -block:]
+    assert bool(jnp.all(jnp.isfinite(o)))
+    assert jnp.max(jnp.abs(o - want)) < TOL
+    with pytest.raises(ValueError, match="whole groups"):
+        gqa_window_attention(q[:3], k, v, interpret=True)
+
+
+# ---------------------------------------------------------------------------
 # The resident kernels through the TPU's own compiler, for a described v5e
 # (no chip: nothing runs; Mosaic refuses here what it would refuse there)
 # ---------------------------------------------------------------------------
@@ -451,3 +517,24 @@ def test_latent_paged_attention_compiles_for_the_v5e(v5e_chip, slots, pps,
     assert not any(op in text for op in (" sort(", " gather(",
                                          " conditional("))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+
+# The prompt's attention of `trinity-serve-mixed` (models/afmoe.py: 48 query
+# on 8 key/value heads of 128, a window of 4096 or none) at its longest
+# bucket, its shortest, and the engine's capacity: ONE kernel, no head is
+# repeated, under 0.3 GB of temporaries (the float32 scores of the blockwise
+# twin are 6.4 GB a layer at 8192).
+@pytest.mark.parametrize("s,window", [(8192, 4096), (8192, 0), (256, 4096),
+                                      (9216, 4096)])
+def test_gqa_window_kernel_compiles_for_the_v5e(v5e_chip, s, window):
+    from horovod_tpu.ops.flash_attention import gqa_window_attention
+
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((48, s, 128), bf, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((8, s, 128), bf, sharding=v5e_chip)
+    compiled = jax.jit(lambda q, k, v: gqa_window_attention(
+        q, k, v, window=window)).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "gqa_flash_fwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6

@@ -326,7 +326,9 @@ def test_latent_view_tokens_is_declared_for_the_two_latent_cells():
 
     bench = cells.load_benchmark()
     mod = cells.load_module("metrics", "latent_view_tokens")
-    assert bench["per_layer"][-1] == {
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "latent_view_tokens"]
+    assert entry == {
         "name": "latent_view_tokens", "unit": mod.UNIT, "better": mod.BETTER,
         "source": mod.SOURCE, "layer": mod.LAYER, "moves": mod.MOVES,
         "workloads": ["axk1-serve-decode", "longcat-serve-turns"]}
