@@ -35,9 +35,13 @@ expert layer and its counters.
 
 **The cache**: two layer GROUPS of the paged store (serving/kv_cache.py):
 ``full`` (the full layers: every position) and ``window`` (the sliding
-layers: a ring of ``ceil(window / page) + 1`` pages a slot).  The decode
-program gathers a group's live pages into one shared view, a chunk list as
-long as the slots alive need together, and attends the rung that holds it.
+layers: a ring of ``ceil(window / page) + 1`` pages a slot).  On the TPU
+the decode program attends a group's pages where they lie, through a
+kernel that walks the group's page table for the slots alive
+(``ops/gqa_paged_attention.py``: the ring is its mask's).  Its twin
+elsewhere gathers a group's live pages into one shared view, a chunk list
+as long as the slots alive need together, and attends the rung that holds
+it; :func:`paged_kernel_runs` says which, from the backend.
 """
 
 from __future__ import annotations
@@ -50,7 +54,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..memory.planner import ring_entries
+from ..ops import gqa_paged_attention as _paged
 from ..ops.flash_attention import gqa_window_attention
+from ..ops.gqa_paged_attention import mapped_entries
 from ..parallel.expert import (moe_layer_held, route_sigmoid_bias_top_k,
                                swiglu)
 from .hybrid_ssm import (_M_SHARED_KV, _M_WINDOW, PREFILL_Q_BLOCK, _dot,
@@ -61,6 +67,10 @@ from .mamba2_hybrid import attend_chunks, rms_norm
 SLIDING, FULL = "sliding_attention", "full_attention"
 # A test's: run the prompt's flash kernel in the Pallas interpreter.
 FLASH_INTERPRET = False
+# ``ops/gqa_paged_attention.py``'s ``interpret``: None is the rule (the
+# kernel on the TPU, the view ladder elsewhere); a test sets True to run the
+# kernel in the Pallas interpreter, before the engine builds its programs.
+PAGED_INTERPRET = None
 # A rung of the decode's chunk ladder over the next longer one.
 LADDER_STEP = 0.8
 
@@ -388,13 +398,6 @@ def pool_ladder(slots: int, a_slot: int, view_chunks: int) -> tuple:
     return tuple(reversed(rungs + [a_slot]))
 
 
-def mapped_entries(cached, entries: int, page_size: int):
-    """Table entries that hold a cached position of a slot with ``cached``
-    positions: all its pages, and of a ring at most its length."""
-    xp = np if isinstance(cached, np.ndarray) else jnp
-    return xp.minimum((cached + page_size - 1) // page_size, entries)
-
-
 def ring_chunks(table, cached, chunk: int, page_size: int, window: int,
                 n_chunks: int):
     """Where each chunk of the shared view comes from, for a group whose
@@ -435,46 +438,56 @@ def ring_chunks(table, cached, chunk: int, page_size: int, window: int,
     return pages, mask.reshape(n_chunks, chunk), owner, mine, ends[-1]
 
 
-def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
-    """One token a slot.  ``tokens [slots]``; ``lengths [slots]``: the
-    position of the new token, the count of cached ones (-1: an idle
-    slot); ``stores = (full_k, full_v [full layers, pages, page,
-    kv_width], win_k, win_v [sliding layers, ...], view [2, slots, view
-    positions a slot, kv_width])``; ``table [slots, pages a slot + ring
-    entries]``, the two groups' tables side by side.
+def paged_kernel_runs() -> bool:
+    """Whether the decode program attends through the kernel that walks
+    the groups' page tables: read off the backend the program is built for
+    (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
+    return _paged.use_kernel(PAGED_INTERPRET)
 
-    ``view`` is ONE layer's room: each layer in turn gathers its own
-    group's live pages of its own paged layer into it as chunks
+
+def paged_attend(lengths, groups, cfg: AfmoeConfig, interpret=None):
+    """The decode step's ``attend`` through the kernel
+    (``ops/gqa_paged_attention.py``): the live slots' own pages of a
+    layer's group, read where they lie, the ring in the kernel's mask.
+    ``groups``: ``{kind: (table, window, k_pages, v_pages)}``."""
+    order, n_live = _paged.live_first(lengths)
+
+    def attend(kind, layer, q, k_self, v_self):
+        table, window, k_pages, v_pages = groups[kind]
+        return _paged.gqa_paged_attention(
+            q, k_self, v_self, k_pages, v_pages, table, lengths, layer,
+            heads=cfg.num_attention_heads, scale=cfg.attention_multiplier,
+            window=window, order=order, n_live=n_live, interpret=interpret)
+
+    return attend
+
+
+def view_ladder_attend(lengths, groups, view, cfg: AfmoeConfig):
+    """The decode step's ``attend`` over a gathered view, the kernel's twin
+    off the TPU.  ``view [2, slots, view positions a slot, kv_width]`` is
+    ONE layer's room: each layer in turn gathers its own group's live
+    pages of its own paged layer into it as chunks
     (``hybrid_ssm.fill_view``) and attends the leading chunks that hold
     the list, how many a rung of the group's ladder picked INSIDE the
-    program from ``lengths``.  The new token's own key and value are not
-    in the view; the caller writes them to both groups at the end.
-
-    Returns ``(logits [slots, vocab], k [layers, slots, kv_width], v,
-    counts [expert layers, held], view)``."""
-    full_k, full_v, win_k, win_v, view = stores
-    b = tokens.shape[0]
-    f32 = jnp.float32
-    ps = full_k.shape[2]
-    ring = ring_entries(cfg.sliding_window, ps)
-    pps = table.shape[1] - ring
+    program from ``lengths``.  Returns ``(attend, left)``: ``left()`` is
+    ``(view,)`` as the last layer left it."""
+    b = lengths.shape[0]
     chunk = cfg.decode_chunk_tokens
+    ps = groups[FULL][2].shape[2]
     if chunk % ps:
         raise ValueError(f"decode_chunk_tokens {chunk} is not whole pages "
                          f"of {ps}")
     per = chunk // ps
     cached = jnp.clip(lengths, 0, None)
-    chunks = view.reshape(2, -1, chunk, view.shape[-1])
-    groups = {}
-    for name, tab, entries, window, k_pages, v_pages in (
-            (FULL, table[:, :pps], pps, 0, full_k, full_v),
-            (SLIDING, table[:, pps:], ring, cfg.sliding_window, win_k,
-             win_v)):
-        rungs = pool_ladder(b, -(-entries // per), chunks.shape[1])
-        index = ring_chunks(tab, cached, chunk, ps, window, rungs[-1])
+    held = [view.reshape(2, -1, chunk, view.shape[-1])]
+    ladders = {}
+    for kind, (table, window, _, _) in groups.items():
+        entries = table.shape[1]
+        rungs = pool_ladder(b, -(-entries // per), held[0].shape[1])
+        index = ring_chunks(table, cached, chunk, ps, window, rungs[-1])
         picked = chunk_rung(mapped_entries(cached, entries, ps) * ps, rungs,
                             chunk)
-        groups[name] = (rungs, index, picked, k_pages, v_pages)
+        ladders[kind] = (rungs, index, picked)
 
     def over(n, index, chunks, q, k_self, v_self):
         _, mask, owner, mine, _ = index
@@ -482,20 +495,58 @@ def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
                              (chunks[0, :n], chunks[1, :n], mask[:n],
                               owner[:n], mine[:n]), cfg)
 
+    def attend(kind, layer, q, k_self, v_self):
+        rungs, index, picked = ladders[kind]
+        _, _, k_pages, v_pages = groups[kind]
+        held[0] = fill_view(held[0], k_pages, v_pages, index[0], index[4],
+                            rungs[0], layer=layer)
+        return jax.lax.switch(picked,
+                              [partial(over, n, index) for n in rungs],
+                              held[0], q, k_self, v_self)
+
+    return attend, lambda: (held[0].reshape(view.shape),)
+
+
+def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
+    """One token a slot.  ``tokens [slots]``; ``lengths [slots]``: the
+    position of the new token, the count of cached ones (-1: an idle
+    slot); ``stores = (full_k, full_v [full layers, pages, page,
+    kv_width], win_k, win_v [sliding layers, ...])`` and, where the view
+    ladder attends, behind them ``view [2, slots, view positions a slot,
+    kv_width]``; ``table [slots, pages a slot + ring entries]``, the two
+    groups' tables side by side.
+
+    Each layer attends its own group's pages of its own paged layer: on
+    the TPU where they lie (:func:`paged_attend`), elsewhere through the
+    view (:func:`view_ladder_attend`); :func:`paged_kernel_runs` says
+    which.  The new token's own key and value are not in the store; the
+    caller writes them to both groups at the end.
+
+    Returns ``(logits [slots, vocab], k [layers, slots, kv_width], v,
+    counts [expert layers, held], view)``, ``view`` a tuple: the view as
+    the ladder left it, or whatever came (the kernel touches none)."""
+    full_k, full_v, win_k, win_v, *view = stores
+    f32 = jnp.float32
+    ring = ring_entries(cfg.sliding_window, full_k.shape[2])
+    pps = table.shape[1] - ring
+    cached = jnp.clip(lengths, 0, None)
+    groups = {FULL: (table[:, :pps], 0, full_k, full_v),
+              SLIDING: (table[:, pps:], cfg.sliding_window, win_k, win_v)}
+    if paged_kernel_runs():
+        attend = paged_attend(lengths, groups, cfg, PAGED_INTERPRET)
+        left = lambda: tuple(view)
+    else:
+        attend, left = view_ladder_attend(lengths, groups, *view, cfg)
+
     x = params["embed"][tokens].astype(f32) * cfg.embedding_multiplier
     new_k, new_v, counts = [], [], []
     seen = {FULL: 0, SLIDING: 0}
     for kind, lp in zip(cfg.layer_types, params["layers"]):
-        rungs, index, picked, k_pages, v_pages = groups[kind]
         with jax.named_scope("gqa_attention"):
             h = rms_norm(x, lp["norm_in"], cfg.rms_norm_eps, cfg.dtype)
             q, k, v, gate = project(h, lp["attn"], cached, kind == SLIDING,
                                     cfg)
-            chunks = fill_view(chunks, k_pages, v_pages, index[0], index[4],
-                               rungs[0], layer=seen[kind])
-            a = jax.lax.switch(picked,
-                               [partial(over, n, index) for n in rungs],
-                               chunks, q, k, v)
+            a = attend(kind, seen[kind], q, k, v)
             x = x + rms_norm(gate_out(a, gate, lp["attn"], cfg),
                              lp["norm_post_attn"], cfg.rms_norm_eps, f32)
         seen[kind] += 1
@@ -505,14 +556,15 @@ def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
         if n is not None:
             counts.append(n)
     return (head(x, params, cfg), jnp.stack(new_k), jnp.stack(new_v),
-            jnp.stack(counts), chunks.reshape(view.shape))
+            jnp.stack(counts), left())
 
 
 # -- what the serving engine asks ---------------------------------------------
 
 class AfmoeServing:
     """The serving protocol (serving/models.py) for this model: two paged
-    layer groups and one scratch store."""
+    layer groups and, where the view ladder attends them
+    (:func:`paged_kernel_runs` says no), one scratch store."""
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False
@@ -550,21 +602,25 @@ class AfmoeServing:
                 "dtype": jnp.dtype(c.dtype).name}
 
     def cache_entry(self) -> dict:
-        """Keys and values, in two layer groups; the shared view is sized
-        by the cache manager from its pools (``"view"``)."""
+        """Keys and values, in two layer groups.  Where the view ladder
+        attends them, the shared view beside them, sized by the cache
+        manager from its pools (``"view"``); the kernel reads the pages in
+        place and asks for none."""
         c = self.cfg
-        return {"n_layers": len(self.full),
-                "n_heads": c.num_key_value_heads, "head_dim": c.head_dim,
-                "widths": (c.kv_width,) * 2,
-                "groups": ({"name": "full", "n_layers": len(self.full)},
-                           {"name": "window", "n_layers": len(self.sliding),
-                            "window": c.sliding_window}),
-                "view_chunk": c.decode_chunk_tokens,
-                "slot_stores": (
-                    # ONE layer's view: the layers gather and attend in
-                    # turn, each at one layer's size.
-                    {"name": "paged_view", "kind": "scratch",
-                     "shape": (2, "view", c.kv_width), "dtype": c.dtype},)}
+        entry = {"n_layers": len(self.full),
+                 "n_heads": c.num_key_value_heads, "head_dim": c.head_dim,
+                 "widths": (c.kv_width,) * 2,
+                 "groups": ({"name": "full", "n_layers": len(self.full)},
+                            {"name": "window", "n_layers": len(self.sliding),
+                             "window": c.sliding_window})}
+        if not paged_kernel_runs():
+            entry["view_chunk"] = c.decode_chunk_tokens
+            # ONE layer's view: the layers gather and attend in turn, each
+            # at one layer's size.
+            entry["slot_stores"] = (
+                {"name": "paged_view", "kind": "scratch",
+                 "shape": (2, "view", c.kv_width), "dtype": c.dtype},)
+        return entry
 
     def observe_stores(self, nbytes: dict) -> None:
         """Bytes of the per-slot stores by kind, once at build: the view's
@@ -584,13 +640,16 @@ class AfmoeServing:
             chunk))]
 
     def decode_view(self, lengths, rungs, page_size=None) -> float:
-        """Positions of view a slot a layer the decode program attends at
-        these (host) lengths: each group's rung, weighted by its layers,
-        over the slots."""
-        full = self._rung_tokens(lengths, rungs[-1] // page_size, page_size)
-        window = self._rung_tokens(
-            lengths, ring_entries(self.cfg.sliding_window, page_size),
-            page_size)
+        """Positions a slot a layer the decode program attends at these
+        (host) lengths, by the rule the program follows: where the kernel
+        runs, what it copies (each group's entries in use of the live
+        slots, whole pages); on the ladder each group's rung; weighted by
+        the groups' layers, over the slots."""
+        read = (_paged.tokens_read if paged_kernel_runs()
+                else self._rung_tokens)
+        full = read(lengths, rungs[-1] // page_size, page_size)
+        window = read(lengths, ring_entries(self.cfg.sliding_window,
+                                            page_size), page_size)
         n_f, n_w = len(self.full), len(self.sliding)
         return ((n_f * full + n_w * window) / (n_f + n_w) / len(lengths))
 
@@ -639,13 +698,13 @@ class AfmoeServing:
                 win_k, k_w[:, slot][:, None, None, :], at)
             win_v = jax.lax.dynamic_update_slice(
                 win_v, v_w[:, slot][:, None, None, :], at)
-        return (logits, counts), (full_k, full_v, win_k, win_v, view)
+        return (logits, counts), (full_k, full_v, win_k, win_v, *view)
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens):
         """``start`` is always 0 here (``prefix_cache`` is off).  The full
         group takes every page of the prompt; the window group the last
         ``ring`` pages' worth, each into its ring entry."""
-        full_k, full_v, win_k, win_v, view = pages
+        full_k, full_v, win_k, win_v, *view = pages
         ps, bucket = full_k.shape[2], tokens.shape[1]
         ring = ring_entries(self.cfg.sliding_window, ps)
         pps = table_row.shape[1] - ring
@@ -680,4 +739,4 @@ class AfmoeServing:
                                            (full_k, full_v))
         win_k, win_v = jax.lax.fori_loop(0, min(ring, n_pages), write_ring,
                                          (win_k, win_v))
-        return (logits,), (full_k, full_v, win_k, win_v, view)
+        return (logits,), (full_k, full_v, win_k, win_v, *view)

@@ -21,9 +21,11 @@ import pytest
 from benchmark import cells
 from benchmark.builders.afmoe import config_of, seeded_params
 from horovod_tpu.models import afmoe as af
+from horovod_tpu.ops import gqa_paged_attention as gpa
 from horovod_tpu.parallel import expert as ex
 from horovod_tpu.serving import InferenceEngine
 from test_hybrid_ssm import counter, rollout
+from test_latent_paged_attention import _primitives, _Run
 
 REF = cells.load_module("refs", "trinity-large-ep8")
 FLOPS = cells.load_module("flops", "trinity-large-ep8")
@@ -597,3 +599,169 @@ def test_the_ladder_of_a_pool_ends_at_what_the_view_holds():
     assert af.pool_ladder(4, 17, 17) == (17,)
     assert af.pool_ladder(8, 16, 128) == (16, 20, 25, 32, 40, 51, 64, 81,
                                           102, 128)
+
+
+# -- decode through the paged kernel (ops/gqa_paged_attention.py) -------------
+
+@functools.lru_cache(maxsize=None)
+def _kernel_engine():
+    eng = InferenceEngine(params(), CFG, max_slots=8, page_size=PAGE,
+                          capacity=128)
+    eng.warm_start()
+    return eng
+
+
+def kernel_engine(monkeypatch):
+    """The engine with the kernel in its decode program, interpreted:
+    ``PAGED_INTERPRET`` is read when the cache and the programs are built
+    and when the host counts what a launch attends."""
+    monkeypatch.setattr(af, "PAGED_INTERPRET", True)
+    return _kernel_engine()
+
+
+# Both sides of the window of 8, as the ladder's cases above: never
+# reaching it; starting under it and wrapping the ring of pages more than
+# once; a prompt longer than twice the window; ragged slots of all kinds.
+@pytest.mark.parametrize("lengths,new", [
+    ((3,), (3,)), ((5,), (30,)), ((30,), (20,)), ((8,), (9,)),
+    ((6, 19, 40), (9, 14, 25))])
+def test_prefill_then_decode_through_the_kernel_equals_the_reference(
+        monkeypatch, lengths, new):
+    eng = kernel_engine(monkeypatch)
+    assert len(eng.cache.arrays) == 4          # no view beside the pages
+    prompts = [prompt(100 + n, n) for n in lengths]
+    views = counter("serving.decode_view_tokens")
+    got = rollout(eng, prompts, new)
+    check_against_reference(prompts, new, got)
+    assert all_pages_are_back(eng) == {"full": (0, 8 * 32),
+                                       "window": (0, 8 * RING)}
+    # Iteration i (0-based) attends the slots with more than i + 1 tokens
+    # to give, each at its prompt's length plus i cached positions: what
+    # the kernel copies of the full group's one layer and of the window
+    # group's four, over the five layers and the eight slots.
+    read = 0
+    for i in range(max(new) - 1):
+        at = [n + i if i + 1 < k else -1 for n, k in zip(lengths, new)]
+        read += (gpa.tokens_read(at, 32, PAGE)
+                 + 4 * gpa.tokens_read(at, RING, PAGE)) / 5 / 8
+    assert counter("serving.decode_view_tokens") - views \
+        == pytest.approx(read)
+
+
+def _attention_program(monkeypatch, interpret):
+    """The decode step's attention alone, one full and one sliding layer,
+    as ``decode_step`` builds it: its primitives and what it returns."""
+    monkeypatch.setattr(af, "PAGED_INTERPRET", interpret)
+    rng = np.random.RandomState(0)
+    slots, pps, kw = 4, 8, CFG.kv_width
+    lengths = jnp.asarray([13, -1, 30, 5], jnp.int32)
+    table = jnp.asarray(1 + rng.permutation(slots * (pps + RING)).reshape(
+        slots, pps + RING), jnp.int32)
+    n_pages = 1 + slots * (pps + RING)
+    full_k, full_v = (jnp.asarray(rng.randn(1, n_pages, PAGE, kw),
+                                  jnp.float32) for _ in range(2))
+    win_k, win_v = (jnp.asarray(rng.randn(2, n_pages, PAGE, kw),
+                                jnp.float32) for _ in range(2))
+    q, k, v = (jnp.asarray(rng.randn(slots, w), jnp.float32)
+               for w in (CFG.q_width, kw, kw))
+    view = jnp.zeros((2, slots, pps * PAGE, kw), jnp.float32)
+
+    def f(lengths, table, full_k, full_v, win_k, win_v, view, q, k, v):
+        groups = {af.FULL: (table[:, :pps], 0, full_k, full_v),
+                  af.SLIDING: (table[:, pps:], WINDOW, win_k, win_v)}
+        if af.paged_kernel_runs():
+            attend = af.paged_attend(lengths, groups, CFG,
+                                     af.PAGED_INTERPRET)
+        else:
+            attend, _ = af.view_ladder_attend(lengths, groups, view, CFG)
+        return attend(af.FULL, 0, q, k, v), attend(af.SLIDING, 1, q, k, v)
+
+    args = (lengths, table, full_k, full_v, win_k, win_v, view, q, k, v)
+    return (_primitives(jax.make_jaxpr(f)(*args).jaxpr), jax.jit(f)(*args),
+            np.asarray(lengths) >= 0)
+
+
+def test_off_the_tpu_the_ladder_runs_unless_the_interpreter_is_asked_for(
+        monkeypatch):
+    """The rule is the backend's (``ops/ssd.py``'s): on the CPU the decode
+    program is the view ladder it was, a conditional around a gather; with
+    the kernel forced neither is left and no view is asked of the cache,
+    and both give the same attention in both groups."""
+    assert jax.default_backend() == "cpu" and not af.paged_kernel_runs()
+    ladder, want, on = _attention_program(monkeypatch, None)
+    assert {"cond", "gather", "while"} <= ladder
+    assert "pallas_call" not in ladder
+    assert "slot_stores" in CFG.serving_model().cache_entry()
+    kernel, got, _ = _attention_program(monkeypatch, True)
+    assert af.paged_kernel_runs()
+    assert "pallas_call" in kernel
+    assert not kernel & {"cond", "gather", "sort", "scatter", "while"}
+    entry = CFG.serving_model().cache_entry()
+    assert "slot_stores" not in entry and "view_chunk" not in entry
+    assert [g["name"] for g in entry["groups"]] == ["full", "window"]
+    for a, b in zip(got, want):
+        assert np.abs(np.asarray(a - b))[on].max() < TOL
+
+
+def test_decode_view_is_what_the_kernel_copies_of_both_groups(monkeypatch):
+    """A fixed batch with idle slots at the cell's sizes (page 16, 576
+    pages a slot, a ring of 257): the full group's one layer copies every
+    live slot's pages, the window group's four at most the ring's; the
+    mean over the five layers and the 8 slots.  On the ladder the same
+    lengths ride rungs."""
+    model = config_of(PUBLISHED).serving_model()
+    lengths = np.asarray([899, -1, 0, 8191, -1, 4095, 4096, 5000], np.int32)
+    rungs = (16, 576 * 16)
+    monkeypatch.setattr(af, "PAGED_INTERPRET", True)
+    full = 912 + 0 + 8192 + 4096 + 4096 + 5008
+    window = 912 + 0 + 257 * 16 + 4096 + 4096 + 257 * 16
+    assert model.decode_view(lengths, rungs, 16) == pytest.approx(
+        (full + 4 * window) / 5 / 8)
+    assert model.decode_view(np.full((8,), -1, np.int32), rungs, 16) == 0
+    monkeypatch.setattr(af, "PAGED_INTERPRET", None)
+    model.observe_stores({"scratch": 2 * 940 * 256 * 1024 * 2})
+    # Rungs of whole chunks of 256, each no shorter than the chunks in use.
+    assert model.decode_view(lengths, rungs, 16) > (full + 4 * window) / 5 / 8
+
+
+# -- the counter's reader (benchmark/metrics/gqa_view_tokens.py) --------------
+
+def test_gqa_view_tokens_is_declared_for_the_cell():
+    bench = cells.load_benchmark()
+    mod = cells.load_module("metrics", "gqa_view_tokens")
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "gqa_view_tokens"]
+    assert entry == {
+        "name": "gqa_view_tokens", "unit": mod.UNIT, "better": mod.BETTER,
+        "source": mod.SOURCE, "layer": mod.LAYER, "moves": mod.MOVES,
+        "workloads": ["trinity-serve-mixed"]}
+    assert bench["per_layer"][-1] == entry      # appended, nothing moved
+    assert (mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER, mod.MOVES) == (
+        "tokens", "lower", "program_counter", "grouped-query attention",
+        "tpot_p90_ms")
+    for cell in (w["name"] for w in bench["workloads"]):
+        listed = [m["name"] for m in cells.resolve(bench, cell)["per_layer"]]
+        assert ("gqa_view_tokens" in listed) == (
+            cell == "trinity-serve-mixed")
+
+
+@pytest.mark.parametrize("before,after,want", [
+    # 500 iterations: 300 that copied 405 positions a slot a layer, 200
+    # that copied 380.
+    ({"serving.decode_view_tokens": {"value": 1280.0},
+      "serving.decode_iterations": {"value": 5}},
+     {"serving.decode_view_tokens": {"value": 1280.0 + 300 * 405 + 200 * 380},
+      "serving.decode_iterations": {"value": 505}}, 395.0),
+    # No decode in the window; a program without the counter; no serving.
+    ({"serving.decode_view_tokens": {"value": 640},
+      "serving.decode_iterations": {"value": 5}},
+     {"serving.decode_view_tokens": {"value": 640},
+      "serving.decode_iterations": {"value": 5}}, None),
+    ({"serving.decode_iterations": {"value": 5}},
+     {"serving.decode_iterations": {"value": 55}}, None),
+    ({}, {}, None)])
+def test_gqa_view_tokens_is_the_view_counter_over_the_iterations(
+        before, after, want):
+    got = cells.load_module("metrics", "gqa_view_tokens").read(
+        _Run(before, after))
+    assert got == (want if want is None else pytest.approx(want))

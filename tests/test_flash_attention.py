@@ -538,3 +538,38 @@ def test_gqa_window_kernel_compiles_for_the_v5e(v5e_chip, s, window):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and "gqa_flash_fwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+
+
+# The decode's attention of `trinity-serve-mixed` (ops/gqa_paged_attention.
+# py) at the cell's two groups: the full group's table of 576 pages a slot
+# over its one layer, the window group's ring of 257 over its four; two
+# layers in a row over the WHOLE stores (0.45 and 1.45 GB each of keys and
+# of values), which stay arguments: nothing of them is copied, and neither a
+# gather nor a conditional nor a sort is left around the calls.
+@pytest.mark.parametrize("entries,layers,pages,window", [
+    (576, 1, 13764, 0), (257, 4, 11054, 4096)])
+def test_gqa_paged_attention_compiles_for_the_v5e(v5e_chip, entries, layers,
+                                                  pages, window):
+    from horovod_tpu.ops import gqa_paged_attention as gpa
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(q, k_self, v_self, k_pages, v_pages, table, lengths):
+        order, n_live = gpa.live_first(lengths)
+        return sum(gpa.gqa_paged_attention(
+            q * (1 + i), k_self, v_self, k_pages, v_pages, table, lengths,
+            layer, heads=48, scale=128 ** -0.5, window=window, order=order,
+            n_live=n_live, interpret=False).astype(jnp.float32)
+            for i, layer in enumerate((0, layers - 1)))
+
+    compiled = jax.jit(two_layers).lower(
+        sd(64, 48 * 128), sd(64, 1024), sd(64, 1024),
+        sd(layers, pages, 16, 1024), sd(layers, pages, 16, 1024),
+        sd(64, entries, dtype=jnp.int32), sd(64, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "gqa_paged_attn" in text
+    assert not any(op in text for op in (" sort(", " gather(",
+                                         " conditional("))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

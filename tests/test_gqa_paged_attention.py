@@ -1,0 +1,294 @@
+"""The paged grouped-query kernel (ops/gqa_paged_attention.py) in the Pallas
+interpreter against its twin, ``mamba2_hybrid.attend_chunks`` over a view
+gathered by ``afmoe.ring_chunks``: a table that holds every page (the full
+group), a ring that has not wrapped, one that has wrapped once and several
+times."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import afmoe as af
+from horovod_tpu.models import mamba2_hybrid as m2
+from horovod_tpu.ops import gqa_paged_attention as gpa
+
+# 32 query heads on 4 key/value heads make a block of 1024 tokens (64 pages
+# of 16), so a slot of 80 pages is a block and a quarter and a ring of 129
+# entries two blocks and one page; the heads are 16 wide.
+CFG = af.AfmoeConfig(
+    num_attention_heads=32, num_key_value_heads=4, head_dim=16,
+    hidden_size=64, num_hidden_layers=2, num_dense_layers=1,
+    layer_types=(af.SLIDING, af.FULL), dtype=jnp.float32)
+PAGE, PPS, LAYERS, SLOTS = 16, 80, 3, 8
+BLOCK = PAGE * gpa.block_pages(PAGE, PPS, 32, CFG.kv_width, 4)
+# The issue's small ring: window 48 in pages of 16 is 4 entries, one block.
+WINDOW, RING = 48, af.ring_entries(48, PAGE)
+# A ring longer than two blocks: 129 entries.
+WIDE, WIDE_RING = 2048, af.ring_entries(2048, PAGE)
+# float32 operands on both sides: what differs is the order of float32
+# sums (a block at a time, the new key first).  A softmax whose scores
+# were rounded to bfloat16 misses it by two decades
+# (test_a_bfloat16_softmax_would_fail).
+TOL = 2e-5
+
+
+def case(lengths, entries, seed=0, dtype=jnp.float32, nan_elsewhere=False,
+         consecutive=False):
+    """Two stores of ``LAYERS`` layers, a table of ``entries`` a slot
+    (permuted unless ``consecutive``), one query and one new key and value
+    a slot; ``SLOTS`` slots, those past ``lengths`` idle (one shape a
+    table: the tests share its compiled programs).  With ``nan_elsewhere``
+    every page that holds no entry in use of a live slot, page 0 among
+    them, is NaN in every layer."""
+    lengths = np.asarray(tuple(lengths) + (-1,) * (SLOTS - len(lengths)),
+                         np.int32)
+    slots = SLOTS
+    n_pages = slots * entries + 1
+    rng = np.random.RandomState(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    kw = CFG.kv_width
+    k_pages = jax.random.normal(ks[0], (LAYERS, n_pages, PAGE, kw))
+    v_pages = jax.random.normal(ks[1], (LAYERS, n_pages, PAGE, kw))
+    pages = np.arange(1, n_pages)
+    table = (pages if consecutive else rng.permutation(pages)).reshape(
+        slots, entries).astype(np.int32)
+    if nan_elsewhere:
+        owned = np.zeros(n_pages, bool)
+        for s, n in enumerate(lengths):
+            if n >= 0:
+                owned[table[s, :int(gpa.mapped_entries(n, entries, PAGE))]
+                      ] = True
+        k_pages, v_pages = (jnp.where(owned[None, :, None, None], x, jnp.nan)
+                            for x in (k_pages, v_pages))
+    cast = lambda x: x.astype(dtype)
+    return dict(lengths=jnp.asarray(lengths), table=jnp.asarray(table),
+                k_pages=cast(k_pages), v_pages=cast(v_pages),
+                q=cast(jax.random.normal(ks[2], (slots, CFG.q_width))),
+                k_self=cast(jax.random.normal(ks[3], (slots, kw))),
+                v_self=cast(jax.random.normal(ks[4], (slots, kw))))
+
+
+@functools.lru_cache(maxsize=None)
+def _twin(window, softmax_dtype):
+    def f(c, layer):
+        slots, entries = c["table"].shape
+        chunk = 2 * PAGE
+        n_chunks = slots * -(-entries // 2)
+        cached = jnp.clip(c["lengths"], 0, None)
+        pages, mask, owner, mine, _ = af.ring_chunks(
+            c["table"], cached, chunk, PAGE, window, n_chunks)
+        k, v = (jnp.where(mask[..., None],
+                          x[layer][pages].reshape(n_chunks, chunk, -1), 0)
+                for x in (c["k_pages"], c["v_pages"]))
+        o = m2.attend_chunks(c["q"], c["k_self"], c["v_self"],
+                             (k, v, mask, owner, mine), CFG)
+        return jnp.where(c["lengths"][:, None] >= 0, o, 0)
+
+    # Jitted: XLA's CPU client has no eager bfloat16 dot.
+    return jax.jit(f)
+
+
+def over_a_gathered_view(c, layer, window=0, softmax_dtype=None):
+    """``attend_chunks`` over every slot's entries in use, gathered by
+    ``ring_chunks`` two pages a chunk: the ladder's arithmetic on one full
+    rung.  Rows the mask hides are zeroed (a hidden NaN would reach the
+    product as ``0 * NaN``); an idle slot's row is its new value alone,
+    which the decode program never reads: zeroed as the kernel writes
+    it."""
+    if softmax_dtype is None:
+        return _twin(window, None)(c, layer)
+    # The same attention with its scores rounded on their way to the
+    # softmax: what the tolerance has to catch.
+    orig = m2._masked_exp
+    m2._masked_exp = lambda s, mask, m: orig(
+        s.astype(softmax_dtype).astype(jnp.float32), mask,
+        m.astype(softmax_dtype).astype(jnp.float32))
+    try:
+        return _twin(window, softmax_dtype)(c, layer)
+    finally:
+        m2._masked_exp = orig
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(window):
+    return jax.jit(lambda c, layer: gpa.gqa_paged_attention(
+        c["q"], c["k_self"], c["v_self"], c["k_pages"], c["v_pages"],
+        c["table"], c["lengths"], layer, heads=CFG.num_attention_heads,
+        scale=CFG.attention_multiplier, window=window, interpret=True))
+
+
+def through_the_kernel(c, layer, window=0):
+    return _kernel(window)(c, layer)
+
+
+def gap(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+# The full group (a table of every page, no window): ragged lengths with
+# idle slots between the live ones; at, one under and one over a page edge
+# and a block edge; slots that hold nothing but their new token; a full
+# slot.  The small ring (4 entries, window 48) before it wraps (up to 64
+# positions: lengths at 0, at a page's edge, at exactly the window and one
+# past it), wrapped once (65..128) and several times.  The ring of 129
+# entries, two blocks and a page: unwrapped, exactly full, wrapped.
+@pytest.mark.parametrize("entries,window,lengths", [
+    (PPS, 0, (300, -1, 37, -1, -1, 600, 5)),
+    (PPS, 0, (0, PAGE - 1, PAGE, PAGE + 1, -1, 2 * PAGE, 1, 0)),
+    (PPS, 0, (BLOCK - 1, BLOCK, BLOCK + 1, -1, BLOCK + PAGE,
+              PAGE * PPS - 1)),
+    (RING, WINDOW, (0, 1, PAGE - 1, PAGE, PAGE + 1, -1, WINDOW - 1, WINDOW)),
+    (RING, WINDOW, (WINDOW + 1, 63, 64, -1, 33, 2 * PAGE)),
+    (RING, WINDOW, (65, 66, 80, 81, -1, 96, 127, 128)),
+    (RING, WINDOW, (129, 200, 500, -1, 1000, 4097, 64 * 7, 64 * 7 + 1)),
+    (WIDE_RING, WIDE, (700, -1, WIDE, WIDE + 1, WIDE + PAGE, 3)),
+    (WIDE_RING, WIDE, (WIDE + PAGE + 1, 2100, -1, 5555, 129 * 16 * 3))])
+def test_kernel_equals_attention_over_a_gathered_view(entries, window,
+                                                      lengths):
+    c = case(lengths, entries, seed=len(lengths) + entries)
+    got = through_the_kernel(c, 1, window)
+    want = over_a_gathered_view(c, 1, window)
+    on = np.asarray(c["lengths"]) >= 0
+    assert gap(got[on], want[on]) < TOL
+    # Idle slots: exact zeros.
+    assert not np.asarray(got)[~on].any()
+
+
+@pytest.mark.parametrize("entries,window,lengths", [
+    (PPS, 0, (300, 37, 600, 5)), (RING, WINDOW, (300, 37, 600, 50))])
+def test_a_bfloat16_softmax_would_fail(entries, window, lengths):
+    c = case(lengths, entries, seed=3)
+    want = over_a_gathered_view(c, 1, window)
+    assert gap(through_the_kernel(c, 1, window), want) < TOL
+    assert gap(over_a_gathered_view(c, 1, window, jnp.bfloat16),
+               want) > 100 * TOL
+
+
+@pytest.mark.parametrize("entries,window,lengths", [
+    (PPS, 0, (300, -1, 37, 600, BLOCK + 5)),
+    (RING, WINDOW, (30, -1, 64, 65, 700))])
+def test_bfloat16_stores_round_where_the_twin_rounds(entries, window,
+                                                     lengths):
+    """The served type: probabilities and the attended values are rounded
+    to bfloat16 as the twin rounds them, one unit in the last place apart
+    at most."""
+    c = case(lengths, entries, seed=4, dtype=jnp.bfloat16)
+    got = through_the_kernel(c, 2, window)
+    want = over_a_gathered_view(c, 2, window)
+    assert got.dtype == jnp.bfloat16
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    assert gap(got, want) <= scale * 2 ** -7
+
+
+@pytest.mark.parametrize("entries,window,lengths", [
+    (PPS, 0, (70, -1, BLOCK + 9, 33)),
+    (RING, WINDOW, (70, -1, 20, 333))])
+def test_a_consecutive_table_and_a_permuted_one_read_the_same_rows(
+        entries, window, lengths):
+    """The same rows behind another table: the kernel follows the table,
+    not the page order."""
+    a = case(lengths, entries, seed=5, consecutive=True)
+    b = dict(a)
+    perm = np.random.RandomState(5).permutation(a["k_pages"].shape[1])
+    inverse = np.argsort(perm)
+    b["k_pages"] = a["k_pages"][:, perm]       # page p of b is perm[p] of a
+    b["v_pages"] = a["v_pages"][:, perm]
+    b["table"] = jnp.asarray(inverse)[a["table"]]
+    assert not np.array_equal(np.diff(np.asarray(b["table"])[0]),
+                              np.ones(entries - 1))
+    got = through_the_kernel(a, 0, window)
+    assert gap(got, through_the_kernel(b, 0, window)) == 0.0
+    assert gap(got, over_a_gathered_view(a, 0, window)) < TOL
+
+
+@pytest.mark.parametrize("entries,window,lengths", [
+    (PPS, 0, (100, -1, BLOCK + 3, -1, 17)),
+    (RING, WINDOW, (40, -1, 64, -1, 777)),
+    (WIDE_RING, WIDE, (100, -1, WIDE + 40, -1, 0))])
+def test_a_traced_layer_reads_only_the_live_slots_entries_in_use(
+        entries, window, lengths):
+    """The whole stores and a ``layer`` under ``lax.scan``; every page that
+    is no entry in use of a live slot (other slots', idle slots', the
+    unmapped rest of a live slot's row, page 0) is NaN in every layer, and
+    nothing of it arrives."""
+    c = case(lengths, entries, seed=6, nan_elsewhere=True)
+    assert bool(jnp.isnan(c["k_pages"]).any())
+    order, n_live = gpa.live_first(c["lengths"])
+
+    def one(carry, layer):
+        return carry, gpa.gqa_paged_attention(
+            c["q"], c["k_self"], c["v_self"], c["k_pages"], c["v_pages"],
+            c["table"], c["lengths"], layer, heads=CFG.num_attention_heads,
+            scale=CFG.attention_multiplier, window=window, order=order,
+            n_live=n_live, interpret=True)
+
+    _, got = jax.jit(lambda: jax.lax.scan(one, 0, jnp.arange(LAYERS)))()
+    assert bool(jnp.isfinite(got).all())
+    for layer in range(LAYERS):
+        assert gap(got[layer], over_a_gathered_view(c, layer, window)) < TOL
+    assert gap(got[0], got[1]) > 0.1           # the layers differ
+
+
+@pytest.mark.parametrize("entries,window", [(PPS, 0), (RING, WINDOW)])
+def test_nobody_alive_is_all_zeros(entries, window):
+    c = case((-1, -1, -1), entries, seed=7, nan_elsewhere=True)
+    got = through_the_kernel(c, 0, window)
+    assert got.shape == (SLOTS, CFG.q_width)
+    assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("entries,window", [(PPS, 0), (RING, WINDOW)])
+def test_the_new_key_and_value_are_attended_though_the_store_lacks_them(
+        entries, window):
+    """A slot that caches nothing attends its new key alone (weight 1: the
+    output is the new value, each query head its key/value head's); with
+    rows cached, another new value moves the output and the twin
+    agrees."""
+    c = case((0, 40), entries, seed=8)
+    got = through_the_kernel(c, 0, window)
+    rep = CFG.num_attention_heads // CFG.num_key_value_heads
+    alone = jnp.repeat(c["v_self"].reshape(SLOTS, -1, CFG.head_dim), rep,
+                       axis=1).reshape(SLOTS, -1)
+    assert gap(got[0], alone[0]) < TOL
+    other = dict(c, v_self=c["v_self"] + 1.0, k_self=c["k_self"] * 2.0)
+    moved = through_the_kernel(other, 0, window)
+    assert gap(moved[1], got[1]) > 1e-3
+    assert gap(moved[1], over_a_gathered_view(other, 0, window)[1]) < TOL
+
+
+def test_the_window_hides_rows_the_ring_still_holds():
+    """A ring holds a page more than the window: with the window the rows
+    at ``cached - window`` and before are out; the same ring read without
+    one attends them, and differs."""
+    c = case((64, 100, 40), RING, seed=9)
+    with_window = through_the_kernel(c, 0, WINDOW)
+    without = through_the_kernel(c, 0, 0)
+    assert gap(with_window[:2], without[:2]) > 1e-3
+    # 40 positions: all of them within 48 of the new token.
+    assert gap(with_window[2], without[2]) == 0.0
+    assert gap(without, over_a_gathered_view(c, 0, 0)) < TOL
+
+
+def test_the_block_and_the_copies_follow_from_the_shapes():
+    # The cell's two groups (48 heads on 8 of 128, bfloat16, page 16): 32
+    # pages, 512 tokens: the blocks' 4 MiB bind (the scores' tile would
+    # allow 682).
+    assert gpa.block_pages(16, 576, 48, 1024, 2) == 32
+    assert gpa.block_pages(16, 257, 48, 1024, 2) == 32
+    assert gpa.block_pages(16, RING, 32, 64, 4) == RING       # a row's all
+    assert BLOCK == 1024                                      # the scores
+    assert gpa.block_pages(16, 80, 8, 4096, 2) == 8           # the blocks
+    # What is copied: the entries in use, whole pages; of a ring no more
+    # than the ring; idle slots nothing.
+    lengths = [-1, 0, 1, 16, 17, -1, 600, 4096, 5000]
+    assert gpa.tokens_read(lengths, 576, 16) == (
+        0 + 16 + 16 + 32 + 608 + 4096 + 5008)
+    assert gpa.tokens_read(lengths, 257, 16) == (
+        0 + 16 + 16 + 32 + 608 + 4096 + 257 * 16)
+    assert gpa.tokens_read([-1, -1], 257, 16) == 0
+    assert gpa.mapped_entries(np.asarray([0, 1, 16, 17, 5000]), 257,
+                              16).tolist() == [0, 1, 1, 2, 257]
