@@ -30,8 +30,9 @@ through the entry points a user calls, at full width per chip:
   F  the decoder-hybrid-decoder the benchmark serves
      (benchmark/configs/phi4-mini-flash.json, whole on one chip:
      state-space, window, full, gated-memory and cross layers): the same
-     comparison through its paged layer and its five per-slot stores,
-     the scan kernel compiled.
+     comparison through its two paged layer groups (the window group a
+     ring of pages a slot, both attended where they lie by the paged
+     kernel) and its two per-slot stores, the scan kernel compiled.
   G  the shortcut-connected mixture of experts the benchmark serves
      (benchmark/configs/longcat-flash-omni-ep32.json, one chip's share
      of a 32-chip expert-parallel group: two latent attentions and two
@@ -793,10 +794,11 @@ def leg_hybrid_ssm(dry):
         (40, 6) if dry else (700, 150), 8 if dry else 24,
         HYBRID_RMS_REL_TOL,
         lambda e: (not e.cache.prefix_enabled and len(e.cache.pages) == 2
-                   and len(e.cache.slot_state) == 5
+                   and e.cache.group_names == ("full", "window")
+                   and len(e.cache.slot_state) == 2
                    and e.cache.n_layers == 1,
-                   "one paged layer and five per-slot stores, prefix "
-                   "cache off"))
+                   "one full and one window group of pages and two "
+                   "per-slot stores, prefix cache off"))
     shared = grew(before, after, "serving.shared_kv_tokens")
     check(shared > 0 and grew(before, after, "serving.state_slot_resets") == 2,
           "the cache manager counts what it holds and replaces")

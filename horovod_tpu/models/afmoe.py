@@ -30,8 +30,8 @@ layers whole.
 This is a SIBLING of ``models/mamba2_hybrid.py`` and of ``models/
 latent_moe.py``, chosen by the published keys (``model_type``,
 ``layer_types``).  Shared with the first: ``attend_chunks``, ``fill_view``
-and ``chunk_rung`` of the decode's paged view; with the second: the held
-expert layer and its counters.
+and ``chunk_rung`` of the decode's paged view, the twin off the TPU; with
+the second: the held expert layer and its counters.
 
 **The cache**: two layer GROUPS of the paged store (serving/kv_cache.py):
 ``full`` (the full layers: every position) and ``window`` (the sliding
@@ -60,9 +60,9 @@ from ..ops.gqa_paged_attention import mapped_entries
 from ..parallel.expert import (moe_layer_held, route_sigmoid_bias_top_k,
                                swiglu)
 from .hybrid_ssm import (_M_SHARED_KV, _M_WINDOW, PREFILL_Q_BLOCK, _dot,
-                         _masked_exp, chunk_rung, fill_view)
+                         _masked_exp)
 from .latent_moe import LatentMoEServing
-from .mamba2_hybrid import attend_chunks, rms_norm
+from .mamba2_hybrid import attend_chunks, chunk_rung, fill_view, rms_norm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # A test's: run the prompt's flash kernel in the Pallas interpreter.
@@ -410,7 +410,7 @@ def ring_chunks(table, cached, chunk: int, page_size: int, window: int,
 
     Returns ``(pages [n_chunks, pages a chunk], mask [n_chunks, chunk],
     owner [n_chunks], mine [n_chunks, slots] float32, used)`` as
-    ``hybrid_ssm.chunk_index`` does for a table that is no ring."""
+    ``mamba2_hybrid.chunk_index`` does for a table that is no ring."""
     b, entries = table.shape
     per = chunk // page_size
     mapped = mapped_entries(cached, entries, page_size)
@@ -467,7 +467,7 @@ def view_ladder_attend(lengths, groups, view, cfg: AfmoeConfig):
     off the TPU.  ``view [2, slots, view positions a slot, kv_width]`` is
     ONE layer's room: each layer in turn gathers its own group's live
     pages of its own paged layer into it as chunks
-    (``hybrid_ssm.fill_view``) and attends the leading chunks that hold
+    (``mamba2_hybrid.fill_view``) and attends the leading chunks that hold
     the list, how many a rung of the group's ladder picked INSIDE the
     program from ``lengths``.  Returns ``(attend, left)``: ``left()`` is
     ``(view,)`` as the last layer left it."""

@@ -12,9 +12,11 @@ mixer in one stack (:func:`layer_kinds`):
   sequence is CONSTANT: the ``[d_state, d_inner]`` float32 state and the
   last ``d_conv - 1`` inputs of the convolution.
 * ``window``: differential attention over the last ``sliding_window``
-  positions; it keeps a ring of that many keys and values a slot.
+  positions; its keys and values are a layer of the cache's WINDOW group,
+  whose table row is a ring of pages a slot.
 * ``full``: differential attention over every position: its keys and
-  values are THE cache, one paged per-token store of one layer.
+  values are the cache's FULL group, one paged per-token store of one
+  layer.
 * ``gmu``: a gated memory unit, ``W_out (m_t * silu(W_in u_t))``, ``m`` the
   last first-half state-space layer's output before its gate.  No state.
 * ``cross``: differential attention with queries of its own onto the
@@ -32,23 +34,27 @@ Differential attention: query heads pair up as ``(q1, q2)``, key heads as
 scaled by ``1 - lambda_init`` (:func:`attend_block`, :func:`attend_view`).
 
 :class:`HybridSSMServing` is the serving protocol (serving/models.py):
-the paged store of the one ``full`` layer and, beside it, the per-slot
-stores the engine's cache manager owns (``slot_stores``): two rings, the
-state, the tails, and the room the decode program gathers its shared
-view into.
+two paged layer groups of the engine's cache manager (the one ``full``
+layer; the window layers, a ring of pages a slot) and, beside them, the
+per-slot stores it owns (``slot_stores``): the state and the tails.  The
+decode attends both groups where they lie, through
+``ops/gqa_paged_attention.py`` with the differential head map
+(:func:`paged_attend`), on the TPU; its twin elsewhere gathers a slot's
+table row and attends that (:func:`gathered_attend`).
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry as _telemetry
+from ..memory.planner import ring_entries
+from ..ops import gqa_paged_attention as _paged
 from ..ops.ssm_scan import ssm_scan, ssm_step
 
 _M_SHARED_KV = _telemetry.counter(
@@ -63,12 +69,12 @@ _M_WINDOW = _telemetry.counter(
 _M_STATE_BYTES = _telemetry.gauge(
     "serving.state_bytes", "bytes of per-slot recurrent state (the "
     "state-space state and the convolution's tail, all layers and slots)")
-_M_WINDOW_BYTES = _telemetry.gauge(
-    "serving.window_store_bytes", "bytes of the window layers' ring "
-    "stores (keys and values, all layers and slots)")
 
 # Queries of one block of the prefill's attention.
 PREFILL_Q_BLOCK = 256
+# A test's: run the decode's paged kernel in the Pallas interpreter.
+PAGED_INTERPRET = None
+
 
 @dataclass(frozen=True)
 class HybridSSMConfig:
@@ -90,7 +96,9 @@ class HybridSSMConfig:
     dt_rank: int = 160
     max_position_embeddings: int = 262144
     dtype: object = jnp.bfloat16
-    # Positions of one chunk of the decode's shared view (whole pages).
+    # Read by nothing since the decode attends its pages where they lie
+    # (it was a chunk of the gathered view); taken because the benchmark's
+    # builder hands it on from a fixture that still names it.
     decode_chunk_tokens: int = 256
 
     def __post_init__(self):
@@ -293,16 +301,23 @@ def attend_block(q, k, v, ap, layer: int, cfg: HybridSSMConfig,
     return jnp.concatenate(outs, axis=0).astype(dt).reshape(t, -1)
 
 
+def key_head_of(cfg: HybridSSMConfig) -> np.ndarray:
+    """The key head each query head reads: query heads pair up as ``(q1,
+    q2)`` within a key/value PAIR's group, ``q1`` onto ``k1``, ``q2`` onto
+    ``k2``."""
+    heads = np.arange(cfg.num_attention_heads)
+    per = cfg.num_attention_heads // (cfg.num_key_value_heads // 2)
+    return 2 * (heads // per) + heads % 2
+
+
 def _lay_queries(q, cfg: HybridSSMConfig):
     """``q [b, heads * hd]`` as ``[b, kv_width, heads]``: a query head laid
     into a column that is zero outside its key head, so that a view is
     contracted in the layout it is stored in (the zeros add nothing)."""
     b = q.shape[0]
     h_n, kv_n = cfg.num_attention_heads, cfg.num_key_value_heads
-    heads = np.arange(h_n)
-    per = h_n // (kv_n // 2)                     # query heads a kv pair
-    key_of = 2 * (heads // per) + heads % 2
-    lay = jnp.asarray(key_of[:, None] == np.arange(kv_n)[None, :], q.dtype)
+    lay = jnp.asarray(key_head_of(cfg)[:, None] == np.arange(kv_n)[None, :],
+                      q.dtype)
     return jnp.einsum("bhd,hg->bgdh", q.reshape(b, h_n, cfg.head_dim), lay
                       ).reshape(b, kv_n * cfg.head_dim, h_n)
 
@@ -317,10 +332,10 @@ def _own_values(o, cfg: HybridSSMConfig):
                       o.reshape(*o.shape[:-1], j, 2 * cfg.head_dim), own)
 
 
-def _finish(o, denom, ap, layer: int, cfg: HybridSSMConfig, dtype):
-    """``o [b, heads, 2 hd]`` unnormalised, ``denom [b, heads]``."""
-    b, h_n = denom.shape
-    o = o / denom[..., None]
+def _finish(o, ap, layer: int, cfg: HybridSSMConfig, dtype):
+    """``o [b, heads, 2 hd]`` float32, each head's softmax over its pair's
+    values: the pairs combined, ``[b, heads * hd]`` in ``dtype``."""
+    b, h_n = o.shape[:2]
     return _combine(o.reshape(b, h_n // 2, 2, 2 * cfg.head_dim), ap, layer,
                     cfg).astype(dtype).reshape(b, -1)
 
@@ -349,113 +364,61 @@ def attend_view(q, k_self, v_self, k_view, v_view, mask, ap, layer: int,
                                preferred_element_type=jnp.float32), cfg)
     o = o + p_self[..., None] * _own_values(
         v_self.astype(jnp.float32)[:, None, :], cfg)
-    return _finish(o, jnp.sum(p, axis=-1) + p_self, ap, layer, cfg, dt)
+    denom = jnp.sum(p, axis=-1) + p_self
+    return _finish(o / denom[..., None], ap, layer, cfg, dt)
 
 
-def chunk_ladder(slots: int, capacity: int, chunk_tokens: int) -> tuple:
-    """``(positions a chunk, rungs)``: the decode's shared view is a list
-    of chunks (whole slots of them where ``chunk_tokens`` does not divide
-    the capacity), as many as the sequences alive need TOGETHER; the
-    rungs are the list's lengths the attention is compiled for, halving
-    from every slot at capacity down to one slot's worth."""
-    chunk = chunk_tokens if capacity % chunk_tokens == 0 else capacity
-    a_slot = capacity // chunk
-    rungs, n = [], slots * a_slot
-    while n > a_slot:
-        rungs.append(n)
-        n = -(-n // 2)
-    return chunk, tuple(reversed(rungs + [a_slot]))
+# -- the decode's attention over the paged groups -----------------------------
+
+def paged_kernel_runs() -> bool:
+    """Whether the decode program attends through the kernel that walks
+    the groups' page tables: read off the backend the program is built for
+    (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
+    return _paged.use_kernel(PAGED_INTERPRET)
 
 
-def chunk_rung(lengths, rungs, chunk: int):
-    """Index of the smallest rung that holds every slot's chunks (a
-    slot's cached positions rounded up to whole chunks; idle: none): the
-    same function for the traced ``lengths`` of the program and for the
-    host's numpy copy."""
-    xp = np if isinstance(lengths, np.ndarray) else jnp
-    need = (xp.clip(lengths, 0, None) + chunk - 1) // chunk
-    return (need.sum() > np.asarray(rungs[:-1], np.int32)).sum()
+def paged_attend(lengths, groups, cfg: HybridSSMConfig, interpret=None):
+    """The decode step's ``attend`` through the kernel
+    (``ops/gqa_paged_attention.py``): the live slots' own pages of a
+    layer's group, read where they lie, the ring in the kernel's mask, the
+    heads paired by the kernel's head map; a head's softmax over its
+    pair's values comes back float32 and the pairs are combined here.
+    ``groups``: ``{kind: (table, window, k_pages, v_pages)}``."""
+    order, n_live = _paged.live_first(lengths)
+    key_head = key_head_of(cfg)
+
+    def attend(kind, at, layer, ap, q, k_self, v_self):
+        table, window, k_pages, v_pages = groups[kind]
+        o = _paged.gqa_paged_attention(
+            q, k_self, v_self, k_pages, v_pages, table, lengths, at,
+            heads=cfg.num_attention_heads, scale=cfg.head_dim ** -0.5,
+            window=window, key_head=key_head,
+            value_heads=cfg.num_key_value_heads // 2, out_dtype=jnp.float32,
+            order=order, n_live=n_live, interpret=interpret)
+        return _finish(o.reshape(q.shape[0], cfg.num_attention_heads, -1),
+                       ap, layer, cfg, q.dtype)
+
+    return attend
 
 
-def chunk_index(table, cached, chunk: int, page_size: int):
-    """Where each chunk of the shared view comes from, for the LONGEST
-    list (every slot at capacity): the cached positions of all slots as
-    one list of chunks, a slot's chunks in a row, slot after slot.
-    Returns ``(pages [chunks, pages a chunk], mask [chunks, chunk], owner
-    [chunks], mine [chunks, slots] float32, used)``: ``mask`` the rows
-    that hold a cached position of the chunk's ``owner``, ``mine`` the
-    owner as one-hot rows (all zero for a chunk past the list's end,
-    whose pages are the trash page), ``used`` the chunks in the list."""
-    b, pps = table.shape
-    per = chunk // page_size
-    need = (cached + chunk - 1) // chunk
-    ends = jnp.cumsum(need)
-    c = jnp.arange(b * (pps // per))
-    live = c < ends[-1]
-    owner = jnp.minimum(jnp.searchsorted(ends, c, side="right"), b - 1)
-    local = c - (ends - need)[owner]
-    page_at = jnp.clip(local[:, None] * per + jnp.arange(per)[None, :],
-                       0, pps - 1)
-    pages = jnp.where(live[:, None], table[owner[:, None], page_at], 0)
-    mask = live[:, None] & (local[:, None] * chunk
-                            + jnp.arange(chunk)[None, :]
-                            < cached[owner][:, None])
-    mine = ((owner[:, None] == jnp.arange(b)[None, :])
-            & live[:, None]).astype(jnp.float32)
-    return pages, mask, owner, mine, ends[-1]
+def gathered_attend(lengths, groups, cfg: HybridSSMConfig):
+    """The kernel's twin off the TPU, the plainest thing that is right: a
+    slot's table row gathered in table order, every entry of it, and
+    :func:`attend_view` over that under the kernel's mask
+    (``gqa_paged_attention.attended_rows``)."""
+    cached = jnp.clip(lengths, 0, None)
 
+    def attend(kind, at, layer, ap, q, k_self, v_self):
+        table, window, k_pages, v_pages = groups[kind]
+        b, entries = table.shape
+        ps = k_pages.shape[2]
+        mask = _paged.attended_rows(cached, entries, ps, window)
+        k_view, v_view = (x[at][table].reshape(b, entries * ps, -1)
+                          for x in (k_pages, v_pages))
+        return attend_view(q, k_self, v_self, k_view, v_view, mask, ap,
+                           layer, cfg)
 
-def fill_view(view, k_pages, v_pages, pages, used, block: int,
-              layer: int = 0):
-    """Gather the chunks in use of paged layer ``layer`` into ``view [2,
-    chunks, chunk, kv_width]`` (keys, values), ``block`` chunks at a time,
-    as many blocks as hold them: a loop whose trip count follows the load,
-    writing in place.  What lies past them is left as it is (stale rows
-    are masked)."""
-    per = pages.shape[1]
-
-    def body(i, view):
-        at = i * block
-        these = jax.lax.dynamic_slice(pages, (at, 0), (block, per))
-        both = jnp.stack([k_pages[layer, these], v_pages[layer, these]])
-        return jax.lax.dynamic_update_slice(
-            view, both.reshape(2, block, -1, view.shape[-1]),
-            (0, at, 0, 0))
-
-    return jax.lax.fori_loop(0, (used + block - 1) // block, body, view)
-
-
-def attend_chunks(q, k_self, v_self, view, ap, layer: int,
-                  cfg: HybridSSMConfig):
-    """:func:`attend_view` over chunks of the shared view: ``view = (k
-    [chunks, n, kv_width], v, mask [chunks, n], owner [chunks], mine
-    [chunks, slots])`` (:func:`chunk_index`).  A slot's softmax runs over
-    ITS chunks (a maximum and two sums over the chunks it owns) and its
-    own new key and value beside them."""
-    k, v, mask, owner, mine = view
-    dt = q.dtype
-    qbd = _lay_queries(q, cfg)
-    scale = cfg.head_dim ** -0.5
-    scores = jnp.einsum("cnk,ckh->chn", k, qbd[owner],
-                        preferred_element_type=jnp.float32) * scale
-    s_self = jnp.einsum("bk,bkh->bh", k_self, qbd,
-                        preferred_element_type=jnp.float32) * scale
-    mask = mask[:, None, :]
-    top = jnp.max(jnp.where(mask, scores, -jnp.inf), axis=-1)    # [c, h]
-    m = jnp.maximum(jnp.max(jnp.where(mine.T[:, :, None] > 0, top[None],
-                                      -jnp.inf), axis=1), s_self)  # [b, h]
-    p = _masked_exp(scores, mask, m[owner][..., None])
-    p_self = jnp.exp(s_self - m)
-    o = _own_values(jnp.einsum("chn,cnk->chk", p.astype(dt), v,
-                               preferred_element_type=jnp.float32), cfg)
-    # A slot's sums over its chunks: tiny one-hot products, in float32.
-    exact = jax.lax.Precision.HIGHEST
-    o = jnp.einsum("cb,chv->bhv", mine, o, precision=exact)
-    denom = jnp.einsum("cb,ch->bh", mine, jnp.sum(p, axis=-1),
-                       precision=exact)
-    o = o + p_self[..., None] * _own_values(
-        v_self.astype(jnp.float32)[:, None, :], cfg)
-    return _finish(o, denom + p_self, ap, layer, cfg, dt)
+    return attend
 
 
 def _project(h, ap, name: str):
@@ -544,28 +507,26 @@ def _attn_out(a, ap):
 def prefill_step(params, tokens, n_valid, cfg: HybridSSMConfig,
                  last_only: bool = True):
     """A padded prompt ``tokens [bucket]`` from empty state; positions
-    ``>= n_valid`` are padding, which advances neither state nor tail nor
-    window.  The layers up to ``full`` run over the block; with
-    ``last_only`` the rest run for token ``n_valid - 1`` alone, which is
-    exact for its logits (``last_only=False`` runs every layer over the
-    block: the tests hold the two equal).
+    ``>= n_valid`` are padding, which advances neither state nor tail.
+    The layers up to ``full`` run over the block; with ``last_only`` the
+    rest run for token ``n_valid - 1`` alone, which is exact for its
+    logits (``last_only=False`` runs every layer over the block: the tests
+    hold the two equal).
 
     Returns ``(logits [vocab] of the last real token (or [bucket, vocab]),
     left)``: ``left["k"]``/``["v"] [bucket, kv_width]`` of the ``full``
-    layer, ``left["window_k"]``/``["window_v"] [window layers, window,
-    kv_width]`` rings as they stand after the last real token,
-    ``left["state"] [ssm layers, n, d_inner]``, ``left["tail"] [ssm
-    layers, d_conv - 1, d_inner]``."""
+    layer, ``left["window_k"]``/``["window_v"] [window layers, bucket,
+    kv_width]`` of the window layers (the caller keeps the last ring's
+    worth), ``left["state"] [ssm layers, n, d_inner]``, ``left["tail"]
+    [ssm layers, d_conv - 1, d_inner]``."""
     t = tokens.shape[0]
     kinds = layer_kinds(cfg.num_hidden_layers)
     full_at = kinds.index("full")
     win = cfg.sliding_window
     eps, dt = cfg.layer_norm_eps, cfg.dtype
     last = n_valid - 1
-    # Ring row r holds the newest real position congruent to r.
-    ring_pos = jnp.clip(last - (last - jnp.arange(win)) % win, 0, t - 1)
     x = params["embed"][tokens].astype(jnp.float32)
-    rings_k, rings_v, states, tails = [], [], [], []
+    win_k, win_v, states, tails = [], [], [], []
     mem = k_full = v_full = None
 
     def at_last(v):
@@ -585,8 +546,8 @@ def prefill_step(params, tokens, n_valid, cfg: HybridSSMConfig,
             mix = _attn_out(attend_block(
                 q, k, v, mp, l, cfg, win if kind == "window" else 0), mp)
             if kind == "window":
-                rings_k.append(k[ring_pos])
-                rings_v.append(v[ring_pos])
+                win_k.append(k)
+                win_v.append(v)
             else:
                 k_full, v_full = k, v
         elif kind == "gmu":
@@ -603,7 +564,7 @@ def prefill_step(params, tokens, n_valid, cfg: HybridSSMConfig,
     logits = head(x, params, cfg)
     return (logits[0] if last_only else logits), {
         "k": k_full, "v": v_full,
-        "window_k": jnp.stack(rings_k), "window_v": jnp.stack(rings_v),
+        "window_k": jnp.stack(win_k), "window_v": jnp.stack(win_v),
         "state": jnp.stack(states), "tail": jnp.stack(tails)}
 
 
@@ -619,55 +580,35 @@ def decode_step(params, tokens, lengths, stores, table,
     """One token a slot.  ``tokens [slots]``; ``lengths [slots]``: the
     position of the new token, the count of cached ones (-1: an idle slot,
     whose state stays as it is); ``stores = (k_pages, v_pages [1, pages,
-    page, kv_width], window_k, window_v [window layers, slots, window,
+    page, kv_width], win_k, win_v [window layers, window pages, page,
     kv_width], state [ssm layers, slots, n, d_inner], tail [ssm layers,
-    slots, d_conv - 1, d_inner], view [2, slots, capacity, kv_width])``.
+    slots, d_conv - 1, d_inner])``; ``table [slots, pages a slot + ring
+    entries]``, the two groups' tables side by side.
 
-    The paged store is gathered ONCE an iteration, as chunks, into
-    ``view`` (:func:`fill_view`), and the full layer and every cross
-    layer attend that one view, each over the leading chunks that hold
-    the list: how many is a rung of :func:`chunk_ladder`, picked INSIDE
-    the program from ``lengths`` (``lax.switch`` around a reader's
-    attention only).  The new token's own key and value are not in any
-    view (``attend_view`` takes them beside it), so no store is written
-    until the end.
+    A window layer attends its own paged layer of the window group, the
+    full layer and every cross layer the full group's one layer (the cross
+    layers with queries of their own): on the TPU where the pages lie
+    (:func:`paged_attend`), elsewhere over a gathered table row
+    (:func:`gathered_attend`); :func:`paged_kernel_runs` says which.  The
+    new token's own key and value are not in any store (the attention takes
+    them beside it), so no paged store is written here: the caller writes
+    both groups at the end.
 
     Returns ``(logits [slots, vocab], new)``: ``new["k"]``/``["v"]
     [slots, kv_width]`` of the ``full`` layer, ``new["window_k"]``/
-    ``["window_v"] [window layers, slots, kv_width]``, ``new["state"]``,
-    ``new["tail"]`` and ``new["view"]`` whole."""
-    k_pages, v_pages, win_k, win_v, state, tail, view = stores
+    ``["window_v"] [window layers, slots, kv_width]``, ``new["state"]``
+    and ``new["tail"]`` whole."""
+    k_pages, v_pages, win_k, win_v, state, tail = stores
     kinds = layer_kinds(cfg.num_hidden_layers)
-    b = tokens.shape[0]
     win = cfg.sliding_window
     eps, dt = cfg.layer_norm_eps, cfg.dtype
     alive = lengths >= 0
-    cached = jnp.clip(lengths, 0, None)
-    ps = k_pages.shape[2]
-    chunk, rungs = chunk_ladder(b, table.shape[1] * ps,
-                                cfg.decode_chunk_tokens)
-    if chunk % ps:
-        raise ValueError(f"decode_chunk_tokens {chunk} is not whole pages "
-                         f"of {ps}")
-    pages, mask, owner, mine, used = chunk_index(table, cached, chunk, ps)
-    chunks = view.reshape(2, -1, chunk, view.shape[-1])
-    chunks = fill_view(chunks, k_pages, v_pages, pages, used, rungs[0])
-    picked = chunk_rung(lengths, rungs, chunk)
-    # Ring row r holds a cached position of the window iff r < cached and
-    # it is not the row the new token will take (which holds the position
-    # that has just left the window).
-    rows = jnp.arange(win)[None, :]
-    ring_mask = (rows < cached[:, None]) & (rows != (cached % win)[:, None])
-
-    def over(n, layer, ap, q, k_self, v_self):
-        return attend_chunks(q, k_self, v_self,
-                             (chunks[0, :n], chunks[1, :n], mask[:n],
-                              owner[:n], mine[:n]), ap, layer, cfg)
-
-    def attend_paged(layer, ap, q, k_self, v_self):
-        return jax.lax.switch(
-            picked, [partial(over, n, layer, ap) for n in rungs],
-            q, k_self, v_self)
+    pps = table.shape[1] - ring_entries(win, k_pages.shape[2])
+    groups = {"full": (table[:, :pps], 0, k_pages, v_pages),
+              "window": (table[:, pps:], win, win_k, win_v)}
+    attend = (paged_attend(lengths, groups, cfg, PAGED_INTERPRET)
+              if paged_kernel_runs() else
+              gathered_attend(lengths, groups, cfg))
 
     x = params["embed"][tokens].astype(jnp.float32)
     new_wk, new_wv = [], []
@@ -687,41 +628,44 @@ def decode_step(params, tokens, lengths, stores, table,
             tail = tail.at[i].set(
                 jnp.where(alive[:, None, None], t_new, tail[i]))
         elif kind == "window":
-            i = len(new_wk)
             q, k, v = _qkv(h, mp, cfg)
-            mix = _attn_out(attend_view(q, k, v, win_k[i], win_v[i],
-                                        ring_mask, mp, l, cfg), mp)
+            with jax.named_scope("diff_attention"):
+                mix = attend("window", len(new_wk), l, mp, q, k, v)
+            mix = _attn_out(mix, mp)
             new_wk.append(k)
             new_wv.append(v)
-        elif kind == "full":
-            q, k_full, v_full = _qkv(h, mp, cfg)
-            mix = _attn_out(attend_paged(l, mp, q, k_full, v_full), mp)
         elif kind == "gmu":
             mix = gmu(h, mem, mp, cfg)
-        else:
-            mix = _attn_out(attend_paged(l, mp, _project(h, mp, "q"),
-                                         k_full, v_full), mp)
+        else:               # full, and cross onto the full layer's new row
+            if kind == "full":
+                q, k_full, v_full = _qkv(h, mp, cfg)
+            else:
+                q = _project(h, mp, "q")
+            with jax.named_scope("diff_attention"):
+                mix = attend("full", 0, l, mp, q, k_full, v_full)
+            mix = _attn_out(mix, mp)
         x = mlp(x + mix, lp, cfg)
     return head(x, params, cfg), {
         "k": k_full, "v": v_full,
         "window_k": jnp.stack(new_wk), "window_v": jnp.stack(new_wv),
-        "state": state, "tail": tail, "view": chunks.reshape(view.shape)}
+        "state": state, "tail": tail}
 
 
 # -- what the serving engine asks ---------------------------------------------
 
 class HybridSSMServing:
-    """The serving protocol (serving/models.py) for this model: the paged
-    keys and values of ONE layer, which every layer after it reads, and
-    five per-slot stores."""
+    """The serving protocol (serving/models.py) for this model: two paged
+    layer groups (the ONE full layer, which every layer after it reads;
+    the window layers, a ring of pages a slot) and two per-slot stores."""
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False
     tensor_parallel_why = ("its per-slot state stores are not written "
                            "for a sharded model axis")
     prefix_cache = False
-    prefix_cache_why = ("state-space state, convolution tails and window "
-                        "rings are per slot and not page-addressable: a "
+    prefix_cache_why = ("state-space state and convolution tails are per "
+                        "slot and not page-addressable, and a window "
+                        "group's pages are a ring written over in place: a "
                         "cached prefix page carries none of them "
                         "(snapshots of recurrent state are not written "
                         "yet)")
@@ -740,52 +684,48 @@ class HybridSSMServing:
                 "heads": [c.num_attention_heads, c.num_key_value_heads],
                 "sliding_window": c.sliding_window,
                 "ssm": [c.d_state, c.d_conv, c.expand, c.dt_rank],
-                "decode_chunk_tokens": c.decode_chunk_tokens,
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
     def cache_entry(self) -> dict:
-        """ONE paged layer (keys, values) and the per-slot stores, each
-        ``[layers, slots, *shape]`` in the cache manager."""
+        """Keys and values in two layer groups (the window group's table
+        row a ring of ``ring_entries`` pages a slot: bounded by the window,
+        whatever the capacity) and the per-slot stores, each ``[layers,
+        slots, *shape]`` in the cache manager.  The decode reads the pages
+        in place and asks for no room to gather into."""
         c = self.cfg
         n_win, n_ssm = self.kinds.count("window"), self.kinds.count("ssm")
-        ring = (n_win, c.sliding_window, c.kv_width)
         return {"n_layers": 1, "n_heads": c.num_key_value_heads,
                 "head_dim": c.head_dim, "widths": (c.kv_width,) * 2,
+                "groups": ({"name": "full", "n_layers": 1},
+                           {"name": "window", "n_layers": n_win,
+                            "window": c.sliding_window}),
                 "slot_stores": (
-                    {"name": "window_k", "kind": "window", "shape": ring,
-                     "dtype": c.dtype},
-                    {"name": "window_v", "kind": "window", "shape": ring,
-                     "dtype": c.dtype},
                     {"name": "ssm_state", "kind": "state",
                      "shape": (n_ssm, c.d_state, c.d_inner),
                      "dtype": jnp.float32},
                     {"name": "conv_tail", "kind": "state",
                      "shape": (n_ssm, c.d_conv - 1, c.d_inner),
-                     "dtype": c.dtype},
-                    # Where a decode iteration gathers the paged layer
-                    # ONCE for its readers: as large as the store (every
-                    # slot at capacity), kept so that it is not
-                    # allocated and zeroed every iteration.
-                    {"name": "shared_view", "kind": "scratch",
-                     "shape": (2, "capacity", c.kv_width),
                      "dtype": c.dtype})}
 
     def observe_stores(self, nbytes: dict) -> None:
         """Bytes of the per-slot stores by kind, once at build."""
         _M_STATE_BYTES.set(nbytes.get("state", 0))
-        _M_WINDOW_BYTES.set(nbytes.get("window", 0))
 
     def decode_view(self, lengths, rungs, page_size=None) -> float:
-        """Positions of shared view a slot the decode program gathers at
-        these (host) lengths: the chunk list's rung, over the slots.  The
-        engine's ladder (``rungs``) is a slot's and only its last rung,
-        the capacity, is used: this model's is of all slots together
-        (:func:`chunk_ladder`)."""
-        chunk, ladder = chunk_ladder(len(lengths), rungs[-1],
-                                     self.cfg.decode_chunk_tokens)
-        return (ladder[int(chunk_rung(lengths, ladder, chunk))] * chunk
-                / len(lengths))
+        """Positions a slot a layer the decode program reads of the paged
+        stores at these (host) lengths: each group's entries in use of the
+        live slots, whole pages (what the kernel copies; its twin gathers
+        the whole rows and masks the rest), the full group's once for each
+        of its readers, the window group's once for each window layer,
+        over those layers and the slots."""
+        full = _paged.tokens_read(lengths, rungs[-1] // page_size, page_size)
+        window = _paged.tokens_read(
+            lengths, ring_entries(self.cfg.sliding_window, page_size),
+            page_size)
+        n_w = self.kinds.count("window")
+        n_f = 1 + self.kinds.count("cross")
+        return (n_f * full + n_w * window) / (n_f + n_w) / len(lengths)
 
     def observe_launch(self, lengths) -> None:
         """Count what a decode iteration attends, from the host's lengths
@@ -798,52 +738,75 @@ class HybridSSMServing:
         k_pages, v_pages, win_k, win_v = pages[:4]
         logits, new = decode_step(params, tokens, lengths, pages, table,
                                   self.cfg)
-        # One row a slot, written where it lies (see DenseLM.decode); an
-        # idle slot's row lands in the trash page and in its own ring.
+        # One row a slot in the one layer of the full group and in every
+        # layer of the window group, written where it lies (see
+        # DenseLM.decode): the full group at the position's own page, the
+        # window group at that page's ring entry; an idle slot's rows land
+        # in the trash pages.
         ps = k_pages.shape[2]
+        ring = ring_entries(self.cfg.sliding_window, ps)
+        pps = table.shape[1] - ring
         pos = jnp.clip(lengths, 0, None)
         b = tokens.shape[0]
-        page, off = table[jnp.arange(b), pos // ps], pos % ps
-        ring = pos % self.cfg.sliding_window
+        at_page, off = pos // ps, pos % ps
+        page_f = table[jnp.arange(b), at_page]
+        page_w = table[jnp.arange(b), pps + at_page % ring]
         zero = jnp.zeros((), jnp.int32)
         for slot in range(b):
-            at = (zero, page[slot], off[slot], zero)
+            at = (zero, page_f[slot], off[slot], zero)
             k_pages = jax.lax.dynamic_update_slice(
                 k_pages, new["k"][slot][None, None, None, :], at)
             v_pages = jax.lax.dynamic_update_slice(
                 v_pages, new["v"][slot][None, None, None, :], at)
-            at = (zero, jnp.int32(slot), ring[slot], zero)
+            at = (zero, page_w[slot], off[slot], zero)
             win_k = jax.lax.dynamic_update_slice(
                 win_k, new["window_k"][:, slot][:, None, None, :], at)
             win_v = jax.lax.dynamic_update_slice(
                 win_v, new["window_v"][:, slot][:, None, None, :], at)
         return (logits,), (k_pages, v_pages, win_k, win_v, new["state"],
-                           new["tail"], new["view"])
+                           new["tail"])
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens,
                 slot):
         """``start`` is always 0 here (``prefix_cache`` is off); ``slot
-        [1]`` is the slot filled: its rings, state and tails are REPLACED
-        by what the prompt leaves."""
-        k_pages, v_pages, win_k, win_v, state, tail, view = pages
+        [1]`` is the slot filled: its state and tails are REPLACED by what
+        the prompt leaves.  The full group takes every page of the prompt,
+        the window group the last ``ring`` pages' worth, each into its
+        ring entry (what the slot's last owner left in a page is past the
+        new length, or written over)."""
+        k_pages, v_pages, win_k, win_v, state, tail = pages
         ps, bucket = k_pages.shape[2], tokens.shape[1]
+        ring = ring_entries(self.cfg.sliding_window, ps)
+        pps = table_row.shape[1] - ring
         logits, left = prefill_step(params, tokens[0], n_valid[0], self.cfg)
         # A page at a time, written where it lies; pages past the prompt
-        # are not mapped: their rows land in trash page 0.
+        # are not mapped: their rows land in a trash page.
         rows = min(ps, bucket)
+        n_pages = max(1, bucket // ps)
         zero = jnp.zeros((), jnp.int32)
-        for j in range(max(1, bucket // ps)):
+        for j in range(n_pages):
             at = (zero, table_row[0, j], zero, zero)
             k_pages = jax.lax.dynamic_update_slice(
                 k_pages, left["k"][None, None, j * ps:j * ps + rows], at)
             v_pages = jax.lax.dynamic_update_slice(
                 v_pages, left["v"][None, None, j * ps:j * ps + rows], at)
+        top = (n_valid[0] - 1) // ps
+
+        def write_ring(e, kv):
+            # The newest logical page congruent to the entry; an entry
+            # the prompt does not reach is unmapped.
+            j = jnp.clip(top - (top - e) % ring, 0, n_pages - 1)
+            at = (zero, table_row[0, pps + e], zero, zero)
+            return tuple(jax.lax.dynamic_update_slice(
+                store, jax.lax.dynamic_slice_in_dim(x, j * ps, rows,
+                                                    axis=1)[:, None], at)
+                for store, x in zip(kv, (left["window_k"],
+                                         left["window_v"])))
+
+        win_k, win_v = jax.lax.fori_loop(0, min(ring, n_pages), write_ring,
+                                         (win_k, win_v))
         at = (zero, slot[0], zero, zero)
-        win_k = jax.lax.dynamic_update_slice(
-            win_k, left["window_k"][:, None], at)
-        win_v = jax.lax.dynamic_update_slice(
-            win_v, left["window_v"][:, None], at)
         state = jax.lax.dynamic_update_slice(state, left["state"][:, None],
                                              at)
         tail = jax.lax.dynamic_update_slice(tail, left["tail"][:, None], at)
-        return (logits,), (k_pages, v_pages, win_k, win_v, state, tail, view)
+        return (logits,), (k_pages, v_pages, win_k, win_v, state, tail)

@@ -27,9 +27,11 @@ This is a SIBLING of ``models/hybrid_ssm.py``, not a mode of it: that
 module fixes its five-kind layout by arithmetic on the layer index and
 every attention in it is differential; which module serves a config is
 decided by the published keys (``model_type``, ``layer_types``).  Shared
-with it: the chunk list of the decode's paged view (``chunk_ladder``,
-``chunk_rung``, ``chunk_index``, ``fill_view``), the convolution, the
-per-slot stores of the cache manager and the counters.
+with it: the convolution, the per-slot stores of the cache manager and the
+counters.  The chunk list of the decode's paged view (``chunk_ladder``,
+``chunk_rung``, ``chunk_index``, ``fill_view``) lives here since that
+module attends its pages where they lie; ``models/afmoe.py``'s twin off
+the TPU shares it.
 
 A prompt runs the chunked form of the recurrence
 (:func:`~horovod_tpu.ops.ssd.ssd_chunk_scan`), decode the one-step form
@@ -49,8 +51,7 @@ import numpy as np
 from .. import telemetry as _telemetry
 from ..ops.ssd import head_pack, ssd_chunk_scan, ssd_step
 from .hybrid_ssm import (_M_SHARED_KV, _M_STATE_BYTES, PREFILL_Q_BLOCK,
-                         _conv, _dot, _masked_exp, chunk_index, chunk_ladder,
-                         chunk_rung, fill_view)
+                         _conv, _dot, _masked_exp)
 
 _M_STATE_MOVED = _telemetry.counter(
     "serving.state_bytes_moved", "bytes of recurrent state and convolution "
@@ -243,6 +244,81 @@ def attend_block(q, k, v, cfg: Mamba2HybridConfig):
     return jnp.concatenate(outs, axis=0).astype(dt).reshape(t, -1)
 
 
+# -- the chunk list of the decode's paged view -------------------------------
+
+def chunk_ladder(slots: int, capacity: int, chunk_tokens: int) -> tuple:
+    """``(positions a chunk, rungs)``: the decode's shared view is a list
+    of chunks (whole slots of them where ``chunk_tokens`` does not divide
+    the capacity), as many as the sequences alive need TOGETHER; the
+    rungs are the list's lengths the attention is compiled for, halving
+    from every slot at capacity down to one slot's worth."""
+    chunk = chunk_tokens if capacity % chunk_tokens == 0 else capacity
+    a_slot = capacity // chunk
+    rungs, n = [], slots * a_slot
+    while n > a_slot:
+        rungs.append(n)
+        n = -(-n // 2)
+    return chunk, tuple(reversed(rungs + [a_slot]))
+
+
+def chunk_rung(lengths, rungs, chunk: int):
+    """Index of the smallest rung that holds every slot's chunks (a
+    slot's cached positions rounded up to whole chunks; idle: none): the
+    same function for the traced ``lengths`` of the program and for the
+    host's numpy copy."""
+    xp = np if isinstance(lengths, np.ndarray) else jnp
+    need = (xp.clip(lengths, 0, None) + chunk - 1) // chunk
+    return (need.sum() > np.asarray(rungs[:-1], np.int32)).sum()
+
+
+def chunk_index(table, cached, chunk: int, page_size: int):
+    """Where each chunk of the shared view comes from, for the LONGEST
+    list (every slot at capacity): the cached positions of all slots as
+    one list of chunks, a slot's chunks in a row, slot after slot.
+    Returns ``(pages [chunks, pages a chunk], mask [chunks, chunk], owner
+    [chunks], mine [chunks, slots] float32, used)``: ``mask`` the rows
+    that hold a cached position of the chunk's ``owner``, ``mine`` the
+    owner as one-hot rows (all zero for a chunk past the list's end,
+    whose pages are the trash page), ``used`` the chunks in the list."""
+    b, pps = table.shape
+    per = chunk // page_size
+    need = (cached + chunk - 1) // chunk
+    ends = jnp.cumsum(need)
+    c = jnp.arange(b * (pps // per))
+    live = c < ends[-1]
+    owner = jnp.minimum(jnp.searchsorted(ends, c, side="right"), b - 1)
+    local = c - (ends - need)[owner]
+    page_at = jnp.clip(local[:, None] * per + jnp.arange(per)[None, :],
+                       0, pps - 1)
+    pages = jnp.where(live[:, None], table[owner[:, None], page_at], 0)
+    mask = live[:, None] & (local[:, None] * chunk
+                            + jnp.arange(chunk)[None, :]
+                            < cached[owner][:, None])
+    mine = ((owner[:, None] == jnp.arange(b)[None, :])
+            & live[:, None]).astype(jnp.float32)
+    return pages, mask, owner, mine, ends[-1]
+
+
+def fill_view(view, k_pages, v_pages, pages, used, block: int,
+              layer: int = 0):
+    """Gather the chunks in use of paged layer ``layer`` into ``view [2,
+    chunks, chunk, kv_width]`` (keys, values), ``block`` chunks at a time,
+    as many blocks as hold them: a loop whose trip count follows the load,
+    writing in place.  What lies past them is left as it is (stale rows
+    are masked)."""
+    per = pages.shape[1]
+
+    def body(i, view):
+        at = i * block
+        these = jax.lax.dynamic_slice(pages, (at, 0), (block, per))
+        both = jnp.stack([k_pages[layer, these], v_pages[layer, these]])
+        return jax.lax.dynamic_update_slice(
+            view, both.reshape(2, block, -1, view.shape[-1]),
+            (0, at, 0, 0))
+
+    return jax.lax.fori_loop(0, (used + block - 1) // block, body, view)
+
+
 def _key_head_of(cfg: Mamba2HybridConfig, dtype):
     """``[heads, kv_heads]`` one-hot: query head ``i`` reads key/value
     head ``i // (heads / kv_heads)``."""
@@ -275,7 +351,7 @@ def attend_chunks(q, k_self, v_self, view, cfg: Mamba2HybridConfig):
     paged view plus the slot's own new key and value, which are not in
     the view.  ``q [b, heads * hd]``; ``k_self``/``v_self`` ``[b,
     kv_width]``; ``view = (k [chunks, n, kv_width], v, mask [chunks, n],
-    owner [chunks], mine [chunks, slots])`` (``hybrid_ssm.chunk_index``).
+    owner [chunks], mine [chunks, slots])`` (:func:`chunk_index`).
     A slot's softmax runs over ITS chunks (a maximum and two sums over
     the chunks it owns) and its own new key and value beside them.
     Returns ``[b, heads * hd]``."""
@@ -426,7 +502,7 @@ def decode_step(params, tokens, lengths, stores, table,
     view [2, slots, capacity, kv_width])``.
 
     ``view`` is ONE layer's room: each attention layer in turn gathers
-    its own paged layer into it as chunks (``hybrid_ssm.fill_view``) and
+    its own paged layer into it as chunks (:func:`fill_view`) and
     attends the leading chunks that hold the list, how many a rung of
     ``chunk_ladder`` picked INSIDE the program from ``lengths``.  The new
     token's own key and value are not in the view, so the paged store is

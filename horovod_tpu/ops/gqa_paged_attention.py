@@ -7,7 +7,9 @@ table whose row may be a RING, the softmax computed online.
     lengths          [slots]             positions cached; -1 idle
 
 Query head ``i`` of ``heads`` reads key/value head ``i // (heads /
-kv_heads)``.  The arithmetic is ``models/mamba2_hybrid.py::attend_chunks``'s
+kv_heads)``, unless the caller hands in another HEAD MAP (differential
+attention's pairs, ``models/hybrid_ssm.py``: :func:`gqa_paged_attention`).
+The arithmetic is ``models/mamba2_hybrid.py::attend_chunks``'s
 (the twin a caller keeps off the TPU): scores times ``scale``, scores,
 softmax and accumulation float32, the probabilities rounded to the store's
 type as the second product's operand, the new token's own key and value
@@ -45,7 +47,9 @@ product ``[heads, kv_width] x [kv_width, block]`` against the key block as
 it is stored, the output ONE product ``[heads, block] x [block,
 kv_width]``, and a head keeps its own head's lanes at the end (the zeros
 add nothing; eight times the multiplications, which the matrix unit has to
-spare in a decode).  The other form, ``kv_heads`` products ``[queries a
+spare in a decode).  Which lanes a query head is laid into and which it
+keeps is all the kernel knows of the heads: the head map lives in the
+wrapper.  The other form, ``kv_heads`` products ``[queries a
 head padded to 8 rows, hd] x [hd, block]`` each against its own lanes and
 as many for the output, loads the same key and value tiles into the matrix
 unit and streams a sixth of the rows through them, with an accumulator of 8
@@ -252,10 +256,11 @@ def _kernel(order_ref, n_ref, len_ref, table_ref, layer_ref,   # scalars
 
 
 @functools.partial(jax.jit, static_argnames=("kv_heads", "scale", "window",
-                                             "interpret"), inline=True)
+                                             "interpret", "out_dtype"),
+                   inline=True)
 def _pallas_attend(q, k_self, v_self, k_pages, v_pages, table, lengths,
                    layer, order, n_live, kv_heads: int, scale: float,
-                   window: int, interpret: bool):
+                   window: int, interpret: bool, out_dtype=None):
     slots, heads, kv_width = q.shape
     page = k_pages.shape[2]
     entries = table.shape[1]
@@ -286,7 +291,8 @@ def _pallas_attend(q, k_self, v_self, k_pages, v_pages, table, lengths,
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, kv_width), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, heads, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, hd),
+                                       out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name="gqa_paged_attn",
@@ -296,8 +302,28 @@ def _pallas_attend(q, k_self, v_self, k_pages, v_pages, table, lengths,
       v_self[:, None, :], k_pages, v_pages)
 
 
+def attended_rows(cached, entries: int, page_size: int, window: int = 0):
+    """The kernel's mask as an array, for a twin that gathers: ``[slots,
+    entries * page_size]`` bool, which rows of a slot's table row laid end
+    to end (table order) the new token at position ``cached [slots]``
+    attends (module docstring, "The table row is a ring")."""
+    cached = cached[:, None]
+    column = jnp.arange(entries * page_size)[None, :]
+    top = jnp.maximum(cached - 1, 0) // page_size
+    newest = top % entries
+    pos = ((top - newest) * page_size + column
+           - jnp.where(column >= (newest + 1) * page_size,
+                       entries * page_size, 0))
+    seen = ((column < mapped_entries(cached, entries, page_size) * page_size)
+            & (pos < cached))
+    if window:
+        seen = seen & (pos > cached - window)
+    return seen
+
+
 def gqa_paged_attention(q, k_self, v_self, k_pages, v_pages, table, lengths,
                         layer, *, heads: int, scale: float, window: int = 0,
+                        key_head=None, value_heads=None, out_dtype=None,
                         order=None, n_live=None, interpret=None):
     """One query a slot over the slot's cached keys and values and its new
     ones.
@@ -310,20 +336,38 @@ def gqa_paged_attention(q, k_self, v_self, k_pages, v_pages, table, lengths,
     idle); ``window``: rows fewer than ``window`` positions before the new
     token are attended (0: all).  ``order``/``n_live``:
     :func:`live_first` of ``lengths``, for a caller that attends several
-    layers at the same lengths.  Returns ``[slots, heads * hd]`` in ``q``'s
-    type; an idle slot's rows are exact zeros.  Only the entries in use of
-    the live slots are read; their rows outside the mask must be finite."""
+    layers at the same lengths.
+
+    **The head map** (left out: grouped-query attention's).  ``key_head
+    [heads]`` (host integers): the key head whose lanes query head ``i``
+    is laid into, ``i // (heads / kv_heads)`` by default.  ``value_heads``:
+    into how many heads the value row divides, ``kv_heads`` by default;
+    query head ``i`` keeps the lanes of value head ``i // (heads /
+    value_heads)``.  Differential attention (``models/hybrid_ssm.py``)
+    pairs its heads: query head ``i`` reads key head ``2 * (i // per) + i %
+    2`` and keeps the double-width value of its PAIR (``value_heads =
+    kv_heads / 2``).  ``out_dtype``: the type the output is handed on in
+    (``q``'s by default; float32 for a caller that goes on in float32).
+
+    Returns ``[slots, heads * kv_width / value_heads]``; an idle slot's
+    rows are exact zeros.  Only the entries in use of the live slots are
+    read; their rows outside the mask must be finite."""
     slots, hd = q.shape[0], q.shape[1] // heads
     kv_heads = k_self.shape[1] // hd
     if order is None:
         order, n_live = live_first(lengths)
-    # Query head ``i`` into the lanes of key/value head ``i // rep``.
+    if key_head is None:
+        key_head = np.arange(heads) // (heads // kv_heads)
     of_head = jnp.asarray(
-        (np.arange(heads) // (heads // kv_heads))[:, None]
-        == np.arange(kv_heads)[None, :], q.dtype)
+        np.asarray(key_head)[:, None] == np.arange(kv_heads)[None, :],
+        q.dtype)
     laid = jnp.einsum("bhd,hg->bhgd", q.reshape(slots, heads, hd),
                       of_head).reshape(slots, heads, kv_heads * hd)
+    # The kernel keeps, of ``kv_heads`` equal parts of the value row, the
+    # part of the head's group: the value heads are its ``kv_heads``.
     o = _pallas_attend(laid, k_self, v_self, k_pages, v_pages, table,
-                       lengths, layer, order, n_live, kv_heads, float(scale),
-                       int(window), bool(interpret))
-    return o.reshape(slots, heads * hd)
+                       lengths, layer, order, n_live,
+                       int(value_heads or kv_heads), float(scale),
+                       int(window), bool(interpret),
+                       None if out_dtype is None else jnp.dtype(out_dtype))
+    return o.reshape(slots, -1)

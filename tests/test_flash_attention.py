@@ -573,3 +573,45 @@ def test_gqa_paged_attention_compiles_for_the_v5e(v5e_chip, entries, layers,
     assert not any(op in text for op in (" sort(", " gather(",
                                          " conditional("))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# The decode's attention of `phi4flash-serve-reason` through the same kernel
+# under the differential head map (models/hybrid_ssm.py): 40 query heads
+# paired onto 20 key heads of 64, a pair's double-width value kept, float32
+# out; the full group's table of 384 pages a slot over its one layer, the
+# window group's ring of 33 over its eight.  The block rule gives 25 pages,
+# 400 tokens: no multiple of 128, which Mosaic must take.
+@pytest.mark.parametrize("entries,layers,window", [(384, 1, 0),
+                                                   (33, 8, 512)])
+def test_gqa_paged_attention_compiles_with_the_differential_head_map(
+        v5e_chip, entries, layers, window):
+    from horovod_tpu.models import hybrid_ssm as hs
+    from horovod_tpu.ops import gqa_paged_attention as gpa
+
+    cfg = hs.HybridSSMConfig()
+    pages = 64 * entries + 1
+    assert gpa.block_pages(16, entries, 40, cfg.kv_width, 2) == 25
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(q, k_self, v_self, k_pages, v_pages, table, lengths):
+        order, n_live = gpa.live_first(lengths)
+        return sum(gpa.gqa_paged_attention(
+            q * (1 + i), k_self, v_self, k_pages, v_pages, table, lengths,
+            layer, heads=40, scale=64 ** -0.5, window=window,
+            key_head=hs.key_head_of(cfg), value_heads=10,
+            out_dtype=jnp.float32, order=order, n_live=n_live,
+            interpret=False)
+            for i, layer in enumerate((0, layers - 1)))
+
+    compiled = jax.jit(two_layers).lower(
+        sd(64, 2560), sd(64, 1280), sd(64, 1280),
+        sd(layers, pages, 16, 1280), sd(layers, pages, 16, 1280),
+        sd(64, entries, dtype=jnp.int32), sd(64, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "gqa_paged_attn" in text
+    assert not any(op in text for op in (" sort(", " gather(",
+                                         " conditional("))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

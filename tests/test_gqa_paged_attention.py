@@ -2,7 +2,9 @@
 interpreter against its twin, ``mamba2_hybrid.attend_chunks`` over a view
 gathered by ``afmoe.ring_chunks``: a table that holds every page (the full
 group), a ring that has not wrapped, one that has wrapped once and several
-times."""
+times.  Then the same kernel under another head map, differential attention's
+(``models/hybrid_ssm.py``), against ``attend_view`` over the positions
+themselves, gathered one by one."""
 
 import functools
 
@@ -12,6 +14,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import afmoe as af
+from horovod_tpu.models import hybrid_ssm as hs
 from horovod_tpu.models import mamba2_hybrid as m2
 from horovod_tpu.ops import gqa_paged_attention as gpa
 
@@ -36,7 +39,7 @@ TOL = 2e-5
 
 
 def case(lengths, entries, seed=0, dtype=jnp.float32, nan_elsewhere=False,
-         consecutive=False):
+         consecutive=False, kw=CFG.kv_width, qw=CFG.q_width):
     """Two stores of ``LAYERS`` layers, a table of ``entries`` a slot
     (permuted unless ``consecutive``), one query and one new key and value
     a slot; ``SLOTS`` slots, those past ``lengths`` idle (one shape a
@@ -49,7 +52,6 @@ def case(lengths, entries, seed=0, dtype=jnp.float32, nan_elsewhere=False,
     n_pages = slots * entries + 1
     rng = np.random.RandomState(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    kw = CFG.kv_width
     k_pages = jax.random.normal(ks[0], (LAYERS, n_pages, PAGE, kw))
     v_pages = jax.random.normal(ks[1], (LAYERS, n_pages, PAGE, kw))
     pages = np.arange(1, n_pages)
@@ -66,7 +68,7 @@ def case(lengths, entries, seed=0, dtype=jnp.float32, nan_elsewhere=False,
     cast = lambda x: x.astype(dtype)
     return dict(lengths=jnp.asarray(lengths), table=jnp.asarray(table),
                 k_pages=cast(k_pages), v_pages=cast(v_pages),
-                q=cast(jax.random.normal(ks[2], (slots, CFG.q_width))),
+                q=cast(jax.random.normal(ks[2], (slots, qw))),
                 k_self=cast(jax.random.normal(ks[3], (slots, kw))),
                 v_self=cast(jax.random.normal(ks[4], (slots, kw))))
 
@@ -292,3 +294,199 @@ def test_the_block_and_the_copies_follow_from_the_shapes():
     assert gpa.tokens_read([-1, -1], 257, 16) == 0
     assert gpa.mapped_entries(np.asarray([0, 1, 16, 17, 5000]), 257,
                               16).tolist() == [0, 1, 1, 2, 257]
+
+
+# -- the head map: differential attention through the same kernel -------------
+
+# 16 query heads on 8 key heads of 8 (4 value pairs of 16), window 512 in
+# pages of 16: the cell's ring of 33 entries, a table of 80 for the full
+# group.  float32, so what differs is the order of float32 sums.
+DIFF = hs.HybridSSMConfig(
+    vocab_size=64, hidden_size=128, intermediate_size=64,
+    num_hidden_layers=4, num_attention_heads=16, num_key_value_heads=8,
+    sliding_window=512, d_state=4, dt_rank=4, dtype=jnp.float32)
+DIFF_RING = af.ring_entries(DIFF.sliding_window, PAGE)
+DIFF_LAYER = 3
+
+
+def diff_case(lengths, entries, seed, **kw):
+    hd = DIFF.head_dim
+    c = case(lengths, entries, seed=seed, kw=DIFF.kv_width,
+             qw=DIFF.num_attention_heads * hd, **kw)
+    ks = jax.random.split(jax.random.PRNGKey(100 + seed), 5)
+    ap = {n: 0.3 * jax.random.normal(k, (hd,)) for n, k in zip(
+        ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"), ks)}
+    ap["subln"] = 1.0 + 0.1 * jax.random.normal(ks[4], (2 * hd,))
+    return c, ap
+
+
+@functools.lru_cache(maxsize=None)
+def _diff(which, window):
+    def f(c, ap, layer):
+        groups = {"g": (c["table"], window, c["k_pages"], c["v_pages"])}
+        attend = (hs.paged_attend(c["lengths"], groups, DIFF, interpret=True)
+                  if which == "kernel" else
+                  hs.gathered_attend(c["lengths"], groups, DIFF))
+        return attend("g", layer, DIFF_LAYER, ap, c["q"], c["k_self"],
+                      c["v_self"])
+    return jax.jit(f)
+
+
+def over_the_positions(c, ap, layer, window):
+    """``attend_view`` over the cached positions a slot attends, gathered
+    ONE BY ONE from where the ring's rule puts them (logical page ``j`` in
+    entry ``j mod entries``): no table order, no mask but the padding."""
+    lengths = np.asarray(c["lengths"])
+    table = np.asarray(c["table"])
+    entries = table.shape[1]
+    n = max(1, int(lengths.max()))
+    page = np.zeros((SLOTS, n), np.int32)
+    row = np.zeros((SLOTS, n), np.int32)
+    mask = np.zeros((SLOTS, n), bool)
+    for s, cached in enumerate(lengths):
+        lo = max(0, cached - window + 1) if window else 0
+        for i, pos in enumerate(range(lo, max(cached, 0))):
+            page[s, i] = table[s, (pos // PAGE) % entries]
+            row[s, i] = pos % PAGE
+            mask[s, i] = True
+    k_view, v_view = (x[layer][page, row] for x in (c["k_pages"],
+                                                   c["v_pages"]))
+    return jax.jit(hs.attend_view, static_argnums=(7, 8))(
+        c["q"], c["k_self"], c["v_self"], k_view, v_view, jnp.asarray(mask),
+        ap, DIFF_LAYER, DIFF)
+
+
+# Both sides of the ring's wrap (33 entries of 16: 528 positions), and of
+# the window: one under it, at it, one past it, at the ring's whole length,
+# two rings and more; idle slots among them.
+@pytest.mark.parametrize("entries,window", [(PPS, 0),
+                                            (DIFF_RING, DIFF.sliding_window)])
+@pytest.mark.parametrize("lengths", [
+    (511, -1, 512, 513, -1, 528, 1100), (0, 1, 529, -1, 16, 1056, 1057)])
+def test_the_differential_head_map_equals_attention_over_the_positions(
+        entries, window, lengths):
+    c, ap = diff_case(lengths, entries, seed=len(lengths) + entries)
+    got = _diff("kernel", window)(c, ap, 1)
+    want = over_the_positions(c, ap, 1, window)
+    on = np.asarray(c["lengths"]) >= 0
+    assert got.shape == (SLOTS, DIFF.num_attention_heads * DIFF.head_dim)
+    assert gap(got[on], want[on]) < TOL
+    # The twin off the TPU, a table row gathered whole under the kernel's
+    # mask, is the same attention.
+    twin = _diff("twin", window)(c, ap, 1)
+    assert gap(twin[on], want[on]) < TOL
+    # Idle slots: the kernel's exact zeros stay zeros through the pairs'
+    # subtraction and norm.
+    assert not np.asarray(got)[~on].any()
+
+
+@pytest.mark.parametrize("entries,window", [(PPS, 0),
+                                            (DIFF_RING, DIFF.sliding_window)])
+def test_the_differential_map_under_a_traced_layer(entries, window):
+    """The whole stores and a ``layer`` under ``lax.scan``, every page that
+    is no entry in use of a live slot NaN in every layer."""
+    c, ap = diff_case((600, -1, 513, -1, 40), entries, seed=11,
+                      nan_elsewhere=True)
+    assert bool(jnp.isnan(c["k_pages"]).any())
+    groups = {"g": (c["table"], window, c["k_pages"], c["v_pages"])}
+
+    def every_layer():
+        attend = hs.paged_attend(c["lengths"], groups, DIFF, interpret=True)
+        return jax.lax.scan(
+            lambda carry, layer: (carry, attend(
+                "g", layer, DIFF_LAYER, ap, c["q"], c["k_self"],
+                c["v_self"])), 0, jnp.arange(LAYERS))[1]
+
+    got = jax.jit(every_layer)()
+    assert bool(jnp.isfinite(got).all())
+    clean = dict(c, k_pages=jnp.nan_to_num(c["k_pages"]),
+                 v_pages=jnp.nan_to_num(c["v_pages"]))
+    on = np.asarray(c["lengths"]) >= 0
+    for layer in range(LAYERS):
+        want = over_the_positions(clean, ap, layer, window)
+        assert gap(got[layer][on], want[on]) < TOL
+    assert gap(got[0], got[1]) > 0.1           # the layers differ
+
+
+def test_a_pair_reads_its_two_keys_and_keeps_its_double_width_value():
+    """Straight from the equations, one slot, one cached row a head can
+    tell apart: query head ``2 i + c`` of a pair scores key head ``2 j +
+    c`` (``j`` its key/value pair) and both heads of the pair weigh the
+    SAME double-width value ``[v_2j | v_2j+1]``."""
+    cfg = DIFF
+    assert hs.key_head_of(cfg).tolist() == [0, 1, 0, 1, 2, 3, 2, 3,
+                                            4, 5, 4, 5, 6, 7, 6, 7]
+    c, _ = diff_case((3,), DIFF_RING, seed=12)
+    o = gpa.gqa_paged_attention(
+        c["q"], c["k_self"], c["v_self"], c["k_pages"], c["v_pages"],
+        c["table"], c["lengths"], 0, heads=cfg.num_attention_heads,
+        scale=cfg.head_dim ** -0.5, window=cfg.sliding_window,
+        key_head=hs.key_head_of(cfg),
+        value_heads=cfg.num_key_value_heads // 2, interpret=True)
+    h_n, hd = cfg.num_attention_heads, cfg.head_dim
+    o = np.asarray(o).reshape(SLOTS, h_n, 2 * hd)
+    table = np.asarray(c["table"])
+    k = np.concatenate([np.asarray(c["k_pages"])[0, table[0, 0], :3],
+                        np.asarray(c["k_self"])[:1]]).reshape(4, -1, hd)
+    v = np.concatenate([np.asarray(c["v_pages"])[0, table[0, 0], :3],
+                        np.asarray(c["v_self"])[:1]]).reshape(4, -1, 2 * hd)
+    q = np.asarray(c["q"])[0].reshape(h_n, hd)
+    for h in range(h_n):
+        j = h // 4                               # 4 query heads a kv pair
+        s = k[:, 2 * j + h % 2] @ q[h] * hd ** -0.5
+        p = np.exp(s - s.max())
+        assert np.abs(o[0, h] - (p / p.sum()) @ v[:, j]).max() < TOL
+
+
+def test_the_default_head_map_is_bit_for_bit_the_grouped_one():
+    """No map given: the queries laid by ``i // (heads / kv_heads)`` and a
+    head keeping its own key/value head's lanes, as the wrapper always did
+    (here laid by hand and handed to the kernel's own entry); the same map
+    handed in by name changes nothing either."""
+    c = case((300, -1, 37, 600, 5), PPS, seed=13)
+    heads, kv_heads, hd = (CFG.num_attention_heads, CFG.num_key_value_heads,
+                           CFG.head_dim)
+    of_head = jnp.asarray(
+        (np.arange(heads) // (heads // kv_heads))[:, None]
+        == np.arange(kv_heads)[None, :], c["q"].dtype)
+    laid = jnp.einsum("bhd,hg->bhgd", c["q"].reshape(SLOTS, heads, hd),
+                      of_head).reshape(SLOTS, heads, kv_heads * hd)
+    order, n_live = gpa.live_first(c["lengths"])
+    by_hand = gpa._pallas_attend(
+        laid, c["k_self"], c["v_self"], c["k_pages"], c["v_pages"],
+        c["table"], c["lengths"], 1, order, n_live, kv_heads,
+        float(CFG.attention_multiplier), 0, True).reshape(SLOTS, -1)
+    default = through_the_kernel(c, 1)
+    named = gpa.gqa_paged_attention(
+        c["q"], c["k_self"], c["v_self"], c["k_pages"], c["v_pages"],
+        c["table"], c["lengths"], 1, heads=heads,
+        scale=CFG.attention_multiplier,
+        key_head=np.arange(heads) // (heads // kv_heads),
+        value_heads=kv_heads, out_dtype=c["q"].dtype, interpret=True)
+    assert default.dtype == by_hand.dtype
+    assert np.array_equal(np.asarray(default), np.asarray(by_hand))
+    assert np.array_equal(np.asarray(default), np.asarray(named))
+
+
+@pytest.mark.parametrize("entries,window", [(PPS, 0), (RING, WINDOW),
+                                            (DIFF_RING, 512)])
+def test_attended_rows_is_the_rings_rule_row_by_row(entries, window):
+    """The mask a gathering twin uses, against the ring's rule written out:
+    entry ``e`` holds logical page ``top - (top - e) mod entries``."""
+    lengths = np.asarray([0, 1, 15, 16, 17, window or 40, (window or 40) + 1,
+                          entries * PAGE - 1, entries * PAGE,
+                          entries * PAGE + 1, 3 * entries * PAGE + 7])
+    lengths = lengths[(lengths <= entries * PAGE) | (window > 0)]
+    got = np.asarray(gpa.attended_rows(jnp.asarray(lengths, jnp.int32),
+                                       entries, PAGE, window))
+    for cached, seen in zip(lengths, got):
+        top = max(cached - 1, 0) // PAGE
+        want = np.zeros(entries * PAGE, bool)
+        for e in range(min(-(-cached // PAGE), entries)):
+            logical = top - (top - e) % entries
+            for r in range(PAGE):
+                pos = logical * PAGE + r
+                want[e * PAGE + r] = pos < cached and (
+                    not window or pos > cached - window)
+        assert np.array_equal(seen, want), cached
+        assert want.sum() == (min(cached, window - 1) if window else cached)

@@ -21,6 +21,8 @@ from benchmark.builders.hybrid_ssm import config_of, seeded_params
 from horovod_tpu.models import hybrid_ssm as hs
 from horovod_tpu.models.transformer import (TransformerConfig,
                                             init_transformer, view_rungs)
+from horovod_tpu.memory.planner import ring_entries
+from horovod_tpu.ops import gqa_paged_attention as gpa
 from horovod_tpu.ops import ssm_scan as scan
 from horovod_tpu.serving import InferenceEngine
 from horovod_tpu.serving.kv_cache import PagedKVCache
@@ -31,10 +33,12 @@ with open(os.path.join(cells.HERE, "tests", "fixtures", "configs",
     MODEL = json.load(f)["model"]          # float32
 CFG = config_of(MODEL)
 WINDOW = MODEL["sliding_window"]
+PAGE = 4
+RING = ring_entries(WINDOW, PAGE)      # ceil(8 / 4) + 1 entries of a slot
 
-# float32 on both sides: what is left is the order of sums (the paged
-# view's block-diagonal products, the ring's order of keys, the new
-# token's key beside the view, blockwise softmax, the kernel's chunks).
+# float32 on both sides: what is left is the order of sums (the gathered
+# rows' block-diagonal products, the ring's order of keys, the new
+# token's key beside the view, blockwise softmax, the kernels' chunks).
 # The logits are of order 1; these differences measure 1e-6.  bfloat16
 # operands in the reference's place move them by 1e-3 and more
 # (test_the_tolerance_would_catch_bfloat16), so the tolerance sits between
@@ -266,9 +270,12 @@ def test_last_token_second_half_equals_every_layer_over_the_prompt(n,
     for name in ("state", "tail"):
         assert float(jnp.abs(left[name] - left_all[name]).max()) == 0.0
         assert float(jnp.abs(left[name] - exact[name]).max()) < 1e-6
-    live = np.arange(WINDOW) < n          # ring rows a prompt this long fills
+    # The window layers' keys and values of the real tokens, whole: the
+    # caller keeps the last ring's worth.
     for name in ("window_k", "window_v"):
-        assert float(jnp.abs(left[name] - exact[name])[:, live].max()) < 1e-6
+        assert left[name].shape[:2] == (hs.layer_kinds(
+            MODEL["num_hidden_layers"]).count("window"), bucket)
+        assert float(jnp.abs(left[name][:, :n] - exact[name]).max()) < 1e-6
 
 
 def test_the_tolerance_would_catch_bfloat16():
@@ -289,8 +296,7 @@ def test_the_tolerance_would_catch_bfloat16():
 
 @functools.lru_cache(maxsize=None)
 def engine():
-    # 8 slots: the paged store is attended in groups of 1, 3 and 4.
-    eng = InferenceEngine(params(), CFG, max_slots=8, page_size=4,
+    eng = InferenceEngine(params(), CFG, max_slots=8, page_size=PAGE,
                           capacity=128)
     assert eng._rungs == view_rungs(4, 32) == (32, 64, 128)
     eng.warm_start()
@@ -339,8 +345,7 @@ def check_against_reference(prompts, new, got):
 # Ragged slots.  Prompts of 3 (bucket 4: a pad that must advance nothing),
 # exactly a bucket (32), shorter than the window, longer than it (the
 # prefill's ring has wrapped) and answers that cross it in decode (the
-# ring wraps under decode); the last case fills all three groups and
-# reaches the third rung.
+# ring wraps under decode); the last case has six of eight slots alive.
 @pytest.mark.parametrize("lengths,new", [
     ((3,), (12,)), ((32,), (3,)), ((6, 19), (9, 4)),
     ((20, 5, 70), (6, 14, 3)),
@@ -365,9 +370,9 @@ def test_every_prefill_bucket_serves_the_reference(bucket):
 
 
 def test_a_reused_slot_answers_as_a_fresh_engine_does():
-    """Recurrent state has no mask: the slot's rings, state and tails must
-    be REPLACED by the next prefill.  A long sequence leaves its state in
-    slot 0, a short one follows it there."""
+    """Recurrent state has no mask: the slot's state and tails must be
+    REPLACED by the next prefill.  A long sequence leaves its state in slot
+    0, a short one follows it there."""
     eng = engine()
     resets = counter("serving.state_slot_resets")
     long_p, short_p = prompt(901, 90), prompt(902, 5)
@@ -383,8 +388,8 @@ def test_a_reused_slot_answers_as_a_fresh_engine_does():
 
 
 def test_run_ahead_loop_equals_the_loop_held_at_depth_0(monkeypatch):
-    """The decode loop one iteration ahead over this model's program (seven
-    donated arrays, state beside pages): staggered admissions and
+    """The decode loop one iteration ahead over this model's program (six
+    donated arrays, state beside two groups' pages): staggered admissions and
     finishes serve the same tokens as the loop that fetches before it
     launches, token for token, and count the same reads."""
     eng = engine()
@@ -433,36 +438,58 @@ def test_the_cache_manager_owns_four_kinds_of_state():
     c = eng.cache
     kinds = hs.layer_kinds(MODEL["num_hidden_layers"])
     kvw = CFG.kv_width
-    # (a) ONE paged layer; (b) rings bounded by the window, not by the
-    # capacity; (c) recurrent state that is no function of position; (d)
-    # nothing at all for the gated-memory and cross layers.
-    assert c.n_layers == 1 and c.entry_widths == (kvw, kvw)
-    assert [p.shape for p in c.pages] == [(1, c.n_pages, 4, kvw)] * 2
-    assert [s["name"] for s in c.slot_stores] == [
-        "window_k", "window_v", "ssm_state", "conv_tail", "shared_view"]
     n_win, n_ssm = kinds.count("window"), kinds.count("ssm")
+    # (a) ONE paged layer of every position; (b) the window layers' pages,
+    # a ring a slot, bounded by the window and not by the capacity; (c)
+    # recurrent state that is no function of position; (d) nothing at all
+    # for the gated-memory and cross layers.
+    assert c.n_layers == 1 and c.entry_widths == (kvw, kvw)
+    assert c.group_names == ("full", "window")
+    assert [p.shape for p in c.pages] == [(1, c.n_pages, PAGE, kvw)] * 2
+    assert c.table_width == 32 + RING and RING * PAGE < c.capacity
+    assert [s["name"] for s in c.slot_stores] == ["ssm_state", "conv_tail"]
     assert [x.shape for x in c.slot_state] == [
-        (n_win, 8, WINDOW, kvw), (n_win, 8, WINDOW, kvw),
         (n_ssm, 8, CFG.d_state, CFG.d_inner),
-        (n_ssm, 8, CFG.d_conv - 1, CFG.d_inner),
-        # Where decode gathers the paged layer once for its readers: as
-        # large as the store itself.
-        (2, 8, c.capacity, kvw)]
-    assert c.slot_state[2].dtype == jnp.float32
-    assert WINDOW < c.capacity
-    assert len(c.arrays) == 7 and c.arrays[:2] == c.pages
+        (n_ssm, 8, CFG.d_conv - 1, CFG.d_inner)]
+    assert c.slot_state[0].dtype == jnp.float32
+    # What the executables take and return: the full group's two arrays,
+    # the window group's two, the two state stores.  No room to gather
+    # into, no ring store.
+    assert len(c.arrays) == 6 and c.arrays[:2] == c.pages
+    assert [x.shape for x in c.arrays[2:4]] == [
+        (n_win, 8 * RING + 1, PAGE, kvw)] * 2
     nbytes = c.slot_store_bytes()
-    assert nbytes["window"] == 2 * n_win * 8 * WINDOW * kvw * 4
+    assert set(nbytes) == {"state"}
     assert nbytes["state"] == n_ssm * 8 * (CFG.d_state + CFG.d_conv - 1) \
         * CFG.d_inner * 4
-    assert nbytes["scratch"] == 2 * 8 * c.capacity * kvw * 4
-    gauges = telemetry.metrics()
-    assert gauges["serving.window_store_bytes"]["value"] == nbytes["window"]
-    assert gauges["serving.state_bytes"]["value"] == nbytes["state"]
-    # Admission headroom counts the paged store alone.
+    assert telemetry.metrics()["serving.state_bytes"]["value"] \
+        == nbytes["state"]
+    # Every slot keeps its ring's pages (no pool, no reservation): a free
+    # slot implies room in both groups.
+    assert c.group_pages() == {"full": (0, 8 * 32), "window": (0, 8 * RING)}
     assert c.total_pages == 8 * 32 and c.free_pages() == c.total_pages
     with pytest.raises(ValueError, match="page arrays"):
         c.replace_pages(*c.pages)
+
+
+def test_the_cache_entry_declares_two_groups_and_two_state_stores():
+    entry = CFG.serving_model().cache_entry()
+    kinds = hs.layer_kinds(MODEL["num_hidden_layers"])
+    assert entry["groups"] == (
+        {"name": "full", "n_layers": 1},
+        {"name": "window", "n_layers": kinds.count("window"),
+         "window": WINDOW})
+    assert [(s["name"], s["kind"]) for s in entry["slot_stores"]] == [
+        ("ssm_state", "state"), ("conv_tail", "state")]
+    assert "view_chunk" not in entry
+    # At the published sizes: 8 window layers behind a ring of 33 pages of
+    # 16 a slot, whatever the capacity.
+    big = hs.HybridSSMConfig().serving_model().cache_entry()
+    assert big["groups"][1] == {"name": "window", "n_layers": 8,
+                                "window": 512}
+    assert ring_entries(512, 16) == 33
+    assert not any(s["kind"] in ("window", "scratch")
+                   for s in big["slot_stores"])
 
 
 def test_the_ledger_holds_every_store():
@@ -474,8 +501,111 @@ def test_the_ledger_holds_every_store():
     got = mem.ledger.bytes_by_category()
     assert got["serving.slot_state"] >= sum(
         eng.cache.slot_store_bytes().values())
+    # Both groups' pages: arrays[:4].
     assert got["serving.kv_pages"] >= sum(
-        mem.resident_nbytes(p) for p in eng.cache.pages)
+        mem.resident_nbytes(p) for p in eng.cache.arrays[:4])
+
+
+def _window_rows(eng, slot, layer=0):
+    """What the window group holds for ``slot`` in one of its layers, by
+    position: ``{position: key row}`` of every position the new token
+    would attend, read through the slot's ring as the program reads it."""
+    c = eng.cache
+    table, lengths = c.host_tables()
+    cached = int(lengths[slot])
+    ring = table[slot, c.pages_per_slot:]
+    k = np.asarray(c.arrays[2])[layer]
+    return {pos: k[ring[(pos // PAGE) % RING], pos % PAGE]
+            for pos in range(max(0, cached - WINDOW + 1), cached)}
+
+
+def _admit(eng, seq):
+    """Admit ``seq`` and stop after the pass that prefilled it (which also
+    launches its first decode iterations), the slot still alive."""
+    req = eng.submit(list(seq), max_new_tokens=6)
+    eng.step(now=0)
+    (slot,) = [s for s in range(eng.max_slots)
+               if eng.cache.length(s) >= len(seq)]
+    return req, slot
+
+
+def _holds_the_prompts_window(eng, slot, seq):
+    """Every position the next token attends lies in the slot's ring, and
+    those of the prompt hold the window layer's keys the prompt left."""
+    _, left = _jitted("last")(params(), jnp.asarray(seq + [0, 0], jnp.int32),
+                              jnp.int32(len(seq)))
+    cached = eng.cache.length(slot)
+    rows = _window_rows(eng, slot)
+    assert sorted(rows) == list(range(max(0, cached - WINDOW + 1), cached))
+    mine = [pos for pos in rows if pos < len(seq)]
+    assert len(mine) >= min(len(seq), WINDOW - 3)
+    for pos in mine:
+        assert np.abs(rows[pos]
+                      - np.asarray(left["window_k"][0, pos])).max() < 1e-6
+
+
+def test_a_prompt_longer_than_the_window_leaves_exactly_its_last_ring():
+    """A prompt of 30 in pages of 4 behind a ring of 3: the window group
+    maps 3 pages where the full group maps 8, and they hold the window
+    layer's keys of the last positions, each where the ring's rule puts
+    it."""
+    eng = engine()
+    seq = prompt(950, 30)
+    req, slot = _admit(eng, seq)
+    try:
+        used = eng.cache.group_pages()
+        assert used["window"][0] == RING and used["full"][0] == 8
+        _holds_the_prompts_window(eng, slot, seq)
+    finally:
+        eng.run_until_idle()
+    assert len(req.result(0)) == 6
+    assert eng.cache.group_pages()["window"][0] == 0
+
+
+def test_a_reused_slots_ring_holds_nothing_of_its_last_owner():
+    """A long sequence wraps slot 0's ring many times and leaves; a short
+    one follows it there: every row the short one attends is its own, and
+    it is served as a fresh engine serves it (its ring's pages may be the
+    very pages the long one wrote)."""
+    eng = engine()
+    long_p, short_p = prompt(960, 70), prompt(961, 5)
+    rollout(eng, [long_p], [20])
+    req, slot = _admit(eng, short_p)
+    try:
+        assert slot == 0
+        _holds_the_prompts_window(eng, slot, short_p)
+        assert eng.cache.group_pages()["window"][0] == 2   # 5 to 8 rows
+    finally:
+        eng.run_until_idle()
+    fresh = InferenceEngine(params(), CFG, max_slots=8, page_size=PAGE,
+                            capacity=128)
+    assert rollout(fresh, [short_p], [6])[0][1] == req.result(0)
+
+
+@pytest.mark.parametrize("lengths", [
+    (899, -1, 0, 511, -1, 512, 528, 6143), (-1,) * 8,
+    (1100,) * 26 + (-1,) * 38])
+def test_decode_view_is_what_the_kernel_copies_of_both_groups(lengths):
+    """At the cell's sizes (page 16, 384 pages a slot, a ring of 33): the
+    full group's entries in use once for each of its 8 readers (the full
+    layer and 7 cross layers), the window group's, at most the ring, once
+    for each of the 8 window layers; over the 16 and the slots."""
+    model = hs.HybridSSMConfig().serving_model()
+    lengths = np.asarray(lengths, np.int32)
+    full = gpa.tokens_read(lengths, 384, 16)
+    window = gpa.tokens_read(lengths, 33, 16)
+    got = model.decode_view(lengths, (16, 6144), 16)
+    assert got == pytest.approx((8 * full + 8 * window) / 16 / len(lengths))
+    live = lengths[lengths >= 0]
+    if not len(live):
+        assert got == 0
+    elif len(live) == 26:
+        # The cell's load: 26 alive at 1100: (1104 + 528) / 2 x 26 / 64.
+        assert got == pytest.approx(331.5)
+    else:
+        by_hand = (912 + 0 + 512 + 512 + 528 + 6144,
+                   528 + 0 + 512 + 512 + 528 + 528)
+        assert (full, window) == by_hand
 
 
 def test_a_dense_model_has_no_slot_store_and_its_two_arrays():
@@ -513,44 +643,120 @@ def test_draft_and_tensor_parallel_are_refused_clearly():
                         capacity=64)
 
 
-def test_the_chunk_ladder_and_its_rung_on_the_host_and_in_the_program():
-    # The cell's geometry: 64 slots of 6144 positions, chunks of 256.
-    chunk, ladder = hs.chunk_ladder(64, 6144, 256)
-    assert chunk == 256 and ladder == (24, 48, 96, 192, 384, 768, 1536)
-    # A capacity under a chunk is one chunk a slot.
-    assert hs.chunk_ladder(4, 128, 256) == (128, (1, 2, 4))
-    # The toy engine's: chunks of 32 positions, 4 a slot.
-    chunk, ladder = hs.chunk_ladder(8, 128, MODEL["decode_chunk_tokens"])
-    assert (chunk, ladder) == (32, (4, 8, 16, 32))
-    lengths = np.asarray([70, -1, 5, 33, -1, 126, 31, 32], np.int32)
-    # Cached positions rounded up to whole chunks: 3 + 0 + 1 + 2 + 0 + 4
-    # + 1 + 1 = 12 chunks, the third rung.
-    host = int(hs.chunk_rung(lengths, ladder, chunk))
-    traced = int(jax.jit(lambda ln: hs.chunk_rung(ln, ladder, chunk))(
-        jnp.asarray(lengths)))
-    assert host == traced == 2
-    assert engine().model.decode_view(lengths, engine()._rungs) \
-        == 16 * 32 / 8
-    idle = np.full((8,), -1, np.int32)
-    assert int(hs.chunk_rung(idle, ladder, chunk)) == 0
 
 
-def test_the_shared_view_is_gathered_once_for_its_readers():
-    """One gather of the paged store's keys and one of its values in the
-    decode program, whatever the depth and whatever the rung: the full
-    layer and every cross layer attend the same chunks."""
-    eng = engine()
+# -- decode through the paged kernel (ops/gqa_paged_attention.py) -------------
+
+@functools.lru_cache(maxsize=None)
+def _kernel_engine():
+    eng = InferenceEngine(params(), CFG, max_slots=8, page_size=PAGE,
+                          capacity=128)
+    eng.warm_start()
+    return eng
+
+
+def kernel_engine(monkeypatch):
+    """The engine with the kernel in its decode program, interpreted:
+    ``PAGED_INTERPRET`` is read when the programs are built."""
+    monkeypatch.setattr(hs, "PAGED_INTERPRET", True)
+    return _kernel_engine()
+
+
+# Both sides of the window of 8 and of its ring of 3 pages of 4: never
+# reaching it; starting under it and wrapping the ring more than once; a
+# prompt longer than twice the window; ragged slots of all kinds.
+@pytest.mark.parametrize("lengths,new", [
+    ((3,), (3,)), ((5,), (30,)), ((30,), (20,)), ((8,), (9,)),
+    ((6, 19, 40), (9, 14, 25))])
+def test_prefill_then_decode_through_the_kernel_equals_the_reference(
+        monkeypatch, lengths, new):
+    eng = kernel_engine(monkeypatch)
+    prompts = [prompt(100 + n, n) for n in lengths]
+    views = counter("serving.decode_view_tokens")
+    got = rollout(eng, prompts, new)
+    check_against_reference(prompts, new, got)
+    assert eng.cache.group_pages() == {"full": (0, 8 * 32),
+                                       "window": (0, 8 * RING)}
+    # Iteration i (0-based) attends the slots with more than i + 1 tokens
+    # to give, each at its prompt's length plus i cached positions: what
+    # the kernel copies of the full group for its two readers (the toy has
+    # one cross layer) and of the window group for its two window layers,
+    # over the four and the eight slots.
+    read = 0
+    for i in range(max(new) - 1):
+        at = [n + i if i + 1 < k else -1 for n, k in zip(lengths, new)]
+        read += (2 * gpa.tokens_read(at, 32, PAGE)
+                 + 2 * gpa.tokens_read(at, RING, PAGE)) / 4 / 8
+    assert counter("serving.decode_view_tokens") - views \
+        == pytest.approx(read)
+
+
+def _decode_primitives(eng):
+    from test_latent_paged_attention import _primitives
+
     table, lengths = eng.cache.device_tables()
-    args = (eng.params, *eng.cache.arrays, table, lengths, eng._no_tokens,
-            eng._no_override)
     n = len(eng.cache.arrays)
 
     def fn(params, *rest):
         outs, pages = eng._decode_step(params, rest[:n], *rest[n:])
         return (*outs, *pages)
 
-    text = jax.jit(fn).lower(*args).as_text()
-    kvw = CFG.kv_width
-    page_gathers = [l for l in text.splitlines() if "gather" in l
-                    and f"x4x{kvw}xf32" in l and "tensor<1x" in l]
-    assert len(page_gathers) == 2
+    return _primitives(jax.make_jaxpr(fn)(
+        eng.params, *eng.cache.arrays, table, lengths, eng._no_tokens,
+        eng._no_override).jaxpr)
+
+
+def test_off_the_tpu_the_rows_are_gathered_unless_the_interpreter_is_asked_for(
+        monkeypatch):
+    """The rule is the backend's (``ops/ssd.py``'s): on the CPU the decode
+    program gathers a slot's table row and attends that; with the kernel
+    forced, sixteen calls' worth of ``pallas_call`` and the only gathers
+    left are the embedding's and the tables'.  Neither holds a ladder: no
+    conditional, no loop over chunks."""
+    assert jax.default_backend() == "cpu" and not hs.paged_kernel_runs()
+    twin = _decode_primitives(engine())
+    assert "gather" in twin and "pallas_call" not in twin
+    assert not twin & {"cond", "while", "sort"}
+    eng = kernel_engine(monkeypatch)
+    assert hs.paged_kernel_runs()
+    kernel = _decode_primitives(eng)
+    assert "pallas_call" in kernel
+    assert not kernel & {"cond", "while", "sort"}
+    # The same store either way: the model asks for no room to gather into.
+    assert len(eng.cache.arrays) == len(engine().cache.arrays) == 6
+
+
+# -- the counter's reader (benchmark/metrics/hybrid_view_tokens.py) -----------
+
+def test_hybrid_view_tokens_is_declared_for_the_cell():
+    bench = cells.load_benchmark()
+    mod = cells.load_module("metrics", "hybrid_view_tokens")
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "hybrid_view_tokens"]
+    assert entry == {
+        "name": "hybrid_view_tokens", "unit": mod.UNIT, "better": mod.BETTER,
+        "source": mod.SOURCE, "layer": mod.LAYER, "moves": mod.MOVES,
+        "workloads": ["phi4flash-serve-reason"]}
+    assert (mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER, mod.MOVES) == (
+        "tokens", "lower", "program_counter", "grouped-query attention",
+        "tpot_p90_ms")
+    for cell in (w["name"] for w in bench["workloads"]):
+        listed = [m["name"] for m in cells.resolve(bench, cell)["per_layer"]]
+        assert ("hybrid_view_tokens" in listed) == (
+            cell == "phi4flash-serve-reason")
+
+
+def test_hybrid_view_tokens_is_the_view_counter_over_the_iterations():
+    from test_latent_paged_attention import _Run
+
+    read = cells.load_module("metrics", "hybrid_view_tokens").read
+    assert read(_Run(
+        {"serving.decode_view_tokens": {"value": 768.0},
+         "serving.decode_iterations": {"value": 1}},
+        {"serving.decode_view_tokens": {"value": 768.0 + 300 * 340
+                                        + 100 * 300},
+         "serving.decode_iterations": {"value": 401}})) \
+        == pytest.approx(330.0)
+    assert read(_Run({"serving.decode_iterations": {"value": 5}},
+                     {"serving.decode_iterations": {"value": 55}})) is None
+    assert read(_Run({}, {})) is None

@@ -278,3 +278,50 @@ def test_a_store_of_one_group_is_the_store_it_was(stores):
     assert c.free_pages() == 24 and not c.host_tables()[0].any()
     # No per-group gauge for a store that has one group.
     assert not c._group_gauges
+
+
+# -- a window group beside per-slot stores: the hybrid's store ----------------
+
+def test_a_window_group_and_slot_stores_live_in_one_manager():
+    """What ``models/hybrid_ssm.py`` declares since its window layers are
+    a group of pages: two groups AND per-slot state.  The arrays come
+    group after group, then the stores; a slot's admission maps pages in
+    both groups and counts one reset of its state; an eviction gives both
+    groups' pages back and leaves the stores' rows (the next prefill
+    replaces them); the executables' outputs go back in the same order."""
+    stores = ({"name": "ssm_state", "kind": "state", "shape": (2, 5, 6),
+               "dtype": jnp.float32},
+              {"name": "conv_tail", "kind": "state", "shape": (2, 3, 6),
+               "dtype": jnp.bfloat16})
+    c = grouped(slot_stores=stores)
+    shapes = [a.shape for a in c.arrays]
+    assert shapes == [(1, 1 + 4 * 16, PAGE, 16)] * 2 \
+        + [(4, 1 + 4 * RING, PAGE, 16)] * 2 + [(2, 4, 5, 6), (2, 4, 3, 6)]
+    assert c.arrays[5].dtype == jnp.bfloat16
+    assert c.slot_store_bytes() == {"state": 2 * 4 * 6 * (5 * 4 + 3 * 2)}
+    resets = counter("serving.state_slot_resets")
+    c.begin_slot(3, 30)                # 8 pages of 4; the ring keeps 3
+    assert counter("serving.state_slot_resets") - resets == 1
+    assert used(c) == {"full": 8, "window": RING}
+    assert c.table_row(3).shape == (1, 16 + RING)
+    c.ensure(3, 40)
+    assert used(c) == {"full": 11, "window": RING}
+    # A slot's rows of the stores are the model's to replace: a marker
+    # written as an executable would return it survives the eviction.
+    marked = tuple(jnp.ones_like(a) for a in c.arrays)
+    c.replace_pages(*marked)
+    assert [a.shape for a in c.arrays] == shapes
+    assert c.slot_state[0].shape == (2, 4, 5, 6) and bool(
+        c.slot_state[0].all())
+    c.free_slot(3)
+    assert used(c) == {"full": 0, "window": 0}
+    assert c.headroom().tolist() == [4 * 16, 4 * RING]
+    assert bool(c.slot_state[1].all())
+    with pytest.raises(ValueError, match="page arrays"):
+        c.replace_pages(*marked[:4])
+    # Reserved pools and per-slot stores together, as admission counts
+    # them: the stores take no page of either pool.
+    pooled = grouped(slot_stores=stores, pool_pages=(20, 6))
+    assert pooled.headroom().tolist() == [20, 6]
+    pooled.begin_slot(0, 9, reserve_tokens=20)
+    assert pooled.headroom().tolist() == [15, 3]
