@@ -47,7 +47,8 @@ through the entry points a user calls, at full width per chip:
      scan, both kernels of ops/ssd.py compiled, the state a decode
      iteration moves counted.
   I  the kernel that walks the page table over the latent store
-     (ops/latent_paged_attention.py) against its twin, the view ladder,
+     (ops/latent_paged_attention.py) against its plain twin (every
+     slot's table row gathered whole and attended under the lengths),
      at the shapes of the two cells that decode through it (64 heads,
      640-wide entries, 16-token pages; 47 of 128 and 14 of 64 slots
      alive): the same attention, and the kernel's time beside what its
@@ -142,7 +143,7 @@ SHORTCUT_RMS_REL_TOL = 0.12
 # the largest single error 0.17 of the spread).  The latent model's
 # limit: its readings say where bf16 ends and fp8 begins.
 HYBRID_RMS_REL_TOL = 0.12
-# Paged latent attention against the view ladder, bfloat16 entries: both
+# Paged latent attention against its gathered twin, bfloat16 entries: both
 # round the probabilities and the attended latent to bfloat16, at other
 # points of the sum; a few units in the last place of the largest output.
 PAGED_REL_TOL = 2.0 ** -6
@@ -866,11 +867,11 @@ def leg_mamba2_hybrid(dry):
 def leg_latent_paged_attn(dry):
     """Leg I.  One decode step's attention over every cache layer of a
     seeded store through ``latent_moe.paged_attend`` (the kernel; in the
-    dry run its interpreter) and through ``view_ladder_attend`` (the
-    twin), at the two latent cells' shapes and the slots alive that
-    PERF.md gives for them, lengths drawn from the cells' mixes."""
+    dry run its interpreter) and through ``gathered_attend`` (the plain
+    twin: every slot's table row gathered whole), at the two latent cells'
+    shapes and the slots alive that PERF.md gives for them, lengths drawn
+    from the cells' mixes."""
     from horovod_tpu.models import latent_moe as lm
-    from horovod_tpu.models.transformer import view_rungs
     from horovod_tpu.ops import latent_paged_attention as lpa
 
     cfg = (lm.LatentMoEConfig(num_attention_heads=8, kv_lora_rank=96,
@@ -906,14 +907,11 @@ def leg_latent_paged_attn(dry):
                 jax.random.normal(ks[2], (slots, 1, h, cfg.qk_rope_head_dim),
                                   dt),
                 jax.random.normal(ks[3], (slots, 1, w), dt),
-                # The weights an ARGUMENT, as the engine's are: closed
-                # over, XLA folds their slices into every branch of the
-                # ladder's conditionals (gigabytes of constants).
+                # The weights an ARGUMENT, as the engine's are.
                 {"w_ukv": (jax.random.normal(
                     ks[4], (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim
                                                    + cfg.v_head_dim)))
                            * 0.04).astype(dt)})
-        rungs = view_rungs(page, pps)
 
         def every_layer(make):
             def f(lengths, store, table, q_nope, q_rope, entry, ap):
@@ -924,15 +922,14 @@ def leg_latent_paged_attn(dry):
 
         kernel = every_layer(lambda n, s, t: lm.paged_attend(
             n, s, t, cfg, True if dry else None))
-        twin = every_layer(lambda n, s, t: lm.view_ladder_attend(
-            n, s, t, cfg, rungs))
+        twin = every_layer(lambda n, s, t: lm.gathered_attend(n, s, t, cfg))
         got = kernel(*args).astype(jnp.float32)
         want = twin(*args).astype(jnp.float32)
         on = lengths >= 0
         err = float(jnp.max(jnp.abs(got - want)[:, on]))
         top = float(jnp.max(jnp.abs(want)))
         check(err <= (1e-5 if dry else PAGED_REL_TOL) * top,
-              f"{name}: kernel and view ladder differ by {err} of {top}")
+              f"{name}: kernel and plain twin differ by {err} of {top}")
         check(not np.asarray(got)[:, ~on].any(),
               f"{name}: an idle slot's attention is not zero")
         live_bytes = (int(lengths[on].sum()) * w * jnp.dtype(dt).itemsize
@@ -940,16 +937,11 @@ def leg_latent_paged_attn(dry):
         out[name] = {
             "alive": int(on.sum()), "live_tokens": int(lengths[on].sum()),
             "kernel_tokens_a_layer": lpa.tokens_read(lengths, page),
-            "ladder_tokens_a_layer": int(lm.view_ladder_tokens(lengths,
-                                                               rungs)),
             "max_err_over_max": err / top,
             "live_bytes_at_819_gb_s_ms": round(live_bytes / 819e9 * 1e3, 4)}
         if not dry:       # a time off the chip is no time
-            out[name].update(
-                kernel_ms=round(timed_steps(lambda: kernel(*args), 5) * 1e3,
-                                3),
-                view_ladder_ms=round(timed_steps(lambda: twin(*args), 5)
-                                     * 1e3, 3))
+            out[name]["kernel_ms"] = round(
+                timed_steps(lambda: kernel(*args), 5) * 1e3, 3)
     return out
 
 

@@ -151,19 +151,17 @@ def size_page_pools(groups: Sequence[dict], token_bytes: int,
 
 
 def slot_store_bytes(slot_stores: Sequence[dict], max_slots: int,
-                     capacity: int, view_tokens: int = 0) -> int:
+                     capacity: int) -> int:
     """The per-slot stores a serving model declares beside its pages
     (``cache_entry()["slot_stores"]``, serving/models.py: window rings,
     recurrent state, scratch), one array ``[layers, max_slots, *shape]``
-    each, a dimension ``"capacity"`` the slot's and ``"view"``
-    ``view_tokens`` (``PagedKVCache.view_tokens``): byte for byte the
+    each, a dimension ``"capacity"`` the slot's: byte for byte the
     ``serving.slot_state`` ledger category the cache manager charges.
     They are fixed at ``max_slots``, so a what-if over slots moves them
     in proportion, and where they outweigh the pages (a matrix state a
     head) they decide how many slots fit."""
-    named = {"capacity": capacity, "view": view_tokens}
     return sum(max_slots * dtype_bytes(s["dtype"]) * int(math.prod(
-        named.get(d, d) for d in s["shape"]))
+        capacity if d == "capacity" else d for d in s["shape"]))
         for s in slot_stores)
 
 
@@ -543,8 +541,7 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
                  capacity: Optional[int] = None,
                  slot_stores: Sequence[dict] = (),
                  groups: Sequence[dict] = (),
-                 pool_pages: Optional[Sequence[int]] = None,
-                 view_tokens: int = 0) -> MemoryPlan:
+                 pool_pages: Optional[Sequence[int]] = None) -> MemoryPlan:
     """Plan for the serving engine: the paged KV store (the dominant
     framework buffer unless ``slot_stores``, a model's per-slot stores
     as its ``cache_entry()`` declares them, outweigh it:
@@ -583,8 +580,7 @@ def plan_serving(n_layers: int, n_heads: int, head_dim: int,
     framework = {"serving.kv_pages": kv}
     if slot_stores:
         framework["serving.slot_state"] = slot_store_bytes(
-            slot_stores, max_slots, pages_per_slot * page_size,
-            view_tokens)
+            slot_stores, max_slots, pages_per_slot * page_size)
     if prefix_pages:
         framework["serving.prefix_pages"] = prefix_pages_bytes(
             n_layers, n_heads, head_dim, prefix_pages, page_size,

@@ -29,9 +29,8 @@ layers whole.
 
 This is a SIBLING of ``models/mamba2_hybrid.py`` and of ``models/
 latent_moe.py``, chosen by the published keys (``model_type``,
-``layer_types``).  Shared with the first: ``attend_chunks``, ``fill_view``
-and ``chunk_rung`` of the decode's paged view, the twin off the TPU; with
-the second: the held expert layer and its counters.
+``layer_types``).  Shared with the first: the RMS norm; with the second:
+the held expert layer and its counters.
 
 **The cache**: two layer GROUPS of the paged store (serving/kv_cache.py):
 ``full`` (the full layers: every position) and ``window`` (the sliding
@@ -39,9 +38,10 @@ layers: a ring of ``ceil(window / page) + 1`` pages a slot).  On the TPU
 the decode program attends a group's pages where they lie, through a
 kernel that walks the group's page table for the slots alive
 (``ops/gqa_paged_attention.py``: the ring is its mask's).  Its twin
-elsewhere gathers a group's live pages into one shared view, a chunk list
-as long as the slots alive need together, and attends the rung that holds
-it; :func:`paged_kernel_runs` says which, from the backend.
+elsewhere is the plain thing (:func:`gathered_attend`): a slot's table row
+of the group gathered whole and attended under the kernel's own mask;
+:func:`paged_kernel_runs` says which, from the backend.  The store is the
+same four arrays on every backend.
 """
 
 from __future__ import annotations
@@ -55,24 +55,21 @@ import numpy as np
 
 from ..memory.planner import ring_entries
 from ..ops import gqa_paged_attention as _paged
-from ..ops.flash_attention import gqa_window_attention
-from ..ops.gqa_paged_attention import mapped_entries
+from ..ops.flash_attention import gqa_window_attention, kernel_runs
 from ..parallel.expert import (moe_layer_held, route_sigmoid_bias_top_k,
                                swiglu)
 from .hybrid_ssm import (_M_SHARED_KV, _M_WINDOW, PREFILL_Q_BLOCK, _dot,
                          _masked_exp)
 from .latent_moe import LatentMoEServing
-from .mamba2_hybrid import attend_chunks, chunk_rung, fill_view, rms_norm
+from .mamba2_hybrid import rms_norm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # A test's: run the prompt's flash kernel in the Pallas interpreter.
 FLASH_INTERPRET = False
 # ``ops/gqa_paged_attention.py``'s ``interpret``: None is the rule (the
-# kernel on the TPU, the view ladder elsewhere); a test sets True to run the
-# kernel in the Pallas interpreter, before the engine builds its programs.
+# kernel on the TPU, the gathered rows elsewhere); a test sets True to run
+# the kernel in the Pallas interpreter, before the engine builds its programs.
 PAGED_INTERPRET = None
-# A rung of the decode's chunk ladder over the next longer one.
-LADDER_STEP = 0.8
 
 
 @dataclass(frozen=True)
@@ -104,7 +101,9 @@ class AfmoeConfig:
     experts_held: int = 256
     expert_offset: int = 0
     dtype: object = jnp.bfloat16
-    # Positions of one chunk of the decode's paged view (whole pages).
+    # Read by nothing since the decode's twin gathers whole table rows (it
+    # was a chunk of the gathered view); taken because the benchmark's
+    # builder hands it on from a fixture that still names it.
     decode_chunk_tokens: int = 256
 
     def __post_init__(self):
@@ -139,8 +138,8 @@ class AfmoeConfig:
 
     @property
     def attention_multiplier(self) -> float:
-        """The scores' scale, under the name ``mamba2_hybrid.attend_chunks``
-        reads it by."""
+        """The scores' scale, under the name the sibling families' configs
+        publish it by."""
         return self.head_dim ** -0.5
 
     @property
@@ -246,9 +245,9 @@ def gate_out(a, gate, ap, cfg: AfmoeConfig):
 
 def flash_runs() -> bool:
     """Whether a prompt's attention goes through the streaming flash
-    kernel: on the TPU, or where a test asks for the interpreter
-    (``ops/ssd.py``'s rule); elsewhere :func:`attend_block`, its twin."""
-    return FLASH_INTERPRET or jax.default_backend() == "tpu"
+    kernel: on the TPU, or where a test asks for the interpreter (the
+    kernels' one rule); elsewhere :func:`attend_block`, its twin."""
+    return kernel_runs(FLASH_INTERPRET or None)
 
 
 def attend_prompt(q, k, v, cfg: AfmoeConfig, window: int = 0):
@@ -380,69 +379,13 @@ def forward_full(params, tokens, cfg: AfmoeConfig):
                         last_only=False)[0]
 
 
-# -- the decode's paged view --------------------------------------------------
-
-def pool_ladder(slots: int, a_slot: int, view_chunks: int) -> tuple:
-    """The lengths of a group's chunk list the attention is compiled for:
-    from the longest one (every slot's whole table, or what the shared view
-    holds, in whole slots' worth) down to one slot's worth ``a_slot``, each
-    rung ``LADDER_STEP`` of the one above.  A rung's attention costs what
-    the rung holds, so a pass's time is a STEP function of the live tokens;
-    halving rungs made it jump by up to a half of the attention at a
-    boundary, and a load near one ran in two modes 13% apart in the gap
-    between tokens (PERF.md section 6, PR 41)."""
-    rungs, n = [], min(slots * a_slot, view_chunks // a_slot * a_slot)
-    while n > a_slot:
-        rungs.append(n)
-        n = min(n - 1, int(n * LADDER_STEP))
-    return tuple(reversed(rungs + [a_slot]))
-
-
-def ring_chunks(table, cached, chunk: int, page_size: int, window: int,
-                n_chunks: int):
-    """Where each chunk of the shared view comes from, for a group whose
-    table row is a ring of pages (``table [slots, entries]``: logical page
-    ``j`` in entry ``j mod entries``; the full group's row is a ring that
-    never wraps): the entries in use of all slots as one list of chunks of
-    ``chunk / page_size`` entries, a slot's in a row, slot after slot,
-    ``n_chunks`` long.  ``window``: a row is attended while the new token
-    at position ``cached`` is less than ``window`` past it (0: always).
-
-    Returns ``(pages [n_chunks, pages a chunk], mask [n_chunks, chunk],
-    owner [n_chunks], mine [n_chunks, slots] float32, used)`` as
-    ``mamba2_hybrid.chunk_index`` does for a table that is no ring."""
-    b, entries = table.shape
-    per = chunk // page_size
-    mapped = mapped_entries(cached, entries, page_size)
-    need = (mapped + per - 1) // per
-    ends = jnp.cumsum(need)
-    c = jnp.arange(n_chunks)
-    live = c < ends[-1]
-    owner = jnp.minimum(jnp.searchsorted(ends, c, side="right"), b - 1)
-    local = c - (ends - need)[owner]
-    entry = local[:, None] * per + jnp.arange(per)[None, :]    # [c, per]
-    held = live[:, None] & (entry < mapped[owner][:, None])
-    pages = jnp.where(held, table[owner[:, None],
-                                  jnp.clip(entry, 0, entries - 1)], 0)
-    # The logical page an entry holds: the newest one congruent to it.
-    top = (cached[owner][:, None] - 1) // page_size
-    logical = top - (top - entry) % entries
-    pos = (logical[:, :, None] * page_size
-           + jnp.arange(page_size)[None, None, :])             # [c, per, ps]
-    at = cached[owner][:, None, None]
-    mask = held[:, :, None] & (pos < at)
-    if window:
-        mask = mask & (pos > at - window)
-    mine = ((owner[:, None] == jnp.arange(b)[None, :])
-            & live[:, None]).astype(jnp.float32)
-    return pages, mask.reshape(n_chunks, chunk), owner, mine, ends[-1]
-
+# -- the decode's attention over the paged groups -----------------------------
 
 def paged_kernel_runs() -> bool:
     """Whether the decode program attends through the kernel that walks
     the groups' page tables: read off the backend the program is built for
     (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
-    return _paged.use_kernel(PAGED_INTERPRET)
+    return _paged.kernel_runs(PAGED_INTERPRET)
 
 
 def paged_attend(lengths, groups, cfg: AfmoeConfig, interpret=None):
@@ -462,81 +405,76 @@ def paged_attend(lengths, groups, cfg: AfmoeConfig, interpret=None):
     return attend
 
 
-def view_ladder_attend(lengths, groups, view, cfg: AfmoeConfig):
-    """The decode step's ``attend`` over a gathered view, the kernel's twin
-    off the TPU.  ``view [2, slots, view positions a slot, kv_width]`` is
-    ONE layer's room: each layer in turn gathers its own group's live
-    pages of its own paged layer into it as chunks
-    (``mamba2_hybrid.fill_view``) and attends the leading chunks that hold
-    the list, how many a rung of the group's ladder picked INSIDE the
-    program from ``lengths``.  Returns ``(attend, left)``: ``left()`` is
-    ``(view,)`` as the last layer left it."""
-    b = lengths.shape[0]
-    chunk = cfg.decode_chunk_tokens
-    ps = groups[FULL][2].shape[2]
-    if chunk % ps:
-        raise ValueError(f"decode_chunk_tokens {chunk} is not whole pages "
-                         f"of {ps}")
-    per = chunk // ps
-    cached = jnp.clip(lengths, 0, None)
-    held = [view.reshape(2, -1, chunk, view.shape[-1])]
-    ladders = {}
-    for kind, (table, window, _, _) in groups.items():
-        entries = table.shape[1]
-        rungs = pool_ladder(b, -(-entries // per), held[0].shape[1])
-        index = ring_chunks(table, cached, chunk, ps, window, rungs[-1])
-        picked = chunk_rung(mapped_entries(cached, entries, ps) * ps, rungs,
-                            chunk)
-        ladders[kind] = (rungs, index, picked)
+def attend_view(q, k_self, v_self, k_view, v_view, mask, cfg: AfmoeConfig):
+    """Grouped-query attention of ONE query a row over a view of cached
+    keys and values plus the row's own new key and value, which are not in
+    the view.  ``q [b, heads * hd]``; ``k_self``/``v_self`` ``[b,
+    kv_width]``; ``k_view``/``v_view`` ``[b, n, kv_width]``; ``mask [b,
+    n]``: which view rows a row attends (none: it attends itself only).
+    Returns ``[b, heads * hd]``."""
+    b, n = mask.shape
+    hd, dt, f32 = cfg.head_dim, q.dtype, jnp.float32
+    g = cfg.num_key_value_heads
+    q4 = q.reshape(b, g, -1, hd)
+    scores = jnp.einsum("bgrd,bngd->bgrn", q4, k_view.reshape(b, n, g, hd),
+                        preferred_element_type=f32
+                        ) * cfg.attention_multiplier
+    s_self = jnp.einsum("bgrd,bgd->bgr", q4, k_self.reshape(b, g, hd),
+                        preferred_element_type=f32
+                        ) * cfg.attention_multiplier
+    mask = mask[:, None, None, :]
+    m = jnp.maximum(jnp.max(jnp.where(mask, scores, -jnp.inf), axis=-1),
+                    s_self)
+    p = _masked_exp(scores, mask, m[..., None])
+    p_self = jnp.exp(s_self - m)
+    o = jnp.einsum("bgrn,bngd->bgrd", p.astype(dt),
+                   v_view.reshape(b, n, g, hd), preferred_element_type=f32)
+    o = o + p_self[..., None] * v_self.astype(f32).reshape(b, g, 1, hd)
+    denom = jnp.sum(p, axis=-1) + p_self
+    return (o / denom[..., None]).astype(dt).reshape(b, -1)
 
-    def over(n, index, chunks, q, k_self, v_self):
-        _, mask, owner, mine, _ = index
-        return attend_chunks(q, k_self, v_self,
-                             (chunks[0, :n], chunks[1, :n], mask[:n],
-                              owner[:n], mine[:n]), cfg)
+
+def gathered_attend(lengths, groups, cfg: AfmoeConfig):
+    """The kernel's twin off the TPU, the plainest thing that is right: a
+    slot's table row gathered in table order, every entry of it, and
+    :func:`attend_view` over that under the kernel's mask
+    (``gqa_paged_attention.gathered_rows``)."""
+    cached = jnp.clip(lengths, 0, None)
 
     def attend(kind, layer, q, k_self, v_self):
-        rungs, index, picked = ladders[kind]
-        _, _, k_pages, v_pages = groups[kind]
-        held[0] = fill_view(held[0], k_pages, v_pages, index[0], index[4],
-                            rungs[0], layer=layer)
-        return jax.lax.switch(picked,
-                              [partial(over, n, index) for n in rungs],
-                              held[0], q, k_self, v_self)
+        table, window, k_pages, v_pages = groups[kind]
+        return attend_view(q, k_self, v_self, *_paged.gathered_rows(
+            cached, table, k_pages, v_pages, layer, window), cfg)
 
-    return attend, lambda: (held[0].reshape(view.shape),)
+    return attend
 
 
 def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
     """One token a slot.  ``tokens [slots]``; ``lengths [slots]``: the
     position of the new token, the count of cached ones (-1: an idle
     slot); ``stores = (full_k, full_v [full layers, pages, page,
-    kv_width], win_k, win_v [sliding layers, ...])`` and, where the view
-    ladder attends, behind them ``view [2, slots, view positions a slot,
-    kv_width]``; ``table [slots, pages a slot + ring entries]``, the two
-    groups' tables side by side.
+    kv_width], win_k, win_v [sliding layers, ...])``; ``table [slots,
+    pages a slot + ring entries]``, the two groups' tables side by side.
 
     Each layer attends its own group's pages of its own paged layer: on
-    the TPU where they lie (:func:`paged_attend`), elsewhere through the
-    view (:func:`view_ladder_attend`); :func:`paged_kernel_runs` says
-    which.  The new token's own key and value are not in the store; the
-    caller writes them to both groups at the end.
+    the TPU where they lie (:func:`paged_attend`), elsewhere over a
+    gathered table row (:func:`gathered_attend`);
+    :func:`paged_kernel_runs` says which, asked here and nowhere else.
+    The new token's own key and value are not in the store; the caller
+    writes them to both groups at the end.
 
     Returns ``(logits [slots, vocab], k [layers, slots, kv_width], v,
-    counts [expert layers, held], view)``, ``view`` a tuple: the view as
-    the ladder left it, or whatever came (the kernel touches none)."""
-    full_k, full_v, win_k, win_v, *view = stores
+    counts [expert layers, held])``."""
+    full_k, full_v, win_k, win_v = stores
     f32 = jnp.float32
     ring = ring_entries(cfg.sliding_window, full_k.shape[2])
     pps = table.shape[1] - ring
     cached = jnp.clip(lengths, 0, None)
     groups = {FULL: (table[:, :pps], 0, full_k, full_v),
               SLIDING: (table[:, pps:], cfg.sliding_window, win_k, win_v)}
-    if paged_kernel_runs():
-        attend = paged_attend(lengths, groups, cfg, PAGED_INTERPRET)
-        left = lambda: tuple(view)
-    else:
-        attend, left = view_ladder_attend(lengths, groups, *view, cfg)
+    attend = (paged_attend(lengths, groups, cfg, PAGED_INTERPRET)
+              if paged_kernel_runs() else
+              gathered_attend(lengths, groups, cfg))
 
     x = params["embed"][tokens].astype(f32) * cfg.embedding_multiplier
     new_k, new_v, counts = [], [], []
@@ -556,20 +494,19 @@ def decode_step(params, tokens, lengths, stores, table, cfg: AfmoeConfig):
         if n is not None:
             counts.append(n)
     return (head(x, params, cfg), jnp.stack(new_k), jnp.stack(new_v),
-            jnp.stack(counts), left())
+            jnp.stack(counts))
 
 
 # -- what the serving engine asks ---------------------------------------------
 
 class AfmoeServing:
     """The serving protocol (serving/models.py) for this model: two paged
-    layer groups and, where the view ladder attends them
-    (:func:`paged_kernel_runs` says no), one scratch store."""
+    layer groups, the same four arrays on every backend."""
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False
-    tensor_parallel_why = ("its window group and shared view are not "
-                           "written for a sharded model axis")
+    tensor_parallel_why = ("its window group is not written for a "
+                           "sharded model axis")
     prefix_cache = False
     prefix_cache_why = ("a window group's pages are a ring written over in "
                         "place: a cached prefix page of the full group has "
@@ -580,7 +517,6 @@ class AfmoeServing:
     def __init__(self, cfg: AfmoeConfig) -> None:
         self.cfg = cfg
         self.full, self.sliding = group_layers(cfg)
-        self._view_chunks = 0
 
     def identity(self) -> dict:
         c = self.cfg
@@ -597,59 +533,31 @@ class AfmoeServing:
                             c.num_experts_per_tok],
                 "route": [c.route_norm, c.route_scale],
                 "mup_enabled": c.mup_enabled,
-                "decode_chunk_tokens": c.decode_chunk_tokens,
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
     def cache_entry(self) -> dict:
-        """Keys and values, in two layer groups.  Where the view ladder
-        attends them, the shared view beside them, sized by the cache
-        manager from its pools (``"view"``); the kernel reads the pages in
-        place and asks for none."""
+        """Keys and values, in two layer groups (the window group's table
+        row a ring of ``ring_entries`` pages a slot).  The decode reads
+        the pages in place and asks for no room to gather into."""
         c = self.cfg
-        entry = {"n_layers": len(self.full),
-                 "n_heads": c.num_key_value_heads, "head_dim": c.head_dim,
-                 "widths": (c.kv_width,) * 2,
-                 "groups": ({"name": "full", "n_layers": len(self.full)},
-                            {"name": "window", "n_layers": len(self.sliding),
-                             "window": c.sliding_window})}
-        if not paged_kernel_runs():
-            entry["view_chunk"] = c.decode_chunk_tokens
-            # ONE layer's view: the layers gather and attend in turn, each
-            # at one layer's size.
-            entry["slot_stores"] = (
-                {"name": "paged_view", "kind": "scratch",
-                 "shape": (2, "view", c.kv_width), "dtype": c.dtype},)
-        return entry
+        return {"n_layers": len(self.full),
+                "n_heads": c.num_key_value_heads, "head_dim": c.head_dim,
+                "widths": (c.kv_width,) * 2,
+                "groups": ({"name": "full", "n_layers": len(self.full)},
+                           {"name": "window", "n_layers": len(self.sliding),
+                            "window": c.sliding_window})}
 
-    def observe_stores(self, nbytes: dict) -> None:
-        """Bytes of the per-slot stores by kind, once at build: the view's
-        say how many chunks the ladders may reach."""
-        c = self.cfg
-        self._view_chunks = nbytes.get("scratch", 0) // (
-            2 * c.decode_chunk_tokens * c.kv_width
-            * jnp.dtype(c.dtype).itemsize)
-
-    def _rung_tokens(self, lengths, entries: int, page_size: int) -> int:
-        chunk = self.cfg.decode_chunk_tokens
-        rungs = pool_ladder(len(lengths), -(-entries * page_size // chunk),
-                            self._view_chunks)
-        cached = np.clip(lengths, 0, None)
-        return chunk * rungs[int(chunk_rung(
-            mapped_entries(cached, entries, page_size) * page_size, rungs,
-            chunk))]
-
-    def decode_view(self, lengths, rungs, page_size=None) -> float:
-        """Positions a slot a layer the decode program attends at these
-        (host) lengths, by the rule the program follows: where the kernel
-        runs, what it copies (each group's entries in use of the live
-        slots, whole pages); on the ladder each group's rung; weighted by
-        the groups' layers, over the slots."""
-        read = (_paged.tokens_read if paged_kernel_runs()
-                else self._rung_tokens)
-        full = read(lengths, rungs[-1] // page_size, page_size)
-        window = read(lengths, ring_entries(self.cfg.sliding_window,
-                                            page_size), page_size)
+    def decode_view(self, lengths, page_size, pages_per_slot) -> float:
+        """Positions a slot a layer the decode program reads of the paged
+        stores at these (host) lengths: each group's entries in use of the
+        live slots, whole pages (what the kernel copies; its twin gathers
+        the whole rows and masks the rest), weighted by the groups'
+        layers, over the slots."""
+        full = _paged.tokens_read(lengths, pages_per_slot, page_size)
+        window = _paged.tokens_read(
+            lengths, ring_entries(self.cfg.sliding_window, page_size),
+            page_size)
         n_f, n_w = len(self.full), len(self.sliding)
         return ((n_f * full + n_w * window) / (n_f + n_w) / len(lengths))
 
@@ -669,9 +577,9 @@ class AfmoeServing:
         return (rows[jnp.asarray(self.full)],
                 rows[jnp.asarray(self.sliding)])
 
-    def decode(self, params, pages, table, lengths, tokens, rungs):
-        full_k, full_v, win_k, win_v = pages[:4]
-        logits, k, v, counts, view = decode_step(
+    def decode(self, params, pages, table, lengths, tokens):
+        full_k, full_v, win_k, win_v = pages
+        logits, k, v, counts = decode_step(
             params, tokens, lengths, pages, table, self.cfg)
         # One row a slot in every layer of both groups, written where it
         # lies (see DenseLM.decode): the full group at the position's own
@@ -698,13 +606,13 @@ class AfmoeServing:
                 win_k, k_w[:, slot][:, None, None, :], at)
             win_v = jax.lax.dynamic_update_slice(
                 win_v, v_w[:, slot][:, None, None, :], at)
-        return (logits, counts), (full_k, full_v, win_k, win_v, *view)
+        return (logits, counts), (full_k, full_v, win_k, win_v)
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens):
         """``start`` is always 0 here (``prefix_cache`` is off).  The full
         group takes every page of the prompt; the window group the last
         ``ring`` pages' worth, each into its ring entry."""
-        full_k, full_v, win_k, win_v, *view = pages
+        full_k, full_v, win_k, win_v = pages
         ps, bucket = full_k.shape[2], tokens.shape[1]
         ring = ring_entries(self.cfg.sliding_window, ps)
         pps = table_row.shape[1] - ring
@@ -739,4 +647,4 @@ class AfmoeServing:
                                            (full_k, full_v))
         win_k, win_v = jax.lax.fori_loop(0, min(ring, n_pages), write_ring,
                                          (win_k, win_v))
-        return (logits,), (full_k, full_v, win_k, win_v, *view)
+        return (logits,), (full_k, full_v, win_k, win_v)

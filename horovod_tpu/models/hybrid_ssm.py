@@ -374,7 +374,7 @@ def paged_kernel_runs() -> bool:
     """Whether the decode program attends through the kernel that walks
     the groups' page tables: read off the backend the program is built for
     (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
-    return _paged.use_kernel(PAGED_INTERPRET)
+    return _paged.kernel_runs(PAGED_INTERPRET)
 
 
 def paged_attend(lengths, groups, cfg: HybridSSMConfig, interpret=None):
@@ -405,18 +405,13 @@ def gathered_attend(lengths, groups, cfg: HybridSSMConfig):
     """The kernel's twin off the TPU, the plainest thing that is right: a
     slot's table row gathered in table order, every entry of it, and
     :func:`attend_view` over that under the kernel's mask
-    (``gqa_paged_attention.attended_rows``)."""
+    (``gqa_paged_attention.gathered_rows``)."""
     cached = jnp.clip(lengths, 0, None)
 
     def attend(kind, at, layer, ap, q, k_self, v_self):
         table, window, k_pages, v_pages = groups[kind]
-        b, entries = table.shape
-        ps = k_pages.shape[2]
-        mask = _paged.attended_rows(cached, entries, ps, window)
-        k_view, v_view = (x[at][table].reshape(b, entries * ps, -1)
-                          for x in (k_pages, v_pages))
-        return attend_view(q, k_self, v_self, k_view, v_view, mask, ap,
-                           layer, cfg)
+        return attend_view(q, k_self, v_self, *_paged.gathered_rows(
+            cached, table, k_pages, v_pages, at, window), ap, layer, cfg)
 
     return attend
 
@@ -712,14 +707,14 @@ class HybridSSMServing:
         """Bytes of the per-slot stores by kind, once at build."""
         _M_STATE_BYTES.set(nbytes.get("state", 0))
 
-    def decode_view(self, lengths, rungs, page_size=None) -> float:
+    def decode_view(self, lengths, page_size, pages_per_slot) -> float:
         """Positions a slot a layer the decode program reads of the paged
         stores at these (host) lengths: each group's entries in use of the
         live slots, whole pages (what the kernel copies; its twin gathers
         the whole rows and masks the rest), the full group's once for each
         of its readers, the window group's once for each window layer,
         over those layers and the slots."""
-        full = _paged.tokens_read(lengths, rungs[-1] // page_size, page_size)
+        full = _paged.tokens_read(lengths, pages_per_slot, page_size)
         window = _paged.tokens_read(
             lengths, ring_entries(self.cfg.sliding_window, page_size),
             page_size)
@@ -734,7 +729,7 @@ class HybridSSMServing:
         _M_SHARED_KV.inc(int(seen.sum()))
         _M_WINDOW.inc(int(np.minimum(seen, self.cfg.sliding_window).sum()))
 
-    def decode(self, params, pages, table, lengths, tokens, rungs):
+    def decode(self, params, pages, table, lengths, tokens):
         k_pages, v_pages, win_k, win_v = pages[:4]
         logits, new = decode_step(params, tokens, lengths, pages, table,
                                   self.cfg)

@@ -23,9 +23,10 @@ attends over the cached entries themselves, one 576-wide "head" shared
 by all query heads.
 
 :class:`LatentMoEServing` is what ``serving.InferenceEngine`` asks for
-the cache entry, the paged decode step (:func:`ladder_attend`: on the TPU
+the cache entry, the paged decode step (:func:`decode_attend`: on the TPU
 a kernel that walks the page table, ``ops/latent_paged_attention.py``;
-elsewhere its twin, the view ladder), the prefill step and the
+elsewhere its twin, :func:`gathered_attend`, every slot's table row
+gathered whole and attended under the lengths), the prefill step and the
 fingerprint (serving/models.py has the protocol).
 """
 
@@ -42,7 +43,6 @@ import numpy as np
 from .. import telemetry as _telemetry
 from ..ops import latent_paged_attention as _paged
 from ..parallel.expert import moe_layer_held, swiglu
-from .transformer import view_rung
 
 _M_MOE_ASSIGN = _telemetry.counter(
     "serving.moe_assignments", "token-expert pairs the decode iterations "
@@ -67,7 +67,7 @@ CACHE_LANE = 128
 # Queries of one block of the prefill's attention.
 PREFILL_Q_BLOCK = 256
 # ``ops/latent_paged_attention.py``'s ``interpret``: None is the rule (the
-# kernel on the TPU, the view ladder elsewhere); a test sets True to run
+# kernel on the TPU, the gathered rows elsewhere); a test sets True to run
 # the kernel in the interpreter through the model.
 PAGED_INTERPRET = None
 
@@ -481,44 +481,11 @@ def prefill_step(params, tokens, n_valid, cfg: LatentMoEConfig):
                    pos < n_valid[:, None])
 
 
-def slot_groups(slots: int) -> tuple:
-    """Sizes of the groups the decode step attends in, longest sequences
-    first: an eighth of the slots, three eighths, the rest.  Each group
-    rides a rung of its own, so one long sequence costs its group the
-    long view and not every slot (at 64 slots and a mix that reaches
-    2816 tokens the one-rung program gathered 64 x 4096 tokens a layer
-    with 21 slots alive)."""
-    if slots < 8:
-        return (slots,)
-    return (slots // 8, 3 * slots // 8, slots - slots // 8 - 3 * slots // 8)
-
-
-def group_rungs(lengths, rungs, groups) -> list:
-    """The rung index of each group of :func:`slot_groups`, from the
-    sorted lengths alone: the same function for the traced ``lengths``
-    of the program and for the host's numpy copy."""
-    sort = np.sort if isinstance(lengths, np.ndarray) else jnp.sort
-    by_length = -sort(-lengths)
-    out, start = [], 0
-    for size in groups:
-        out.append(view_rung(by_length[start:start + size], rungs, 1))
-        start += size
-    return out
-
-
-def view_ladder_tokens(lengths, rungs) -> int:
-    """Tokens of view the ladder gathers in one cache layer at these
-    (host) lengths: its groups' rungs times their sizes."""
-    groups = slot_groups(len(lengths))
-    return sum(size * rungs[int(i)] for size, i in zip(
-        groups, group_rungs(lengths, rungs, groups)))
-
-
 def paged_kernel_runs() -> bool:
     """Whether the decode program attends through the kernel that walks
     the page table: read off the backend the program is built for
     (``PAGED_INTERPRET`` is a test's), nothing a user sets."""
-    return _paged.use_kernel(PAGED_INTERPRET)
+    return _paged.kernel_runs(PAGED_INTERPRET)
 
 
 def paged_attend(lengths, store, table, cfg, interpret=None):
@@ -539,64 +506,45 @@ def paged_attend(lengths, store, table, cfg, interpret=None):
     return attend
 
 
-def view_ladder_attend(lengths, store, table, cfg, rungs):
-    """The decode step's ``attend`` over gathered views, the kernel's twin
-    off the TPU.  The slots are sorted by length (idle ones last) and
-    attended in :func:`slot_groups`; each cache layer gathers, for a
-    group, the first ``n`` pages of its slots' ``table`` rows, ``n`` the
-    smallest of ``rungs`` that holds the group's longest sequence and its
-    new token, picked INSIDE the program from ``lengths`` (``lax.switch``
-    around the gather and the attention only)."""
-    ps = store.shape[2]
+def gathered_attend(lengths, store, table, cfg):
+    """The kernel's twin off the TPU, the plainest thing that is right:
+    every slot's table row gathered whole, the new entry put at its
+    position, one :func:`mla_absorbed_attention` over it under the
+    lengths (an idle slot's mask is empty: zeros)."""
+    b = lengths.shape[0]
     pos = jnp.clip(lengths, 0, None)
-    groups = slot_groups(lengths.shape[0])
-    order = jnp.argsort(-lengths)
-    picked = group_rungs(lengths, rungs, groups)
-    bounds = np.cumsum((0,) + groups)
-
-    def over(n_tokens, layer, rows, q_nope, q_rope, entry, ap):
-        """Slots ``rows`` (indices into the batch) over a view of
-        ``n_tokens``."""
-        pages = table[rows, :n_tokens // ps]
-        view = store[layer, pages].reshape(rows.shape[0], n_tokens, -1)
-        view = view.at[jnp.arange(rows.shape[0]), pos[rows]].set(
-            entry[rows, 0], mode="drop")
-        return mla_absorbed_attention(q_nope[rows], q_rope[rows], view,
-                                      lengths[rows, None], ap, cfg)
 
     def attend(layer, q_nope, q_rope, entry, ap):
-        outs = [jax.lax.switch(
-            picked[g], [partial(over, n, layer) for n in rungs],
-            order[bounds[g]:bounds[g + 1]], q_nope, q_rope, entry, ap)
-            for g in range(len(groups))]
-        # Back into slot order.
-        return jnp.concatenate(outs)[jnp.argsort(order)]
+        view = store[layer][table].reshape(b, -1, store.shape[-1])
+        view = view.at[jnp.arange(b), pos].set(entry[:, 0], mode="drop")
+        return mla_absorbed_attention(q_nope, q_rope, view,
+                                      lengths[:, None], ap, cfg)
 
     return attend
 
 
-def ladder_attend(lengths, store, table, cfg, rungs):
-    """The decode step's attention over the paged store, attending the
-    live tokens and not the capacity: on the TPU :func:`paged_attend`,
-    elsewhere :func:`view_ladder_attend` (:func:`paged_kernel_runs`).
+def decode_attend(lengths, store, table, cfg):
+    """The decode step's attention over the paged store: on the TPU
+    :func:`paged_attend`, elsewhere :func:`gathered_attend`
+    (:func:`paged_kernel_runs`, asked here and nowhere else).
     ``lengths [slots]`` (-1 idle: such a slot attends nothing); ``store
     [cache layers, pages, page, width]``.  Returns ``(attend, pos)``:
     ``attend(layer, q_nope, q_rope, entry, ap)`` with ``layer`` the index
     into the store, and the new tokens' positions ``[slots, 1]``."""
     attend = (paged_attend(lengths, store, table, cfg, PAGED_INTERPRET)
               if paged_kernel_runs()
-              else view_ladder_attend(lengths, store, table, cfg, rungs))
+              else gathered_attend(lengths, store, table, cfg))
     return attend, jnp.clip(lengths, 0, None)[:, None]
 
 
 def decode_step(params, tokens, lengths, store, table,
-                cfg: LatentMoEConfig, rungs):
+                cfg: LatentMoEConfig):
     """One token a slot over the paged store through
-    :func:`ladder_attend`.  ``tokens [slots]``; ``lengths [slots]`` (-1
+    :func:`decode_attend`.  ``tokens [slots]``; ``lengths [slots]`` (-1
     idle: such a slot reaches no expert).  Returns ``(logits [slots,
     vocab], entries [layers, slots, width], counts [expert layers,
     held])``."""
-    attend, pos = ladder_attend(lengths, store, table, cfg, rungs)
+    attend, pos = decode_attend(lengths, store, table, cfg)
     logits, entries, counts = _layers(params, tokens[:, None], pos, cfg,
                                       attend, lengths[:, None] >= 0)
     return logits[:, 0], entries[:, :, 0], counts
@@ -642,16 +590,12 @@ class LatentMoEServing:
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
-    def decode_view(self, lengths, rungs, page_size=None) -> float:
+    def decode_view(self, lengths, page_size, pages_per_slot) -> float:
         """Tokens of the store a slot the decode program attends in one
-        cache layer at these (host) lengths, by the rule the program
-        follows: where the kernel runs, what it copies (the live lengths
-        rounded up to the page, an idle slot nothing); on the ladder its
-        groups' rungs, weighted by their sizes."""
-        read = (_paged.tokens_read(lengths, page_size)
-                if paged_kernel_runs()
-                else view_ladder_tokens(lengths, rungs))
-        return read / len(lengths)
+        cache layer at these (host) lengths: the live lengths rounded up
+        to the page (what the kernel copies; its twin gathers the whole
+        rows and masks the rest), an idle slot nothing."""
+        return _paged.tokens_read(lengths, page_size) / len(lengths)
 
     def cache_entry(self) -> dict:
         """One store; to the cache it is one key/value head as wide as
@@ -661,11 +605,11 @@ class LatentMoEServing:
         return {"n_layers": self.cfg.cache_layers, "n_heads": 1,
                 "head_dim": w, "widths": (w,)}
 
-    def decode(self, params, pages, table, lengths, tokens, rungs):
+    def decode(self, params, pages, table, lengths, tokens):
         (store,) = pages
         ps = store.shape[2]
         logits, entries, *extras = self.decode_step(
-            params, tokens, lengths, store, table, self.cfg, rungs)
+            params, tokens, lengths, store, table, self.cfg)
         # One row a slot, written where it lies (see DenseLM.decode).
         pos = jnp.clip(lengths, 0, None)
         b = tokens.shape[0]

@@ -632,11 +632,12 @@ class Mamba2HybridServing:
         """Bytes of the per-slot stores by kind, once at build."""
         _M_STATE_BYTES.set(nbytes.get("state", 0))
 
-    def decode_view(self, lengths, rungs, page_size=None) -> float:
+    def decode_view(self, lengths, page_size, pages_per_slot) -> float:
         """Positions of paged view a slot the decode program gathers (for
         EACH attention layer) at these (host) lengths: the chunk list's
         rung, over the slots."""
-        chunk, ladder = chunk_ladder(len(lengths), rungs[-1],
+        chunk, ladder = chunk_ladder(len(lengths),
+                                     page_size * pages_per_slot,
                                      self.cfg.decode_chunk_tokens)
         return (ladder[int(chunk_rung(lengths, ladder, chunk))] * chunk
                 / len(lengths))
@@ -649,7 +650,7 @@ class Mamba2HybridServing:
         _M_STATE_MOVED.inc(2 * len(live) * self.n_mamba
                            * self.slot_layer_bytes)
 
-    def decode(self, params, pages, table, lengths, tokens, rungs):
+    def decode(self, params, pages, table, lengths, tokens):
         k_pages, v_pages = pages[:2]
         logits, new = decode_step(params, tokens, lengths, pages, table,
                                   self.cfg)

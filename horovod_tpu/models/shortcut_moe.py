@@ -31,8 +31,8 @@ shared expert.
 This module holds what the family does NOT share with
 ``models/latent_moe.py``: the layer's structure, which no published key
 expresses (it is the family's), its router and its parameters.  The
-attention functions, the view ladder of the decode step and the serving
-protocol's store handling are imported from there.
+attention functions, the decode step's attention over the store and the
+serving protocol's store handling are imported from there.
 
 A member of an expert-parallel group holds ``experts_held`` of the real
 experts (``expert_offset`` on) and computes their part of each layer and
@@ -277,15 +277,14 @@ def prefill_step(params, tokens, n_valid, cfg: ShortcutMoEConfig):
 
 
 def decode_step(params, tokens, lengths, store, table,
-                cfg: ShortcutMoEConfig, rungs):
+                cfg: ShortcutMoEConfig):
     """One token a slot over the paged store through the latent
-    family's attention (``latent_moe.ladder_attend``: the paged kernel on
-    the TPU, the view ladder elsewhere), two cache layers a decoder
+    family's attention (``latent_moe.decode_attend``: the paged kernel on
+    the TPU, the gathered rows elsewhere), two cache layers a decoder
     layer.  Returns ``(logits [slots, vocab], entries [cache layers,
     slots, width], counts [layers, held], zero_pairs [layers],
     routed_pairs [layers])``."""
-    attend, pos = _latent.ladder_attend(lengths, store, table, cfg.mla,
-                                        rungs)
+    attend, pos = _latent.decode_attend(lengths, store, table, cfg.mla)
     logits, entries, *counted = _layers(params, tokens[:, None], pos, cfg,
                                         attend, lengths[:, None] >= 0)
     return (logits[:, 0], entries[:, :, 0], *counted)

@@ -634,11 +634,12 @@ def view_rung(start_pos, rungs, width: int = 2):
 
 
 def forward_step_paged(params, tokens, start_pos, k_pages, v_pages,
-                       table, cfg: TransformerConfig, rungs):
+                       table, cfg: TransformerConfig):
     """:func:`forward_step` straight over the page store, attending the
     live tokens and not the capacity: each layer gathers only the first
     ``n`` pages of every sequence's ``table`` row into its view, ``n``
-    the smallest of ``rungs`` (:func:`view_rungs`) that covers the
+    the smallest rung of :func:`view_rungs` (a function of the shapes
+    held here: the page size and the table's width) that covers the
     batch's longest sequence plus this block (:func:`view_rung`, from
     ``start_pos`` INSIDE the program, ``lax.switch`` around the layer's
     gather, put and attention only — the projections, the FFN and the
@@ -657,6 +658,7 @@ def forward_step_paged(params, tokens, start_pos, k_pages, v_pages,
         raise ValueError(f"KV capacity {pps * ps} exceeds "
                          f"cfg.max_seq_len {cfg.max_seq_len}")
     b, s = tokens.shape
+    rungs = view_rungs(ps, pps)
     rung = view_rung(start_pos, rungs, s)
 
     def over(n_tokens, layer, q, k, v, pos):

@@ -70,8 +70,16 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def kernel_runs(interpret) -> bool:
+    """THE rule of every Pallas kernel under ``ops/``: the kernel on the
+    TPU, or wherever a caller asks for it by name (``interpret`` not
+    ``None``; ``True`` is the interpreter, a test's); elsewhere the
+    caller's twin in plain ``jnp``.  The one place the backend is asked."""
+    return interpret is not None or jax.default_backend() == "tpu"
+
+
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return not kernel_runs(None)
 
 
 def _dense_default() -> bool:
@@ -82,7 +90,7 @@ def _dense_default() -> bool:
     or ``HVD_TPU_FLASH_INTERPRET=1``."""
     force_interpret = os.environ.get(
         "HVD_TPU_FLASH_INTERPRET", "").lower() in ("1", "true", "yes")
-    return jax.default_backend() != "tpu" and not force_interpret
+    return not kernel_runs(True if force_interpret else None)
 
 
 def _dense_mask(s, *, causal, q_block_offset, q_len, k_len):

@@ -9,14 +9,14 @@ table whose row may be a RING, the softmax computed online.
 Query head ``i`` of ``heads`` reads key/value head ``i // (heads /
 kv_heads)``, unless the caller hands in another HEAD MAP (differential
 attention's pairs, ``models/hybrid_ssm.py``: :func:`gqa_paged_attention`).
-The arithmetic is ``models/mamba2_hybrid.py::attend_chunks``'s
-(the twin a caller keeps off the TPU): scores times ``scale``, scores,
+The arithmetic is the twins' a caller keeps off the TPU
+(``models/afmoe.py::attend_view``): scores times ``scale``, scores,
 softmax and accumulation float32, the probabilities rounded to the store's
 type as the second product's operand, the new token's own key and value
 (the engine writes them to the store after the program) joining from their
 operands as the softmax's first column.
 
-**The table row is a ring** (``models/afmoe.py::ring_chunks``' rule): with
+**The table row is a ring** (the cache manager's window group): with
 ``cached`` positions in the store, the entries in use are the first
 ``min(ceil(cached / page), entries)``; entry ``e`` holds logical page ``top
 - (top - e) % entries`` with ``top = (cached - 1) // page``; a row at
@@ -40,16 +40,16 @@ way before the last of this one is computed; entries past the ones in use
 are never copied, an idle slot costs one grid step that writes its zeros.
 It takes the WHOLE stores and a ``layer`` that may be traced.
 
-**Block-diagonal, not per key/value head.**  The queries go in laid as the
-twin lays them, ``[heads, kv_width]`` with a head's ``hd`` values in its
-key/value head's lanes and zeros elsewhere, so a block's scores are ONE
-product ``[heads, kv_width] x [kv_width, block]`` against the key block as
-it is stored, the output ONE product ``[heads, block] x [block,
-kv_width]``, and a head keeps its own head's lanes at the end (the zeros
-add nothing; eight times the multiplications, which the matrix unit has to
-spare in a decode).  Which lanes a query head is laid into and which it
-keeps is all the kernel knows of the heads: the head map lives in the
-wrapper.  The other form, ``kv_heads`` products ``[queries a
+**Block-diagonal, not per key/value head.**  The queries go in laid as
+``hybrid_ssm.attend_view`` lays them, ``[heads, kv_width]`` with a head's
+``hd`` values in its key/value head's lanes and zeros elsewhere, so a
+block's scores are ONE product ``[heads, kv_width] x [kv_width, block]``
+against the key block as it is stored, the output ONE product ``[heads,
+block] x [block, kv_width]``, and a head keeps its own head's lanes at the
+end (the zeros add nothing; eight times the multiplications, which the
+matrix unit has to spare in a decode).  Which lanes a query head is laid
+into and which it keeps is all the kernel knows of the heads: the head map
+lives in the wrapper.  The other form, ``kv_heads`` products ``[queries a
 head padded to 8 rows, hd] x [hd, block]`` each against its own lanes and
 as many for the output, loads the same key and value tiles into the matrix
 unit and streams a sixth of the rows through them, with an accumulator of 8
@@ -63,7 +63,9 @@ products a block, each with its own fill and drain of the unit and its own
 softmax statistics, cost more than the rows saved.
 
 It runs on the TPU, or wherever a test asks for it by name
-(``interpret=True``); :func:`use_kernel` is the rule, ``ops/ssd.py``'s.
+(``interpret=True``); elsewhere a caller keeps the plain twin, a slot's
+table row gathered whole under :func:`attended_rows`.  :func:`kernel_runs`
+(``ops/flash_attention.py``) is the rule.
 """
 
 from __future__ import annotations
@@ -77,7 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .latent_paged_attention import live_first
-from .ssd import _use_kernel as use_kernel   # one rule for every kernel
+from .flash_attention import kernel_runs   # the rule a caller asks
 
 # The two pairs of blocks (keys and values, twice) a slot's pages are
 # copied into may take this much VMEM.
@@ -319,6 +321,17 @@ def attended_rows(cached, entries: int, page_size: int, window: int = 0):
     if window:
         seen = seen & (pos > cached - window)
     return seen
+
+
+def gathered_rows(cached, table, k_pages, v_pages, layer, window: int = 0):
+    """For a twin that gathers: every slot's table row of paged layer
+    ``layer`` laid end to end in table order, ``(k [slots, entries * page,
+    kv_width], v, mask)``, ``mask`` :func:`attended_rows`."""
+    b, entries = table.shape
+    ps = k_pages.shape[2]
+    k, v = (x[layer][table].reshape(b, entries * ps, -1)
+            for x in (k_pages, v_pages))
+    return k, v, attended_rows(cached, entries, ps, window)
 
 
 def gqa_paged_attention(q, k_self, v_self, k_pages, v_pages, table, lengths,

@@ -27,8 +27,8 @@ and a ``layer`` that may be traced (``ops/ssd.py::ssd_step`` likewise).
 
 It runs on the TPU, or wherever a test asks for it by name
 (``interpret=True``); elsewhere the caller keeps its own twin
-(``latent_moe.ladder_attend``'s view ladder): :func:`use_kernel` is the
-rule, ``ops/ssd.py``'s.
+(``latent_moe.gathered_attend``: every slot's table row gathered whole):
+:func:`kernel_runs` (``ops/flash_attention.py``) is the rule.
 """
 
 from __future__ import annotations
@@ -41,7 +41,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ssd import _use_kernel as use_kernel   # one rule for every kernel
+from .flash_attention import kernel_runs   # the rule a caller asks
 
 # The two blocks a slot's pages are copied into may take this much VMEM.
 BLOCK_VMEM_BYTES = 2 << 20

@@ -41,6 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import kernel_runs
+
 LANES = 128
 HEAD_BLOCK = 8          # heads a grid cell of the chunked scan holds
 _EXACT = jax.lax.Precision.HIGHEST
@@ -70,12 +72,6 @@ def unpack_state(s, head_dim: int):
     pack = w // head_dim
     s = s.reshape(*lead, r, n, pack, head_dim)
     return jnp.moveaxis(s, -3, -1).reshape(*lead, r * pack, head_dim, n)
-
-
-def _use_kernel(interpret) -> bool:
-    """The Pallas kernel on the TPU, or wherever a test asks for it by
-    name (``interpret=True``: the interpreter); elsewhere the twin."""
-    return interpret is not None or jax.default_backend() == "tpu"
 
 
 # -- one token a slot ---------------------------------------------------------
@@ -182,7 +178,7 @@ def ssd_step(state, x, dt, a, b, c, d, alive, *, layer=None,
     live slots' rows advanced and every other row BIT FOR BIT what it was
     (on the TPU the kernel's output IS its input buffer, and an idle
     slot's block is never moved; an idle slot's ``y`` is zero)."""
-    if not _use_kernel(interpret):
+    if not kernel_runs(interpret):
         y, state = ssd_step_jnp(state, x, dt, a, b, c, d, alive,
                                 layer=layer)
         return jnp.where(alive[:, None, None], y, 0.0), state
@@ -373,7 +369,7 @@ def ssd_chunk_scan(x, dt, a, b, c, s0, n_valid, *, chunk: int = 256,
     elsewhere :func:`ssd_chunk_scan_jnp`, unless ``interpret=True`` asks
     for the kernel in the interpreter (its own tests)."""
     dt, a, s0 = (v.astype(jnp.float32) for v in (dt, a, s0))
-    if not _use_kernel(interpret):
+    if not kernel_runs(interpret):
         return ssd_chunk_scan_jnp(x, dt, a, b, c, s0, n_valid, chunk=chunk)
     return _pallas_chunk_scan(x, dt, a, b, c, s0, n_valid, chunk,
                               bool(interpret))

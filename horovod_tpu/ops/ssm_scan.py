@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import kernel_runs
+
 TIME_BLOCK = 128        # steps a grid cell walks
 LANE_BLOCK = 1024       # channels a grid cell holds
 
@@ -138,6 +140,6 @@ def ssm_scan(x, dt, b, c, a, s0, n_valid, *, interpret=None):
     own tests)."""
     x, dt, b, c, a, s0 = (v.astype(jnp.float32)
                           for v in (x, dt, b, c, a, s0))
-    if interpret is None and jax.default_backend() != "tpu":
+    if not kernel_runs(interpret):
         return ssm_scan_reference(x, dt, b, c, a, s0, n_valid)
     return _pallas_scan(x, dt, b, c, a, s0, n_valid, bool(interpret))

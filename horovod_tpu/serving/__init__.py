@@ -14,8 +14,9 @@ pieces, each its own module:
   ``parallel/tensor.py`` tensor-parallel layout so serving reuses the
   training partition.
 * :mod:`~horovod_tpu.serving.models` — what the engine asks of a
-  model (cache entry, paged decode step over the view ladder, prefill
-  step, fingerprint), and the dense multi-head decoder's answers.
+  model (cache entry, paged decode step, what that step attends of the
+  store, prefill step, fingerprint), and the dense multi-head decoder's
+  answers.
 * :mod:`~horovod_tpu.serving.engine` — prefill and decode compiled as
   donated AOT executables (megakernel-style: gather → forward →
   scatter in ONE program), recorded in the PR-5 persistent-cache

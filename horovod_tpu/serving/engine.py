@@ -14,7 +14,9 @@ capacity-long view (:func:`..models.transformer.forward_step`); decode
 gathers, layer by layer, only the leading pages of each row that the
 iteration's longest live sequence reaches — the smallest rung of a
 ladder of page counts, picked inside the program from its ``lengths``
-(:func:`..models.transformer.forward_step_paged`).  The decode
+(:func:`..models.transformer.forward_step_paged`; the ladder is the
+dense decoder's alone: the other families' kernels walk the page
+table).  The decode
 loop runs one iteration ahead (:meth:`InferenceEngine._decode_iteration`):
 the executable takes the greedy token itself and hands it to the next
 launch on the device, so the host prepares and launches iteration i+1
@@ -74,7 +76,6 @@ from ..core.topology import MODEL_AXIS
 from ..memory import oom as _oom
 from ..memory import planner as _mem_planner
 from ..telemetry import flight as _flight
-from ..models import transformer as _transformer
 from ..ops import megakernel as _megakernel
 from .kv_cache import PagedKVCache
 from .models import serving_model as _serving_model
@@ -108,9 +109,10 @@ _M_PREFILL_AHEAD = _telemetry.counter(
     "over serving.prefills it is the share of admissions that did not "
     "drain the decode loop")
 _M_VIEW_TOKENS = _telemetry.counter(
-    "serving.decode_view_tokens", "tokens of KV view per slot that the "
-    "decode iterations attended (the ladder rung each one rode; where a "
-    "kernel reads the pages in place, what it copied); over "
+    "serving.decode_view_tokens", "tokens of the KV store per slot that "
+    "the decode iterations attended (where a kernel reads the pages in "
+    "place, the live tokens rounded up to whole pages; the rung the "
+    "dense decoder's ladder or mamba2_hybrid's chunk list rode); over "
     "serving.decode_iterations it is the mean view")
 _M_PREFILL_TOKENS = _telemetry.counter(
     "serving.prefill_tokens", "real prompt tokens the admission prefills "
@@ -198,8 +200,7 @@ def _make_cache(model, max_slots: int, pages_per_slot: int,
                          page_size, dtype=model.cfg.dtype,
                          entry_widths=entry["widths"],
                          slot_stores=entry.get("slot_stores", ()),
-                         groups=groups, pool_pages=pools,
-                         view_chunk=entry.get("view_chunk", 0), **kw)
+                         groups=groups, pool_pages=pools, **kw)
     if cache.slot_state:
         model.observe_stores(cache.slot_store_bytes())
     return cache
@@ -412,10 +413,6 @@ class InferenceEngine:
                          if b <= self.capacity]
         if self._buckets[-1] != self.capacity:
             self._buckets.append(self.capacity)
-        # The decode program's view ladder (tokens), from the cache's
-        # geometry alone.
-        self._rungs = _transformer.view_rungs(self.cache.page_size,
-                                              self.cache.pages_per_slot)
         self._exec: Dict[Tuple, Any] = {}
         # The decode loop runs one iteration ahead (_decode_iteration):
         # the iteration launched and not yet fetched, and the two token
@@ -692,7 +689,7 @@ class InferenceEngine:
         and keeps the logits and the model's extras behind it."""
         tokens = jnp.where(override >= 0, override, prev)
         outs, pages = self.model.decode(params, pages, table, lengths,
-                                        tokens, rungs=self._rungs)
+                                        tokens)
         chosen = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
         if self._replicated is not None:
             # It is the next call's ``prev``: the layout that was
@@ -1227,9 +1224,9 @@ class InferenceEngine:
             0 if override is None else override.nbytes)
         # The view the program is about to attend at ``lengths``.
         return _Flight(
-            riding, self.model.decode_view(lengths, self._rungs,
-                                           self.cache.page_size), lengths,
-            inputs, n_fresh, (nbytes, mapped, copy_s))
+            riding, self.model.decode_view(lengths, self.cache.page_size,
+                                           self.cache.pages_per_slot),
+            lengths, inputs, n_fresh, (nbytes, mapped, copy_s))
 
     @staticmethod
     def _note_sent(tables, *flights: Optional[_Flight]) -> None:

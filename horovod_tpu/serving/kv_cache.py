@@ -190,25 +190,6 @@ def _group_gauges(name: str, total_pages: int) -> Tuple:
                 f"have mapped at once since the store was built"))
 
 
-def view_tokens_per_slot(pools: Sequence[Tuple[int, int]], page_size: int,
-                         max_slots: int, chunk: int) -> int:
-    """Positions a slot of a ``"view"`` scratch store (``[.., max_slots,
-    "view", ..]``): room for the longest list of ``chunk``-position chunks
-    any ONE group can hand a decode launch.  ``pools``: ``(allocatable
-    pages, table entries a slot)`` a group.  No launch gathers more
-    chunks of a group than its pool holds plus one part-filled chunk a
-    slot, nor more than every slot's whole table (``a_slot`` chunks each;
-    the list is gathered ``a_slot`` chunks at a time, so it is rounded up
-    to that)."""
-    most = 0
-    for pages, entries in pools:
-        a_slot = -(-entries * page_size // chunk)
-        top = min(max_slots * a_slot,
-                  -(-pages * page_size // chunk) + max_slots)
-        most = max(most, -(-top // a_slot) * a_slot)
-    return -(-most // max_slots) * chunk
-
-
 @_races.race_checked
 class PagedKVCache:
     """The paged store for one :class:`~horovod_tpu.serving.engine.
@@ -231,8 +212,7 @@ class PagedKVCache:
                  entry_widths: Optional[Sequence[int]] = None,
                  slot_stores: Sequence[dict] = (),
                  groups: Sequence[dict] = (),
-                 pool_pages: Optional[Sequence[int]] = None,
-                 view_chunk: int = 0) -> None:
+                 pool_pages: Optional[Sequence[int]] = None) -> None:
         if pages_per_slot < 1 or page_size < 1:
             raise ValueError("pages_per_slot and page_size must be >= 1")
         # Layer groups (module docstring): ``{"name", "n_layers"[,
@@ -310,10 +290,6 @@ class PagedKVCache:
         self._mapped = np.zeros((len(groups), max_slots), np.int64)
         self.table_width = pages_per_slot + sum(g.entries
                                                 for g in self._extra)
-        self.view_tokens = view_tokens_per_slot(
-            [(self.n_pages - 1 - self.prefix_pages, pages_per_slot)]
-            + [(g.total_pages, g.entries) for g in self._extra],
-            page_size, max_slots, view_chunk) if view_chunk else 0
         # Gauges a group, of a store with more than one (the full group's
         # are serving.kv_pages_* as ever).
         self._group_gauges = [
@@ -399,12 +375,12 @@ class PagedKVCache:
                              key=self._ledger_key)
 
     def _slot_dim(self, d) -> int:
-        """A per-slot store's dimension: a number, ``"capacity"`` (the
-        slot's positions) or ``"view"`` (:func:`view_tokens_per_slot`)."""
-        if d == "view" and not self.view_tokens:
-            raise ValueError('a "view" dimension needs view_chunk')
-        return {"capacity": self.capacity,
-                "view": self.view_tokens}.get(d, d)
+        """A per-slot store's dimension: a number, or ``"capacity"`` (the
+        slot's positions)."""
+        if isinstance(d, str) and d != "capacity":
+            raise ValueError(f"a per-slot store's dimension is a number "
+                             f"or \"capacity\", not {d!r}")
+        return self.capacity if d == "capacity" else d
 
     # -- sharding ----------------------------------------------------------
     def page_sharding(self) -> Optional[NamedSharding]:

@@ -17,22 +17,24 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
     "dtype"}`` each (``kind``: ``"window"`` a ring of the last positions,
     ``"state"`` recurrent state, ``"scratch"`` room a program keeps across
     iterations; ``shape`` led by the store's own layer count, a dimension
-    ``"capacity"`` the slot's): the cache manager holds one array
-    ``[layers, max_slots, *shape]`` a store and tells the model their
-    bytes by kind once (``observe_stores``).  ``pages`` below is then
-    ``cache.arrays``: the page arrays, then these.  A model with
+    a number or ``"capacity"``, the slot's positions): the cache manager
+    holds one array ``[layers, max_slots, *shape]`` a store and tells the
+    model their bytes by kind once (``observe_stores``).  ``pages`` below
+    is then ``cache.arrays``: the page arrays, then these.  A model with
     ``slot_state = True`` is told which slot a prefill fills.
-``decode(params, pages, table, lengths, tokens, rungs) -> (outs, pages)``
-    One token a slot over the paged store, the view rung picked inside
-    the program; writes the new entries back.  ``outs[0]`` is ``logits
-    [slots, vocab]``, which stay on the device unless a slot samples
-    (the engine's executable takes their argmax itself); anything after
-    it is fetched and goes to ``observe_decode`` inside ``serve.sample``.
-``decode_view(lengths, rungs, page_size) -> tokens``
-    The view a slot the decode program attends at these host lengths
-    (the rung it is about to pick, by the same pure function; for a
-    program that reads the pages in place, the tokens it copies): what
-    ``serving.decode_view_tokens`` counts.
+``decode(params, pages, table, lengths, tokens) -> (outs, pages)``
+    One token a slot over the paged store; writes the new entries back.
+    ``outs[0]`` is ``logits [slots, vocab]``, which stay on the device
+    unless a slot samples (the engine's executable takes their argmax
+    itself); anything after it is fetched and goes to ``observe_decode``
+    inside ``serve.sample``.
+``decode_view(lengths, page_size, pages_per_slot) -> tokens``
+    What a slot the decode program attends of the store at these host
+    lengths, from the store's geometry: the live tokens in whole pages
+    where a kernel reads the pages in place; the rung about to be picked,
+    by the program's own pure function, where a view is gathered (the
+    dense decoder, ``mamba2_hybrid``).  ``serving.decode_view_tokens``
+    counts it.
 ``prefill(params, pages, table_row, start, n_valid, tokens[, slot]) -> (outs, pages)``
     A padded prompt block from ``start`` cached positions; ``outs`` is
     ``(last,)``, the last real token's logits.  ``slot [1]`` only where
@@ -96,8 +98,9 @@ class DenseLM:
             "dtype": jnp.dtype(cfg.dtype).name,
         }
 
-    def decode_view(self, lengths, rungs, page_size=None) -> int:
+    def decode_view(self, lengths, page_size, pages_per_slot) -> int:
         """One rung for every slot, holding the [token, dummy] block."""
+        rungs = _transformer.view_rungs(page_size, pages_per_slot)
         return rungs[_transformer.view_rung(lengths, rungs)]
 
     def cache_entry(self) -> dict:
@@ -106,7 +109,7 @@ class DenseLM:
         return {"n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
                 "head_dim": hd, "widths": (cfg.n_heads * hd,) * 2}
 
-    def decode(self, params, pages, table, lengths, tokens, rungs):
+    def decode(self, params, pages, table, lengths, tokens):
         cfg = self.cfg
         k_pages, v_pages = pages
         ps, L, B = k_pages.shape[2], cfg.n_layers, tokens.shape[0]
@@ -118,7 +121,7 @@ class DenseLM:
         # the last rung; every other rung is picked to hold it.
         blk = jnp.stack([tokens, jnp.zeros_like(tokens)], axis=1)
         logits, k_new, v_new = _transformer.forward_step_paged(
-            params, blk, lengths, k_pages, v_pages, table, cfg, rungs)
+            params, blk, lengths, k_pages, v_pages, table, cfg)
         # One row a slot, written where it lies: B in-place
         # dynamic-update-slices.  (A scatter over the flattened
         # store makes the TPU copy all of it into a layout of the

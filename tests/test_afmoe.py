@@ -24,6 +24,12 @@ from horovod_tpu.models import afmoe as af
 from horovod_tpu.ops import gqa_paged_attention as gpa
 from horovod_tpu.parallel import expert as ex
 from horovod_tpu.serving import InferenceEngine
+import test_hybrid_ssm as th
+import test_latent_paged_attention as tp
+import test_ssd
+from horovod_tpu.models import hybrid_ssm as hs
+from horovod_tpu.models import latent_moe as lm
+from horovod_tpu.ops import ssd, ssm_scan
 from test_hybrid_ssm import counter, rollout
 from test_latent_paged_attention import _primitives, _Run
 
@@ -40,9 +46,9 @@ CFG = config_of(MODEL)
 WINDOW, PAGE, RING = 8, 4, 3               # ceil(8 / 4) + 1 entries
 
 # float32 on both sides: what is left is the order of sums (the prompt's
-# blocks of queries, the paged view's chunk products, the new token's key
-# beside the view, a slot's softmax over its chunks, the experts' grouped
-# products against one expert after another).  The logits have a spread of
+# blocks of queries, the gathered rows' products, the new token's key
+# beside them, the experts' grouped products against one expert after
+# another).  The logits have a spread of
 # 0.16; these differences measure 2e-7.  bfloat16 operands in the
 # reference's place move them by 5e-3 (test_the_tolerance_would_catch_
 # bfloat16), the nearest other reading of the config by 7e-2.
@@ -547,23 +553,21 @@ def test_the_counters_of_a_fixed_batch_with_idle_slots():
     assert counter("serving.window_tokens") - before[1] == 900 + 1 + 8192
 
 
-def test_the_cache_entry_is_two_paged_groups_and_one_view():
+def test_the_cache_entry_is_two_paged_groups_and_nothing_beside_them():
     model = af.AfmoeConfig().serving_model()
     entry = model.cache_entry()
     assert entry["widths"] == (1024, 1024) and entry["n_layers"] == 15
     assert entry["groups"] == (
         {"name": "full", "n_layers": 15},
         {"name": "window", "n_layers": 45, "window": 4096})
-    assert [(s["name"], s["kind"], s["shape"]) for s in entry["slot_stores"]
-            ] == [("paged_view", "scratch", (2, "view", 1024))]
-    assert entry["view_chunk"] == 256
+    # No per-slot store at all: no window ring a slot, no room to gather a
+    # view into, on any backend.
+    assert set(entry) == {"n_layers", "n_heads", "head_dim", "widths",
+                          "groups"}
     assert not (model.prefix_cache or model.speculative
                 or model.tensor_parallel or model.slot_state)
     assert "ring" in model.prefix_cache_why
     assert af.ring_entries(4096, 16) == 257
-    # No window ring a slot: the cell's store has no per-slot store of
-    # kind "window" at all.
-    assert all(s["kind"] != "window" for s in entry["slot_stores"])
     cell = config_of(PUBLISHED).serving_model().cache_entry()
     assert [g["n_layers"] for g in cell["groups"]] == [1, 4]
 
@@ -573,32 +577,15 @@ def test_the_engines_store_is_the_groups_the_model_declares():
     c = eng.cache
     assert c.group_names == ("full", "window")
     assert c.table_width == 32 + RING
-    full_k, full_v, win_k, win_v, view = c.arrays
+    full_k, full_v, win_k, win_v = c.arrays    # the pages and no view
     assert full_k.shape == full_v.shape == (1, 1 + 8 * 32, PAGE, 32)
     assert win_k.shape == win_v.shape == (4, 1 + 8 * RING, PAGE, 32)
-    # Every slot at capacity fits the view: 128 positions a slot.
-    assert view.shape == (2, 8, 128, 32) and c.view_tokens == 128
+    assert not c.slot_state
     table, lengths = c.host_tables()
     assert table.shape == (8, 35) and not table.any()
     ident = eng.model.identity()
     assert ident["family"] == "afmoe" and ident["sliding_window"] == WINDOW
     assert ident["experts"] == [32, 8, 0, 4]
-
-
-def test_the_ladder_of_a_pool_ends_at_what_the_view_holds():
-    # Every slot's whole table: 64 slots x 36 chunks, down to one slot's,
-    # each rung four fifths of the next: a pass's attention never costs more
-    # than a quarter over what the live chunks need.
-    whole = af.pool_ladder(64, 36, 64 * 36)
-    assert whole[0] == 36 and whole[-1] == 64 * 36 and len(whole) == 20
-    assert all(0.75 < a / b <= 0.98 for a, b in zip(whole[1:-1], whole[2:]))
-    assert all(a < b for a, b in zip(whole, whole[1:]))
-    # A view sized by the pool: the top rung is what it holds, in whole
-    # slots' worth.
-    assert af.pool_ladder(64, 36, 940)[-3:] == (598, 748, 936)
-    assert af.pool_ladder(4, 17, 17) == (17,)
-    assert af.pool_ladder(8, 16, 128) == (16, 20, 25, 32, 40, 51, 64, 81,
-                                          102, 128)
 
 
 # -- decode through the paged kernel (ops/gqa_paged_attention.py) -------------
@@ -619,7 +606,7 @@ def kernel_engine(monkeypatch):
     return _kernel_engine()
 
 
-# Both sides of the window of 8, as the ladder's cases above: never
+# Both sides of the window of 8, as the twin's cases above: never
 # reaching it; starting under it and wrapping the ring of pages more than
 # once; a prompt longer than twice the window; ragged slots of all kinds.
 @pytest.mark.parametrize("lengths,new", [
@@ -664,64 +651,55 @@ def _attention_program(monkeypatch, interpret):
                                 jnp.float32) for _ in range(2))
     q, k, v = (jnp.asarray(rng.randn(slots, w), jnp.float32)
                for w in (CFG.q_width, kw, kw))
-    view = jnp.zeros((2, slots, pps * PAGE, kw), jnp.float32)
 
-    def f(lengths, table, full_k, full_v, win_k, win_v, view, q, k, v):
+    def f(lengths, table, full_k, full_v, win_k, win_v, q, k, v):
         groups = {af.FULL: (table[:, :pps], 0, full_k, full_v),
                   af.SLIDING: (table[:, pps:], WINDOW, win_k, win_v)}
-        if af.paged_kernel_runs():
-            attend = af.paged_attend(lengths, groups, CFG,
-                                     af.PAGED_INTERPRET)
-        else:
-            attend, _ = af.view_ladder_attend(lengths, groups, view, CFG)
+        attend = (af.paged_attend(lengths, groups, CFG, af.PAGED_INTERPRET)
+                  if af.paged_kernel_runs() else
+                  af.gathered_attend(lengths, groups, CFG))
         return attend(af.FULL, 0, q, k, v), attend(af.SLIDING, 1, q, k, v)
 
-    args = (lengths, table, full_k, full_v, win_k, win_v, view, q, k, v)
+    args = (lengths, table, full_k, full_v, win_k, win_v, q, k, v)
     return (_primitives(jax.make_jaxpr(f)(*args).jaxpr), jax.jit(f)(*args),
             np.asarray(lengths) >= 0)
 
 
-def test_off_the_tpu_the_ladder_runs_unless_the_interpreter_is_asked_for(
+def test_off_the_tpu_the_plain_twin_runs_unless_the_interpreter_is_asked_for(
         monkeypatch):
-    """The rule is the backend's (``ops/ssd.py``'s): on the CPU the decode
-    program is the view ladder it was, a conditional around a gather; with
-    the kernel forced neither is left and no view is asked of the cache,
-    and both give the same attention in both groups."""
+    """The rule is the backend's (``ops/flash_attention.kernel_runs``): on
+    the CPU the decode program gathers a slot's table row whole and attends
+    it under the kernel's mask, no conditional, no sort, no loop; with the
+    kernel forced the gather is gone too; the cache is asked for the same
+    store either way, and both give the same attention in both groups."""
     assert jax.default_backend() == "cpu" and not af.paged_kernel_runs()
-    ladder, want, on = _attention_program(monkeypatch, None)
-    assert {"cond", "gather", "while"} <= ladder
-    assert "pallas_call" not in ladder
-    assert "slot_stores" in CFG.serving_model().cache_entry()
+    plain, want, on = _attention_program(monkeypatch, None)
+    assert "gather" in plain
+    assert not plain & {"pallas_call", "cond", "sort", "while"}
+    assert "slot_stores" not in CFG.serving_model().cache_entry()
     kernel, got, _ = _attention_program(monkeypatch, True)
     assert af.paged_kernel_runs()
     assert "pallas_call" in kernel
     assert not kernel & {"cond", "gather", "sort", "scatter", "while"}
     entry = CFG.serving_model().cache_entry()
-    assert "slot_stores" not in entry and "view_chunk" not in entry
+    assert "slot_stores" not in entry
     assert [g["name"] for g in entry["groups"]] == ["full", "window"]
     for a, b in zip(got, want):
         assert np.abs(np.asarray(a - b))[on].max() < TOL
 
 
-def test_decode_view_is_what_the_kernel_copies_of_both_groups(monkeypatch):
+def test_decode_view_is_what_the_kernel_copies_of_both_groups():
     """A fixed batch with idle slots at the cell's sizes (page 16, 576
     pages a slot, a ring of 257): the full group's one layer copies every
     live slot's pages, the window group's four at most the ring's; the
-    mean over the five layers and the 8 slots.  On the ladder the same
-    lengths ride rungs."""
+    mean over the five layers and the 8 slots, whatever the backend."""
     model = config_of(PUBLISHED).serving_model()
     lengths = np.asarray([899, -1, 0, 8191, -1, 4095, 4096, 5000], np.int32)
-    rungs = (16, 576 * 16)
-    monkeypatch.setattr(af, "PAGED_INTERPRET", True)
     full = 912 + 0 + 8192 + 4096 + 4096 + 5008
     window = 912 + 0 + 257 * 16 + 4096 + 4096 + 257 * 16
-    assert model.decode_view(lengths, rungs, 16) == pytest.approx(
+    assert model.decode_view(lengths, 16, 576) == pytest.approx(
         (full + 4 * window) / 5 / 8)
-    assert model.decode_view(np.full((8,), -1, np.int32), rungs, 16) == 0
-    monkeypatch.setattr(af, "PAGED_INTERPRET", None)
-    model.observe_stores({"scratch": 2 * 940 * 256 * 1024 * 2})
-    # Rungs of whole chunks of 256, each no shorter than the chunks in use.
-    assert model.decode_view(lengths, rungs, 16) > (full + 4 * window) / 5 / 8
+    assert model.decode_view(np.full((8,), -1, np.int32), 16, 576) == 0
 
 
 # -- the counter's reader (benchmark/metrics/gqa_view_tokens.py) --------------
@@ -764,3 +742,99 @@ def test_gqa_view_tokens_is_the_view_counter_over_the_iterations(
     got = cells.load_module("metrics", "gqa_view_tokens").read(
         _Run(before, after))
     assert got == (want if want is None else pytest.approx(want))
+
+
+# -- one rule, one store: every family, every kernel ---------------------------
+
+# The four families whose decode attends through a paged kernel, with the
+# module whose ``PAGED_INTERPRET`` steers them (the shortcut family rides
+# the latent one's).
+SERVED = {"latent": (lm, tp.tl.CFG), "shortcut": (lm, tp.ts.CFG),
+          "afmoe": (af, CFG), "hybrid_ssm": (hs, th.CFG)}
+
+
+@pytest.mark.parametrize("family", sorted(SERVED))
+def test_the_store_and_the_view_are_the_same_on_every_backend(monkeypatch,
+                                                               family):
+    """What a model asks of the cache manager and what it says a launch
+    attends do not depend on where its decode attends: the kernel's twin
+    gathers the same pages the kernel walks and keeps no store of its own."""
+    module, cfg = SERVED[family]
+    lengths = np.asarray([70, -1, 9, 140, -1, 30, 0, 255], np.int32)
+    answers = []
+    for interpret in (None, True):
+        monkeypatch.setattr(module, "PAGED_INTERPRET", interpret)
+        assert module.paged_kernel_runs() == bool(interpret)
+        model = cfg.serving_model()
+        answers.append((model.cache_entry(),
+                        model.decode_view(lengths, 8, 32)))
+    assert answers[0] == answers[1]
+    # The live lengths in whole pages at most, never a view's size.
+    assert 0 < answers[0][1] <= (72 + 16 + 144 + 32 + 0 + 256) / 8
+
+
+def _latent_attend(interpret, patch):
+    patch(lm, "PAGED_INTERPRET", interpret)
+    c = tp.case((40, -1, 7, 90), seed=9)
+    return jax.make_jaxpr(
+        lambda n, store, table: lm.decode_attend(n, store, table, tp.CFG)[0](
+            1, c["q_nope"], c["q_rope"], c["entry"], c["ap"]))(
+        c["lengths"], c["store"], c["table"])
+
+
+def _gqa_attend(interpret, patch):
+    patch(af, "PAGED_INTERPRET", interpret)
+    pages = jnp.zeros((1, 9, PAGE, CFG.kv_width), jnp.float32)
+    stores = (pages, pages, pages, pages)
+    tokens = jnp.zeros((2,), jnp.int32)
+    table = jnp.asarray(1 + np.arange(2 * (1 + RING)).reshape(2, -1),
+                        jnp.int32)
+    cfg = config_of(dict(MODEL, num_hidden_layers=2, num_dense_layers=1,
+                         layer_types=[af.SLIDING, af.FULL]))
+    p = jax.eval_shape(lambda: af.init_afmoe(jax.random.PRNGKey(0), cfg))
+    return jax.make_jaxpr(
+        lambda p, n: af.decode_step(p, tokens, n, stores, table, cfg))(
+        p, jnp.asarray([3, -1], jnp.int32))
+
+
+def _flash_prompt(interpret, patch):
+    patch(af, "FLASH_INTERPRET", bool(interpret))
+    q = jnp.zeros((16, CFG.q_width), jnp.float32)
+    kv = jnp.zeros((16, CFG.kv_width), jnp.float32)
+    return jax.make_jaxpr(lambda q, k, v: af.attend_prompt(q, k, v, CFG, 8))(
+        q, kv, kv)
+
+
+def _ssd_step(interpret, patch):
+    store, x, dt, a, b, c, d = test_ssd.step_operands()
+    return jax.make_jaxpr(lambda store: ssd.ssd_step(
+        store, x, dt, a, b, c, d, jnp.ones((6,), bool), layer=1,
+        interpret=interpret))(store)
+
+
+def _ssd_chunk_scan(interpret, patch):
+    x, dt, a, b, c, s0 = test_ssd.operands(12)
+    return jax.make_jaxpr(lambda x: ssd.ssd_chunk_scan(
+        x, dt, a, b, c, ssd.pack_state(s0), 12, chunk=8,
+        interpret=interpret))(x)
+
+
+def _ssm_scan(interpret, patch):
+    x = jnp.zeros((16, 256), jnp.float32)
+    bc = jnp.zeros((16, 4), jnp.float32)
+    a = jnp.zeros((4, 256), jnp.float32)
+    return jax.make_jaxpr(lambda x: ssm_scan.ssm_scan(
+        x, x, bc, bc, a, a, 16, interpret=interpret))(x)
+
+
+@pytest.mark.parametrize("trace", [
+    _ssd_step, _ssd_chunk_scan, _ssm_scan, _latent_attend, _gqa_attend,
+    _flash_prompt], ids=lambda f: f.__name__.strip("_"))
+def test_one_rule_says_where_every_kernel_runs(monkeypatch, trace):
+    """``ops/flash_attention.kernel_runs``, through each of its callers: on
+    the CPU with nothing asked the program holds no ``pallas_call``; with
+    the interpreter asked for by name it holds one."""
+    assert jax.default_backend() == "cpu"
+    patch = monkeypatch.setattr
+    assert "pallas_call" not in _primitives(trace(None, patch).jaxpr)
+    assert "pallas_call" in _primitives(trace(True, patch).jaxpr)

@@ -72,8 +72,7 @@ def test_chip_smoke_dry_run_reaches_every_leg():
     assert legs["H_mamba2_hybrid"]["state_bytes_moved"] > 0
     toy = legs["I_latent_paged_attn"]["toy"]
     assert toy["alive"] == 3 and toy["max_err_over_max"] < 1e-5
-    assert (toy["live_tokens"] <= toy["kernel_tokens_a_layer"]
-            < toy["ladder_tokens_a_layer"])
+    assert toy["live_tokens"] <= toy["kernel_tokens_a_layer"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """The paged grouped-query kernel (ops/gqa_paged_attention.py) in the Pallas
-interpreter against its twin, ``mamba2_hybrid.attend_chunks`` over a view
-gathered by ``afmoe.ring_chunks``: a table that holds every page (the full
+interpreter against its twin, ``afmoe.attend_view`` over every slot's table
+row gathered whole under ``attended_rows`` (what ``afmoe.gathered_attend``
+does in the decode program): a table that holds every page (the full
 group), a ring that has not wrapped, one that has wrapped once and several
 times.  Then the same kernel under another head map, differential attention's
 (``models/hybrid_ssm.py``), against ``attend_view`` over the positions
@@ -15,7 +16,6 @@ import pytest
 
 from horovod_tpu.models import afmoe as af
 from horovod_tpu.models import hybrid_ssm as hs
-from horovod_tpu.models import mamba2_hybrid as m2
 from horovod_tpu.ops import gqa_paged_attention as gpa
 
 # 32 query heads on 4 key/value heads make a block of 1024 tokens (64 pages
@@ -76,17 +76,11 @@ def case(lengths, entries, seed=0, dtype=jnp.float32, nan_elsewhere=False,
 @functools.lru_cache(maxsize=None)
 def _twin(window, softmax_dtype):
     def f(c, layer):
-        slots, entries = c["table"].shape
-        chunk = 2 * PAGE
-        n_chunks = slots * -(-entries // 2)
-        cached = jnp.clip(c["lengths"], 0, None)
-        pages, mask, owner, mine, _ = af.ring_chunks(
-            c["table"], cached, chunk, PAGE, window, n_chunks)
-        k, v = (jnp.where(mask[..., None],
-                          x[layer][pages].reshape(n_chunks, chunk, -1), 0)
-                for x in (c["k_pages"], c["v_pages"]))
-        o = m2.attend_chunks(c["q"], c["k_self"], c["v_self"],
-                             (k, v, mask, owner, mine), CFG)
+        k, v, mask = gpa.gathered_rows(
+            jnp.clip(c["lengths"], 0, None), c["table"], c["k_pages"],
+            c["v_pages"], layer, window)
+        k, v = (jnp.where(mask[..., None], x, 0) for x in (k, v))
+        o = af.attend_view(c["q"], c["k_self"], c["v_self"], k, v, mask, CFG)
         return jnp.where(c["lengths"][:, None] >= 0, o, 0)
 
     # Jitted: XLA's CPU client has no eager bfloat16 dot.
@@ -94,24 +88,24 @@ def _twin(window, softmax_dtype):
 
 
 def over_a_gathered_view(c, layer, window=0, softmax_dtype=None):
-    """``attend_chunks`` over every slot's entries in use, gathered by
-    ``ring_chunks`` two pages a chunk: the ladder's arithmetic on one full
-    rung.  Rows the mask hides are zeroed (a hidden NaN would reach the
-    product as ``0 * NaN``); an idle slot's row is its new value alone,
-    which the decode program never reads: zeroed as the kernel writes
-    it."""
+    """``afmoe.attend_view`` over every slot's table row, gathered whole in
+    table order, under the kernel's own mask as an array: the decode
+    program's twin off the TPU.  Rows the mask hides are zeroed (a hidden
+    NaN would reach the product as ``0 * NaN``); an idle slot's row is its
+    new value alone, which the decode program never reads: zeroed as the
+    kernel writes it."""
     if softmax_dtype is None:
         return _twin(window, None)(c, layer)
     # The same attention with its scores rounded on their way to the
     # softmax: what the tolerance has to catch.
-    orig = m2._masked_exp
-    m2._masked_exp = lambda s, mask, m: orig(
+    orig = af._masked_exp
+    af._masked_exp = lambda s, mask, m: orig(
         s.astype(softmax_dtype).astype(jnp.float32), mask,
         m.astype(softmax_dtype).astype(jnp.float32))
     try:
         return _twin(window, softmax_dtype)(c, layer)
     finally:
-        m2._masked_exp = orig
+        af._masked_exp = orig
 
 
 @functools.lru_cache(maxsize=None)

@@ -20,7 +20,7 @@ from benchmark import cells
 from benchmark.builders.hybrid_ssm import config_of, seeded_params
 from horovod_tpu.models import hybrid_ssm as hs
 from horovod_tpu.models.transformer import (TransformerConfig,
-                                            init_transformer, view_rungs)
+                                            init_transformer)
 from horovod_tpu.memory.planner import ring_entries
 from horovod_tpu.ops import gqa_paged_attention as gpa
 from horovod_tpu.ops import ssm_scan as scan
@@ -298,7 +298,6 @@ def test_the_tolerance_would_catch_bfloat16():
 def engine():
     eng = InferenceEngine(params(), CFG, max_slots=8, page_size=PAGE,
                           capacity=128)
-    assert eng._rungs == view_rungs(4, 32) == (32, 64, 128)
     eng.warm_start()
     return eng
 
@@ -481,7 +480,6 @@ def test_the_cache_entry_declares_two_groups_and_two_state_stores():
          "window": WINDOW})
     assert [(s["name"], s["kind"]) for s in entry["slot_stores"]] == [
         ("ssm_state", "state"), ("conv_tail", "state")]
-    assert "view_chunk" not in entry
     # At the published sizes: 8 window layers behind a ring of 33 pages of
     # 16 a slot, whatever the capacity.
     big = hs.HybridSSMConfig().serving_model().cache_entry()
@@ -594,7 +592,7 @@ def test_decode_view_is_what_the_kernel_copies_of_both_groups(lengths):
     lengths = np.asarray(lengths, np.int32)
     full = gpa.tokens_read(lengths, 384, 16)
     window = gpa.tokens_read(lengths, 33, 16)
-    got = model.decode_view(lengths, (16, 6144), 16)
+    got = model.decode_view(lengths, 16, 384)
     assert got == pytest.approx((8 * full + 8 * window) / 16 / len(lengths))
     live = lengths[lengths >= 0]
     if not len(live):
@@ -708,8 +706,8 @@ def _decode_primitives(eng):
 
 def test_off_the_tpu_the_rows_are_gathered_unless_the_interpreter_is_asked_for(
         monkeypatch):
-    """The rule is the backend's (``ops/ssd.py``'s): on the CPU the decode
-    program gathers a slot's table row and attends that; with the kernel
+    """The rule is the backend's (``ops/flash_attention.kernel_runs``): on
+    the CPU the decode program gathers a slot's table row and attends that; with the kernel
     forced, sixteen calls' worth of ``pallas_call`` and the only gathers
     left are the embedding's and the tables'.  Neither holds a ladder: no
     conditional, no loop over chunks."""

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.memory import planner
-from horovod_tpu.serving.kv_cache import PagedKVCache, view_tokens_per_slot
+from horovod_tpu.serving.kv_cache import PagedKVCache
 from horovod_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                            Request)
 from test_hybrid_ssm import counter
@@ -118,7 +118,9 @@ def test_what_a_store_of_groups_refuses():
     with pytest.raises(ValueError, match="window must be"):
         PagedKVCache(1, 2, 8, 2, 4, PAGE, groups=(
             GROUPS[0], {"name": "w", "n_layers": 1, "window": -1}))
-    with pytest.raises(ValueError, match="view_chunk"):
+    # A per-slot store's dimension is a number or the slot's "capacity":
+    # no other name (the "view" the ladder's scratch store was sized by).
+    with pytest.raises(ValueError, match='number or "capacity"'):
         grouped(slot_stores=({"name": "v", "kind": "scratch",
                               "shape": (2, "view", 16),
                               "dtype": jnp.float32},))
@@ -209,35 +211,19 @@ def test_the_planner_splits_a_budget_so_that_neither_pool_runs_dry_first():
     assert huge == (64 * 576, 64 * 257)
     with pytest.raises(ValueError, match="less than one slot"):
         planner.size_page_pools(groups, token, 16, 576, 64, 10**8)
-    # The plan prices what the pools take, trash pages included, and the
-    # view the store sizes from them.
-    view = view_tokens_per_slot([(pools[0], 576), (pools[1], 257)], 16, 64,
-                                256)
-    assert view == 3840
+    # The plan prices what the pools take, trash pages included, and a
+    # per-slot store beside them by the slot's capacity, whatever the pools.
     plan = planner.plan_serving(
         1, 8, 128, 64, 576, 16, dtype=jnp.bfloat16, groups=groups,
-        pool_pages=pools, view_tokens=view, slot_stores=(
+        pool_pages=pools, slot_stores=(
             {"name": "paged_view", "kind": "scratch",
-             "shape": (2, "view", 1024), "dtype": jnp.bfloat16},))
+             "shape": (2, "capacity", 1024), "dtype": jnp.bfloat16},))
     assert plan.framework["serving.kv_pages"] == spent
-    assert plan.framework["serving.slot_state"] == 2 * 64 * 3840 * 1024 * 2
+    assert plan.framework["serving.slot_state"] == 2 * 64 * 9216 * 1024 * 2
     whole = planner.plan_serving(1, 8, 128, 64, 576, 16,
                                  dtype=jnp.bfloat16, groups=groups)
     assert whole.framework["serving.kv_pages"] == 16 * token * (
         (1 + 64 * 576) + 4 * (1 + 64 * 257))
-
-
-def test_a_view_is_sized_by_the_largest_pool_and_a_chunk_a_slot():
-    # Every slot at capacity: the whole table, slots x capacity.
-    assert view_tokens_per_slot([(64 * 576, 576)], 16, 64, 256) == 9216
-    # A pool of 13763 pages is 861 chunks of 256, and 64 part-filled ones:
-    # 925, gathered 36 at a time: 936 chunks, 15 a slot.
-    assert view_tokens_per_slot([(13763, 576)], 16, 64, 256) == 15 * 256
-    assert view_tokens_per_slot([(13763, 576), (11053, 257)], 16, 64,
-                                256) == 15 * 256
-    # The window group's pool the larger: 691 chunks and 64, 17 at a time.
-    assert view_tokens_per_slot([(100, 576), (11053, 257)], 16, 64,
-                                256) == 12 * 256
 
 
 # -- one group: the store the four served models build ------------------------

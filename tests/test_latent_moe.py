@@ -18,7 +18,8 @@ from benchmark import cells
 from benchmark.builders.latent_moe import config_of
 from horovod_tpu.models import latent_moe as lm
 from horovod_tpu.models.transformer import (TransformerConfig,
-                                            init_transformer, view_rungs)
+                                            init_transformer)
+from horovod_tpu.ops.latent_paged_attention import tokens_read
 from horovod_tpu.parallel.expert import (held_chunk_rows, moe_layer_held,
                                          route_sigmoid_top_k, swiglu)
 from horovod_tpu.serving import InferenceEngine
@@ -31,8 +32,8 @@ with open(os.path.join(cells.HERE, "tests", "fixtures", "configs",
 CFG = config_of(MODEL)
 UNCUT = dict(MODEL, n_routed_experts=16)   # every expert held
 
-# float32 on both sides: what is left is the order of sums (the paged
-# view, the absorbed form, sorted rows against dense masked products).
+# float32 on both sides: what is left is the order of sums (the gathered
+# rows, the absorbed form against dense masked products).
 # bfloat16 operands in the reference's place move the logits by 1e-3 and
 # more (test_the_tolerance_would_catch_bfloat16), so the tolerance sits
 # between the two with a decade on each side.
@@ -57,11 +58,8 @@ def counter(name):
 
 @functools.lru_cache(maxsize=None)
 def engine():
-    # 8 slots: the decode step attends them in groups of 1, 3 and 4,
-    # longest first, each group on a rung of its own.
     eng = InferenceEngine(params(), CFG, max_slots=8, page_size=8,
                           capacity=256)
-    assert eng._rungs == view_rungs(8, 32) == (64, 128, 256)
     eng.warm_start()
     return eng
 
@@ -97,13 +95,22 @@ def rollout(eng, prompts, max_new):
     return [(np.stack(rows[r.rid]), r.result(0)) for r in reqs]
 
 
-# Ragged slots; the longest sequence decides its group's rung, and each
-# case ends on another one (64, 128, 256 tokens of view); the last two fill
-# the second group, and the last the third.
-@pytest.mark.parametrize("lengths,rung", [
-    ((20,), 64), ((20, 70), 128), ((9, 70, 140), 256),
-    ((70, 9, 140, 30, 66, 12), 256)])
-def test_prefill_then_decode_equals_the_reference(lengths, rung):
+def view_tokens_of(lengths, new, page_size=8, slots=8):
+    """What ``serving.decode_view_tokens`` moves by over a rollout of
+    prompts of ``lengths`` admitted together, ``new`` tokens each:
+    iteration ``i`` (0-based) attends the slots with more than ``i + 1``
+    tokens to give, each at its prompt's length plus ``i`` cached entries
+    rounded up to the page, the mean over the slots."""
+    return sum(tokens_read([n + i if i + 1 < k else -1
+                            for n, k in zip(lengths, new)], page_size)
+               for i in range(max(new) - 1)) / slots
+
+
+# Ragged slots, one to six of the eight alive, from under a page to more
+# than half the capacity.
+@pytest.mark.parametrize("lengths", [
+    (20,), (20, 70), (9, 70, 140), (70, 9, 140, 30, 66, 12)])
+def test_prefill_then_decode_equals_the_reference(lengths):
     eng = engine()
     prompts = [prompt(100 + n, n) for n in lengths]
     new = [6 + i for i in range(len(lengths))]
@@ -112,8 +119,10 @@ def test_prefill_then_decode_equals_the_reference(lengths, rung):
     got = rollout(eng, prompts, new)
     d_iter = counter("serving.decode_iterations") - iters
     assert d_iter == max(new) - 1
-    # Every iteration with the longest sequence alive rode ``rung``.
-    assert (counter("serving.decode_view_tokens") - views) <= rung * d_iter
+    # The counter moved by the live lengths, whole pages, and not by a
+    # view's size: the same on every backend.
+    assert (counter("serving.decode_view_tokens") - views
+            == view_tokens_of(lengths, new))
     seqs = [p + toks for p, (_, toks) in zip(prompts, got)]
     want = REF.served_logits(MODEL, params(), seqs, "f32")
     for p, n, (rows, toks), ref in zip(prompts, new, got, want):
@@ -125,17 +134,17 @@ def test_prefill_then_decode_equals_the_reference(lengths, rung):
     assert eng.cache.free_pages() == eng.cache.total_pages
 
 
-def test_every_rung_was_ridden_by_the_longest_group_alone():
-    """One sequence alive: its group of one slot rides the rung that
-    holds it, the seven idle slots the shortest, and the counter moves by
-    the mean over the slots."""
+def test_one_sequence_alive_counts_its_own_pages_alone():
+    """One sequence alive: the counter moves by its length rounded up to
+    the page, the seven idle slots nothing, the mean over the slots; no
+    rung of any view is in it."""
     eng = engine()
     seen = []
     for n in (20, 70, 140):
         before = counter("serving.decode_view_tokens")
         rollout(eng, [prompt(300 + n, n)], [2])
         seen.append(counter("serving.decode_view_tokens") - before)
-    assert seen == [64, (128 + 7 * 64) / 8, (256 + 7 * 64) / 8]
+    assert seen == [24 / 8, 72 / 8, 144 / 8]
 
 
 def test_run_ahead_loop_equals_the_loop_held_at_depth_0(monkeypatch):
@@ -175,23 +184,6 @@ def test_run_ahead_loop_equals_the_loop_held_at_depth_0(monkeypatch):
     # this share holds some: the counters saw the same slots.
     assert tokens == h_tokens == sum(n for _, n, _ in trace)
     assert assigned == h_assigned > 0
-
-
-@pytest.mark.parametrize("lengths,want", [
-    ([-1] * 8, [0, 0, 0]),
-    ([10, -1, 200, 64, 63, -1, 127, 5], [2, 1, 0]),
-    ([254, 100, 254, 100, 254, 100, 254, 100], [2, 2, 1]),
-])
-def test_group_rungs_are_the_same_on_the_host_and_in_the_program(lengths,
-                                                                 want):
-    rungs, groups = (64, 128, 256), lm.slot_groups(8)
-    assert groups == (1, 3, 4) and lm.slot_groups(64) == (8, 24, 32)
-    assert lm.slot_groups(3) == (3,)
-    host = np.asarray(lengths, np.int32)
-    assert [int(i) for i in lm.group_rungs(host, rungs, groups)] == want
-    traced = jax.jit(lambda x: lm.group_rungs(x, rungs, groups))(
-        jnp.asarray(host))
-    assert [int(i) for i in traced] == want
 
 
 def test_the_tolerance_would_catch_bfloat16():

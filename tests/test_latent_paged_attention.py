@@ -1,6 +1,7 @@
 """The paged latent-attention kernel (ops/latent_paged_attention.py) in the
 Pallas interpreter against ``mla_absorbed_attention`` over a gathered
-view, and through the serving engine for the two latent families with the
+view (what ``latent_moe.gathered_attend``, its twin off the TPU, does in
+the decode program), and through the serving engine for the two latent families with the
 kernel forced (``latent_moe.PAGED_INTERPRET``)."""
 
 import jax
@@ -11,7 +12,6 @@ import pytest
 import test_latent_moe as tl
 import test_shortcut_moe as ts
 from horovod_tpu.models import latent_moe as lm
-from horovod_tpu.models.transformer import view_rungs
 from horovod_tpu.ops import latent_paged_attention as lpa
 from horovod_tpu.serving import InferenceEngine
 
@@ -243,10 +243,9 @@ def _primitives(jaxpr, found=None):
 def _program(monkeypatch, interpret):
     monkeypatch.setattr(lm, "PAGED_INTERPRET", interpret)
     c = case((40, -1, 7, 90), seed=9)
-    rungs = view_rungs(PAGE, PPS)
 
     def f(lengths, store, table, q_nope, q_rope, entry):
-        attend, pos = lm.ladder_attend(lengths, store, table, CFG, rungs)
+        attend, pos = lm.decode_attend(lengths, store, table, CFG)
         return attend(1, q_nope, q_rope, entry, c["ap"])[:, 0], pos
 
     args = [c[k] for k in ("lengths", "store", "table", "q_nope", "q_rope",
@@ -255,21 +254,24 @@ def _program(monkeypatch, interpret):
             jax.jit(f)(*args)[0])
 
 
-def test_off_the_tpu_the_ladder_runs_unless_the_interpreter_is_asked_for(
+def test_off_the_tpu_the_plain_twin_runs_unless_the_interpreter_is_asked_for(
         monkeypatch):
-    """The rule is the backend's (``ops/ssd.py``'s): on the CPU the decode
-    program is the view ladder it was, conditionals and sort and gather;
-    with the kernel forced none of the three is left, and both give the
-    same attention."""
+    """The rule is the backend's (``ops/flash_attention.kernel_runs``): on
+    the CPU the decode program gathers every slot's table row whole and
+    attends it under the lengths, no conditional, no sort, no loop; with
+    the kernel forced the gather is gone too, and both give the same
+    attention."""
     assert jax.default_backend() == "cpu" and not lm.paged_kernel_runs()
-    ladder, want = _program(monkeypatch, None)
-    assert {"cond", "sort", "gather"} <= ladder
-    assert "pallas_call" not in ladder
+    plain, want = _program(monkeypatch, None)
+    assert "gather" in plain
+    assert not plain & {"pallas_call", "cond", "sort", "while"}
     kernel, got = _program(monkeypatch, True)
     assert lm.paged_kernel_runs()
     assert "pallas_call" in kernel
-    assert not kernel & {"cond", "sort", "gather", "scatter"}
+    assert not kernel & {"cond", "sort", "while", "gather", "scatter"}
     assert gap(got, want) < TOL
+    # Neither asks the cache manager for a store beside the pages.
+    assert "slot_stores" not in lm.LatentMoEServing(CFG).cache_entry()
 
 
 def _engine(monkeypatch, cfg, params):

@@ -11,17 +11,20 @@ and elastic resize observably side-effect-free.
 """
 
 import functools
+import hashlib
 import json
 import os
 import threading
 import types
 import urllib.request
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.models import transformer
 from horovod_tpu.models.transformer import (TransformerConfig,
                                             forward_step,
                                             init_transformer,
@@ -614,7 +617,7 @@ def test_engine_capacity_finished_rollout_is_bitwise(capacity):
 # that covers the iteration's longest live sequence
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("page_size,pages_per_slot,want", [
+GEOMETRIES = [
     (16, 64, (128, 256, 512, 1024)),
     (8, 32, (64, 128, 256)),
     (16, 16, (128, 256)),
@@ -622,10 +625,45 @@ def test_engine_capacity_finished_rollout_is_bitwise(capacity):
     (8, 15, (120,)),            # fewer than 16 pages: the one full rung
     (16, 8, (128,)),
     (8, 4, (32,)),
-])
+]
+
+
+@pytest.mark.parametrize("page_size,pages_per_slot,want", GEOMETRIES)
 def test_view_rungs_follow_the_page_geometry(page_size, pages_per_slot,
                                              want):
     assert view_rungs(page_size, pages_per_slot) == want
+
+
+@pytest.mark.parametrize("page_size,pages_per_slot,want", GEOMETRIES)
+def test_the_dense_decoder_reads_its_ladder_off_the_shapes_it_holds(
+        page_size, pages_per_slot, want):
+    """Nobody hands the dense decoder a ladder: its decode program derives
+    the rungs from the store's page size and the table's width, and the
+    host's ``decode_view`` from the same two numbers, so what the counter
+    says a launch rides is what the program picks."""
+    from horovod_tpu.serving.models import DenseLM
+
+    cfg = TransformerConfig(vocab_size=97, d_model=64, n_heads=4,
+                            n_layers=2, d_ff=128, max_seq_len=1024)
+    model = DenseLM(cfg)
+    slots = 3
+    store = jax.ShapeDtypeStruct(
+        (2, 1 + slots * pages_per_slot, page_size, 64), jnp.float32)
+    with mock.patch.object(transformer, "view_rung",
+                           wraps=transformer.view_rung) as picked:
+        jax.eval_shape(
+            model.decode,
+            jax.eval_shape(lambda: init_transformer(jax.random.PRNGKey(0),
+                                                    cfg)),
+            (store, store),
+            jax.ShapeDtypeStruct((slots, pages_per_slot), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32))
+    assert picked.call_args[0][1] == want
+    # The host: a batch whose longest sequence just fits a rung rides it.
+    for rung in want:
+        lengths = np.asarray([rung - 2, 0, -1], np.int32)
+        assert model.decode_view(lengths, page_size, pages_per_slot) == rung
 
 
 @pytest.mark.parametrize("lengths,want", [
@@ -679,11 +717,19 @@ class Ladder:
         self.full = self.make(full=True)
 
     def make(self, full=False):
-        eng = InferenceEngine(self.params, self.cfg, **self.kw)
-        assert eng._rungs == self.rungs
+        """The engine as it is built; ``full``: its decode program traced
+        under a ladder of the one full rung (the ladder is derived inside
+        ``forward_step_paged``, so that is where it is cut) and the host
+        told to count the same."""
+        cut = (lambda page_size, pages_per_slot:
+               (page_size * pages_per_slot,)) if full else view_rungs
+        with mock.patch.object(transformer, "view_rungs", cut):
+            eng = InferenceEngine(self.params, self.cfg, **self.kw)
+            eng.warm_start()
         if full:
-            eng._rungs = eng._rungs[-1:]
-        eng.warm_start()
+            eng.model.decode_view = (
+                lambda lengths, page_size, pages_per_slot:
+                page_size * pages_per_slot)
         return eng
 
     def prompt(self, seed, n):
@@ -699,6 +745,36 @@ class Ladder:
 @functools.lru_cache(maxsize=None)
 def _ladder(name):
     return Ladder(**LADDERS[name])
+
+
+# sha256 of the printed jaxpr of the engine's decode program on the CPU,
+# taken on the tree BEFORE the engine stopped handing the model its ladder
+# (commit 2fe4ef9, ``decode(..., rungs=eng._rungs)``): deriving the rungs
+# from the shapes left the program what it was, operation for operation.
+DECODE_JAXPR_SHA256 = {
+    "cap256-page8":
+        "665cdcc258faddfd0943ee1a45089f44bf8b77c24c76497017e4b964d2566e44",
+    "cap64-page4":
+        "87e9b060453c9dc4dc395388cb3c7a737166569de5f59f339423aaecdc7bc01e",
+}
+
+
+@pytest.mark.parametrize("name", sorted(LADDERS))
+def test_the_dense_decode_program_is_the_one_it_was(name):
+    eng = _ladder(name).ladder
+    table, lengths = eng.cache.device_tables()
+    n = len(eng.cache.arrays)
+
+    def fn(params, *rest):
+        outs, pages = eng._decode_step(params, rest[:n], *rest[n:])
+        return (*outs, *pages)
+
+    text = str(jax.make_jaxpr(fn)(
+        eng.params, *eng.cache.arrays, table, lengths, eng._no_tokens,
+        eng._no_override))
+    assert ".py" not in text                    # no path, no line number
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == DECODE_JAXPR_SHA256[name])
 
 
 @pytest.fixture(params=sorted(LADDERS))
