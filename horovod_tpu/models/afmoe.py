@@ -243,14 +243,17 @@ def gate_out(a, gate, ap, cfg: AfmoeConfig):
                  ).astype(cfg.dtype), ap["w_o"])
 
 
-def flash_runs() -> bool:
+def flash_runs(interpret=None) -> bool:
     """Whether a prompt's attention goes through the streaming flash
     kernel: on the TPU, or where a test asks for the interpreter (the
-    kernels' one rule); elsewhere :func:`attend_block`, its twin."""
-    return kernel_runs(FLASH_INTERPRET or None)
+    kernels' one rule; ``interpret``: a sibling family's own switch, else
+    this module's); elsewhere :func:`attend_block`, its twin."""
+    return kernel_runs((FLASH_INTERPRET if interpret is None
+                        else interpret) or None)
 
 
-def attend_prompt(q, k, v, cfg: AfmoeConfig, window: int = 0):
+def attend_prompt(q, k, v, cfg: AfmoeConfig, window: int = 0,
+                  interpret=None):
     """Grouped-query attention of one sequence over ITSELF, causal (and
     within ``window`` keys, itself included, where given).  ``q [t, heads
     * hd]``, ``k``/``v`` ``[t, kv_heads * hd]``; query head ``i`` reads
@@ -260,13 +263,15 @@ def attend_prompt(q, k, v, cfg: AfmoeConfig, window: int = 0):
     outside the window or ahead of the queries are skipped); in blocks of
     plain ``jnp`` they cross HBM several times, 48 heads x t x t float32
     a pass (517 ms of a 4096-token prompt: PERF.md section 6, PR 41)."""
-    if not flash_runs():
+    if interpret is None:
+        interpret = FLASH_INTERPRET
+    if not flash_runs(interpret):
         return attend_block(q, k, v, cfg, window)
     t, hd = q.shape[0], cfg.head_dim
     heads = lambda x: x.reshape(t, -1, hd).transpose(1, 0, 2)
     o = gqa_window_attention(heads(q), heads(k), heads(v), window=window,
                              sm_scale=cfg.attention_multiplier,
-                             interpret=FLASH_INTERPRET)
+                             interpret=bool(interpret))
     return o.transpose(1, 0, 2).reshape(t, -1)
 
 

@@ -439,10 +439,14 @@ def _ssm_inputs(xc, mp, cfg: HybridSSMConfig):
 
 
 def _conv(window, mp):
-    """``window [.., d_conv, d_inner]``: a step's own input last."""
+    """``window [.., d_conv, d_inner]``: a step's own input last.  A
+    convolution without a bias (``models/olmo_hybrid.py``) has no
+    ``conv_b``."""
     w = mp["conv_w"].astype(jnp.float32)
-    return jax.nn.silu(jnp.sum(window.astype(jnp.float32) * w, axis=-2)
-                       + mp["conv_b"].astype(jnp.float32))
+    y = jnp.sum(window.astype(jnp.float32) * w, axis=-2)
+    if "conv_b" in mp:
+        y = y + mp["conv_b"].astype(jnp.float32)
+    return jax.nn.silu(y)
 
 
 def ssm_prefill(h, mp, n_valid, cfg: HybridSSMConfig):

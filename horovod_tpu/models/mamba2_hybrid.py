@@ -563,6 +563,54 @@ def decode_step(params, tokens, lengths, stores, table,
 
 # -- what the serving engine asks ---------------------------------------------
 
+def write_token_rows(k_pages, v_pages, k, v, table, lengths):
+    """A decode iteration's new keys and values ``k``/``v`` ``[layers,
+    slots, kv_width]`` into the paged stores: one row a slot in every
+    paged layer, written where it lies (see DenseLM.decode); an idle slot's
+    row lands in the trash page."""
+    ps = k_pages.shape[2]
+    pos = jnp.clip(lengths, 0, None)
+    b = lengths.shape[0]
+    page, off = table[jnp.arange(b), pos // ps], pos % ps
+    zero = jnp.zeros((), jnp.int32)
+    for slot in range(b):
+        at = (zero, page[slot], off[slot], zero)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, k[:, slot][:, None, None, :], at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, v[:, slot][:, None, None, :], at)
+    return k_pages, v_pages
+
+
+def write_prompt_pages(k_pages, v_pages, k, v, table_row):
+    """A prompt's keys and values ``k``/``v`` ``[layers, bucket,
+    kv_width]`` into the paged stores, a page at a time, written where it
+    lies; pages past the prompt are not mapped: their rows land in trash
+    page 0."""
+    ps, bucket = k_pages.shape[2], k.shape[1]
+    rows = min(ps, bucket)
+    zero = jnp.zeros((), jnp.int32)
+    for j in range(max(1, bucket // ps)):
+        at = (zero, table_row[0, j], zero, zero)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, k[:, None, j * ps:j * ps + rows], at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, v[:, None, j * ps:j * ps + rows], at)
+    return k_pages, v_pages
+
+
+def replace_slot_rows(state, tail, new_state, new_tail, slot):
+    """What a prompt leaves (``new_state [layers, *state_shape]``,
+    ``new_tail [layers, d_conv - 1, conv_width]``) in the place of slot
+    ``slot [1]``'s rows of the per-slot stores."""
+    zero = jnp.zeros((), jnp.int32)
+    state = jax.lax.dynamic_update_slice(
+        state, new_state[:, None], (zero, slot[0], zero, zero, zero))
+    tail = jax.lax.dynamic_update_slice(
+        tail, new_tail[:, None], (zero, slot[0], zero, zero))
+    return state, tail
+
+
 class Mamba2HybridServing:
     """The serving protocol (serving/models.py) for this model: one paged
     layer an attention layer and three per-slot stores."""
@@ -651,22 +699,10 @@ class Mamba2HybridServing:
                            * self.slot_layer_bytes)
 
     def decode(self, params, pages, table, lengths, tokens):
-        k_pages, v_pages = pages[:2]
         logits, new = decode_step(params, tokens, lengths, pages, table,
                                   self.cfg)
-        # One row a slot in every attention layer, written where it lies
-        # (see DenseLM.decode); an idle slot's row lands in the trash page.
-        ps = k_pages.shape[2]
-        pos = jnp.clip(lengths, 0, None)
-        b = tokens.shape[0]
-        page, off = table[jnp.arange(b), pos // ps], pos % ps
-        zero = jnp.zeros((), jnp.int32)
-        for slot in range(b):
-            at = (zero, page[slot], off[slot], zero)
-            k_pages = jax.lax.dynamic_update_slice(
-                k_pages, new["k"][:, slot][:, None, None, :], at)
-            v_pages = jax.lax.dynamic_update_slice(
-                v_pages, new["v"][:, slot][:, None, None, :], at)
+        k_pages, v_pages = write_token_rows(*pages[:2], new["k"], new["v"],
+                                            table, lengths)
         return (logits,), (k_pages, v_pages, new["state"], new["tail"],
                            new["view"])
 
@@ -676,20 +712,9 @@ class Mamba2HybridServing:
         [1]`` is the slot filled: its state and tails are REPLACED by what
         the prompt leaves."""
         k_pages, v_pages, state, tail, view = pages
-        ps, bucket = k_pages.shape[2], tokens.shape[1]
         logits, left = prefill_step(params, tokens[0], n_valid[0], self.cfg)
-        # A page at a time, written where it lies; pages past the prompt
-        # are not mapped: their rows land in trash page 0.
-        rows = min(ps, bucket)
-        zero = jnp.zeros((), jnp.int32)
-        for j in range(max(1, bucket // ps)):
-            at = (zero, table_row[0, j], zero, zero)
-            k_pages = jax.lax.dynamic_update_slice(
-                k_pages, left["k"][:, None, j * ps:j * ps + rows], at)
-            v_pages = jax.lax.dynamic_update_slice(
-                v_pages, left["v"][:, None, j * ps:j * ps + rows], at)
-        state = jax.lax.dynamic_update_slice(
-            state, left["state"][:, None], (zero, slot[0], zero, zero, zero))
-        tail = jax.lax.dynamic_update_slice(
-            tail, left["tail"][:, None], (zero, slot[0], zero, zero))
+        k_pages, v_pages = write_prompt_pages(k_pages, v_pages, left["k"],
+                                              left["v"], table_row)
+        state, tail = replace_slot_rows(state, tail, left["state"],
+                                        left["tail"], slot)
         return (logits,), (k_pages, v_pages, state, tail, view)

@@ -290,13 +290,14 @@ class PagedKVCache:
         self._mapped = np.zeros((len(groups), max_slots), np.int64)
         self.table_width = pages_per_slot + sum(g.entries
                                                 for g in self._extra)
-        # Gauges a group, of a store with more than one (the full group's
-        # are serving.kv_pages_* as ever).
+        # Gauges a group, of a store with more than one or with pools
+        # smaller than its slots (the full group's are serving.kv_pages_*
+        # as ever).
         self._group_gauges = [
             _group_gauges(name, total) for name, total in zip(
                 self.group_names,
                 [self.n_pages - 1] + [g.total_pages for g in self._extra])
-        ] if self._extra else []
+        ] if self._extra or self._pooled else []
         # guarded_by: _lock
         self._peak = np.zeros((len(groups),), np.int64)
         # Per-slot stores (module docstring): ``{"name", "kind", "shape",

@@ -54,7 +54,8 @@ every model goes through the ONE ``_step`` / ``_admit`` / ``_prefill`` /
 
 A config object that has a ``serving_model()`` method supplies its own
 (``models/latent_moe.py``, ``models/hybrid_ssm.py``,
-``models/mamba2_hybrid.py``); every other config is the dense multi-head
+``models/mamba2_hybrid.py``, ``models/afmoe.py``,
+``models/olmo_hybrid.py``); every other config is the dense multi-head
 decoder of ``models/transformer.py``, :class:`DenseLM`, whose programs
 are operation for operation the ones the engine built itself before.
 """

@@ -615,3 +615,86 @@ def test_gqa_paged_attention_compiles_with_the_differential_head_map(
     assert not any(op in text for op in (" sort(", " gather(",
                                          " conditional("))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# The gated delta rule's two kernels (ops/gated_delta.py) at the shapes of
+# `olmohybrid-serve-chat96` (here for the same reason as the scan kernels
+# above): the chunked scan at the longest bucket, a short one and the
+# shortest (30 heads of 96 x 192, chunks of 64); the one-step kernel over
+# the cell's WHOLE state store (12 layers x 96 slots x 2.2 MB), two layers
+# in a row, which must go in and come out as one buffer: the compiled
+# program aliases all 2.55 GB of it and keeps under two megabytes of
+# temporaries.
+@pytest.mark.parametrize("t", [2048, 64, 2])
+def test_gdn_chunk_scan_compiles_for_the_v5e(v5e_chip, t):
+    from horovod_tpu.ops import gated_delta as gd
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    bf = jnp.bfloat16
+    text = jax.jit(
+        lambda q, k, v, g, beta, s0, n: gd._pallas_chunk_scan(
+            q, k, v, g, beta, s0, n, 64, False)
+    ).lower(sd(t, 30, 96, dtype=bf), sd(t, 30, 96, dtype=bf),
+            sd(t, 30, 192, dtype=bf), sd(t, 30), sd(t, 30), sd(15, 96, 384),
+            sd(dtype=jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "gdn_chunk_scan" in text
+
+
+def test_gdn_step_compiles_for_the_v5e_and_never_copies_the_store(v5e_chip):
+    from horovod_tpu.ops import gated_delta as gd
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(store, q, k, v, g, beta, alive):
+        for layer in (0, 11):
+            o, store = gd._pallas_step(store, q, k, v, g, beta, alive,
+                                       layer, False)
+        return o, store
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(two_layers, donate_argnums=(0,)).lower(
+        sd(12, 96, 15, 96, 384), sd(96, 30, 96, dtype=bf),
+        sd(96, 30, 96, dtype=bf), sd(96, 30, 192, dtype=bf), sd(96, 30),
+        sd(96, 30), sd(96, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "gdn_step" in text
+    memory = compiled.memory_analysis()
+    store = 12 * 96 * 15 * 96 * 384 * 4
+    assert memory.alias_size_in_bytes >= store
+    assert memory.temp_size_in_bytes < 2 << 20
+
+
+# The multi-head full layers of `olmohybrid-serve-chat96` through the paged
+# kernel as it stands: 30 query heads on 30 key/value heads of 128 (a group
+# of ONE: rows of 3840 values, which the block-diagonal form multiplies
+# thirty times over), the pool's table of 176 pages a slot over its four
+# layers; the block rule gives 8 pages.
+def test_gqa_paged_attention_compiles_at_thirty_heads_on_thirty(v5e_chip):
+    from horovod_tpu.ops import gqa_paged_attention as gpa
+
+    assert gpa.block_pages(16, 176, 30, 3840, 2) == 8
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def two_layers(q, k_self, v_self, k_pages, v_pages, table, lengths):
+        order, n_live = gpa.live_first(lengths)
+        return sum(gpa.gqa_paged_attention(
+            q * (1 + i), k_self, v_self, k_pages, v_pages, table, lengths,
+            layer, heads=30, scale=128 ** -0.5, order=order, n_live=n_live,
+            interpret=False).astype(jnp.float32)
+            for i, layer in enumerate((0, 3)))
+
+    compiled = jax.jit(two_layers).lower(
+        sd(96, 3840), sd(96, 3840), sd(96, 3840),
+        sd(4, 3051, 16, 3840), sd(4, 3051, 16, 3840),
+        sd(96, 176, dtype=jnp.int32), sd(96, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "gqa_paged_attn" in text
+    assert not any(op in text for op in (" sort(", " gather(",
+                                         " conditional("))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
