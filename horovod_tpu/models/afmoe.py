@@ -29,8 +29,9 @@ layers whole.
 
 This is a SIBLING of ``models/mamba2_hybrid.py`` and of ``models/
 latent_moe.py``, chosen by the published keys (``model_type``,
-``layer_types``).  Shared with the first: the RMS norm; with the second:
-the held expert layer and its counters.
+``layer_types``).  Shared with the first: the RMS norm and the plain
+grouped-query ``attend_view``; with the second: the held expert layer and
+its counters.
 
 **The cache**: two layer GROUPS of the paged store (serving/kv_cache.py):
 ``full`` (the full layers: every position) and ``window`` (the sliding
@@ -61,7 +62,7 @@ from ..parallel.expert import (moe_layer_held, route_sigmoid_bias_top_k,
 from .hybrid_ssm import (_M_SHARED_KV, _M_WINDOW, PREFILL_Q_BLOCK, _dot,
                          _masked_exp)
 from .latent_moe import LatentMoEServing
-from .mamba2_hybrid import rms_norm
+from .mamba2_hybrid import attend_view, rms_norm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # A test's: run the prompt's flash kernel in the Pallas interpreter.
@@ -408,35 +409,6 @@ def paged_attend(lengths, groups, cfg: AfmoeConfig, interpret=None):
             window=window, order=order, n_live=n_live, interpret=interpret)
 
     return attend
-
-
-def attend_view(q, k_self, v_self, k_view, v_view, mask, cfg: AfmoeConfig):
-    """Grouped-query attention of ONE query a row over a view of cached
-    keys and values plus the row's own new key and value, which are not in
-    the view.  ``q [b, heads * hd]``; ``k_self``/``v_self`` ``[b,
-    kv_width]``; ``k_view``/``v_view`` ``[b, n, kv_width]``; ``mask [b,
-    n]``: which view rows a row attends (none: it attends itself only).
-    Returns ``[b, heads * hd]``."""
-    b, n = mask.shape
-    hd, dt, f32 = cfg.head_dim, q.dtype, jnp.float32
-    g = cfg.num_key_value_heads
-    q4 = q.reshape(b, g, -1, hd)
-    scores = jnp.einsum("bgrd,bngd->bgrn", q4, k_view.reshape(b, n, g, hd),
-                        preferred_element_type=f32
-                        ) * cfg.attention_multiplier
-    s_self = jnp.einsum("bgrd,bgd->bgr", q4, k_self.reshape(b, g, hd),
-                        preferred_element_type=f32
-                        ) * cfg.attention_multiplier
-    mask = mask[:, None, None, :]
-    m = jnp.maximum(jnp.max(jnp.where(mask, scores, -jnp.inf), axis=-1),
-                    s_self)
-    p = _masked_exp(scores, mask, m[..., None])
-    p_self = jnp.exp(s_self - m)
-    o = jnp.einsum("bgrn,bngd->bgrd", p.astype(dt),
-                   v_view.reshape(b, n, g, hd), preferred_element_type=f32)
-    o = o + p_self[..., None] * v_self.astype(f32).reshape(b, g, 1, hd)
-    denom = jnp.sum(p, axis=-1) + p_self
-    return (o / denom[..., None]).astype(dt).reshape(b, -1)
 
 
 def gathered_attend(lengths, groups, cfg: AfmoeConfig):

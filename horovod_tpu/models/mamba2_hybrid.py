@@ -28,27 +28,25 @@ module fixes its five-kind layout by arithmetic on the layer index and
 every attention in it is differential; which module serves a config is
 decided by the published keys (``model_type``, ``layer_types``).  Shared
 with it: the convolution, the per-slot stores of the cache manager and the
-counters.  The chunk list of the decode's paged view (``chunk_ladder``,
-``chunk_rung``, ``chunk_index``, ``fill_view``) lives here since that
-module attends its pages where they lie; ``models/afmoe.py``'s twin off
-the TPU shares it.
+counters.
 
 A prompt runs the chunked form of the recurrence
 (:func:`~horovod_tpu.ops.ssd.ssd_chunk_scan`), decode the one-step form
 (:func:`~horovod_tpu.ops.ssd.ssd_step`), which updates the live slots'
-rows of the state store in place.
+rows of the state store in place; its attention layers read their pages
+where they lie (``ops/gqa_paged_attention.py``; :func:`paged_attend`).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry as _telemetry
+from ..ops import gqa_paged_attention as _paged
 from ..ops.ssd import head_pack, ssd_chunk_scan, ssd_step
 from .hybrid_ssm import (_M_SHARED_KV, _M_STATE_BYTES, PREFILL_Q_BLOCK,
                          _conv, _dot, _masked_exp)
@@ -58,6 +56,11 @@ _M_STATE_MOVED = _telemetry.counter(
     "tails the decode iterations had to read and write: 2 x live slots x "
     "state-space layers x a slot's bytes in one layer, from the host's "
     "lengths (what an implementation moves beyond that is not in it)")
+
+# ``ops/gqa_paged_attention.py``'s ``interpret``: None is the rule (the
+# kernel on the TPU, the gathered rows elsewhere); a test sets True to run
+# the kernel in the Pallas interpreter, before the engine builds its programs.
+PAGED_INTERPRET = None
 
 # granite-4.0-h-micro's published list: attention at 5, 15, 25, 35.
 GRANITE_4_0_H_MICRO_LAYERS = tuple(
@@ -88,7 +91,7 @@ class Mamba2HybridConfig:
     mamba_chunk_size: int = 256
     max_position_embeddings: int = 131072
     dtype: object = jnp.bfloat16
-    # Positions of one chunk of the decode's paged view (whole pages).
+    # Read by nothing: the benchmark's builder hands it on from a fixture.
     decode_chunk_tokens: int = 256
 
     def __post_init__(self):
@@ -244,141 +247,63 @@ def attend_block(q, k, v, cfg: Mamba2HybridConfig):
     return jnp.concatenate(outs, axis=0).astype(dt).reshape(t, -1)
 
 
-# -- the chunk list of the decode's paged view -------------------------------
+# -- the decode's attention over the paged layers -----------------------------
 
-def chunk_ladder(slots: int, capacity: int, chunk_tokens: int) -> tuple:
-    """``(positions a chunk, rungs)``: the decode's shared view is a list
-    of chunks (whole slots of them where ``chunk_tokens`` does not divide
-    the capacity), as many as the sequences alive need TOGETHER; the
-    rungs are the list's lengths the attention is compiled for, halving
-    from every slot at capacity down to one slot's worth."""
-    chunk = chunk_tokens if capacity % chunk_tokens == 0 else capacity
-    a_slot = capacity // chunk
-    rungs, n = [], slots * a_slot
-    while n > a_slot:
-        rungs.append(n)
-        n = -(-n // 2)
-    return chunk, tuple(reversed(rungs + [a_slot]))
-
-
-def chunk_rung(lengths, rungs, chunk: int):
-    """Index of the smallest rung that holds every slot's chunks (a
-    slot's cached positions rounded up to whole chunks; idle: none): the
-    same function for the traced ``lengths`` of the program and for the
-    host's numpy copy."""
-    xp = np if isinstance(lengths, np.ndarray) else jnp
-    need = (xp.clip(lengths, 0, None) + chunk - 1) // chunk
-    return (need.sum() > np.asarray(rungs[:-1], np.int32)).sum()
-
-
-def chunk_index(table, cached, chunk: int, page_size: int):
-    """Where each chunk of the shared view comes from, for the LONGEST
-    list (every slot at capacity): the cached positions of all slots as
-    one list of chunks, a slot's chunks in a row, slot after slot.
-    Returns ``(pages [chunks, pages a chunk], mask [chunks, chunk], owner
-    [chunks], mine [chunks, slots] float32, used)``: ``mask`` the rows
-    that hold a cached position of the chunk's ``owner``, ``mine`` the
-    owner as one-hot rows (all zero for a chunk past the list's end,
-    whose pages are the trash page), ``used`` the chunks in the list."""
-    b, pps = table.shape
-    per = chunk // page_size
-    need = (cached + chunk - 1) // chunk
-    ends = jnp.cumsum(need)
-    c = jnp.arange(b * (pps // per))
-    live = c < ends[-1]
-    owner = jnp.minimum(jnp.searchsorted(ends, c, side="right"), b - 1)
-    local = c - (ends - need)[owner]
-    page_at = jnp.clip(local[:, None] * per + jnp.arange(per)[None, :],
-                       0, pps - 1)
-    pages = jnp.where(live[:, None], table[owner[:, None], page_at], 0)
-    mask = live[:, None] & (local[:, None] * chunk
-                            + jnp.arange(chunk)[None, :]
-                            < cached[owner][:, None])
-    mine = ((owner[:, None] == jnp.arange(b)[None, :])
-            & live[:, None]).astype(jnp.float32)
-    return pages, mask, owner, mine, ends[-1]
-
-
-def fill_view(view, k_pages, v_pages, pages, used, block: int,
-              layer: int = 0):
-    """Gather the chunks in use of paged layer ``layer`` into ``view [2,
-    chunks, chunk, kv_width]`` (keys, values), ``block`` chunks at a time,
-    as many blocks as hold them: a loop whose trip count follows the load,
-    writing in place.  What lies past them is left as it is (stale rows
-    are masked)."""
-    per = pages.shape[1]
-
-    def body(i, view):
-        at = i * block
-        these = jax.lax.dynamic_slice(pages, (at, 0), (block, per))
-        both = jnp.stack([k_pages[layer, these], v_pages[layer, these]])
-        return jax.lax.dynamic_update_slice(
-            view, both.reshape(2, block, -1, view.shape[-1]),
-            (0, at, 0, 0))
-
-    return jax.lax.fori_loop(0, (used + block - 1) // block, body, view)
-
-
-def _key_head_of(cfg: Mamba2HybridConfig, dtype):
-    """``[heads, kv_heads]`` one-hot: query head ``i`` reads key/value
-    head ``i // (heads / kv_heads)``."""
-    h_n, g = cfg.num_attention_heads, cfg.num_key_value_heads
-    return jnp.asarray((np.arange(h_n) // (h_n // g))[:, None]
-                       == np.arange(g)[None, :], dtype)
-
-
-def _lay_queries(q, cfg: Mamba2HybridConfig):
-    """``q [b, heads * hd]`` as ``[b, kv_width, heads]``: a query head laid
-    into a column that is zero outside its key head, so that a view is
-    contracted in the layout it is stored in (the zeros add nothing)."""
-    b = q.shape[0]
-    return jnp.einsum(
-        "bhd,hg->bgdh", q.reshape(b, cfg.num_attention_heads, cfg.head_dim),
-        _key_head_of(cfg, q.dtype)).reshape(b, cfg.kv_width, -1)
-
-
-def _own_values(o, cfg: Mamba2HybridConfig):
-    """``o [.., heads, kv_width]``, a head's probabilities times EVERY
-    value head: head ``h`` keeps the block of its own."""
-    return jnp.einsum(
-        "...hgv,hg->...hv",
-        o.reshape(*o.shape[:-1], cfg.num_key_value_heads, cfg.head_dim),
-        _key_head_of(cfg, jnp.float32))
-
-
-def attend_chunks(q, k_self, v_self, view, cfg: Mamba2HybridConfig):
-    """Grouped-query attention of ONE query a slot over chunks of the
-    paged view plus the slot's own new key and value, which are not in
-    the view.  ``q [b, heads * hd]``; ``k_self``/``v_self`` ``[b,
-    kv_width]``; ``view = (k [chunks, n, kv_width], v, mask [chunks, n],
-    owner [chunks], mine [chunks, slots])`` (:func:`chunk_index`).
-    A slot's softmax runs over ITS chunks (a maximum and two sums over
-    the chunks it owns) and its own new key and value beside them.
-    Returns ``[b, heads * hd]``."""
-    k, v, mask, owner, mine = view
-    dt = q.dtype
-    qbd = _lay_queries(q, cfg)
-    scale = cfg.attention_multiplier
-    scores = jnp.einsum("cnk,ckh->chn", k, qbd[owner],
-                        preferred_element_type=jnp.float32) * scale
-    s_self = jnp.einsum("bk,bkh->bh", k_self, qbd,
-                        preferred_element_type=jnp.float32) * scale
-    mask = mask[:, None, :]
-    top = jnp.max(jnp.where(mask, scores, -jnp.inf), axis=-1)    # [c, h]
-    m = jnp.maximum(jnp.max(jnp.where(mine.T[:, :, None] > 0, top[None],
-                                      -jnp.inf), axis=1), s_self)  # [b, h]
-    p = _masked_exp(scores, mask, m[owner][..., None])
+def attend_view(q, k_self, v_self, k_view, v_view, mask, cfg):
+    """Grouped-query attention of ONE query a row over a view of cached
+    keys and values plus the row's own new key and value, which are not in
+    the view (the paged kernel's arithmetic in plain ``jnp``; ``cfg``: this
+    family's config or a sibling's with the same keys).  ``q [b, heads *
+    hd]``; ``k_self``/``v_self`` ``[b, kv_width]``; ``k_view``/``v_view``
+    ``[b, n, kv_width]``; ``mask [b, n]``: which view rows a row attends
+    (none: it attends itself only).  Returns ``[b, heads * hd]``."""
+    b, n = mask.shape
+    hd, dt, f32 = cfg.head_dim, q.dtype, jnp.float32
+    g = cfg.num_key_value_heads
+    q4 = q.reshape(b, g, -1, hd)
+    scores = jnp.einsum("bgrd,bngd->bgrn", q4, k_view.reshape(b, n, g, hd),
+                        preferred_element_type=f32
+                        ) * cfg.attention_multiplier
+    s_self = jnp.einsum("bgrd,bgd->bgr", q4, k_self.reshape(b, g, hd),
+                        preferred_element_type=f32
+                        ) * cfg.attention_multiplier
+    mask = mask[:, None, None, :]
+    m = jnp.maximum(jnp.max(jnp.where(mask, scores, -jnp.inf), axis=-1),
+                    s_self)
+    p = _masked_exp(scores, mask, m[..., None])
     p_self = jnp.exp(s_self - m)
-    o = _own_values(jnp.einsum("chn,cnk->chk", p.astype(dt), v,
-                               preferred_element_type=jnp.float32), cfg)
-    # A slot's sums over its chunks: tiny one-hot products, in float32.
-    exact = jax.lax.Precision.HIGHEST
-    o = jnp.einsum("cb,chv->bhv", mine, o, precision=exact)
-    denom = jnp.einsum("cb,ch->bh", mine, jnp.sum(p, axis=-1),
-                       precision=exact) + p_self
-    o = o + p_self[..., None] * _own_values(
-        v_self.astype(jnp.float32)[:, None, :], cfg)
-    return (o / denom[..., None]).astype(dt).reshape(q.shape[0], -1)
+    o = jnp.einsum("bgrn,bngd->bgrd", p.astype(dt),
+                   v_view.reshape(b, n, g, hd), preferred_element_type=f32)
+    o = o + p_self[..., None] * v_self.astype(f32).reshape(b, g, 1, hd)
+    denom = jnp.sum(p, axis=-1) + p_self
+    return (o / denom[..., None]).astype(dt).reshape(b, -1)
+
+
+def paged_attend(lengths, table, k_pages, v_pages, cfg: Mamba2HybridConfig):
+    """The decode step's ``attend(layer, q, k_self, v_self)``, built once a
+    program.  On the TPU the kernel that walks the page table
+    (``ops/gqa_paged_attention.py``): the live slots' own pages of paged
+    layer ``layer``, read where they lie.  Elsewhere its plain twin: a
+    slot's table row gathered whole and :func:`attend_view` over it under
+    the kernel's mask.  Which of the two is read off the backend the
+    program is built for (``PAGED_INTERPRET`` is a test's)."""
+    if _paged.kernel_runs(PAGED_INTERPRET):
+        order, n_live = _paged.live_first(lengths)
+
+        def attend(layer, q, k_self, v_self):
+            return _paged.gqa_paged_attention(
+                q, k_self, v_self, k_pages, v_pages, table, lengths, layer,
+                heads=cfg.num_attention_heads,
+                scale=cfg.attention_multiplier, window=0, order=order,
+                n_live=n_live, interpret=PAGED_INTERPRET)
+    else:
+        cached = jnp.clip(lengths, 0, None)
+
+        def attend(layer, q, k_self, v_self):
+            return attend_view(q, k_self, v_self, *_paged.gathered_rows(
+                cached, table, k_pages, v_pages, layer), cfg)
+
+    return attend
 
 
 def _split_in(h, mp, cfg: Mamba2HybridConfig):
@@ -498,39 +423,21 @@ def decode_step(params, tokens, lengths, stores, table,
     position of the new token, the count of cached ones (-1: an idle slot,
     whose state stays bit for bit as it is); ``stores = (k_pages, v_pages
     [attention layers, pages, page, kv_width], state [mamba layers, slots,
-    *state_shape], tail [mamba layers, slots, d_conv - 1, conv_width],
-    view [2, slots, capacity, kv_width])``.
+    *state_shape], tail [mamba layers, slots, d_conv - 1, conv_width])``.
 
-    ``view`` is ONE layer's room: each attention layer in turn gathers
-    its own paged layer into it as chunks (:func:`fill_view`) and
-    attends the leading chunks that hold the list, how many a rung of
-    ``chunk_ladder`` picked INSIDE the program from ``lengths``.  The new
-    token's own key and value are not in the view, so the paged store is
-    written at the end only; the state store is advanced layer by layer
-    in place (``ops/ssd.py`` ``ssd_step``).
+    Each attention layer attends its own paged layer through ONE ``attend``
+    (:func:`paged_attend`).  The new token's own key and value are not in
+    the store, so the paged store is written at the end only; the state
+    store is advanced layer by layer in place (``ops/ssd.py``
+    ``ssd_step``).
 
     Returns ``(logits [slots, vocab], new)``: ``new["k"]``/``["v"]
-    [attention layers, slots, kv_width]``, ``new["state"]``,
-    ``new["tail"]`` and ``new["view"]`` whole."""
-    k_pages, v_pages, state, tail, view = stores
-    b = tokens.shape[0]
+    [attention layers, slots, kv_width]``, ``new["state"]`` and
+    ``new["tail"]`` whole."""
+    k_pages, v_pages, state, tail = stores
     eps, dt, r = cfg.rms_norm_eps, cfg.dtype, cfg.residual_multiplier
     alive = lengths >= 0
-    cached = jnp.clip(lengths, 0, None)
-    ps = k_pages.shape[2]
-    chunk, rungs = chunk_ladder(b, table.shape[1] * ps,
-                                cfg.decode_chunk_tokens)
-    if chunk % ps:
-        raise ValueError(f"decode_chunk_tokens {chunk} is not whole pages "
-                         f"of {ps}")
-    pages, mask, owner, mine, used = chunk_index(table, cached, chunk, ps)
-    chunks = view.reshape(2, -1, chunk, view.shape[-1])
-    picked = chunk_rung(lengths, rungs, chunk)
-
-    def over(n, chunks, q, k_self, v_self):
-        return attend_chunks(q, k_self, v_self,
-                             (chunks[0, :n], chunks[1, :n], mask[:n],
-                              owner[:n], mine[:n]), cfg)
+    attend = paged_attend(lengths, table, k_pages, v_pages, cfg)
 
     x = params["embed"][tokens].astype(jnp.float32) * cfg.embedding_multiplier
     new_k, new_v = [], []
@@ -548,17 +455,13 @@ def decode_step(params, tokens, lengths, stores, table,
         else:
             with jax.named_scope("gqa_attention"):
                 q, k, v = _qkv(h, mp, cfg)
-                chunks = fill_view(chunks, k_pages, v_pages, pages, used,
-                                   rungs[0], layer=len(new_k))
-                mix = _dot(jax.lax.switch(
-                    picked, [partial(over, n) for n in rungs], chunks, q, k,
-                    v), mp["w_o"])
+                mix = _dot(attend(len(new_k), q, k, v), mp["w_o"])
             new_k.append(k)
             new_v.append(v)
         x = mlp(x + r * mix, lp, cfg)
     return head(x, params, cfg), {
         "k": jnp.stack(new_k), "v": jnp.stack(new_v), "state": state,
-        "tail": tail, "view": chunks.reshape(view.shape)}
+        "tail": tail}
 
 
 # -- what the serving engine asks ---------------------------------------------
@@ -613,7 +516,7 @@ def replace_slot_rows(state, tail, new_state, new_tail, slot):
 
 class Mamba2HybridServing:
     """The serving protocol (serving/models.py) for this model: one paged
-    layer an attention layer and three per-slot stores."""
+    layer an attention layer and two per-slot stores."""
 
     speculative = False        # no verify / propose programs
     tensor_parallel = False
@@ -650,14 +553,14 @@ class Mamba2HybridServing:
                 "multipliers": [c.attention_multiplier,
                                 c.embedding_multiplier,
                                 c.residual_multiplier, c.logits_scaling],
-                "decode_chunk_tokens": c.decode_chunk_tokens,
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
     def cache_entry(self) -> dict:
         """A paged layer (keys, values) for each attention layer and the
         per-slot stores, each ``[layers, slots, *shape]`` in the cache
-        manager."""
+        manager.  The decode reads the pages in place and asks for no room
+        to gather into."""
         c = self.cfg
         return {"n_layers": self.n_attention,
                 "n_heads": c.num_key_value_heads, "head_dim": c.head_dim,
@@ -669,11 +572,6 @@ class Mamba2HybridServing:
                     {"name": "conv_tail", "kind": "state",
                      "shape": (self.n_mamba, c.mamba_d_conv - 1,
                                c.conv_width),
-                     "dtype": c.dtype},
-                    # ONE layer's view: the attention layers gather and
-                    # attend in turn, each at one layer's size.
-                    {"name": "paged_view", "kind": "scratch",
-                     "shape": (2, "capacity", c.kv_width),
                      "dtype": c.dtype})}
 
     def observe_stores(self, nbytes: dict) -> None:
@@ -681,13 +579,11 @@ class Mamba2HybridServing:
         _M_STATE_BYTES.set(nbytes.get("state", 0))
 
     def decode_view(self, lengths, page_size, pages_per_slot) -> float:
-        """Positions of paged view a slot the decode program gathers (for
-        EACH attention layer) at these (host) lengths: the chunk list's
-        rung, over the slots."""
-        chunk, ladder = chunk_ladder(len(lengths),
-                                     page_size * pages_per_slot,
-                                     self.cfg.decode_chunk_tokens)
-        return (ladder[int(chunk_rung(lengths, ladder, chunk))] * chunk
+        """Positions a slot an attention layer the decode program reads of
+        the paged store at these (host) lengths: the live slots' entries
+        in use, whole pages (what the kernel copies; its twin gathers the
+        whole rows and masks the rest), over the slots."""
+        return (_paged.tokens_read(lengths, pages_per_slot, page_size)
                 / len(lengths))
 
     def observe_launch(self, lengths) -> None:
@@ -703,18 +599,17 @@ class Mamba2HybridServing:
                                   self.cfg)
         k_pages, v_pages = write_token_rows(*pages[:2], new["k"], new["v"],
                                             table, lengths)
-        return (logits,), (k_pages, v_pages, new["state"], new["tail"],
-                           new["view"])
+        return (logits,), (k_pages, v_pages, new["state"], new["tail"])
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens,
                 slot):
         """``start`` is always 0 here (``prefix_cache`` is off); ``slot
         [1]`` is the slot filled: its state and tails are REPLACED by what
         the prompt leaves."""
-        k_pages, v_pages, state, tail, view = pages
+        k_pages, v_pages, state, tail = pages
         logits, left = prefill_step(params, tokens[0], n_valid[0], self.cfg)
         k_pages, v_pages = write_prompt_pages(k_pages, v_pages, left["k"],
                                               left["v"], table_row)
         state, tail = replace_slot_rows(state, tail, left["state"],
                                         left["tail"], slot)
-        return (logits,), (k_pages, v_pages, state, tail, view)
+        return (logits,), (k_pages, v_pages, state, tail)

@@ -10,7 +10,7 @@ Query head ``i`` of ``heads`` reads key/value head ``i // (heads /
 kv_heads)``, unless the caller hands in another HEAD MAP (differential
 attention's pairs, ``models/hybrid_ssm.py``: :func:`gqa_paged_attention`).
 The arithmetic is the twins' a caller keeps off the TPU
-(``models/afmoe.py::attend_view``): scores times ``scale``, scores,
+(``models/mamba2_hybrid.py::attend_view``): scores times ``scale``, scores,
 softmax and accumulation float32, the probabilities rounded to the store's
 type as the second product's operand, the new token's own key and value
 (the engine writes them to the store after the program) joining from their

@@ -698,3 +698,64 @@ def test_gqa_paged_attention_compiles_at_thirty_heads_on_thirty(v5e_chip):
     assert not any(op in text for op in (" sort(", " gather(",
                                          " conditional("))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# The WHOLE decode program of `granite4h-serve-sessions` as its serving
+# model builds it (models/mamba2_hybrid.py), from shapes: 64 slots x 192
+# pages of 16, four paged layers of 512-wide keys and values (1.61 GB),
+# 4.89 GB of state.  The four attention layers each call the paged kernel
+# over the WHOLE stores and the 36 state-space layers the step kernel; no
+# conditional picks a rung, nothing gathers pages into a view, and the four
+# stores are updated where they lie.
+def test_granites_decode_program_compiles_for_the_v5e(v5e_chip, monkeypatch):
+    import functools
+    import re
+
+    from horovod_tpu.models import mamba2_hybrid as mh
+    from horovod_tpu.ops import gqa_paged_attention as gpa
+    from horovod_tpu.ops import ssd
+
+    # The program is built for the chip described, not for this backend:
+    # both kernels asked for by name (False: compiled, not interpreted).
+    monkeypatch.setattr(mh, "PAGED_INTERPRET", False)
+    monkeypatch.setattr(mh, "ssd_step", functools.partial(ssd.ssd_step,
+                                                          interpret=False))
+    cfg = mh.Mamba2HybridConfig()
+    model = cfg.serving_model()
+    slots, page, pps = 64, 16, 192
+    assert gpa.block_pages(page, pps, 32, cfg.kv_width, 2) == 64
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    entry = model.cache_entry()
+    assert [s["kind"] for s in entry["slot_stores"]] == ["state", "state"]
+    store = (entry["n_layers"], slots * pps + 1, page, cfg.kv_width)
+    stores = (sd(*store), sd(*store),
+              *(sd(s["shape"][0], slots, *s["shape"][1:], dtype=s["dtype"])
+                for s in entry["slot_stores"]))
+    params = jax.tree_util.tree_map(
+        lambda x: sd(*x.shape, dtype=x.dtype), jax.eval_shape(
+            lambda: mh.init_mamba2_hybrid(jax.random.PRNGKey(0), cfg)))
+
+    def step(params, k, v, state, tail, table, lengths, tokens):
+        (logits,), new = model.decode(params, (k, v, state, tail), table,
+                                      lengths, tokens)
+        return (logits, *new)
+
+    i32 = functools.partial(sd, dtype=jnp.int32)
+    compiled = jax.jit(step, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *stores, i32(slots, pps), i32(slots), i32(slots)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_paged_attn(\.\d+)? = ", text)) == 4
+    assert len(re.findall(r"%ssd_step(\.\d+)? = ", text)) == 36
+    assert text.count('custom_call_target="tpu_custom_call"') == 40
+    assert " conditional(" not in text and " while(" not in text
+    paged = "bf16[%d,%d,%d,%d]" % store
+    assert paged in text
+    assert not [line for line in text.splitlines()
+                if " gather(" in line and paged in line]
+    memory = compiled.memory_analysis()
+    held = sum(s.size * s.dtype.itemsize for s in stores)
+    assert memory.alias_size_in_bytes >= held          # 6.50 GB, in place
+    assert memory.temp_size_in_bytes < 256 << 20

@@ -1,11 +1,13 @@
 """The paged grouped-query kernel (ops/gqa_paged_attention.py) in the Pallas
-interpreter against its twin, ``afmoe.attend_view`` over every slot's table
-row gathered whole under ``attended_rows`` (what ``afmoe.gathered_attend``
-does in the decode program): a table that holds every page (the full
-group), a ring that has not wrapped, one that has wrapped once and several
-times.  Then the same kernel under another head map, differential attention's
+interpreter against its twin, ``mamba2_hybrid.attend_view`` (``afmoe``'s
+too) over every slot's table row gathered whole under ``attended_rows``
+(what ``afmoe.gathered_attend`` does in the decode program): a table that
+holds every page (the full group), a ring that has not wrapped, one that
+has wrapped once and several times.  Then the same kernel under another head map, differential attention's
 (``models/hybrid_ssm.py``), against ``attend_view`` over the positions
-themselves, gathered one by one."""
+themselves, gathered one by one.  Last, kernel and twin at
+``granite-4.0-h-micro``'s head layout against the softmax written out in
+float64."""
 
 import functools
 
@@ -16,6 +18,7 @@ import pytest
 
 from horovod_tpu.models import afmoe as af
 from horovod_tpu.models import hybrid_ssm as hs
+from horovod_tpu.models import mamba2_hybrid as mh
 from horovod_tpu.ops import gqa_paged_attention as gpa
 
 # 32 query heads on 4 key/value heads make a block of 1024 tokens (64 pages
@@ -88,7 +91,7 @@ def _twin(window, softmax_dtype):
 
 
 def over_a_gathered_view(c, layer, window=0, softmax_dtype=None):
-    """``afmoe.attend_view`` over every slot's table row, gathered whole in
+    """``attend_view`` over every slot's table row, gathered whole in
     table order, under the kernel's own mask as an array: the decode
     program's twin off the TPU.  Rows the mask hides are zeroed (a hidden
     NaN would reach the product as ``0 * NaN``); an idle slot's row is its
@@ -98,14 +101,14 @@ def over_a_gathered_view(c, layer, window=0, softmax_dtype=None):
         return _twin(window, None)(c, layer)
     # The same attention with its scores rounded on their way to the
     # softmax: what the tolerance has to catch.
-    orig = af._masked_exp
-    af._masked_exp = lambda s, mask, m: orig(
+    orig = mh._masked_exp
+    mh._masked_exp = lambda s, mask, m: orig(
         s.astype(softmax_dtype).astype(jnp.float32), mask,
         m.astype(softmax_dtype).astype(jnp.float32))
     try:
         return _twin(window, softmax_dtype)(c, layer)
     finally:
-        af._masked_exp = orig
+        mh._masked_exp = orig
 
 
 @functools.lru_cache(maxsize=None)
@@ -484,3 +487,85 @@ def test_attended_rows_is_the_rings_rule_row_by_row(entries, window):
                     not window or pos > cached - window)
         assert np.array_equal(seen, want), cached
         assert want.sum() == (min(cached, window - 1) if window else cached)
+
+
+# -- granite-4.0-h-micro's layout: 32 heads on 8 of 64, scale 1/64 -----------
+
+GRANITE = mh.Mamba2HybridConfig(dtype=jnp.float32)
+G_BLOCK = PAGE * gpa.block_pages(PAGE, PPS, 32, GRANITE.kv_width, 4)
+
+
+def softmax_written_out(c, layer, cfg):
+    """Every live slot's attention over its cached positions, looked up one
+    by one through the table, and its own new key and value: float64
+    ``numpy``, nothing of the program.  Idle slots: zeros."""
+    heads, hd = cfg.num_attention_heads, cfg.head_dim
+    rep = heads // cfg.num_key_value_heads
+    table, lengths = np.asarray(c["table"]), np.asarray(c["lengths"])
+    kp, vp = (np.asarray(c[x][layer], np.float64)
+              for x in ("k_pages", "v_pages"))
+    out = np.zeros((len(lengths), heads, hd))
+    for s, n in enumerate(lengths):
+        if n < 0:
+            continue
+        at = np.arange(n)
+        k, v = (np.concatenate([x[table[s, at // PAGE], at % PAGE],
+                                np.asarray(c[own][s], np.float64)[None]])
+                for x, own in ((kp, "k_self"), (vp, "v_self")))
+        q = np.asarray(c["q"][s], np.float64).reshape(heads, hd)
+        for h in range(heads):
+            lanes = slice(h // rep * hd, (h // rep + 1) * hd)
+            score = k[:, lanes] @ q[h] * cfg.attention_multiplier
+            p = np.exp(score - score.max())
+            out[s, h] = p @ v[:, lanes] / p.sum()
+    return out.reshape(len(lengths), -1)
+
+
+def granite_attend(c, layer, interpret):
+    """``mamba2_hybrid.paged_attend``, the decode program's own: through
+    the kernel in the interpreter, or the twin the rule gives here."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mh, "PAGED_INTERPRET", interpret)
+        return jax.jit(lambda c: mh.paged_attend(
+            c["lengths"], c["table"], c["k_pages"], c["v_pages"], GRANITE)(
+                layer, c["q"], c["k_self"], c["v_self"]))(c)
+
+
+# Nothing cached, one position, a page's edge and one past it, a block's
+# edge and one past it, several blocks (512 tokens each here), idle slots
+# between; pages that are nobody's entries in use hold NaN.
+@pytest.mark.parametrize("path", ["kernel", "twin"])
+@pytest.mark.parametrize("lengths", [
+    (0, 1, -1, PAGE, PAGE + 1, -1, 300, 5),
+    (G_BLOCK, -1, G_BLOCK + 1, 2 * G_BLOCK + 40, -1, PAGE * PPS - 1)])
+def test_granites_heads_attend_as_the_softmax_written_out(lengths, path):
+    assert (GRANITE.num_attention_heads, GRANITE.num_key_value_heads,
+            GRANITE.head_dim, GRANITE.attention_multiplier, G_BLOCK) == (
+                32, 8, 64, 1 / 64, 512)
+    kernel = path == "kernel"
+    c = case(lengths, PPS, seed=47, nan_elsewhere=kernel,
+             kw=GRANITE.kv_width, qw=2048)
+    got = np.asarray(granite_attend(c, 2, True if kernel else None))
+    on = np.asarray(c["lengths"]) >= 0
+    want = softmax_written_out(c, 2, GRANITE)
+    assert np.isfinite(got[on]).all()
+    assert np.abs(got[on] - want[on]).max() < TOL
+    if kernel:
+        assert not got[~on].any()          # idle slots: exact zeros
+
+
+def test_granites_decode_program_takes_the_kernel_by_the_backend_alone(
+        monkeypatch):
+    """Which of the two ``paged_attend`` builds is ``kernel_runs`` of the
+    module's ``PAGED_INTERPRET`` (None off a test): the kernel on the TPU,
+    the gathered rows elsewhere, and no argument says otherwise."""
+    c = case((5, 40), PPS, seed=1, kw=GRANITE.kv_width, qw=2048)
+    asked = []
+    monkeypatch.setattr(gpa, "kernel_runs",
+                        lambda interpret: asked.append(interpret) or False)
+    monkeypatch.setattr(gpa, "gqa_paged_attention", None)    # never reached
+    twin = granite_attend(c, 0, None)
+    assert asked == [None]
+    monkeypatch.undo()
+    assert mh.PAGED_INTERPRET is None and not gpa.kernel_runs(None)
+    assert gap(twin, granite_attend(c, 0, None)) == 0.0

@@ -23,6 +23,7 @@ from horovod_tpu.models import hybrid_ssm as hs
 from horovod_tpu.models import mamba2_hybrid as mh
 from horovod_tpu.models.transformer import (TransformerConfig,
                                             init_transformer)
+from horovod_tpu.ops import gqa_paged_attention as gpa
 from horovod_tpu.ops import ssd
 from horovod_tpu.serving import InferenceEngine
 from test_hybrid_ssm import counter, rollout
@@ -38,8 +39,8 @@ with open(os.path.join(cells.HERE, "configs",
 CFG = config_of(MODEL)
 
 # float32 on both sides: what is left is the order of sums (the chunked
-# form's products against the step-by-step recurrence, the paged view's
-# block products, the new token's key beside the view, blockwise softmax).
+# form's products against the step-by-step recurrence, the paged kernel's
+# blocks, the new token's key beside the cached ones, blockwise softmax).
 # The logits have a spread of 0.0024 (the embedding is drawn at 0.02 / 12
 # and logits_scaling divides by 8); these differences measure 1e-8.
 # bfloat16 operands in the reference's place move them by 6e-5 and more
@@ -47,6 +48,9 @@ CFG = config_of(MODEL)
 # 2e-2: the tolerance sits a decade and more under the first and two over
 # what is measured.
 TOL = 2e-6
+# The paged kernel's block at the toy sizes, for the tests that want several
+# blocks a slot: 16 tokens (4 pages of 4) where the shapes would give 128.
+SMALL_TILE = 16 * 4 * CFG.num_attention_heads
 
 
 @functools.lru_cache(maxsize=None)
@@ -232,11 +236,11 @@ def test_the_logits_are_not_decided_by_the_tied_embedding():
     assert 0.9 < w2.std() / 0.02 < 1.1          # not divided by depth
 
 
-@pytest.mark.parametrize("what", ["prefill", "decode"])
+@pytest.mark.parametrize("what", ["prefill", "decode", "paged"])
 def test_the_model_runs_the_kernels_it_is_tested_with(monkeypatch, what):
-    """The model's prefill through the chunked kernel and its decode
-    through the step kernel (both interpreted) equal what it computes
-    through their twins."""
+    """The model's prefill through the chunked kernel, its decode through
+    the step kernel and its decode's attention through the paged kernel
+    (all interpreted) equal what it computes through their twins."""
     toks = jnp.asarray(prompt(1, 24) + [0] * 8, jnp.int32)
     if what == "prefill":
         plain, left = _jitted("last")(params(), toks, jnp.int32(24))
@@ -248,21 +252,33 @@ def test_the_model_runs_the_kernels_it_is_tested_with(monkeypatch, what):
         assert float(jnp.abs(left["state"] - left_k["state"]).max()) < 1e-6
     else:
         eng = engine()
-        table, lengths = eng.cache.device_tables()
-        lengths = jnp.asarray([5, -1, 0, 17, -1, -1, 30, -1], jnp.int32)
+        # Every slot's table row mapped: nothing cached, one position, a
+        # page's edge, one past it, several of the paged kernel's blocks.
+        table = jnp.asarray(1 + np.random.default_rng(3).permutation(
+            eng.cache.total_pages).reshape(8, -1), jnp.int32)
+        lengths = jnp.asarray([5, -1, 0, 17, 1, -1, 70, 4], jnp.int32)
         stores = tuple(jax.random.normal(jax.random.PRNGKey(i), a.shape,
                                          a.dtype) * 0.1
                        for i, a in enumerate(eng.cache.arrays))
         step = lambda: jax.jit(lambda p, t: mh.decode_step(
             p, t, lengths, stores, table, CFG))(params(), toks[:8])
         plain, new = step()
-        monkeypatch.setattr(mh, "ssd_step", functools.partial(
-            ssd.ssd_step, interpret=True))
+        if what == "decode":
+            monkeypatch.setattr(mh, "ssd_step", functools.partial(
+                ssd.ssd_step, interpret=True))
+        else:
+            # 70 cached positions are five blocks.
+            monkeypatch.setattr(gpa, "SCORE_TILE_BYTES", SMALL_TILE)
+            monkeypatch.setattr(mh, "PAGED_INTERPRET", True)
         kernel, new_k = step()
         on = np.asarray(lengths) >= 0
         assert float(jnp.abs(new["state"] - new_k["state"]).max()) < 1e-6
+        # (An idle slot's new key lands in the trash page: the kernel
+        # attends nothing for it, the twin its own new value.)
+        assert float(jnp.abs(new["k"][:, on] - new_k["k"][:, on]).max()) < 1e-6
         # An idle slot's state is bit for bit what it was, either way.
         for got in (new, new_k):
+            assert sorted(got) == ["k", "state", "tail", "v"]
             assert np.array_equal(np.asarray(got["state"])[:, ~on],
                                   np.asarray(stores[2])[:, ~on])
             assert np.array_equal(np.asarray(got["tail"])[:, ~on],
@@ -273,12 +289,25 @@ def test_the_model_runs_the_kernels_it_is_tested_with(monkeypatch, what):
 
 # -- prefill then decode through the engine's stores --------------------------
 
-@functools.lru_cache(maxsize=None)
-def engine():
+def build(path="twin", warm=False):
+    """A toy engine.  ``"twin"``: what the rule gives off the TPU, a table
+    row gathered whole; ``"kernel"``: the paged kernel in the interpreter,
+    16 tokens (4 pages) a block.  The path is the program's when it is
+    traced, so ``warm`` builds every program here."""
     eng = InferenceEngine(params(), CFG, max_slots=8, page_size=4,
                           capacity=128)
-    eng.warm_start()
+    with pytest.MonkeyPatch.context() as patch:
+        if path == "kernel":
+            patch.setattr(mh, "PAGED_INTERPRET", True)
+            patch.setattr(gpa, "SCORE_TILE_BYTES", SMALL_TILE)
+        if warm or path == "kernel":
+            eng.warm_start()
     return eng
+
+
+@functools.lru_cache(maxsize=None)
+def engine(path="twin"):
+    return build(path, warm=True)
 
 
 def check_against_reference(prompts, new, got):
@@ -290,13 +319,16 @@ def check_against_reference(prompts, new, got):
 
 # Ragged slots: a prompt of 3 (bucket 4: a pad that must advance nothing),
 # exactly a bucket, prompts across several chunks of the scan, answers that
-# carry the state on step by step; the last case fills every rung's group.
+# carry the state on step by step; one cached position, a page's edge (4)
+# and one past it; the last case holds several blocks of the paged kernel a
+# slot beside idle slots.  Off the TPU's twin and through the kernel.
+@pytest.mark.parametrize("path", ["twin", "kernel"])
 @pytest.mark.parametrize("lengths,new", [
     ((3,), (12,)), ((32,), (3,)), ((6, 19), (9, 4)),
-    ((20, 5, 70), (6, 14, 3)),
+    ((1, 4, 5), (6, 14, 3)),
     ((40, 9, 100, 30, 66, 12), (5, 6, 7, 8, 9, 10))])
-def test_prefill_then_decode_equals_the_reference(lengths, new):
-    eng = engine()
+def test_prefill_then_decode_equals_the_reference(lengths, new, path):
+    eng = engine(path)
     prompts = [prompt(100 + n, n) for n in lengths]
     got = rollout(eng, prompts, new)
     check_against_reference(prompts, new, got)
@@ -314,19 +346,18 @@ def test_every_prefill_bucket_serves_the_reference(bucket):
     check_against_reference([p], [3], rollout(eng, [p], [3]))
 
 
-def test_a_slot_admitted_after_an_eviction_carries_nothing_over():
+@pytest.mark.parametrize("path", ["twin", "kernel"])
+def test_a_slot_admitted_after_an_eviction_carries_nothing_over(path):
     """Recurrent state has no mask: the slot's state and tails must be
     REPLACED by the next prefill.  A long sequence leaves its state in
-    slot 0, a short one follows it there."""
-    eng = engine()
+    slot 0 and its keys in pages, a short one follows it there."""
+    eng = engine(path)
     resets = counter("serving.state_slot_resets")
     long_p, short_p = prompt(901, 90), prompt(902, 5)
     rollout(eng, [long_p], [20])
     second = rollout(eng, [short_p], [12])
     assert counter("serving.state_slot_resets") - resets == 2
-    fresh = InferenceEngine(params(), CFG, max_slots=8, page_size=4,
-                            capacity=128)
-    first = rollout(fresh, [short_p], [12])
+    first = rollout(build(path), [short_p], [12])
     assert second[0][1] == first[0][1]
     assert np.abs(second[0][0] - first[0][0]).max() == 0.0
     check_against_reference([short_p], [12], second)
@@ -390,24 +421,22 @@ def test_the_counters_of_a_fixed_batch_with_idle_slots():
         == 2 * 3 * 36 * 2_123_264
 
 
-def test_the_cache_entry_is_four_paged_layers_the_state_and_one_view():
+def test_the_cache_entry_is_four_paged_layers_the_state_and_no_view():
     entry = mh.Mamba2HybridConfig().serving_model().cache_entry()
     assert entry["n_layers"] == 4 and entry["widths"] == (512, 512)
     assert [(s["name"], s["kind"], s["shape"]) for s in entry["slot_stores"]
             ] == [("ssm_state", "state", (36, 32, 128, 128)),
-                  ("conv_tail", "state", (36, 3, 4352)),
-                  ("paged_view", "scratch", (2, "capacity", 512))]
+                  ("conv_tail", "state", (36, 3, 4352))]
     assert entry["slot_stores"][0]["dtype"] == jnp.float32
-    # The toy engine's cache manager holds them as told.
+    # The toy engine's cache manager holds them as told: four arrays, no
+    # room to gather into.
     c = engine().cache
-    assert c.n_layers == 2 and len(c.arrays) == 5 and c.arrays[:2] == c.pages
+    assert c.n_layers == 2 and len(c.arrays) == 4 and c.arrays[:2] == c.pages
     assert [x.shape for x in c.slot_state] == [
-        (4, 8, *CFG.state_shape), (4, 8, 3, CFG.conv_width),
-        (2, 8, c.capacity, CFG.kv_width)]
+        (4, 8, *CFG.state_shape), (4, 8, 3, CFG.conv_width)]
     nbytes = c.slot_store_bytes()
-    assert nbytes["state"] == 8 * 4 * (4 * math.prod(CFG.state_shape)
-                                       + 3 * CFG.conv_width * 4)
-    assert nbytes["scratch"] == 2 * 8 * c.capacity * CFG.kv_width * 4
+    assert nbytes == {"state": 8 * 4 * (4 * math.prod(CFG.state_shape)
+                                        + 3 * CFG.conv_width * 4)}
     assert telemetry.metrics()["serving.state_bytes"]["value"] \
         == nbytes["state"]
     assert c.total_pages == 8 * 32
@@ -415,7 +444,7 @@ def test_the_cache_entry_is_four_paged_layers_the_state_and_one_view():
 
 def test_the_planner_prices_the_per_slot_stores_the_entry_declares():
     """The state store is what decides how many slots fit: 76.4 MB of
-    state and 6.3 MB of view a slot, against 25 MB of pages."""
+    state a slot against 25 MB of pages."""
     from horovod_tpu.memory import planner
 
     c = engine().cache
@@ -431,11 +460,27 @@ def test_the_planner_prices_the_per_slot_stores_the_entry_declares():
             slot_stores=entry["slot_stores"]).framework
 
     got = plan(64)
-    assert round(got["serving.slot_state"] / 1e9, 2) == 5.29     # 4.89 + 0.40
+    assert round(got["serving.slot_state"] / 1e9, 2) == 4.89     # no view
     assert round(got["serving.kv_pages"] / 1e9, 2) == 1.61
     assert plan(32)["serving.slot_state"] * 2 == got["serving.slot_state"]
     assert "serving.slot_state" not in planner.plan_serving(
         4, 8, 64, 64, 192, 16).framework
+
+
+# The cell's load (23 of 64 alive), every slot at a page's edge or idle, and
+# nobody alive: what the kernel copies of one layer, over the slots.
+@pytest.mark.parametrize("lengths,page,entries", [
+    (tuple(np.random.default_rng(7).integers(64, 2800, 23)) + (-1,) * 41,
+     16, 192),
+    ((0, 1, 16, 17, 3071, -1, -1, 512), 16, 192),
+    ((-1,) * 8, 4, 32)])
+def test_decode_view_is_what_the_kernel_copies(lengths, page, entries):
+    model = mh.Mamba2HybridConfig().serving_model()
+    lengths = np.asarray(lengths, np.int32)
+    got = model.decode_view(lengths, page, entries)
+    assert got == gpa.tokens_read(lengths, entries, page) / len(lengths)
+    live = lengths[lengths >= 0]
+    assert got * len(lengths) == sum(-(-int(n) // page) * page for n in live)
 
 
 def test_the_identity_tells_the_family_from_the_other_hybrid():
@@ -445,6 +490,7 @@ def test_the_identity_tells_the_family_from_the_other_hybrid():
     assert mine["layer_types"] == MODEL["layer_types"]
     assert mine["mamba"] == [8, 32, 16, 4, 2, 1, 8]
     assert mine["multipliers"] == [1.0, 12, 0.22, 8]
+    assert "decode_chunk_tokens" not in mine      # a field nothing reads
     assert set(mine) != set(other)
     moved = config_of(dict(MODEL, layer_types=[
         "mamba", "attention", "mamba", "mamba", "attention", "mamba"]))
@@ -471,10 +517,26 @@ def test_prefix_cache_draft_and_tensor_parallel_are_refused_with_reasons():
                         capacity=64)
 
 
-def test_each_attention_layer_gathers_its_own_paged_layer_into_one_view():
-    """Two gathers (keys, values) an attention layer in the decode
-    program, each from its own layer of the store, into the one scratch."""
-    eng = engine()
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls, a Pallas kernel's
+    own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("path", ["twin", "kernel"])
+def test_each_attention_layer_attends_its_own_paged_layer_in_place(
+        monkeypatch, path):
+    """The decode program as the engine builds it holds no conditional and
+    no loop either way.  Through the kernel: one ``gqa_paged_attn`` call an
+    attention layer over the WHOLE stores, and no gather of pages.  The
+    twin: a table row of keys and one of values gathered an attention
+    layer."""
+    eng = engine(path)
     table, lengths = eng.cache.device_tables()
     args = (eng.params, *eng.cache.arrays, table, lengths, eng._no_tokens,
             eng._no_override)
@@ -484,8 +546,21 @@ def test_each_attention_layer_gathers_its_own_paged_layer_into_one_view():
         outs, pages = eng._decode_step(params, rest[:n], *rest[n:])
         return (*outs, *pages)
 
-    text = jax.jit(fn).lower(*args).as_text()
-    kvw = CFG.kv_width
-    page_gathers = [l for l in text.splitlines() if "gather" in l
-                    and f"x4x{kvw}xf32" in l and "tensor<2x" in l]
-    assert len(page_gathers) == 2 * 2
+    if path == "kernel":
+        monkeypatch.setattr(mh, "PAGED_INTERPRET", True)
+    eqns = list(_equations(jax.make_jaxpr(fn)(*args).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert "cond" not in names and "while" not in names
+    c = eng.cache
+    rows = (8, c.pages_per_slot, c.page_size, CFG.kv_width)
+    gathers = [e for e in eqns if e.primitive.name == "gather"
+               and e.outvars[0].aval.shape == rows]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    if path == "twin":
+        assert not calls and len(gathers) == 2 * 2
+        return
+    assert not gathers and len(calls) == 2
+    for e in calls:
+        assert e.params["name"] == "gqa_paged_attn"
+        assert [v.aval.shape for v in e.invars].count(
+            c.pages[0].shape) == 2
