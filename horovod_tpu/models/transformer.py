@@ -64,12 +64,13 @@ class TransformerConfig:
     # activation memory drops from O(n_layers) to O(1) layers at ~1/3
     # more FLOPs — the standard trade for long sequences / deep stacks.
     remat: bool = False
-    # Chunked cross-entropy: compute the loss over sequence chunks of
-    # this many positions, rematerializing each chunk's logits in the
-    # backward pass.  The [batch, seq, vocab] float32 logits tensor —
-    # the dominant long-context allocation (e.g. 8.6 GB at batch 8,
-    # seq 8192, vocab 32768) — never materializes; peak extra memory is
-    # one chunk's logits.  0 = off (single full-logits matmul).
+    # Chunked cross-entropy: compute the loss (next_token_nll, below)
+    # over sequence chunks of this many positions, rematerializing each
+    # chunk's logits in the backward pass.  The [batch, seq, vocab]
+    # float32 logits tensor — the dominant long-context allocation (e.g.
+    # 8.6 GB at batch 8, seq 8192, vocab 32768) — never materializes;
+    # peak extra memory is one chunk's logits.  0 = off (one full-logits
+    # matmul, whose logits live until the loss's backward has read them).
     loss_chunk: int = 0
 
 
@@ -312,10 +313,75 @@ def forward(params: dict, tokens, cfg: TransformerConfig,
     return logits, aux
 
 
+def _nll_and_grad(hidden, unembed, targets):
+    """Per-row loss and ``d nll / d logits`` (bfloat16) from ONE set of
+    float32 logits."""
+    logits = jnp.dot(hidden, unembed, preferred_element_type=jnp.float32)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1, keepdims=True))
+    picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)
+    column = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    g = (jnp.exp(logits - lse)
+         - (column == targets[:, None])).astype(jnp.bfloat16)
+    return (lse - picked)[:, 0], g
+
+
+@jax.custom_vjp
+def _rows_nll(hidden, unembed, targets):
+    return _nll_and_grad(hidden, unembed, targets)[0]
+
+
+def _rows_nll_fwd(hidden, unembed, targets):
+    nll, g = _nll_and_grad(hidden, unembed, targets)
+    return nll, (g, hidden, unembed)
+
+
+def _rows_nll_bwd(res, c):
+    # The cotangent scales the two [rows, d] arrays, never a pass over
+    # [rows, vocab]; ``d unembed`` comes out as [d, vocab].
+    g, hidden, unembed = res
+    c = c[:, None]
+    d_hidden = c * jax.lax.dot_general(
+        g, unembed, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    d_unembed = jax.lax.dot_general(
+        (c * hidden).astype(hidden.dtype), g, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return (d_hidden.astype(hidden.dtype), d_unembed.astype(unembed.dtype),
+            None)
+
+
+_rows_nll.defvjp(_rows_nll_fwd, _rows_nll_bwd)
+
+
+def next_token_nll(hidden, unembed, targets):
+    """Next-token negative log-likelihood of every position: ``hidden``
+    ``[..., d]`` times ``unembed`` ``[d, vocab]``, ``targets`` int
+    ``[...]`` -> float32 ``[...]``.
+
+    The one place the LM's loss lives (``make_loss_fn``'s dense and
+    chunked forms, ``chained_lm_loss``'s head stage).  Logits, row
+    maximum, sum and ``lse`` are float32, as the plain formula has them, and
+    the log-probabilities are never written: the target's logit is
+    gathered from the logits themselves.  The gradient of the logits is
+    ``softmax - onehot`` in bfloat16 (what the MXU rounds a float32
+    operand to anyway), formed in the forward by an ``iota`` compare: no
+    scatter, no float32 ``[rows, vocab]`` array but the logits.  On the
+    TPU the compiler folds that pass into the operand reads of the two
+    backward products (PERF.md section 6, PR 48: 3.1 ms a step faster at
+    ``[8192, 50257]`` than holding the bfloat16 array).
+    """
+    with jax.named_scope("next_token_loss"):
+        nll = _rows_nll(hidden.reshape(-1, hidden.shape[-1]), unembed,
+                        targets.reshape(-1))
+    return nll.reshape(targets.shape)
+
+
 def make_loss_fn(cfg: TransformerConfig, ax: ParallelAxes = ParallelAxes(),
                  mesh_axes: Optional[tuple] = None):
     """Local shard loss for use inside shard_map: next-token cross-entropy
-    pmean-ed over every mesh axis (a replicated logical scalar, so
+    (:func:`next_token_nll`, dense or over ``cfg.loss_chunk`` positions at
+    a time) pmean-ed over every mesh axis (a replicated logical scalar, so
     ``jax.grad`` outside the shard_map yields exact global gradients).
 
     ``mesh_axes``: all axis names of the mesh (defaults to the axes named
@@ -329,11 +395,8 @@ def make_loss_fn(cfg: TransformerConfig, ax: ParallelAxes = ParallelAxes(),
             if a is not None))
 
     def dense_ce(params, tokens, targets):
-        logits, aux = forward(params, tokens, cfg, ax)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1)[..., 0]
-        return jnp.mean(nll) + aux
+        x, aux = forward(params, tokens, cfg, ax, return_hidden=True)
+        return jnp.mean(next_token_nll(x, params["unembed"], targets)) + aux
 
     def chunked_ce(params, tokens, targets):
         x, aux = forward(params, tokens, cfg, ax, return_hidden=True)
@@ -349,11 +412,7 @@ def make_loss_fn(cfg: TransformerConfig, ax: ParallelAxes = ParallelAxes(),
 
         @jax.checkpoint
         def chunk_nll(xc, tc):
-            logits = jnp.dot(xc, params["unembed"],
-                             preferred_element_type=jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return jnp.sum(
-                -jnp.take_along_axis(logp, tc[..., None], axis=-1))
+            return jnp.sum(next_token_nll(xc, params["unembed"], tc))
 
         def body(total, xt):
             return total + chunk_nll(*xt), None
@@ -411,12 +470,7 @@ def chained_lm_loss(cfg: TransformerConfig):
     def head_stage(p, carry, batch):
         _tokens, targets = batch
         x = _layernorm(carry, p["ln_f"]["scale"], p["ln_f"]["bias"])
-        logits = jnp.dot(x, p["unembed"],
-                         preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1)[..., 0]
-        return jnp.mean(nll)
+        return jnp.mean(next_token_nll(x, p["unembed"], targets))
 
     stages = [embed_stage]
     stages += [make_layer_stage() for _ in range(cfg.n_layers)]

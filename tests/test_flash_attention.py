@@ -759,3 +759,34 @@ def test_granites_decode_program_compiles_for_the_v5e(v5e_chip, monkeypatch):
     held = sum(s.size * s.dtype.itemsize for s in stores)
     assert memory.alias_size_in_bytes >= held          # 6.50 GB, in place
     assert memory.temp_size_in_bytes < 256 << 20
+
+
+# The LM's loss (models/transformer.py::next_token_nll) with both gradients at
+# `gpt2m-train-1k`'s width, through the TPU's compiler: ONE float32
+# [8192, 50257] array is ever written (the logits; 1.65 GB of temporaries
+# where log_softmax + take_along_axis wrote the log-probabilities too, 3.3),
+# and the two backward products read it under their own time.  Here for the
+# same reason as the scan kernel's: this file holds libtpu.
+def test_lm_loss_writes_its_logits_once_on_the_v5e(v5e_chip):
+    import re
+
+    from horovod_tpu.models.transformer import next_token_nll
+
+    rows, d, vocab = 8192, 1024, 50257
+    h = jax.ShapeDtypeStruct((8, 1024, d), jnp.bfloat16, sharding=v5e_chip)
+    w = jax.ShapeDtypeStruct((d, vocab), jnp.bfloat16, sharding=v5e_chip)
+    t = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=v5e_chip)
+
+    def step(h, w, t):
+        # An activation before and an update after, as a train step has.
+        loss, (dh, dw) = jax.value_and_grad(
+            lambda h, w: jnp.mean(next_token_nll(h, w, t)),
+            argnums=(0, 1))(jnp.tanh(h), w)
+        return loss, dh, w - dw
+
+    compiled = jax.jit(step).lower(h, w, t).compile()
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    wide = re.findall(
+        r"f32\[(?:8,1024|8192),50257\]\S* (?:fusion|convolution)\(", entry)
+    assert len(wide) == 1, wide
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * vocab * 4.2

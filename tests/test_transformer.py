@@ -10,9 +10,12 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.core.topology import make_mesh
 from horovod_tpu.models.transformer import (ParallelAxes,
-                                            TransformerConfig, forward,
+                                            TransformerConfig,
+                                            chained_lm_loss,
+                                            chained_lm_params, forward,
                                             init_transformer,
                                             make_loss_fn,
+                                            next_token_nll,
                                             synthetic_lm_batch)
 from horovod_tpu.parallel.training import (make_parallel_train_step,
                                            shard_parallel_batch)
@@ -241,3 +244,152 @@ def test_pipeline_rejects_indivisible_layers():
         in_specs=(P(), P()), out_specs=P(), check_vma=False)
     with pytest.raises(ValueError, match="not divisible"):
         sm(params, tokens)
+
+
+# ---------------------------------------------------------------------------
+# next_token_nll: the one custom-VJP loss behind make_loss_fn (dense and
+# chunked) and chained_lm_loss's head stage.
+# ---------------------------------------------------------------------------
+
+ROWS, DIM, VOCAB = 48, 16, 503          # 503: not a multiple of 128
+
+
+def _plain_nll(hidden, unembed, targets):
+    """The log_softmax formula in float32."""
+    logits = jnp.dot(hidden.astype(jnp.float32),
+                     unembed.astype(jnp.float32))
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def _loss_inputs(dtype):
+    kh, kw, kt, kr = jax.random.split(jax.random.PRNGKey(48), 4)
+    hidden = jax.random.normal(kh, (ROWS, DIM), jnp.float32).astype(dtype)
+    unembed = (jax.random.normal(kw, (DIM, VOCAB), jnp.float32)
+               * 0.5).astype(dtype)
+    targets = jax.random.randint(kt, (ROWS,), 0, VOCAB)
+    targets = targets.at[0].set(0).at[1].set(VOCAB - 1)   # the two edges
+    weights = jax.random.uniform(kr, (ROWS,), jnp.float32, 0.2, 2.0)
+    return hidden, unembed, targets, weights
+
+
+def _mean(nll, h, w, t, weights):
+    return jnp.mean(nll(h, w, t))
+
+
+def _three_times(nll, h, w, t, weights):
+    return 3.0 * jnp.mean(nll(h, w, t))
+
+
+def _row_weighted(nll, h, w, t, weights):
+    return jnp.sum(weights * nll(h, w, t))
+
+
+def _checkpointed_scan(nll, h, w, t, weights):
+    # The chunked caller: chunks of rows, each rematerialised.
+    n = 4
+    hs, ts = h.reshape(n, ROWS // n, DIM), t.reshape(n, ROWS // n)
+
+    @jax.checkpoint
+    def chunk(hc, tc):
+        return jnp.sum(nll(hc, w, tc))
+
+    total, _ = jax.lax.scan(lambda acc, xt: (acc + chunk(*xt), None),
+                            jnp.zeros((), jnp.float32), (hs, ts))
+    return total / ROWS
+
+
+def _data_shard_map(nll, h, w, t, weights):
+    mesh = make_mesh(data=4, devices=jax.devices()[:4])
+
+    def local(h, w, t):
+        return jax.lax.pmean(jnp.mean(nll(h, w, t)), "data")
+
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("data"), P(), P("data")),
+                         out_specs=P(), check_vma=False)(h, w, t)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("use", [_mean, _three_times, _row_weighted,
+                                 _checkpointed_scan, _data_shard_map],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_next_token_nll_matches_log_softmax(use, dtype):
+    # Loss AND both gradients against the plain formula in float32 on the
+    # same (rounded) operands.  The gradient of the logits is kept in
+    # bfloat16 (2^-9 a number, signs mixed), and bfloat16 operands get
+    # bfloat16 gradients back.
+    h, w, t, weights = _loss_inputs(dtype)
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda h, w: use(next_token_nll, h, w, t, weights),
+        argnums=(0, 1)))(h, w)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda h, w: use(_plain_nll, h, w, t, weights), argnums=(0, 1)))(
+            h.astype(jnp.float32), w.astype(jnp.float32))
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=2e-6)
+    for got, want, arg in zip(got_g, want_g, (h, w)):
+        assert got.dtype == arg.dtype and got.shape == arg.shape
+        got, want = np.asarray(got, np.float32), np.asarray(want)
+        gap = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert gap < (3e-3 if dtype == jnp.float32 else 8e-3), gap
+    # The edge targets' own columns of d unembed: -hidden's share is there.
+    d_w = np.asarray(got_g[1], np.float32)
+    for col in (0, VOCAB - 1):
+        want_col = np.asarray(want_g[1])[:, col]
+        assert np.linalg.norm(d_w[:, col] - want_col) \
+            < 2e-2 * np.linalg.norm(want_col)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_loss_gradient_is_one_bfloat16_array_and_no_scatter():
+    # The mechanism, where no chip is: the gradient of make_loss_fn's loss
+    # scatters into nothing as wide as the logits (the embedding's own
+    # scatter-add into [vocab, d] stays), the loss keeps ONE [rows, vocab]
+    # array for its backward, in bfloat16, and the backward forms no
+    # float32 one.
+    params, tokens, targets = _data(batch=2, seq=16)
+    loss_fn = make_loss_fn(CFG, ParallelAxes(data=None), mesh_axes=())
+    grad_jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, (tokens, targets))))(params)
+    scattered = [v.aval.shape for e in _eqns(grad_jaxpr.jaxpr)
+                 if e.primitive.name.startswith("scatter")
+                 for v in e.outvars]
+    assert scattered and all(s[-1] != CFG.vocab_size for s in scattered), \
+        scattered
+
+    h, w, t, _ = _loss_inputs(jnp.float32)
+    _, pullback = jax.vjp(lambda h, w: next_token_nll(h, w, t), h, w)
+    wide = [r for r in jax.tree_util.tree_leaves(pullback)
+            if getattr(r, "shape", ()) == (ROWS, VOCAB)]
+    assert [r.dtype for r in wide] == [jnp.bfloat16]
+    back = jax.make_jaxpr(pullback)(jnp.ones((ROWS,), jnp.float32))
+    made = [v.aval for e in _eqns(back.jaxpr) for v in e.outvars]
+    assert made and not [a for a in made
+                         if a.shape == (ROWS, VOCAB)
+                         and a.dtype == jnp.float32]
+
+
+def test_chained_head_stage_is_the_same_loss():
+    # The stream schedule's last stage calls the one loss too: the chain's
+    # value and gradients are make_loss_fn's on one device.
+    params, tokens, targets = _data(batch=2, seq=16)
+    single = make_loss_fn(CFG, ParallelAxes(data=None), mesh_axes=())
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: single(p, (tokens, targets))))(params)
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda p: chained_lm_loss(CFG)(chained_lm_params(p, CFG),
+                                       (tokens, targets))))(params)
+    np.testing.assert_allclose(np.asarray(got_l), np.asarray(want_l),
+                               rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
