@@ -39,10 +39,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .. import telemetry as _telemetry
 from ..ops import latent_paged_attention as _paged
-from ..parallel.expert import moe_layer_held, swiglu
+from ..ops import sparse_latent_attention as _sparse
+from ..parallel.expert import (moe_layer_held,
+                               route_sigmoid_bias_group_top_k, swiglu)
 
 _M_MOE_ASSIGN = _telemetry.counter(
     "serving.moe_assignments", "token-expert pairs the decode iterations "
@@ -54,6 +57,18 @@ _M_MOE_LOAD_MAX = _telemetry.counter(
 _M_MOE_TOUCHED = _telemetry.counter(
     "serving.moe_experts_touched", "held experts with at least one "
     "token, summed over decode iterations and expert layers")
+_M_DSA_SCORED = _telemetry.counter(
+    "serving.dsa_scored_tokens", "cached positions the indexer scored for "
+    "the decode iterations' queries, summed over live slots and layers")
+_M_DSA_SELECTED = _telemetry.counter(
+    "serving.dsa_selected_tokens", "positions the decode iterations' "
+    "queries attended after the indexer's selection, summed over live "
+    "slots and layers")
+_M_DSA_SCORED_TRACED = _telemetry.counter(
+    "serving.dsa_scored_tokens_traced", "serving.dsa_scored_tokens of the "
+    "iterations retired WHILE a profiler session recorded: what a device "
+    "trace's kernel seconds are held against (a trace of a few seconds "
+    "between prompt passes of one to three is no fixed share of a window)")
 _M_ENTRY_BYTES = _telemetry.gauge(
     "serving.cache_entry_bytes", "bytes one token leaves in the paged "
     "store in one layer (all of the model's stores)")
@@ -66,6 +81,19 @@ _M_ENTRY_BYTES = _telemetry.gauge(
 CACHE_LANE = 128
 # Queries of one block of the prefill's attention.
 PREFILL_Q_BLOCK = 256
+# With an indexer (prompts of many thousand tokens in one program): query
+# blocks whose keys end inside the same stretch of this many run as ONE
+# mapped body against the keys up to the stretch's end; attention heads
+# and indexer heads go through in groups; and the per-token matmuls of a
+# dense layer in blocks of tokens.  Memory only.
+PREFILL_KEY_CHUNK = 2048
+PREFILL_HEAD_GROUP = 8
+INDEX_HEAD_GROUP = 8
+FFN_TOKEN_BLOCK = 2048
+# Rows the head is applied to for a prompt's ONE wanted row (lm_head).
+HEAD_ROWS = 8
+# The indexer's key norm is a LayerNorm with this epsilon.
+INDEX_NORM_EPS = 1e-6
 # ``ops/latent_paged_attention.py``'s ``interpret``: None is the rule (the
 # kernel on the TPU, the gathered rows elsewhere); a test sets True to run
 # the kernel in the interpreter through the model.
@@ -109,6 +137,17 @@ class LatentMoEConfig:
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
     dtype: object = jnp.bfloat16
+    # The lightning indexer (0 heads: none): ``index_topk`` cached tokens
+    # a query, chosen by ``index_n_heads`` heads of ``index_head_dim``.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Group-limited expert choice by biased scores ("noaux_tc"): the
+    # ``topk_group`` best of ``n_group`` groups, then the experts among
+    # them.  Off: plain top-k over all sigmoid scores.
+    group_limited: bool = False
+    n_group: int = 1
+    topk_group: int = 1
     # The share held here.
     experts_held: int = 192
     expert_offset: int = 0
@@ -132,6 +171,17 @@ class LatentMoEConfig:
         the rotated key, and zeros up to the lane multiple."""
         w = self.kv_lora_rank + self.qk_rope_head_dim
         return -(-w // CACHE_LANE) * CACHE_LANE
+
+    @property
+    def indexed(self) -> bool:
+        return self.index_n_heads > 0
+
+    @property
+    def entry_widths(self) -> tuple:
+        """The minor width of each store: the latent entry and, with an
+        indexer, its key beside it on the same page table."""
+        return (self.entry_width,) + ((self.index_head_dim,)
+                                      if self.indexed else ())
 
     def serving_model(self) -> "LatentMoEServing":
         return LatentMoEServing(self)
@@ -228,20 +278,27 @@ def init_latent_moe(key, cfg: LatentMoEConfig) -> dict:
                     cfg.v_head_dim)
     e, v, dt = cfg.experts_held, cfg.vocab_size, cfg.dtype
     std, res = 0.02, 0.02 / (2 * cfg.num_hidden_layers) ** 0.5
-    keys = iter(jax.random.split(key, 4 + 16 * cfg.num_hidden_layers))
+    per_layer = 24 if cfg.indexed or cfg.group_limited else 16
+    keys = iter(jax.random.split(key, 4 + per_layer * cfg.num_hidden_layers))
 
     def w(shape, scale):
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * scale).astype(dt)
 
     def attn():
-        return {"norm": jnp.ones((d,), dt),
-                "w_dq": w((d, rq), std), "q_norm": jnp.ones((rq,), dt),
-                "w_uq": w((rq, h_n * (nope + rp)), std),
-                "w_dkv": w((d, rkv + rp), std),
-                "kv_norm": jnp.ones((rkv,), dt),
-                "w_ukv": w((rkv, h_n * (nope + vd)), std),
-                "w_o": w((h_n * vd, d), res)}
+        ap = {"norm": jnp.ones((d,), dt),
+              "w_dq": w((d, rq), std), "q_norm": jnp.ones((rq,), dt),
+              "w_uq": w((rq, h_n * (nope + rp)), std),
+              "w_dkv": w((d, rkv + rp), std),
+              "kv_norm": jnp.ones((rkv,), dt),
+              "w_ukv": w((rkv, h_n * (nope + vd)), std),
+              "w_o": w((h_n * vd, d), res)}
+        if cfg.indexed:
+            ih, idim = cfg.index_n_heads, cfg.index_head_dim
+            ap.update(w_qi=w((rq, ih * idim), std), w_ki=w((d, idim), std),
+                      ki_norm=jnp.ones((idim,), dt),
+                      ki_bias=w((idim,), std), w_w=w((d, ih), std))
+        return ap
 
     def ffn(width):
         return {"w_gate": w((d, width), std), "w_up": w((d, width), std),
@@ -251,11 +308,17 @@ def init_latent_moe(key, cfg: LatentMoEConfig) -> dict:
         if i < cfg.first_k_dense_replace:
             return {"attn": attn(), "ffn_norm": jnp.ones((d,), dt),
                     "ffn": ffn(f)}
-        return {"attn": attn(), "ffn_norm": jnp.ones((d,), dt),
-                "router": w((d, cfg.n_routed_experts), 1.5 / d ** 0.5),
-                "shared": ffn(fm * cfg.n_shared_experts),
-                "w_gate": w((e, d, fm), std), "w_up": w((e, d, fm), std),
-                "w_down": w((e, fm, d), res)}
+        lp = {"attn": attn(), "ffn_norm": jnp.ones((d,), dt),
+              "router": w((d, cfg.n_routed_experts), 1.5 / d ** 0.5),
+              "shared": ffn(fm * cfg.n_shared_experts),
+              "w_gate": w((e, d, fm), std), "w_up": w((e, d, fm), std),
+              "w_down": w((e, fm, d), res)}
+        if cfg.group_limited:
+            # The choice's bias stays float32 (it is added to float32
+            # scores and never multiplied).
+            lp["router_bias"] = jax.random.normal(
+                next(keys), (cfg.n_routed_experts,), jnp.float32) * 0.05
+        return lp
 
     return {
         "embed": w((v, d), std),
@@ -295,6 +358,44 @@ def mla_project(h, ap, cfg: LatentMoEConfig, pos):
     return (q[..., :nope].astype(dt),
             rope(q[..., nope:], pos, cfg).astype(dt),
             jnp.concatenate([c, k_rope, fill], axis=-1))
+
+
+def mla_latents(h, ap, cfg: LatentMoEConfig, pos):
+    """:func:`mla_project` in two steps, for a caller that needs what
+    lies between them (the indexer's queries start from the normed query
+    latent; a long prompt takes its heads in groups).  This one: ``c_q
+    [b, s, q_lora_rank]`` and the cache ``entry [b, s, entry_width]``."""
+    b, s, _ = h.shape
+    rp = cfg.qk_rope_head_dim
+    dt = h.dtype
+    _, kv_scale = lora_scales(cfg)
+    # The bottlenecks' outputs reach their norms and the rotation in
+    # float32; only matmul operands and the cache entry are rounded.
+    c_q = rmsnorm(jnp.dot(h, ap["w_dq"],
+                          preferred_element_type=jnp.float32),
+                  ap["q_norm"], cfg.rms_norm_eps, dt)
+    ckv = jnp.dot(h, ap["w_dkv"], preferred_element_type=jnp.float32)
+    c = rmsnorm(ckv[..., :cfg.kv_lora_rank], ap["kv_norm"],
+                cfg.rms_norm_eps, dt, kv_scale)
+    k_rope = rope(ckv[..., cfg.kv_lora_rank:], pos, cfg).astype(dt)
+    fill = jnp.zeros((b, s, cfg.entry_width - cfg.kv_lora_rank - rp), dt)
+    return c_q, jnp.concatenate([c, k_rope, fill], axis=-1)
+
+
+def mla_queries(c_q, w_uq, cfg: LatentMoEConfig, pos):
+    """``(q_nope, q_rope)`` of the heads whose columns ``w_uq [q_lora_rank,
+    heads * (nope + rope)]`` holds (all of them, or a group's), as
+    :func:`mla_project` makes them."""
+    b, s, _ = c_q.shape
+    nope = cfg.qk_nope_head_dim
+    dt = c_q.dtype
+    q_scale, _ = lora_scales(cfg)
+    q = jnp.dot(c_q, w_uq, preferred_element_type=jnp.float32)
+    if q_scale is not None:
+        q = q * q_scale
+    q = q.reshape(b, s, -1, nope + cfg.qk_rope_head_dim)
+    return (q[..., :nope].astype(dt),
+            rope(q[..., nope:], pos, cfg).astype(dt))
 
 
 def _masked_softmax(scores, mask):
@@ -370,11 +471,12 @@ def _absorbed_output(o_lat, ap, cfg: LatentMoEConfig):
 
 
 def mla_absorbed_attention(q_nope, q_rope, view, q_pos, ap,
-                           cfg: LatentMoEConfig):
+                           cfg: LatentMoEConfig, allowed=None):
     """Attention over cached entries themselves: ``W_uk`` absorbed into
     the query, ``W_uv`` into the output.  ``view [b, n, entry_width]``
     holds position ``j`` at row ``j``; row ``j`` takes part in query ``(b,
-    i)`` iff ``j <= q_pos[b, i]``.  The query is padded like the entry,
+    i)`` iff ``j <= q_pos[b, i]`` (and ``allowed[b, i, j]``, an indexer's
+    selection, where given).  The query is padded like the entry,
     so the scores contract whole rows (the zeros add nothing).  Returns
     ``[b, s, heads * v_dim]``."""
     dt = q_nope.dtype
@@ -384,6 +486,8 @@ def mla_absorbed_attention(q_nope, q_rope, view, q_pos, ap,
                         ) * softmax_scale(cfg)
     mask = (jnp.arange(view.shape[1], dtype=jnp.int32)[None, None, None, :]
             <= q_pos[:, None, :, None])
+    if allowed is not None:
+        mask = mask & allowed[:, None]
     p = _masked_softmax(scores, mask)
     o_lat = jnp.einsum("bhsn,bnc->bshc", p.astype(dt),
                        view[..., :cfg.kv_lora_rank],
@@ -391,23 +495,212 @@ def mla_absorbed_attention(q_nope, q_rope, view, q_pos, ap,
     return _absorbed_output(o_lat, ap, cfg)
 
 
+# -- the lightning indexer and its selection ------------------------------------
+# (docs/inference.md "Learned sparse attention over the latent store".)
+
+def _rotate_head(x, pos, cfg: LatentMoEConfig):
+    """An indexer head's first ``qk_rope_head_dim`` dims rotated."""
+    rp = cfg.qk_rope_head_dim
+    return jnp.concatenate([rope(x[..., :rp], pos, cfg), x[..., rp:]],
+                           axis=-1)
+
+
+def index_queries(c_q, ap, cfg: LatentMoEConfig, pos):
+    """The indexer's queries ``[b, s, index heads, index dim]`` from the
+    normed query latent ``c_q [b, s, q_lora_rank]``, rotated.  The source
+    multiplies queries and keys by an orthonormal Hadamard matrix before
+    it quantises them to 8 bits; nothing is quantised here and the
+    products are the same without it, so it is left out."""
+    b, s, _ = c_q.shape
+    q = jnp.dot(c_q, ap["w_qi"], preferred_element_type=jnp.float32)
+    q = q.reshape(b, s, cfg.index_n_heads, cfg.index_head_dim)
+    return _rotate_head(q, pos, cfg).astype(c_q.dtype)
+
+
+def index_keys(h, ap, cfg: LatentMoEConfig, pos):
+    """What the indexer keeps of a token and how it weighs its heads for
+    one: ONE key ``[b, s, index dim]`` (LayerNorm with weight and bias,
+    rotated; cached beside the latent entry) and the heads' weights ``[b,
+    s, index heads]`` float32 with both ``^-0.5`` factors in them."""
+    k = jnp.dot(h, ap["w_ki"], preferred_element_type=jnp.float32)
+    mu = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+    k = ((k - mu) * jax.lax.rsqrt(var + INDEX_NORM_EPS)
+         * ap["ki_norm"].astype(jnp.float32)
+         + ap["ki_bias"].astype(jnp.float32))
+    w = jnp.dot(h, ap["w_w"], preferred_element_type=jnp.float32)
+    return (_rotate_head(k, pos, cfg).astype(h.dtype),
+            w * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5))
+
+
+def index_project(h, c_q, ap, cfg: LatentMoEConfig, pos):
+    """``(queries, key, weights)``: :func:`index_queries` and
+    :func:`index_keys` of a block."""
+    return (index_queries(c_q, ap, cfg, pos),
+            *index_keys(h, ap, cfg, pos))
+
+
+def index_scores(q, k, w):
+    """``I [n, m] = sum_j w[n, j] relu(q[n, j] . k[m])`` float32, of
+    queries ``q [n, heads, dim]``, ``w [n, heads]`` against keys ``k [m,
+    dim]``; the heads in groups of ``INDEX_HEAD_GROUP``, so the per-head
+    scores alive are ``[group, n, m]``."""
+    g = min(INDEX_HEAD_GROUP, q.shape[1])
+    total = jnp.zeros((q.shape[0], k.shape[0]), jnp.float32)
+    for lo in range(0, q.shape[1], g):
+        part = jnp.einsum("nhd,md->hnm", q[:, lo:lo + g], k,
+                          preferred_element_type=jnp.float32)
+        total = total + jnp.einsum("hnm,nh->nm", jax.nn.relu(part),
+                                   w[:, lo:lo + g])
+    return total
+
+
+def _stretches(s: int, qb: int):
+    """``(lo, hi)`` of the stretches of ``PREFILL_KEY_CHUNK`` positions a
+    sequence of ``s`` is cut into, whole query blocks each."""
+    step = max(qb, PREFILL_KEY_CHUNK // qb * qb)
+    return [(lo, min(lo + step, s)) for lo in range(0, s, step)]
+
+
+def _q_block(s: int) -> int:
+    return PREFILL_Q_BLOCK if s % PREFILL_Q_BLOCK == 0 else s
+
+
+def prefill_selection(c_q, k_i, w_i, ap, cfg: LatentMoEConfig, pos):
+    """What the indexer selects for every query of ONE sequence attending
+    itself: ``[s, s]`` bool, row ``t`` the ``min(index_topk, t + 1)``
+    positions ``<= t`` of largest index score (``None`` where ``s <=
+    index_topk``: every position is selected).  A block of queries at a
+    time, made from ``c_q [s, q_lora_rank]`` there and then, against the
+    keys ``k_i [s, index dim]`` up to its stretch's end: the per-row
+    threshold of the ``index_topk``-th largest, never all heads' scores
+    at once."""
+    s, top = c_q.shape[0], cfg.index_topk
+    if s <= top:
+        return None
+    qb = _q_block(s)
+    rows = []
+    for lo, hi in _stretches(s, qb):
+        if hi <= top:
+            block = jnp.tril(jnp.ones((hi - lo, hi), bool), lo)
+        else:
+            def one(at, hi=hi):
+                cut = partial(jax.lax.dynamic_slice_in_dim,
+                              start_index=at, slice_size=qb)
+                with jax.named_scope("dsa_index"):
+                    q_i = index_queries(cut(c_q)[None], ap, cfg,
+                                        cut(pos)[None])[0]
+                    scores = index_scores(q_i, k_i[:hi], cut(w_i))
+                valid = (jnp.arange(hi)[None, :]
+                         <= at + jnp.arange(qb)[:, None])
+                with jax.named_scope("dsa_select"):
+                    return _sparse.topk_mask(scores, valid, top)
+
+            block = jax.lax.map(one, jnp.arange(lo, hi, qb)
+                                ).reshape(hi - lo, hi)
+        rows.append(jnp.pad(block, ((0, 0), (0, s - hi))))
+    return jnp.concatenate(rows)
+
+
+def selected_rebuilt_attention(c_q, entry, allowed, ap,
+                               cfg: LatentMoEConfig, pos):
+    """:func:`mla_rebuilt_attention` of ONE long sequence under a
+    selection: ``c_q [s, q_lora_rank]``, ``entry [s, entry_width]``,
+    ``allowed [s, s]`` bool or ``None``; causal on top of it.  Heads go
+    through in groups of ``PREFILL_HEAD_GROUP`` (queries, keys and values
+    of one group alive at a time), a group's query blocks of one stretch
+    as one mapped body against the keys up to the stretch's end, and its
+    rows of ``w_o`` applied at once (all heads' outputs never stand side
+    by side).  Returns the block's output ``[s, hidden]`` float32.
+    Roundings are :func:`mla_rebuilt_attention`'s; the float32 sum over
+    heads is taken a group at a time."""
+    s = c_q.shape[0]
+    dt = c_q.dtype
+    h_n, nope, rp, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    g = min(PREFILL_HEAD_GROUP, h_n)
+    c = entry[:, :cfg.kv_lora_rank]
+    k_rope = entry[:, cfg.kv_lora_rank:cfg.kv_lora_rank + rp]
+    scale = softmax_scale(cfg)
+    qb = _q_block(s)
+
+    def group(n, y):
+        w_uq = jax.lax.dynamic_slice_in_dim(
+            ap["w_uq"], n * g * (nope + rp), g * (nope + rp), axis=1)
+        w_ukv = jax.lax.dynamic_slice_in_dim(
+            ap["w_ukv"], n * g * (nope + vd), g * (nope + vd), axis=1)
+        q_nope, q_rope = mla_queries(c_q[None], w_uq, cfg, pos[None])
+        q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
+        kv = jnp.dot(c, w_ukv, preferred_element_type=jnp.float32
+                     ).astype(dt).reshape(s, g, nope + vd)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope[:, None, :],
+                                              (s, g, rp))], axis=-1)
+        v = kv[..., nope:]
+        outs = []
+        for lo, hi in _stretches(s, qb):
+            def one(at, hi=hi):
+                scores = jnp.einsum(
+                    "qhd,khd->hqk", jax.lax.dynamic_slice_in_dim(q, at, qb),
+                    k[:hi], preferred_element_type=jnp.float32) * scale
+                mask = (jnp.arange(hi)[None, :]
+                        <= at + jnp.arange(qb)[:, None])
+                if allowed is not None:
+                    mask = mask & jax.lax.dynamic_slice_in_dim(
+                        allowed, at, qb)[:, :hi]
+                p = _masked_softmax(scores, mask[None])
+                return jnp.einsum("hqk,khd->qhd", p.astype(dt), v[:hi],
+                                  preferred_element_type=jnp.float32
+                                  ).astype(dt)
+
+            outs.append(jax.lax.map(one, jnp.arange(lo, hi, qb)
+                                    ).reshape(hi - lo, g * vd))
+        w_o = jax.lax.dynamic_slice_in_dim(ap["w_o"], n * g * vd, g * vd)
+        return y + jnp.dot(jnp.concatenate(outs), w_o,
+                           preferred_element_type=jnp.float32)
+
+    with jax.named_scope("dsa_attend"):
+        return jax.lax.fori_loop(
+            0, h_n // g, group,
+            jnp.zeros((s, ap["w_o"].shape[1]), jnp.float32))
+
+
 # -- layers -------------------------------------------------------------------
 
 def _attn_block(x, ap, cfg, pos, attend):
     """Pre-norm residual attention; ``attend(q_nope, q_rope, entry, ap)``
-    is the one part that sees cached entries.  Returns ``(x, entry)``."""
+    is the one part that sees cached entries.  Returns ``(x, entry)``.
+    With an indexer the projections depend on the form too (a long
+    prompt takes its heads in groups): ``attend(h, ap)`` makes them and
+    returns ``(y, (entry, index key))``: the block's output ``[b, s,
+    hidden]`` float32 and what the layer's two stores get."""
     h = rmsnorm(x, ap["norm"], cfg.rms_norm_eps, cfg.dtype)
+    if cfg.indexed:
+        y, entry = attend(h, ap)
+        return x + y, entry
     q_nope, q_rope, entry = mla_project(h, ap, cfg, pos)
     o = attend(q_nope, q_rope, entry, ap)
     return x + jnp.dot(o, ap["w_o"],
                        preferred_element_type=jnp.float32), entry
 
 
+def _token_blocks(f, h):
+    """``f`` over ``h [b, s, d]``, ``FFN_TOKEN_BLOCK`` tokens at a time
+    where there are more (a 16384-token prompt's gate and up products in
+    float32 are 2.4 GB at once)."""
+    b, s, d = h.shape
+    if b * s <= FFN_TOKEN_BLOCK or (b * s) % FFN_TOKEN_BLOCK:
+        return f(h)
+    blocks = jax.lax.map(f, h.reshape(-1, 1, FFN_TOKEN_BLOCK, d))
+    return blocks.reshape(b, s, -1)
+
+
 def _dense_layer(x, lp, cfg, pos, attend):
     x, entry = _attn_block(x, lp["attn"], cfg, pos, attend)
     h = rmsnorm(x, lp["ffn_norm"], cfg.rms_norm_eps, cfg.dtype)
     f = lp["ffn"]
-    return x + swiglu(h, f["w_gate"], f["w_up"], f["w_down"]), entry
+    return x + _token_blocks(
+        lambda t: swiglu(t, f["w_gate"], f["w_up"], f["w_down"]), h), entry
 
 
 def _moe_layer(x, lp, cfg, pos, attend, token_mask):
@@ -415,21 +708,68 @@ def _moe_layer(x, lp, cfg, pos, attend, token_mask):
     b, s, d = x.shape
     h = rmsnorm(x, lp["ffn_norm"], cfg.rms_norm_eps,
                 cfg.dtype).reshape(b * s, d)
-    held = moe_layer_held(
-        h, lp, num_experts=cfg.n_routed_experts,
-        expert_offset=cfg.expert_offset, top_k=cfg.num_experts_per_tok,
-        routed_scale=cfg.routed_scaling_factor,
-        norm_topk=cfg.norm_topk_prob,
-        token_mask=None if token_mask is None else token_mask.reshape(-1))
-    return x + held.out.reshape(b, s, d), entry, held.counts
+    routing = None
+    if cfg.group_limited:
+        routing = partial(
+            route_sigmoid_bias_group_top_k, router=lp["router"],
+            bias=lp["router_bias"], top_k=cfg.num_experts_per_tok,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            routed_scale=cfg.routed_scaling_factor,
+            norm_topk=cfg.norm_topk_prob)
+    mask = None if token_mask is None else token_mask.reshape(-1)
+
+    def experts(h, mask):
+        held = moe_layer_held(
+            h, lp, num_experts=cfg.n_routed_experts,
+            expert_offset=cfg.expert_offset, top_k=cfg.num_experts_per_tok,
+            routed_scale=cfg.routed_scaling_factor,
+            norm_topk=cfg.norm_topk_prob, routing=routing, token_mask=mask)
+        return held.out, held.counts
+
+    t = b * s
+    if t <= FFN_TOKEN_BLOCK or t % FFN_TOKEN_BLOCK or mask is None:
+        out, counts = experts(h, mask)
+    else:
+        # A long prompt, FFN_TOKEN_BLOCK tokens at a time: routing is a
+        # token's own, so the blocks' counts add up.
+        out, counts = jax.lax.map(
+            lambda hm: experts(*hm),
+            (h.reshape(-1, FFN_TOKEN_BLOCK, d),
+             mask.reshape(-1, FFN_TOKEN_BLOCK)))
+        out, counts = out.reshape(t, d), jnp.sum(counts, axis=0)
+    return x + out.reshape(b, s, d), entry, counts
 
 
-def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask):
+def lm_head(params, x, cfg, rows=None):
+    """The final norm and the head over ``x [b, s, d]``, or for row
+    ``rows[b]`` of each sequence alone (a prompt's last valid one: the
+    other rows' logits are never read, 1.06 GB of them at 16384 tokens):
+    ``[b, s, vocab]`` or ``[b, vocab]`` float32.  The one row goes through
+    the head inside a slab of ``HEAD_ROWS`` rows that ends at it: a
+    product of ONE row the TPU's compiler turns into a multiply-and-reduce
+    down the head's columns, 5 ms at 7168 x 20480 where the slab's matmul
+    is a fraction of one."""
+    if rows is None:
+        x = rmsnorm(x, params["norm_f"], cfg.rms_norm_eps, cfg.dtype)
+        return jnp.dot(x, params["unembed"],
+                       preferred_element_type=jnp.float32)
+    m = min(HEAD_ROWS, x.shape[1])
+    start = jnp.clip(rows - (m - 1), 0, x.shape[1] - m)
+    slab = jax.vmap(partial(jax.lax.dynamic_slice_in_dim, slice_size=m))(
+        x, start)
+    logits = lm_head(params, slab, cfg)
+    return logits[jnp.arange(x.shape[0]), rows - start]
+
+
+def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask,
+            rows=None):
     """The forward around its attention: ``attend(layer, q_nope, q_rope,
     entry, ap)`` with ``layer`` the index into the cache and ``ap`` the
-    layer's attention parameters.  Returns ``(logits [b, s, vocab]
-    float32, entries [layers, b, s, width], counts [expert layers,
-    held])``."""
+    layer's attention parameters (with an indexer: ``attend(layer, h,
+    ap)``, see :func:`_attn_block`).  Returns ``(logits [b, s, vocab]
+    float32, or [b, vocab] of row ``rows[b]``; entries [layers, b, s,
+    width], with an indexer a pair of such, the index keys second; counts
+    [expert layers, held])``."""
     # The residual stream is float32 from the embedding to the final
     # norm; matmul operands, the cache entry and the attention's
     # probabilities are what is rounded to the served type.
@@ -443,10 +783,40 @@ def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask):
                                      token_mask)
             counts.append(n)
         entries.append(entry)
-    x = rmsnorm(x, params["norm_f"], cfg.rms_norm_eps, cfg.dtype)
-    logits = jnp.dot(x, params["unembed"],
-                     preferred_element_type=jnp.float32)
-    return logits, jnp.stack(entries), jnp.stack(counts)
+    logits = lm_head(params, x, cfg, rows)
+    if cfg.indexed:
+        entries = tuple(jnp.stack(e) for e in zip(*entries))
+    else:
+        entries = jnp.stack(entries)
+    return logits, entries, jnp.stack(counts)
+
+
+def selected_attend(cfg: LatentMoEConfig, pos, absorbed: bool = False):
+    """The ``attend(layer, h, ap)`` of whole sequences attending
+    themselves under the indexer's selection (``pos [b, s]``): a
+    sequence at a time, :func:`prefill_selection`, then
+    :func:`selected_rebuilt_attention` (``absorbed``: the decode's form
+    over the block's own entries, as a test compares them)."""
+
+    def one(h, ap, pos):
+        c_q, entry = mla_latents(h[None], ap, cfg, pos[None])
+        k_i, w_i = index_keys(h[None], ap, cfg, pos[None])
+        allowed = prefill_selection(c_q[0], k_i[0], w_i[0], ap, cfg, pos)
+        if absorbed:
+            q_nope, q_rope = mla_queries(c_q, ap["w_uq"], cfg, pos[None])
+            o = mla_absorbed_attention(
+                q_nope, q_rope, entry, pos[None], ap, cfg,
+                None if allowed is None else allowed[None])[0]
+            y = jnp.dot(o, ap["w_o"], preferred_element_type=jnp.float32)
+        else:
+            y = selected_rebuilt_attention(c_q[0], entry[0], allowed, ap,
+                                           cfg, pos)
+        return y, (entry[0], k_i[0])
+
+    def attend(layer, h, ap):
+        return jax.vmap(one, in_axes=(0, None, 0))(h, ap, pos)
+
+    return attend
 
 
 def forward_full(params, tokens, cfg: LatentMoEConfig,
@@ -463,22 +833,28 @@ def forward_full(params, tokens, cfg: LatentMoEConfig,
                                           cfg)
         return mla_rebuilt_attention(q_nope, q_rope, entry, ap, cfg)
 
+    if cfg.indexed:
+        attend = selected_attend(cfg, pos, absorbed)
     return _layers(params, tokens, pos, cfg, attend, None)
 
 
 def prefill_step(params, tokens, n_valid, cfg: LatentMoEConfig):
     """A padded prompt ``[1, bucket]`` from an empty cache: positions
     ``>= n_valid`` are padding (they reach no expert; their entries are
-    garbage the caller maps to trash or overwrites).  Returns ``(logits
-    [1, bucket, vocab], entries [layers, 1, bucket, width], counts)``."""
+    garbage the caller maps to trash or overwrites).  Returns ``(last
+    [1, vocab]``, the last valid row's logits, ``entries [layers, 1,
+    bucket, width]`` (with an indexer a pair, the index keys second),
+    ``counts)``."""
     b, s = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
     def attend(layer, q_nope, q_rope, entry, ap):
         return mla_rebuilt_attention(q_nope, q_rope, entry, ap, cfg)
 
+    if cfg.indexed:
+        attend = selected_attend(cfg, pos)
     return _layers(params, tokens, pos, cfg, attend,
-                   pos < n_valid[:, None])
+                   pos < n_valid[:, None], rows=n_valid - 1)
 
 
 def paged_kernel_runs() -> bool:
@@ -523,6 +899,66 @@ def gathered_attend(lengths, store, table, cfg):
     return attend
 
 
+def selected_decode_attend(lengths, stores, table, cfg):
+    """The decode step's ``attend(layer, h, ap)`` with an indexer over
+    the two stores ``(latent entries, index keys)``: score the slot's
+    cached index keys, select ``index_topk`` of them and the new token,
+    attend the selected entries in the absorbed form.  On the TPU
+    ``ops/sparse_latent_attention.py``'s two kernels read both stores
+    where they lie and the selection is a threshold found without a sort;
+    elsewhere the plain twin: gather the row, score, ``top_k``, mask."""
+    store, keys = stores
+    b = lengths.shape[0]
+    pos = jnp.clip(lengths, 0, None)
+    cap = table.shape[1] * store.shape[2]
+    col = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    top = cfg.index_topk
+    kernel = paged_kernel_runs()
+    if kernel:
+        order, n_live = _paged.live_first(lengths)
+
+    def attend(layer, h, ap):
+        c_q, entry = mla_latents(h, ap, cfg, pos[:, None])
+        q_nope, q_rope = mla_queries(c_q, ap["w_uq"], cfg, pos[:, None])
+        q_i, k_i, w_i = index_project(h, c_q, ap, cfg, pos[:, None])
+        if kernel:
+            scores = _sparse.index_paged_scores(
+                q_i[:, 0], w_i[:, 0], keys, table, lengths, layer,
+                order=order, n_live=n_live, interpret=PAGED_INTERPRET)
+            own = jnp.sum(jax.nn.relu(jnp.einsum(
+                "bhd,bd->bh", q_i[:, 0], k_i[:, 0],
+                preferred_element_type=jnp.float32)) * w_i[:, 0], axis=-1)
+            scores = jnp.where(col == pos[:, None], own[:, None], scores)
+            selected = _sparse.select_paged(scores, lengths, top,
+                                            interpret=PAGED_INTERPRET)
+            q = _absorbed_query(q_nope, q_rope, store.shape[-1], ap, cfg)
+            o_lat = _sparse.sparse_paged_attention(
+                q[:, 0], entry[:, 0], store, table, lengths, layer,
+                selected, scale=softmax_scale(cfg),
+                kv_rank=cfg.kv_lora_rank, order=order, n_live=n_live,
+                interpret=PAGED_INTERPRET)
+            o = _absorbed_output(o_lat[:, None], ap, cfg)
+        else:
+            rows = jnp.arange(b)
+            key_view = keys[layer][table].reshape(b, cap, -1).at[
+                rows, pos].set(k_i[:, 0], mode="drop")
+            scores = jax.vmap(index_scores)(q_i, key_view, w_i)[:, 0]
+            _, idx = jax.lax.top_k(
+                jnp.where(col <= lengths[:, None], scores, -jnp.inf),
+                min(top, cap))
+            selected = jnp.zeros((b, cap), bool).at[
+                rows[:, None], idx].set(True)
+            view = store[layer][table].reshape(b, cap, -1).at[
+                rows, pos].set(entry[:, 0], mode="drop")
+            o = mla_absorbed_attention(q_nope, q_rope, view,
+                                       lengths[:, None], ap, cfg,
+                                       selected[:, None])
+        return (jnp.dot(o, ap["w_o"], preferred_element_type=jnp.float32),
+                (entry, k_i))
+
+    return attend, pos[:, None]
+
+
 def decode_attend(lengths, store, table, cfg):
     """The decode step's attention over the paged store: on the TPU
     :func:`paged_attend`, elsewhere :func:`gathered_attend`
@@ -540,13 +976,20 @@ def decode_attend(lengths, store, table, cfg):
 def decode_step(params, tokens, lengths, store, table,
                 cfg: LatentMoEConfig):
     """One token a slot over the paged store through
-    :func:`decode_attend`.  ``tokens [slots]``; ``lengths [slots]`` (-1
-    idle: such a slot reaches no expert).  Returns ``(logits [slots,
-    vocab], entries [layers, slots, width], counts [expert layers,
+    :func:`decode_attend` (with an indexer ``store`` is the pair of
+    stores and the attention :func:`selected_decode_attend`'s).
+    ``tokens [slots]``; ``lengths [slots]`` (-1 idle: such a slot reaches
+    no expert).  Returns ``(logits [slots, vocab], entries [layers,
+    slots, width] (a pair with an indexer), counts [expert layers,
     held])``."""
-    attend, pos = decode_attend(lengths, store, table, cfg)
+    if cfg.indexed:
+        attend, pos = selected_decode_attend(lengths, store, table, cfg)
+    else:
+        attend, pos = decode_attend(lengths, store, table, cfg)
     logits, entries, counts = _layers(params, tokens[:, None], pos, cfg,
                                       attend, lengths[:, None] >= 0)
+    if cfg.indexed:
+        return logits[:, 0], tuple(e[:, :, 0] for e in entries), counts
     return logits[:, 0], entries[:, :, 0], counts
 
 
@@ -587,6 +1030,10 @@ class LatentMoEServing:
                 "widths": [c.intermediate_size, c.moe_intermediate_size],
                 "experts": [c.n_routed_experts, c.experts_held,
                             c.expert_offset, c.num_experts_per_tok],
+                **({"indexer": [c.index_n_heads, c.index_head_dim,
+                                c.index_topk]} if c.indexed else {}),
+                **({"expert_groups": [c.n_group, c.topk_group]}
+                   if c.group_limited else {}),
                 "max_seq_len": c.max_seq_len,
                 "dtype": jnp.dtype(c.dtype).name}
 
@@ -598,46 +1045,71 @@ class LatentMoEServing:
         return _paged.tokens_read(lengths, page_size) / len(lengths)
 
     def cache_entry(self) -> dict:
-        """One store; to the cache it is one key/value head as wide as
-        the entry."""
-        w = self.cfg.entry_width
-        _M_ENTRY_BYTES.set(w * jnp.dtype(self.cfg.dtype).itemsize)
+        """One store, with an indexer two on one page table (the latent
+        entry and the index key); to the cache each is one key/value
+        head as wide as the entry."""
+        widths = self.cfg.entry_widths
+        _M_ENTRY_BYTES.set(sum(widths)
+                           * jnp.dtype(self.cfg.dtype).itemsize)
         return {"n_layers": self.cfg.cache_layers, "n_heads": 1,
-                "head_dim": w, "widths": (w,)}
+                "head_dim": widths[0], "widths": widths}
 
     def decode(self, params, pages, table, lengths, tokens):
-        (store,) = pages
-        ps = store.shape[2]
+        ps = pages[0].shape[2]
         logits, entries, *extras = self.decode_step(
-            params, tokens, lengths, store, table, self.cfg)
+            params, tokens, lengths, pages if self.cfg.indexed else pages[0],
+            table, self.cfg)
+        if not self.cfg.indexed:
+            entries = (entries,)
         # One row a slot, written where it lies (see DenseLM.decode).
         pos = jnp.clip(lengths, 0, None)
         b = tokens.shape[0]
         page, off = table[jnp.arange(b), pos // ps], pos % ps
         zero = jnp.zeros((), jnp.int32)
+        pages = list(pages)
         for slot in range(b):
-            store = jax.lax.dynamic_update_slice(
-                store, entries[:, slot][:, None, None, :],
-                (zero, page[slot], off[slot], zero))
-        return (logits, *extras), (store,)
+            for i, rows in enumerate(entries):
+                pages[i] = jax.lax.dynamic_update_slice(
+                    pages[i], rows[:, slot][:, None, None, :],
+                    (zero, page[slot], off[slot], zero))
+        return (logits, *extras), tuple(pages)
 
     def prefill(self, params, pages, table_row, start, n_valid, tokens):
         """``start`` is always 0 here (``prefix_cache`` is off)."""
-        (store,) = pages
-        ps, bucket = store.shape[2], tokens.shape[1]
-        logits, entries, *_ = self.prefill_step(params, tokens, n_valid,
-                                                self.cfg)
+        ps, bucket = pages[0].shape[2], tokens.shape[1]
+        last, entries, *_ = self.prefill_step(params, tokens, n_valid,
+                                              self.cfg)
+        if not self.cfg.indexed:
+            entries = (entries,)
         # A page at a time, written where it lies.  (A scatter over the
         # flattened store makes the TPU copy all of it into a layout of
         # the scatter's own, and back.)  Pages past the prompt are not
         # mapped: their rows land in trash page 0.
         rows = min(ps, bucket)
         zero = jnp.zeros((), jnp.int32)
+        pages = list(pages)
         for j in range(max(1, bucket // ps)):
-            store = jax.lax.dynamic_update_slice(
-                store, entries[:, :, j * ps:j * ps + rows],
-                (zero, table_row[0, j], zero, zero))
-        return (logits[0, n_valid[0] - 1],), (store,)
+            for i, new in enumerate(entries):
+                pages[i] = jax.lax.dynamic_update_slice(
+                    pages[i], new[:, :, j * ps:j * ps + rows],
+                    (zero, table_row[0, j], zero, zero))
+        return (last[0],), tuple(pages)
+
+    def observe_launch(self, lengths) -> None:
+        """What the indexer scored and selected in one decode iteration
+        launched at these host lengths: a live slot's cached positions
+        and its new token, ``index_topk`` of them at most, in every
+        layer."""
+        if not self.cfg.indexed:
+            return
+        lengths = np.asarray(lengths)
+        seen = lengths[lengths >= 0] + 1
+        layers = self.cfg.cache_layers
+        _M_DSA_SCORED.inc(int(seen.sum()) * layers)
+        if TraceAnnotation.is_enabled():
+            _M_DSA_SCORED_TRACED.inc(int(seen.sum()) * layers)
+        _M_DSA_SELECTED.inc(
+            int(np.minimum(seen, self.cfg.index_topk).sum()) * layers)
 
     def observe_decode(self, extras) -> None:
         """Feed the counters from what the decode program returned

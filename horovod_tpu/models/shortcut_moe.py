@@ -129,6 +129,13 @@ class ShortcutMoEConfig:
     def entry_width(self) -> int:
         return self.mla.entry_width
 
+    # What ``LatentMoEServing`` asks beside it: no indexer, one store.
+    indexed = False
+
+    @property
+    def entry_widths(self) -> tuple:
+        return self.mla.entry_widths
+
     def serving_model(self) -> "ShortcutMoEServing":
         return ShortcutMoEServing(self)
 
@@ -218,12 +225,14 @@ def _shortcut_layer(x, lp, cfg: ShortcutMoEConfig, pos, attend, token_mask,
     return x, entries, held
 
 
-def _layers(params, tokens, pos, cfg: ShortcutMoEConfig, attend, token_mask):
+def _layers(params, tokens, pos, cfg: ShortcutMoEConfig, attend, token_mask,
+            rows=None):
     """The forward around its attention: ``attend(layer, q_nope, q_rope,
     entry, ap)`` with ``layer`` the index into the CACHE (``2i + j`` for
     sublayer ``j`` of decoder layer ``i``).  Returns ``(logits [b, s,
-    vocab] float32, entries [cache layers, b, s, width], counts [layers,
-    held], zero_pairs [layers], routed_pairs [layers])``."""
+    vocab] float32 (``[b, vocab]`` of row ``rows[b]`` alone where given:
+    ``latent_moe.lm_head``), entries [cache layers, b, s, width], counts
+    [layers, held], zero_pairs [layers], routed_pairs [layers])``."""
     x = params["embed"][tokens].astype(jnp.float32)
     entries, held = [], []
     for i, lp in enumerate(params["layers"]):
@@ -231,9 +240,7 @@ def _layers(params, tokens, pos, cfg: ShortcutMoEConfig, attend, token_mask):
                                      cache_layer=2 * i)
         entries += pair
         held.append(h)
-    x = rmsnorm(x, params["norm_f"], cfg.rms_norm_eps, cfg.dtype)
-    logits = jnp.dot(x, params["unembed"],
-                     preferred_element_type=jnp.float32)
+    logits = _latent.lm_head(params, x, cfg, rows)
     return (logits, jnp.stack(entries),
             jnp.stack([h.counts for h in held]),
             jnp.stack([h.zero_pairs for h in held]),
@@ -264,8 +271,8 @@ def forward_full(params, tokens, cfg: ShortcutMoEConfig,
 
 def prefill_step(params, tokens, n_valid, cfg: ShortcutMoEConfig):
     """A padded prompt ``[1, bucket]`` from an empty cache (see
-    ``latent_moe.prefill_step``): padding reaches no expert, real or
-    zero-compute."""
+    ``latent_moe.prefill_step``, the last valid row's logits alone):
+    padding reaches no expert, real or zero-compute."""
     pos = _positions(tokens)
 
     def attend(layer, q_nope, q_rope, entry, ap):
@@ -273,7 +280,7 @@ def prefill_step(params, tokens, n_valid, cfg: ShortcutMoEConfig):
                                              cfg.mla)
 
     return _layers(params, tokens, pos, cfg, attend,
-                   pos < n_valid[:, None])
+                   pos < n_valid[:, None], rows=n_valid - 1)
 
 
 def decode_step(params, tokens, lengths, store, table,

@@ -275,6 +275,34 @@ def route_sigmoid_bias_top_k(x, router, bias, top_k: int,
     return idx.astype(jnp.int32), gate * routed_scale
 
 
+def route_sigmoid_bias_group_top_k(x, router, bias, top_k: int,
+                                   n_group: int, topk_group: int,
+                                   routed_scale: float = 1.0,
+                                   norm_topk: bool = True):
+    """:func:`route_sigmoid_bias_top_k` with the choice limited to groups
+    (DeepSeek-V3's ``noaux_tc``): the router's outputs are ``n_group``
+    groups of equal size; the ``topk_group`` groups with the largest sum
+    of their two best ``scores + bias`` are kept, and the ``top_k``
+    largest ``scores + bias`` among THEIR experts are chosen.  Weights
+    from the unbiased scores, as there.  Returns ``(experts [t, k] int32,
+    weights [t, k] float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    t, n = scores.shape
+    biased = (scores + bias.astype(jnp.float32)).reshape(t, n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(biased, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)
+    _, idx = jax.lax.top_k(
+        jnp.where(kept[:, :, None], biased, -jnp.inf).reshape(t, n), top_k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gate * routed_scale
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """``down(silu(gate(x)) * up(x))``, float32 accumulation."""
     g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)

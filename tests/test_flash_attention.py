@@ -5,6 +5,8 @@ Style follows the reference's self-verifying collective tests
 tolerance.  Runs in Pallas interpreter mode on the CPU test mesh.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -516,6 +518,52 @@ def test_latent_paged_attention_compiles_for_the_v5e(v5e_chip, slots, pps,
     assert "latent_paged_attn" in text
     assert not any(op in text for op in (" sort(", " gather(",
                                          " conditional("))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# The three kernels a decode iteration adds with a lightning indexer
+# (ops/sparse_latent_attention.py) at `dsv32-serve-longdoc`'s shapes: 32
+# slots of 1088 pages of 16, five cache layers, 128 heads on a 640-wide
+# latent store beside a 128-wide store of index keys, 64 indexer heads, the
+# 2048 best of 17408 positions.  The selection compiles with its 63 counting
+# passes as loops, not unrolled.
+def test_sparse_latent_kernels_compile_for_the_v5e(v5e_chip):
+    from horovod_tpu.ops import sparse_latent_attention as sla
+
+    slots, pps, page, layers, pages = 32, 1088, 16, 5, 24416
+    assert sla.block_pages(page, pps, 128, 640, 2) == 16
+
+    def decode_layer(q, entry, store, q_i, w_i, keys, table, lengths):
+        total = 0.0
+        for layer in range(layers):
+            scores = sla.index_paged_scores(q_i, w_i, keys, table, lengths,
+                                            layer)
+            selected = sla.select_paged(scores, lengths, 2048)
+            total = total + sla.sparse_paged_attention(
+                q, entry, store, table, lengths, layer, selected,
+                scale=0.135, kv_rank=512).astype(jnp.float32)
+        return total
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    compiled = jax.jit(decode_layer).lower(
+        sd(slots, 128, 640), sd(slots, 640),
+        sd(layers, pages, page, 640), sd(slots, 64, 128),
+        sd(slots, 64, dtype=jnp.float32), sd(layers, pages, page, 128),
+        sd(slots, pps, dtype=jnp.int32), sd(slots, dtype=jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    for name in ("dsa_index_score", "dsa_select", "dsa_sparse_attn"):
+        assert len(re.findall(r"%" + name + r"(\.\d+)? = ", text)) == layers
+    # Neither store is sliced, gathered or copied: the kernels take them
+    # whole, with the layer as a scalar.
+    for width in (640, 128):
+        whole = f"bf16[{layers},{pages},{page},{width}]"
+        assert not [line for line in text.splitlines()
+                    if whole in line and (" copy(" in line
+                                          or " gather(" in line
+                                          or " slice(" in line)]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
