@@ -555,66 +555,84 @@ def index_scores(q, k, w):
     return total
 
 
-def _stretches(s: int, qb: int):
-    """``(lo, hi)`` of the stretches of ``PREFILL_KEY_CHUNK`` positions a
-    sequence of ``s`` is cut into, whole query blocks each."""
-    step = max(qb, PREFILL_KEY_CHUNK // qb * qb)
+def _q_block(s: int) -> int:
+    return PREFILL_Q_BLOCK if s % PREFILL_Q_BLOCK == 0 else s
+
+
+def _stretch_len(s: int) -> int:
+    """Positions in one stretch of a sequence of ``s``: ``PREFILL_KEY_CHUNK``
+    in whole query blocks."""
+    qb = _q_block(s)
+    return max(qb, PREFILL_KEY_CHUNK // qb * qb)
+
+
+def _stretches(s: int):
+    """``(lo, hi)`` of the stretches a sequence of ``s`` is cut into."""
+    step = _stretch_len(s)
     return [(lo, min(lo + step, s)) for lo in range(0, s, step)]
 
 
-def _q_block(s: int) -> int:
-    return PREFILL_Q_BLOCK if s % PREFILL_Q_BLOCK == 0 else s
+def _stretch_selection(c_q, w_i, k_i, ap, cfg: LatentMoEConfig, pos, lo: int):
+    """What the indexer selects for the queries of ONE stretch, rows ``[lo,
+    lo + n)`` of a sequence (``c_q [n, q_lora_rank]``, ``w_i [n, index
+    heads]``, ``pos [n]``), among the keys ``k_i [hi, index dim]`` up to
+    the stretch's end: ``[n, hi]`` bool, or ``None`` where ``hi <=
+    index_topk`` (every position is selected).  A block of queries at a
+    time, made from ``c_q`` there and then: the per-row threshold of the
+    ``index_topk``-th largest, never all heads' scores at once."""
+    n, hi, top = c_q.shape[0], k_i.shape[0], cfg.index_topk
+    if hi <= top:
+        return None
+    qb = _q_block(n)
+
+    def one(at):
+        cut = partial(jax.lax.dynamic_slice_in_dim, start_index=at,
+                      slice_size=qb)
+        with jax.named_scope("dsa_index"):
+            q_i = index_queries(cut(c_q)[None], ap, cfg, cut(pos)[None])[0]
+            scores = index_scores(q_i, k_i, cut(w_i))
+        valid = (jnp.arange(hi)[None, :]
+                 <= lo + at + jnp.arange(qb)[:, None])
+        with jax.named_scope("dsa_select"):
+            return _sparse.topk_mask(scores, valid, top)
+
+    return jax.lax.map(one, jnp.arange(0, n, qb)).reshape(n, hi)
 
 
 def prefill_selection(c_q, k_i, w_i, ap, cfg: LatentMoEConfig, pos):
     """What the indexer selects for every query of ONE sequence attending
     itself: ``[s, s]`` bool, row ``t`` the ``min(index_topk, t + 1)``
     positions ``<= t`` of largest index score (``None`` where ``s <=
-    index_topk``: every position is selected).  A block of queries at a
-    time, made from ``c_q [s, q_lora_rank]`` there and then, against the
-    keys ``k_i [s, index dim]`` up to its stretch's end: the per-row
-    threshold of the ``index_topk``-th largest, never all heads' scores
-    at once."""
-    s, top = c_q.shape[0], cfg.index_topk
-    if s <= top:
+    index_topk``: every position is selected); :func:`_stretch_selection`
+    of ``c_q [s, q_lora_rank]`` a stretch at a time, against the keys ``k_i
+    [s, index dim]`` up to its end."""
+    s = c_q.shape[0]
+    if s <= cfg.index_topk:
         return None
-    qb = _q_block(s)
     rows = []
-    for lo, hi in _stretches(s, qb):
-        if hi <= top:
+    for lo, hi in _stretches(s):
+        block = _stretch_selection(c_q[lo:hi], w_i[lo:hi], k_i[:hi], ap,
+                                   cfg, pos[lo:hi], lo)
+        if block is None:
             block = jnp.tril(jnp.ones((hi - lo, hi), bool), lo)
-        else:
-            def one(at, hi=hi):
-                cut = partial(jax.lax.dynamic_slice_in_dim,
-                              start_index=at, slice_size=qb)
-                with jax.named_scope("dsa_index"):
-                    q_i = index_queries(cut(c_q)[None], ap, cfg,
-                                        cut(pos)[None])[0]
-                    scores = index_scores(q_i, k_i[:hi], cut(w_i))
-                valid = (jnp.arange(hi)[None, :]
-                         <= at + jnp.arange(qb)[:, None])
-                with jax.named_scope("dsa_select"):
-                    return _sparse.topk_mask(scores, valid, top)
-
-            block = jax.lax.map(one, jnp.arange(lo, hi, qb)
-                                ).reshape(hi - lo, hi)
         rows.append(jnp.pad(block, ((0, 0), (0, s - hi))))
     return jnp.concatenate(rows)
 
 
-def selected_rebuilt_attention(c_q, entry, allowed, ap,
-                               cfg: LatentMoEConfig, pos):
-    """:func:`mla_rebuilt_attention` of ONE long sequence under a
-    selection: ``c_q [s, q_lora_rank]``, ``entry [s, entry_width]``,
-    ``allowed [s, s]`` bool or ``None``; causal on top of it.  Heads go
-    through in groups of ``PREFILL_HEAD_GROUP`` (queries, keys and values
-    of one group alive at a time), a group's query blocks of one stretch
-    as one mapped body against the keys up to the stretch's end, and its
-    rows of ``w_o`` applied at once (all heads' outputs never stand side
-    by side).  Returns the block's output ``[s, hidden]`` float32.
-    Roundings are :func:`mla_rebuilt_attention`'s; the float32 sum over
-    heads is taken a group at a time."""
-    s = c_q.shape[0]
+def _stretch_attention(c_q, entry, allowed, ap, cfg: LatentMoEConfig, pos,
+                       lo: int):
+    """:func:`mla_rebuilt_attention` of ONE stretch of a long sequence
+    under a selection: the queries of rows ``[lo, lo + n)`` (``c_q [n,
+    q_lora_rank]``, ``pos [n]``) against the entries up to the stretch's
+    end (``entry [hi, entry_width]``), ``allowed [n, hi]`` bool or
+    ``None``; causal on top of it.  Heads go through in groups of
+    ``PREFILL_HEAD_GROUP`` (queries, keys and values of one group alive at
+    a time), a group's query blocks as one mapped body, and its rows of
+    ``w_o`` applied at once (all heads' outputs never stand side by side).
+    Returns the block's output ``[n, hidden]`` float32.  Roundings are
+    :func:`mla_rebuilt_attention`'s; the float32 sum over heads is taken
+    a group at a time."""
+    n, hi = c_q.shape[0], entry.shape[0]
     dt = c_q.dtype
     h_n, nope, rp, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -622,47 +640,56 @@ def selected_rebuilt_attention(c_q, entry, allowed, ap,
     c = entry[:, :cfg.kv_lora_rank]
     k_rope = entry[:, cfg.kv_lora_rank:cfg.kv_lora_rank + rp]
     scale = softmax_scale(cfg)
-    qb = _q_block(s)
+    qb = _q_block(n)
 
-    def group(n, y):
+    def group(i, y):
         w_uq = jax.lax.dynamic_slice_in_dim(
-            ap["w_uq"], n * g * (nope + rp), g * (nope + rp), axis=1)
+            ap["w_uq"], i * g * (nope + rp), g * (nope + rp), axis=1)
         w_ukv = jax.lax.dynamic_slice_in_dim(
-            ap["w_ukv"], n * g * (nope + vd), g * (nope + vd), axis=1)
+            ap["w_ukv"], i * g * (nope + vd), g * (nope + vd), axis=1)
         q_nope, q_rope = mla_queries(c_q[None], w_uq, cfg, pos[None])
         q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
         kv = jnp.dot(c, w_ukv, preferred_element_type=jnp.float32
-                     ).astype(dt).reshape(s, g, nope + vd)
+                     ).astype(dt).reshape(hi, g, nope + vd)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope[:, None, :],
-                                              (s, g, rp))], axis=-1)
+                                              (hi, g, rp))], axis=-1)
         v = kv[..., nope:]
-        outs = []
-        for lo, hi in _stretches(s, qb):
-            def one(at, hi=hi):
-                scores = jnp.einsum(
-                    "qhd,khd->hqk", jax.lax.dynamic_slice_in_dim(q, at, qb),
-                    k[:hi], preferred_element_type=jnp.float32) * scale
-                mask = (jnp.arange(hi)[None, :]
-                        <= at + jnp.arange(qb)[:, None])
-                if allowed is not None:
-                    mask = mask & jax.lax.dynamic_slice_in_dim(
-                        allowed, at, qb)[:, :hi]
-                p = _masked_softmax(scores, mask[None])
-                return jnp.einsum("hqk,khd->qhd", p.astype(dt), v[:hi],
-                                  preferred_element_type=jnp.float32
-                                  ).astype(dt)
 
-            outs.append(jax.lax.map(one, jnp.arange(lo, hi, qb)
-                                    ).reshape(hi - lo, g * vd))
-        w_o = jax.lax.dynamic_slice_in_dim(ap["w_o"], n * g * vd, g * vd)
-        return y + jnp.dot(jnp.concatenate(outs), w_o,
-                           preferred_element_type=jnp.float32)
+        def one(at):
+            scores = jnp.einsum(
+                "qhd,khd->hqk", jax.lax.dynamic_slice_in_dim(q, at, qb), k,
+                preferred_element_type=jnp.float32) * scale
+            mask = (jnp.arange(hi)[None, :]
+                    <= lo + at + jnp.arange(qb)[:, None])
+            if allowed is not None:
+                mask = mask & jax.lax.dynamic_slice_in_dim(allowed, at, qb)
+            p = _masked_softmax(scores, mask[None])
+            return jnp.einsum("hqk,khd->qhd", p.astype(dt), v,
+                              preferred_element_type=jnp.float32).astype(dt)
+
+        out = jax.lax.map(one, jnp.arange(0, n, qb)).reshape(n, g * vd)
+        w_o = jax.lax.dynamic_slice_in_dim(ap["w_o"], i * g * vd, g * vd)
+        return y + jnp.dot(out, w_o, preferred_element_type=jnp.float32)
 
     with jax.named_scope("dsa_attend"):
         return jax.lax.fori_loop(
             0, h_n // g, group,
-            jnp.zeros((s, ap["w_o"].shape[1]), jnp.float32))
+            jnp.zeros((n, ap["w_o"].shape[1]), jnp.float32))
+
+
+def selected_rebuilt_attention(c_q, entry, allowed, ap,
+                               cfg: LatentMoEConfig, pos):
+    """:func:`mla_rebuilt_attention` of ONE long sequence under a
+    selection: ``c_q [s, q_lora_rank]``, ``entry [s, entry_width]``,
+    ``allowed [s, s]`` bool or ``None``; :func:`_stretch_attention` a
+    stretch at a time.  Returns ``[s, hidden]`` float32."""
+    return jnp.concatenate([
+        _stretch_attention(
+            c_q[lo:hi], entry[:hi],
+            None if allowed is None else allowed[lo:hi, :hi], ap, cfg,
+            pos[lo:hi], lo)
+        for lo, hi in _stretches(c_q.shape[0])])
 
 
 # -- layers -------------------------------------------------------------------
@@ -761,15 +788,10 @@ def lm_head(params, x, cfg, rows=None):
     return logits[jnp.arange(x.shape[0]), rows - start]
 
 
-def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask,
-            rows=None):
-    """The forward around its attention: ``attend(layer, q_nope, q_rope,
-    entry, ap)`` with ``layer`` the index into the cache and ``ap`` the
-    layer's attention parameters (with an indexer: ``attend(layer, h,
-    ap)``, see :func:`_attn_block`).  Returns ``(logits [b, s, vocab]
-    float32, or [b, vocab] of row ``rows[b]``; entries [layers, b, s,
-    width], with an indexer a pair of such, the index keys second; counts
-    [expert layers, held])``."""
+def _trunk(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask):
+    """The layers of :func:`_layers` without the head and unstacked: ``(x
+    [b, s, hidden] float32, what ``attend`` returned for each layer's
+    store(s), the expert layers' counts)``."""
     # The residual stream is float32 from the embedding to the final
     # norm; matmul operands, the cache entry and the attention's
     # probabilities are what is rounded to the served type.
@@ -783,6 +805,19 @@ def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask,
                                      token_mask)
             counts.append(n)
         entries.append(entry)
+    return x, entries, counts
+
+
+def _layers(params, tokens, pos, cfg: LatentMoEConfig, attend, token_mask,
+            rows=None):
+    """The forward around its attention: ``attend(layer, q_nope, q_rope,
+    entry, ap)`` with ``layer`` the index into the cache and ``ap`` the
+    layer's attention parameters (with an indexer: ``attend(layer, h,
+    ap)``, see :func:`_attn_block`).  Returns ``(logits [b, s, vocab]
+    float32, or [b, vocab] of row ``rows[b]``; entries [layers, b, s,
+    width], with an indexer a pair of such, the index keys second; counts
+    [expert layers, held])``."""
+    x, entries, counts = _trunk(params, tokens, pos, cfg, attend, token_mask)
     logits = lm_head(params, x, cfg, rows)
     if cfg.indexed:
         entries = tuple(jnp.stack(e) for e in zip(*entries))
@@ -838,14 +873,99 @@ def forward_full(params, tokens, cfg: LatentMoEConfig,
     return _layers(params, tokens, pos, cfg, attend, None)
 
 
+def walked_stretch(cfg: LatentMoEConfig, b: int, s: int) -> int:
+    """The rows of one stretch where the prompt program of a ``[b, s]``
+    block WALKS it (:func:`walked_prefill`), else 0.  The shape decides:
+    one prompt, an indexer's stretches, more than one of them and whole
+    ones."""
+    step = _stretch_len(s)
+    return (step if cfg.indexed and b == 1 and s > step and s % step == 0
+            else 0)
+
+
+def walked_stretches(n_valid, step: int, s: int):
+    """How many of a bucket's ``s // step`` stretches hold a token of a
+    prompt of ``n_valid``: the walk's trip count, host integers and
+    traced ones alike."""
+    clip = np.clip if isinstance(n_valid, (int, np.integer)) else jnp.clip
+    return clip(-(-n_valid // step), 1, s // step)
+
+
+def walked_prefill(params, tokens, n_valid, cfg: LatentMoEConfig, step: int):
+    """:func:`prefill_step` of ONE long prompt ``[1, bucket]``,
+    stretch-major: ``step`` rows at a time through the embedding and ALL
+    layers before the next stretch begins, each layer's entries and index
+    keys of the stretches so far carried in ``[bucket, width]`` buffers
+    (zeros to start with).  The trip count is computed on the device
+    (:func:`walked_stretches`): a stretch whose first row is at or past
+    ``n_valid`` is not run, and its rows of the returned entries stay
+    zero.  The per-row parts are traced once, at ``step`` rows; the
+    selection and the attention of a stretch's queries against the
+    positions ``[0, hi)`` are one ``lax.switch`` on the stretch (the key
+    extent of each stays static, so threshold and softmax are one piece
+    over it, as in the layer-major form).  The head takes the last valid
+    row out of the last stretch that ran; the experts' counts add up."""
+    _, s = tokens.shape
+    layers = params["layers"]
+
+    def attend_stretch(c: int, ap):
+        lo, hi = c * step, (c + 1) * step
+        pos = jnp.arange(lo, hi, dtype=jnp.int32)
+
+        def attend(c_q, w_i, entry, k_i):
+            allowed = _stretch_selection(c_q, w_i, k_i[:hi], ap, cfg, pos,
+                                         lo)
+            return _stretch_attention(c_q, entry[:hi], allowed, ap, cfg,
+                                      pos, lo)
+
+        return attend
+
+    def stretch(c, carry):
+        bufs, counts, _ = carry
+        lo = c * step
+        pos = (lo + jnp.arange(step, dtype=jnp.int32))[None]
+
+        def attend(layer, h, ap):
+            c_q, entry = mla_latents(h, ap, cfg, pos)
+            k_i, w_i = index_keys(h, ap, cfg, pos)
+            so_far = tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, new[0], lo, 0)
+                for buf, new in zip(bufs[layer], (entry, k_i)))
+            y = jax.lax.switch(
+                c, [attend_stretch(i, ap) for i in range(s // step)],
+                c_q[0], w_i[0], *so_far)
+            return y[None], so_far
+
+        x, held, n = _trunk(
+            params, jax.lax.dynamic_slice_in_dim(tokens, lo, step, axis=1),
+            pos, cfg, attend, pos < n_valid[:, None])
+        return held, counts + jnp.stack(n), x
+
+    trips = walked_stretches(n_valid[0], step, s)
+    held, counts, x = jax.lax.fori_loop(0, trips, stretch, (
+        [tuple(jnp.zeros((s, w), cfg.dtype) for w in cfg.entry_widths)
+         for _ in layers],
+        jnp.zeros((cfg.n_moe_layers, cfg.experts_held), jnp.int32),
+        jnp.zeros((1, step, cfg.hidden_size), jnp.float32)))
+    last = lm_head(params, x, cfg, rows=n_valid - 1 - (trips - 1) * step)
+    return (last, tuple(jnp.stack(e)[:, None] for e in zip(*held)), counts)
+
+
 def prefill_step(params, tokens, n_valid, cfg: LatentMoEConfig):
     """A padded prompt ``[1, bucket]`` from an empty cache: positions
     ``>= n_valid`` are padding (they reach no expert; their entries are
     garbage the caller maps to trash or overwrites).  Returns ``(last
     [1, vocab]``, the last valid row's logits, ``entries [layers, 1,
     bucket, width]`` (with an indexer a pair, the index keys second),
-    ``counts)``."""
+    ``counts)``.  A bucket of several stretches is walked a stretch at a
+    time as far as the prompt reaches (:func:`walked_stretch`,
+    :func:`walked_prefill`); without an indexer every row of a long
+    bucket is still computed (no cell of that family has a prompt past
+    one stretch)."""
     b, s = tokens.shape
+    step = walked_stretch(cfg, b, s)
+    if step:
+        return walked_prefill(params, tokens, n_valid, cfg, step)
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
     def attend(layer, q_nope, q_rope, entry, ap):
@@ -1083,10 +1203,22 @@ class LatentMoEServing:
             entries = (entries,)
         # A page at a time, written where it lies.  (A scatter over the
         # flattened store makes the TPU copy all of it into a layout of
-        # the scatter's own, and back.)  Pages past the prompt are not
-        # mapped: their rows land in trash page 0.
-        rows = min(ps, bucket)
+        # the scatter's own, and back.)
         zero = jnp.zeros((), jnp.int32)
+        if walked_stretch(self.cfg, *tokens.shape) and bucket % ps == 0:
+            # A walked prompt: the pages that hold a token, and no other
+            # (the stretches past them were not run).
+            def write(j, pages):
+                return tuple(jax.lax.dynamic_update_slice(
+                    store, jax.lax.dynamic_slice_in_dim(new, j * ps, ps, 2),
+                    (zero, table_row[0, j], zero, zero))
+                    for store, new in zip(pages, entries))
+
+            return (last[0],), jax.lax.fori_loop(
+                0, -(-n_valid[0] // ps), write, tuple(pages))
+        # Pages past the prompt are not mapped: their rows land in trash
+        # page 0.
+        rows = min(ps, bucket)
         pages = list(pages)
         for j in range(max(1, bucket // ps)):
             for i, new in enumerate(entries):
@@ -1094,6 +1226,16 @@ class LatentMoEServing:
                     pages[i], new[:, :, j * ps:j * ps + rows],
                     (zero, table_row[0, j], zero, zero))
         return (last[0],), tuple(pages)
+
+    def prefill_rows(self, bucket: int, n_valid: int) -> int:
+        """Rows the prompt program of ``bucket`` computes for a prompt of
+        ``n_valid`` tokens (``serving.prefill_rows``): the bucket, or the
+        stretches a walk runs (:func:`walked_stretches`, which the
+        program takes its trip count from)."""
+        step = walked_stretch(self.cfg, 1, bucket)
+        if not step:
+            return bucket
+        return int(walked_stretches(n_valid, step, bucket)) * step
 
     def observe_launch(self, lengths) -> None:
         """What the indexer scored and selected in one decode iteration

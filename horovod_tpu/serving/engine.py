@@ -118,6 +118,11 @@ _M_PREFILL_TOKENS = _telemetry.counter(
     "serving.prefill_tokens", "real prompt tokens the admission prefills "
     "ran through the model (a prefix-cache hit's shared tokens and a "
     "bucket's padding are not among them)")
+_M_PREFILL_ROWS = _telemetry.counter(
+    "serving.prefill_rows", "rows the admission prefills' programs "
+    "computed: a prompt's bucket, or what the model's ``prefill_rows("
+    "bucket, n_valid)`` says of a program that follows the prompt inside "
+    "its bucket; over serving.prefill_tokens it is the padding's cost")
 _M_WARM = _telemetry.counter(
     "serving.warm_starts", "serving executables AOT-rebuilt at startup")
 _M_TABLES_BYTES = _telemetry.counter(
@@ -1117,6 +1122,9 @@ class InferenceEngine:
                 self._rep(np.asarray([slot], np.int32)))
         self.cache.replace_pages(*pages)
         _M_PREFILL_TOKENS.inc(len(suffix))
+        rows_of = getattr(self.model, "prefill_rows", None)
+        _M_PREFILL_ROWS.inc(bucket if rows_of is None
+                            else rows_of(bucket, len(suffix)))
         self.cache.publish_prefix(slot, prompt)
         if self._draft_params is not None:
             dshared = self.draft_cache.lookup_prefix(prompt)

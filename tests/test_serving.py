@@ -1402,6 +1402,20 @@ def test_a_prefix_hits_suffix_prefill_rides_as_a_cold_one_does():
     assert [b - a for a, b in zip(c1, _counters(*names))] == [1, 1, 4, 1]
 
 
+@pytest.mark.parametrize("n,bucket", [(17, 32), (4, 4), (5, 8)])
+def test_a_model_that_says_nothing_of_its_rows_counts_its_bucket(n, bucket):
+    """``serving.prefill_rows`` beside ``serving.prefill_tokens``: the rows
+    the prompt program computed are the bucket's, padding and all, unless
+    the model has a ``prefill_rows(bucket, n_valid)`` to say otherwise
+    (``models/latent_moe.py`` has: tests/test_sparse_latent.py)."""
+    eng = make_engine()
+    assert not hasattr(eng.model, "prefill_rows")
+    c0 = _counters("prefill_tokens", "prefill_rows")
+    eng.generate(list(range(1, n + 1)), max_new_tokens=2)
+    c1 = _counters("prefill_tokens", "prefill_rows")
+    assert [b - a for a, b in zip(c0, c1)] == [n, bucket]
+
+
 def test_a_prefill_error_surfaces_at_the_fetch_under_its_name(monkeypatch):
     """The prefill is only enqueued at admission, so what its program
     raises arrives with its token: still under ``serving/prefill/

@@ -51,7 +51,10 @@ def prompt(seed, n):
 
 
 @functools.lru_cache(maxsize=None)
-def engine(kernel=False):
+def engine(kernel=False, chunk=2048):
+    """``chunk``: an engine of its own for the prompt programs a test
+    builds under ``short_stretches`` (they are compiled when first asked
+    for and kept)."""
     lm.PAGED_INTERPRET = True if kernel else None
     try:
         eng = InferenceEngine(params(), CFG, max_slots=6, page_size=8,
@@ -83,19 +86,98 @@ def test_program_and_reference_agree_on_whole_sequences(absorbed):
         assert np.abs(other - want).max() > 1000 * TOL
 
 
-@pytest.mark.parametrize("q_block,chunk", [(8, 16), (16, 16), (256, 2048)])
-def test_blocks_and_stretches_change_nothing(q_block, chunk, monkeypatch):
-    """The prompt's attention cut into query blocks of 8 in stretches of
-    16 keys (at the shipped sizes a toy sequence is one block)."""
+def short_stretches(monkeypatch, q_block, chunk):
+    """Query blocks of ``q_block`` in stretches of ``chunk`` keys, heads
+    two at a time: a toy bucket then holds several stretches."""
     monkeypatch.setattr(lm, "PREFILL_Q_BLOCK", q_block)
     monkeypatch.setattr(lm, "PREFILL_KEY_CHUNK", chunk)
     monkeypatch.setattr(lm, "PREFILL_HEAD_GROUP", 2)
     monkeypatch.setattr(lm, "INDEX_HEAD_GROUP", 2)
+
+
+@pytest.mark.parametrize("q_block,chunk", [(8, 16), (16, 16), (256, 2048)])
+def test_blocks_and_stretches_change_nothing(q_block, chunk, monkeypatch):
+    """The prompt's attention cut into query blocks of 8 in stretches of
+    16 keys (at the shipped sizes a toy sequence is one block)."""
+    short_stretches(monkeypatch, q_block, chunk)
     toks = jnp.asarray([prompt(21, 48)], jnp.int32)
     logits = jax.jit(lambda p, t: lm.forward_full(p, t, CFG))(
         params(), toks)[0]
     want = REF.served_logits(MODEL, params(), np.asarray(toks).tolist())
     assert np.abs(np.asarray(logits) - np.stack(want)).max() < TOL
+
+
+@pytest.mark.parametrize("bucket,n_valid", [
+    (64, 32), (64, 33), (64, 34), (64, 64),     # a stretch's last row, the
+    (128, 1), (128, 49), (128, 113),            # next one's first, one past
+    (128, 128)])                                # it, the whole bucket
+def test_a_walked_prompt_is_the_unpadded_sequence(bucket, n_valid,
+                                                  monkeypatch):
+    """``prefill_step`` of a bucket of 4 or 8 stretches of 16 rows: the
+    last valid row's logits, every valid row's entries and index keys and
+    the experts' counts are ``forward_full``'s on the unpadded sequence;
+    the rows of the stretches past ``n_valid`` are the carried buffers'
+    zeros: they were not run."""
+    short_stretches(monkeypatch, 8, 16)
+    assert lm.walked_stretch(CFG, 1, bucket) == 16
+    toks = np.zeros((1, bucket), np.int32)
+    toks[0, :n_valid] = prompt(81, n_valid)
+    last, (entries, keys), counts = jax.jit(
+        lambda p, t, n: lm.prefill_step(p, t, n, CFG))(
+            params(), jnp.asarray(toks), jnp.asarray([n_valid], jnp.int32))
+    every, (want_entries, want_keys), want_counts = jax.jit(
+        lambda p, t: lm.forward_full(p, t, CFG))(
+            params(), jnp.asarray(toks[:, :n_valid]))
+    assert np.abs(np.asarray(last[0] - every[0, -1])).max() < TOL
+    assert entries.shape == (3, 1, bucket, CFG.entry_width)
+    assert keys.shape == (3, 1, bucket, MODEL["index_head_dim"])
+    for got, want in ((entries, want_entries), (keys, want_keys)):
+        assert np.abs(np.asarray(got[:, :, :n_valid] - want)).max() < TOL
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(want_counts))
+    ran = -(-n_valid // 16) * 16
+    assert CFG.serving_model().prefill_rows(bucket, n_valid) == ran
+    # Padding inside the last stretch that ran is computed, as it was.
+    if ran > n_valid:
+        assert np.asarray(entries[:, :, n_valid:ran]).any()
+    assert not np.asarray(entries[:, :, ran:]).any()
+    assert not np.asarray(keys[:, :, ran:]).any()
+
+
+@pytest.mark.parametrize("b,bucket,chunk", [(1, 16, 16), (1, 8, 16),
+                                            (1, 40, 16), (2, 64, 16),
+                                            (1, 2048, 2048)])
+def test_a_bucket_of_one_stretch_never_enters_the_walk(b, bucket, chunk,
+                                                       monkeypatch):
+    """One stretch or less, stretches that are not whole, more than one
+    sequence: the layer-major code, as before the walk; and at the
+    shipped sizes a bucket of 2048."""
+    short_stretches(monkeypatch, 8, chunk)
+
+    def walk(*a, **kw):
+        raise AssertionError("the walk was entered")
+
+    monkeypatch.setattr(lm, "walked_prefill", walk)
+    assert lm.walked_stretch(CFG, b, bucket) == 0
+    if b == 1:                     # (the engine's prompts are one a pass)
+        assert CFG.serving_model().prefill_rows(bucket, 3) == bucket
+    if bucket > 64:
+        return                     # the rule alone: no toy runs 2048 rows
+    toks = jnp.asarray([prompt(82 + i, bucket) for i in range(b)], jnp.int32)
+    n_valid = jnp.full((b,), bucket - 3, jnp.int32)
+    last, (entries, _), _ = jax.jit(
+        lambda p, t, n: lm.prefill_step(p, t, n, CFG))(params(), toks,
+                                                       n_valid)
+    every = jax.jit(lambda p, t: lm.forward_full(p, t, CFG))(params(),
+                                                             toks)[0]
+    assert np.abs(np.asarray(last - every[:, bucket - 4])).max() < TOL
+    assert np.asarray(entries[:, :, bucket - 3:]).any()
+    # Without an indexer no shape walks.
+    assert lm.walked_stretch(tl.CFG, 1, 64) == 0
+    # (And the trap is a trap: a bucket of two stretches springs it.)
+    with pytest.raises(AssertionError, match="was entered"):
+        lm.prefill_step(params(), jnp.zeros((1, 32), jnp.int32),
+                        n_valid[:1], CFG)
 
 
 def _layer_inputs(seed, s):
@@ -166,12 +248,19 @@ def test_the_threshold_chooses_what_top_k_chooses(k):
 # -- prefill, then decode through the two stores --------------------------------
 
 @pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("lengths", [(40,), (9, 70, 140), (70, 24, 33, 130)])
-def test_prefill_then_decode_equals_the_reference(lengths, kernel):
+@pytest.mark.parametrize("lengths,chunk", [
+    ((40,), 2048), ((9, 70, 140), 2048), ((70, 24, 33, 130), 2048),
+    ((70, 33, 140), 32)])
+def test_prefill_then_decode_equals_the_reference(lengths, chunk, kernel,
+                                                  monkeypatch):
     """Every prompt but the 9-token one is past ``index_topk``, so the
     selection binds from the first decoded token; ``kernel``: the two
-    Pallas kernels and the sortless threshold in the interpreter."""
-    eng = engine(kernel)
+    Pallas kernels and the sortless threshold in the interpreter.  In
+    stretches of 32 the prompts of 70 and 140 tokens are WALKED: three of
+    their bucket's four stretches, five of eight."""
+    if chunk != 2048:
+        short_stretches(monkeypatch, 8, chunk)
+    eng = engine(kernel, chunk)
     prompts = [prompt(100 + n, n) for n in lengths]
     new = [4 + i for i in range(len(lengths))]
     scored = counter("serving.dsa_scored_tokens")
@@ -188,6 +277,35 @@ def test_prefill_then_decode_equals_the_reference(lengths, kernel):
     assert counter("serving.dsa_scored_tokens") - scored == 3 * sum(seen)
     assert (counter("serving.dsa_selected_tokens") - selected
             == 3 * sum(min(s, TOP) for s in seen))
+    assert eng.cache.free_pages() == eng.cache.total_pages
+
+
+def test_the_engine_counts_the_rows_a_walked_prompt_computed(monkeypatch):
+    """One token over a stretch's boundary in a longer bucket: the rows
+    of the stretches up to that token's, the prompt's own tokens, no page
+    mapped past the prompt, and the reference's logits."""
+    short_stretches(monkeypatch, 8, 32)
+    eng = engine(False, 32)
+    names = ("serving.prefill_rows", "serving.prefill_tokens")
+    before = [counter(n) for n in names]
+    mapped = []
+    orig = eng._prefill
+
+    def prefill(slot, req, *a, **kw):
+        out = orig(slot, req, *a, **kw)
+        mapped.append(np.asarray(eng.cache.table_row(slot))[0].copy())
+        return out
+
+    monkeypatch.setattr(eng, "_prefill", prefill)
+    p = prompt(91, 65)                       # bucket 128: stretches 0-2
+    ((rows, toks),) = tl.rollout(eng, [p], [3])
+    assert [counter(n) - b for n, b in zip(names, before)] == [96, 65]
+    want = REF.served_logits(MODEL, params(), [p + toks], "f32")[0]
+    assert np.abs(rows - want[64:67]).max() < TOL
+    # 65 tokens and 3 to come: nine pages of 8, the rest of the row is the
+    # trash page.
+    (row,) = mapped
+    assert (row[:9] > 0).all() and not row[9:].any()
     assert eng.cache.free_pages() == eng.cache.total_pages
 
 
